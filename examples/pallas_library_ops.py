@@ -1,7 +1,7 @@
 """Direct-call Pallas library ops: the retired-but-retained kernels.
 
 Round 5 retired the online LM-head cross-entropy and fused LayerNorm Pallas
-kernels from the TRAINING path (BASELINE.md: compile pathology / no measured
+kernels from the TRAINING path (compile pathology / no measured
 headroom against the 91 TFLOP/s chunked fused-CE) — but both remain in the
 library as direct-call ops with pinned math. This example is their living
 caller (VERDICT r5 next #6): it invokes each against a dense reference, in

@@ -35,8 +35,8 @@ from .core.random import get_rng_state, seed, set_rng_state
 get_cuda_rng_state = get_rng_state
 set_cuda_rng_state = set_rng_state
 from .core.flags import get_flags, set_flags
-from .core import compile_cache as _compile_cache  # noqa: F401  (applies
-#   FLAGS_compile_cache_dir / PADDLE_TPU_COMPILE_CACHE at import)
+from .core import compile_cache as _compile_cache  # noqa: F401  (places
+#   the persistent compile cache at import, before the first compile)
 from .core.tensor import Tensor
 from .core.autograd import enable_grad, grad, is_grad_enabled, no_grad, set_grad_enabled
 from .core.dispatch import amp_guard as _amp_guard  # noqa: F401

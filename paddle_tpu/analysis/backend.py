@@ -14,10 +14,9 @@ exactly one answer.
 from __future__ import annotations
 
 import functools
-import re
 from typing import Optional
 
-_ALL_REDUCE_OP = re.compile(r"^\s*%?all-reduce[.\d]*\s*=", re.MULTILINE)
+from .program import Program
 
 
 @functools.lru_cache(maxsize=1)
@@ -32,7 +31,6 @@ def collective_combining_reason() -> Optional[str]:
     """
     import jax
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     devs = jax.devices()
@@ -43,11 +41,11 @@ def collective_combining_reason() -> Optional[str]:
     def two_psums(a, b):
         return jax.lax.psum(a, "dp"), jax.lax.psum(b, "dp")
 
-    fm = shard_map(two_psums, mesh=mesh,
-                   in_specs=(P("dp"), P("dp")), out_specs=(P(), P()))
+    fm = jax.shard_map(two_psums, mesh=mesh,
+                       in_specs=(P("dp"), P("dp")), out_specs=(P(), P()))
     z = np.zeros((len(devs), 4), np.float32)
     txt = jax.jit(fm).lower(z, z).compile().as_text()
-    n = len(_ALL_REDUCE_OP.findall(txt))
+    n = Program("combining-probe", hlo_text=txt).count_ops("all-reduce")
     if n <= 1:
         return None
     return (f"XLA {jax.default_backend()} backend does not run the "
@@ -73,7 +71,6 @@ def native_bf16_collective_reason() -> Optional[str]:
     """
     import jax
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     devs = jax.devices()
@@ -85,15 +82,14 @@ def native_bf16_collective_reason() -> Optional[str]:
         return jax.lax.psum(a.astype(jax.numpy.bfloat16),
                             "dp").astype(jax.numpy.float32)
 
-    fm = shard_map(halfwire, mesh=mesh, in_specs=(P("dp"),),
-                   out_specs=P())
+    fm = jax.shard_map(halfwire, mesh=mesh, in_specs=(P("dp"),),
+                       out_specs=P())
     z = np.zeros((len(devs), 4), np.float32)
     txt = jax.jit(fm).lower(z).compile().as_text()
-    for line in txt.splitlines():
-        # result dtype sits between '=' and the 'all-reduce(' call; the
-        # metadata tail can spell any dtype in op_name, so don't scan it
-        if (_ALL_REDUCE_OP.match(line)
-                and "bf16[" in line.split("all-reduce(", 1)[0]):
+    # read the parsed RESULT type: the metadata tail of the line can spell
+    # any dtype in op_name
+    for ins in Program("bf16-probe", hlo_text=txt).op_defs("all-reduce"):
+        if "bf16[" in ins.result:
             return None
     return (f"XLA {jax.default_backend()} backend upcasts bf16 collective "
             f"payloads to f32 (float normalization legalizes bf16 compute) "

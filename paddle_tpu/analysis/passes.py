@@ -161,9 +161,6 @@ def recompile_hazard(prog: Program, c: ProgramContract) -> PassResult:
     return vs, []
 
 
-# all-gather DEFINITION lines with their instruction name captured; async
-# `-done` halves complete the matching `-start` and define no new gather
-_AG_DEF_RE = re.compile(r"^\s*(%?all-gather(?!-done)[-.\w]*)\s*=")
 _CHANNEL_RE = re.compile(r"channel_id=(\d+)")
 
 
@@ -210,12 +207,10 @@ def schedule_order(prog: Program, c: ProgramContract) -> PassResult:
     # channel ids (assigned in emission = bucket order) when present.
     lines = prog.hlo_text.splitlines()
     ags = []
-    for i, ln in enumerate(lines):
-        m = _AG_DEF_RE.match(ln)
-        if m:
-            ch = _CHANNEL_RE.search(ln)
-            ags.append((int(ch.group(1)) if ch else len(ags),
-                        i, m.group(1).strip()))
+    for ins in prog.op_defs("all-gather"):
+        ch = _CHANNEL_RE.search(ins.line)
+        ags.append((int(ch.group(1)) if ch else len(ags),
+                    ins.index, ins.name))
     ags.sort(key=lambda t: (t[0], t[1]))
     vs: List[Violation] = []
     for (_, li, ni), (_, lj, nj) in zip(ags, ags[1:]):

@@ -1,23 +1,26 @@
-"""Persistent XLA compilation cache (FLAGS_compile_cache_dir).
+"""Persistent XLA compilation cache.
 
 Every new process pays full XLA compile cost for programs it has compiled a
 thousand times before — for the bench-config GPT step that is minutes of
-startup on TPU. The reference ships no analogue (its Executor caches live
-only in-process); XLA's persistent compilation cache closes the gap: with a
-cache directory configured, compiled executables are serialized keyed on
-(HLO, compile options, backend version), and a second process deserializes
-in milliseconds instead of recompiling.
+start-up on a TPU. XLA's persistent compilation cache closes the gap:
+compiled executables are serialized keyed on (HLO, compile options, backend
+version), and a second process deserializes instead of recompiling.
 
-Wiring: `FLAGS_compile_cache_dir` (env `FLAGS_compile_cache_dir` or
-`PADDLE_TPU_COMPILE_CACHE`) names the directory; empty means OFF and
-nothing here touches jax.config — the default is bit-identical behavior.
-`configure()` runs once at package import and again on set_flags, so
+Where the cache lives, in order:
 
-    PADDLE_TPU_COMPILE_CACHE=/var/cache/xla python train.py
+1. ``JAX_COMPILATION_CACHE_DIR`` set: jax itself reads it at import and the
+   cache is THAT directory. Nothing here ever sets or clears
+   ``jax_compilation_cache_dir`` then — a directory given from outside is
+   never moved or wiped by the program.
+2. otherwise ``FLAGS_compile_cache_dir``, whose default is the fixed path
+   ``<checkout>/.jax_cache`` (the cache key includes the path, so a
+   directory that moves never hits).
 
-is the whole deployment story. The min-compile-time / min-entry-size
-thresholds are zeroed so even the CPU test programs cache (jax's defaults
-skip sub-second compiles — exactly the ones the subprocess test measures).
+``FLAGS_compile_cache_dir=""`` turns the cache OFF in either case, through
+``jax_enable_compilation_cache`` (tests/conftest.py does this for the whole
+suite: cache-SERVED multi-device CPU executables can produce
+nondeterministic collective results on this jax). The min-compile-time /
+min-entry-size thresholds are zeroed so even sub-second programs cache.
 
 Cold/warm accounting: `entries()` counts serialized executables; the train
 engines snapshot it around a dispatch that compiled — if the persistent
@@ -33,7 +36,10 @@ from typing import Optional
 from . import monitor as _monitor
 from .flags import flag
 
-_configured_dir: Optional[str] = None
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+# what configure() last applied: None = nothing yet, "" = off, else the dir
+_applied: Optional[str] = None
 
 _COLD = _monitor.stat("engine.compile_cold")
 _WARM = _monitor.stat("engine.compile_warm")
@@ -41,73 +47,60 @@ _COLD_MS = _monitor.stat("engine.compile_cold_ms")
 _WARM_MS = _monitor.stat("engine.compile_warm_ms")
 
 
+def placed_from_outside() -> Optional[str]:
+    """The directory JAX_COMPILATION_CACHE_DIR names, or None when unset."""
+    return os.environ.get(ENV_DIR, "").strip() or None
+
+
 def cache_dir() -> Optional[str]:
     """The active persistent-cache directory, or None when off."""
-    return _configured_dir
+    return _applied or None
 
 
 def enabled() -> bool:
-    return _configured_dir is not None
+    return bool(_applied)
 
 
 def configure() -> Optional[str]:
-    """Apply FLAGS_compile_cache_dir to jax.config. Idempotent; called at
-    package import and on every set_flags touching the flag. Returns the
-    active dir (None = off).
-
-    Turning the cache OFF (flag set back to empty) fully unwires it: the
-    config dir is unset AND jax's latched in-memory cache object is dropped
-    via reset_cache(). The latter matters — jax initializes its cache
-    singleton at the first post-configure compile and keeps serving it even
-    after the config dir is cleared, so without the reset a test that
-    enabled the cache would leak it into every later compile in the
-    process. (On this jax/XLA CPU, cache-SERVED multi-device executables
-    can additionally produce nondeterministic collective results — the
-    order-dependent test_dist_checkpoint failure traced to exactly this
-    leak — so severing it on disable is a correctness fix, not hygiene.)"""
-    global _configured_dir
+    """Apply the rule above to jax.config. Idempotent; called at package
+    import and on every set_flags touching the flag. Returns the active dir
+    (None = off)."""
+    global _applied
+    outside = placed_from_outside()
     d = str(flag("compile_cache_dir") or "").strip()
-    if d == (_configured_dir or ""):
-        return _configured_dir
+    if d and outside:
+        d = outside
+    if d == _applied:
+        return cache_dir()
     import jax
 
-    if not d:
-        jax.config.update("jax_compilation_cache_dir", None)
-        try:
-            from jax._src import compilation_cache as _jcc
+    jax.config.update("jax_enable_compilation_cache", bool(d))
+    if not outside:
+        jax.config.update("jax_compilation_cache_dir", d or None)
+    if d:
+        os.makedirs(d, exist_ok=True)
+        # cache EVERYTHING: the default thresholds skip fast compiles, which
+        # on CPU is every test program — and on TPU would skip the small
+        # eager rules whose aggregate compile time dominates dygraph warmup
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax latches its cache singleton (and whether it is used at all) at the
+    # first compile; without a reset a change made here after that compile
+    # would not take effect for the life of the process
+    from jax._src import compilation_cache as _jcc
 
-            _jcc.reset_cache()
-        except Exception:
-            pass
-        _configured_dir = None
-        return None
-    os.makedirs(d, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", d)
-    # cache EVERYTHING: the default thresholds skip fast compiles, which on
-    # CPU is every test program — and on TPU would skip the small eager
-    # rules whose aggregate compile time dominates dygraph warmup
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    try:
-        # jax latches cache-used once per process at the first compile; a
-        # compile that ran before this configuration would otherwise pin
-        # the cache off for the process lifetime
-        from jax._src import compilation_cache as _jcc
-
-        _jcc.reset_cache()
-    except Exception:
-        pass
-    _configured_dir = d
-    return d
+    _jcc.reset_cache()
+    _applied = d
+    return cache_dir()
 
 
 def entries() -> int:
     """Number of serialized executables in the cache dir (-1 when off).
     Cheap enough to snapshot around a compile: one readdir."""
-    if _configured_dir is None:
+    if not _applied:
         return -1
     try:
-        return sum(1 for n in os.listdir(_configured_dir)
+        return sum(1 for n in os.listdir(_applied)
                    if n.endswith("-cache"))
     except OSError:
         return -1
@@ -140,4 +133,4 @@ def _on_flag_change(name):
 from . import flags as _flags  # noqa: E402
 
 _flags.on_change(_on_flag_change)
-configure()  # env-set FLAGS_compile_cache_dir / PADDLE_TPU_COMPILE_CACHE
+configure()

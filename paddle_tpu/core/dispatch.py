@@ -15,6 +15,7 @@ phi's KernelFactory::kernels() does.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import Callable, Dict
 
@@ -98,6 +99,19 @@ class amp_guard:
 
 def amp_ctx():
     return getattr(_amp_state, "ctx", None)
+
+
+@contextlib.contextmanager
+def amp_scope(ctx):
+    """Install an existing autocast context (or None for none) for the
+    duration — unlike re-entering the amp_guard itself, safe while that
+    guard is still active further up the stack."""
+    prev = amp_ctx()
+    _amp_state.ctx = ctx
+    try:
+        yield
+    finally:
+        _amp_state.ctx = prev
 
 
 def register_kernel(name: str):

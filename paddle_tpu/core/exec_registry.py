@@ -132,6 +132,29 @@ class ExecEntry:
         return self.pins > 0
 
 
+class _LowersAsTraced:
+    """A stashed jitted fn whose later AOT ``lower()`` re-enters the autocast
+    scope that was active when it was stashed, i.e. first traced. Autocast
+    is trace-time state: the same function lowered outside that scope is an
+    f32 program that never ran, and introspection or analysis of it would
+    describe the wrong executable."""
+
+    def __init__(self, fn):
+        from .dispatch import amp_ctx
+
+        self._fn = fn
+        self._amp = amp_ctx()
+
+    def lower(self, *args, **kw):
+        from .dispatch import amp_scope
+
+        with amp_scope(self._amp):
+            return self._fn.lower(*args, **kw)
+
+    def __getattr__(self, name):
+        return getattr(self._fn, name)
+
+
 class ExecutableRegistry:
     """Keyed executable store with LRU eviction, pinning, donation metadata,
     compile telemetry, and optional AOT precompilation.
@@ -390,7 +413,7 @@ class ExecutableRegistry:
             return
         self._donated[label] = tuple(donate)
         avals = abstract_args(call_args, aval_fn)
-        self._stash[label] = (fn, avals)
+        self._stash[label] = (_LowersAsTraced(fn), avals)
         if entry is not None and entry.avals is None:
             entry.avals = avals
         if _flags.flag("exec_introspect"):

@@ -80,7 +80,7 @@ define_flag("apply_ir_passes", True, "run CSE/DCE/fuse passes before lowering st
 define_flag("use_autotune", False, "enable kernel autotune (pallas block-size search + cache)")
 define_flag("enable_unused_var_check", False, "warn when an op kernel never reads a declared input")
 # use_pallas_lm_loss / pallas_lm_loss_block_n / use_pallas_layernorm were
-# RETIRED in round 5 (BASELINE.md): the kernels stay as direct-call library
+# RETIRED in round 5: the kernels stay as direct-call library
 # ops in ops/pallas/, but nothing routes to them and no flag re-enables that.
 define_flag("fused_ce_chunk", 2048,
             "rows per scan step of the chunked fused LM-head cross-entropy "
@@ -213,11 +213,13 @@ define_flag("ckpt_rollback", False,
             "flight-recorder dump and restores the newest valid checkpoint "
             "in place of the diverged state (ckpt.rollbacks counter). "
             "Costs one loss fetch per step while enabled")
-define_flag("compile_cache_dir", os.environ.get("PADDLE_TPU_COMPILE_CACHE", ""),
-            "persistent XLA compilation cache directory (also settable as "
-            "PADDLE_TPU_COMPILE_CACHE). Empty = off (bit-identical default); "
-            "set, every process reuses serialized executables so steady-state "
-            "restarts skip recompilation (core/compile_cache.py)")
+define_flag("compile_cache_dir",
+            os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))), ".jax_cache"),
+            "persistent XLA compilation cache directory; the default is a "
+            "fixed path inside the checkout. JAX_COMPILATION_CACHE_DIR, "
+            "when set, places the cache instead and this flag cannot move "
+            "it. Empty = off (core/compile_cache.py)")
 define_flag("analysis_flight_dump", False,
             "when engine.analyze()/hlo_lint finds contract violations and a "
             "flight recorder is installed, dump the ring naming the "

@@ -185,7 +185,7 @@ def saved_residual_bytes(f, *args) -> int:
     actually keep live between forward and backward, with jax.checkpoint
     policies APPLIED. This is the remat-sensitive peak component that XLA's
     AOT memory_analysis does not credit (it reported identical peaks with
-    and without selective remat — BASELINE.md round-4 limitation (b)), so
+    and without selective remat), so
     remat variants get distinct predicted peaks only through this term.
     Trace-level (jaxpr) analysis: nothing compiles or executes."""
     from jax._src.ad_checkpoint import saved_residuals

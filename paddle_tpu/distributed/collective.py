@@ -99,15 +99,14 @@ def _sharded_over(data, axis_name):
 
 def _eager_axis_collective(x, axis, fn_traced):
     """Run a collective over a mesh axis on an axis-sharded global array via shard_map."""
-    from ..core.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = fleet_default_mesh()
     spec = x.sharding.spec if hasattr(x.sharding, "spec") else P()
     # check_vma=False: ops like broadcast (all_gather + index) produce values
     # that ARE replicated but can't be statically inferred as such
-    f = shard_map(fn_traced, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                  check_vma=False)
+    f = jax.shard_map(fn_traced, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                      check_vma=False)
     return f(x)
 
 
@@ -315,7 +314,6 @@ def _p2p_pair_program(src: int, dst: int, shape, dtype_str: str):
 
 @_functools.lru_cache(maxsize=256)
 def _p2p_program_cached(src, dst, shape, dtype_str):
-    from ..core.jax_compat import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     # one device per endpoint process (rank = process; a multi-chip host
@@ -333,8 +331,8 @@ def _p2p_program_cached(src, dst, shape, dtype_str):
         keep = jax.lax.axis_index("pair") == 1
         return jnp.where(keep, moved, v)
 
-    fn = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("pair"),),
-                           out_specs=P("pair"), check_vma=False))
+    fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("pair"),),
+                               out_specs=P("pair"), check_vma=False))
     return fn, mesh, sharding
 
 
@@ -479,7 +477,6 @@ def batch_isend_irecv(p2p_op_list):
     Limits: at most one isend and one irecv per rank per batch (one mesh
     row each way), all tensors one shape/dtype.
     """
-    from ..core.jax_compat import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     rank = jax.process_index()
@@ -577,8 +574,8 @@ def batch_isend_irecv(p2p_op_list):
     def f(v):
         return jax.lax.ppermute(v, "p", perm)
 
-    out = shard_map(f, mesh=mesh, in_specs=(P("p"),), out_specs=P("p"),
-                    check_vma=False)(glob)
+    out = jax.shard_map(f, mesh=mesh, in_specs=(P("p"),), out_specs=P("p"),
+                        check_vma=False)(glob)
     my_row = jnp.asarray(out.addressable_shards[0].data)[0]
     results = []
     for op in p2p_op_list:

@@ -88,9 +88,22 @@ def model_input_count(n_batch_args, num_model_inputs=None):
 
 
 def _param_spec(p, shape, hcg) -> P:
-    if getattr(p, "dist_attr", None) is not None:
-        return p.dist_attr if isinstance(p.dist_attr, P) else P(*p.dist_attr)
-    return P()
+    """The param's declared PartitionSpec with every mesh axis of degree 1
+    dropped. Sharding over a one-device axis is no sharding, and the
+    pure-data-parallel paths (deferred reduce, ZeRO, FSDP) recognise a
+    replicated param by its spec: a GPT built from the mp layers names 'mp'
+    on every matmul weight and would otherwise never reach them at mp=1."""
+    if getattr(p, "dist_attr", None) is None:
+        return P()
+
+    def live(entry):
+        names = entry if isinstance(entry, tuple) else (entry,)
+        names = tuple(a for a in names
+                      if a is not None and hcg.degrees.get(a, 2) > 1)
+        return names[0] if len(names) == 1 else (names or None)
+
+    entries = [live(e) for e in tuple(p.dist_attr)]
+    return P(*entries) if any(e is not None for e in entries) else P()
 
 
 def _opt_state_spec(param_spec: P, shape, hcg, use_sharding: bool) -> P:
@@ -167,10 +180,8 @@ class TrainStepEngine:
         # group_sharded_optimizer_stage2.py:48): optimizer state lives in host
         # memory between steps — XLA streams it to HBM for the update and back,
         # freeing per-device HBM at the cost of host<->device traffic.
-        # (pinned_host on TPU/GPU; older CPU clients expose unpinned_host only)
-        from ..core.jax_compat import host_memory_kind
-
-        self._opt_memory_kind = (host_memory_kind()
+        # (pinned_host: the TPU v5e and the CPU client both expose it)
+        self._opt_memory_kind = ("pinned_host"
                                  if getattr(optimizer, "_offload", False) else None)
         self.opt_specs = {}
         self.opt_state = {}
@@ -588,13 +599,15 @@ class TrainStepEngine:
 
     # ---- static analysis (paddle_tpu.analysis) ----------------------------
     def _analysis_state_bytes(self, include_opt: bool = True) -> int:
-        """Bytes of the donation-eligible carried state (replicated host
-        view) — the same params(+opt) accounting the donation perf gate
-        measures alias coverage against."""
+        """Bytes of the donation-eligible carried state ONE device holds —
+        what the per-device program's alias bytes are measured against (a
+        param sharded over 'mp' contributes its shard, not its global
+        size)."""
         tree = (self.params, self.opt_state) if include_opt else self.params
-        return sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
-                   for a in jax.tree_util.tree_leaves(tree)
-                   if hasattr(a, "shape"))
+        return sum(
+            int(np.prod(a.sharding.shard_shape(a.shape)))
+            * np.dtype(a.dtype).itemsize
+            for a in jax.tree_util.tree_leaves(tree) if hasattr(a, "shape"))
 
     def default_contracts(self) -> list:
         """The contracts this engine's own executables are expected to meet,
@@ -627,28 +640,31 @@ class TrainStepEngine:
             # consumer (ISSUE 20's schedule-order pass)
             sched = ("all-gather-ahead" if self._fsdp_prefetch() >= 2
                      else None)
+            # the K-microbatch scan is the one loop these programs promise;
+            # at K = 1 there is nothing to scan over
+            loops = (1, None) if self.microbatches > 1 else None
             cs += [
                 _an.ProgramContract(
                     "train.accum_*_f32",
                     collectives={"all-reduce": (1, clip_hi)},
-                    while_loops=(1, None), name="accum-fused-reduce"),
+                    while_loops=loops, name="accum-fused-reduce"),
                 _an.ProgramContract(
                     "train.accum_*_bf16*",
                     collectives={"all-reduce": (1, clip_hi)},
-                    while_loops=(1, None), comm_dtype="bf16",
+                    while_loops=loops, comm_dtype="bf16",
                     name="accum-fused-reduce-bf16"),
                 _an.ProgramContract(
                     "train.accum_*_int8*",
                     collectives={"all-gather": (1, None),
                                  "reduce-scatter": 0},
-                    while_loops=(1, None), comm_dtype="int8",
+                    while_loops=loops, comm_dtype="int8",
                     name="accum-quantized-gather"),
                 _an.ProgramContract(
                     "train.zero_*",
                     collectives={"reduce-scatter": 1, "all-gather": (1, 2),
                                  "all-reduce": (0, clip_hi - 1),
                                  "all-to-all": 0},
-                    while_loops=(1, None), name="zero-decomposition"),
+                    while_loops=loops, name="zero-decomposition"),
                 # fsdp: exactly L per-bucket weight gathers + ONE grad
                 # reduce-scatter, zero full-buffer all-reduces, K-independent
                 # (int8 swaps the scatter for two EQuARX all-to-alls)
@@ -658,7 +674,7 @@ class TrainStepEngine:
                                  "reduce-scatter": 1,
                                  "all-reduce": (0, clip_hi - 1),
                                  "all-to-all": 0},
-                    while_loops=(1, None), schedule_order=sched,
+                    while_loops=loops, schedule_order=sched,
                     name="fsdp-decomposition"),
                 _an.ProgramContract(
                     "train.fsdp_*_bf16*",
@@ -666,7 +682,7 @@ class TrainStepEngine:
                                  "reduce-scatter": 1,
                                  "all-reduce": (0, clip_hi - 1),
                                  "all-to-all": 0},
-                    while_loops=(1, None), schedule_order=sched,
+                    while_loops=loops, schedule_order=sched,
                     name="fsdp-decomposition-bf16"),
                 _an.ProgramContract(
                     "train.fsdp_*_int8*",
@@ -674,7 +690,7 @@ class TrainStepEngine:
                                  "reduce-scatter": 0,
                                  "all-to-all": 2,
                                  "all-reduce": (0, clip_hi - 1)},
-                    while_loops=(1, None), name="fsdp-quantized"),
+                    while_loops=loops, name="fsdp-quantized"),
                 _an.ProgramContract(
                     "train.step", requires_combining=True,
                     collectives={"all-reduce": (1, 4)},
@@ -766,11 +782,12 @@ class TrainStepEngine:
 
         import contextlib
 
+        from ..ops.pallas._common import mesh_scope as _pallas_mesh_scope
         from .meta_parallel.sequence_parallel import sequence_parallel_scope
 
         sp_deg = self.hcg.degrees["sp"]
         # default matches DistributedStrategy.sep_impl: Ulysses wins on the
-        # XLA cost model at moderate seq (BASELINE.md); ring for seq >> 100k
+        # XLA cost model at moderate seq; ring for seq >> 100k
         sp_impl = getattr(self.strategy, "sep_impl", "ulysses") \
             if self.strategy else "ulysses"
         mesh = self.mesh
@@ -797,7 +814,8 @@ class TrainStepEngine:
                 state[bn] = buffers[bn]
             sp_ctx = (sequence_parallel_scope(mesh, "sp", sp_impl)
                       if sp_deg > 1 else contextlib.nullcontext())
-            with sp_ctx, _amp_ctx(), random_mod.trace_key_scope(key):
+            with sp_ctx, _amp_ctx(), random_mod.trace_key_scope(key), \
+                    _pallas_mesh_scope(mesh):
                 inputs = [Tensor(b, stop_gradient=True) for b in batch]
                 if loss_fn is None:
                     out = functional_call(model, state, *inputs)
@@ -901,9 +919,8 @@ class TrainStepEngine:
         The analogue of the reference's fleet_executor running a whole section
         of iterations per dispatch (fleet_executor/compute_interceptor.cc's
         LoopCounter / max_run_times) instead of one step per Executor.run —
-        on TPU it also collapses K PJRT execute round-trips into one, which
-        matters through remote/tunneled backends where each execute pays
-        network latency. With fixed_batch=False, batch arrays carry a leading
+        on TPU it also collapses K PJRT executes into one. With
+        fixed_batch=False, batch arrays carry a leading
         [K] axis and the scan consumes one slice per step; with
         fixed_batch=True the same single batch feeds every step (scan
         xs=None — one device copy, not K). Per-step learning rates arrive as

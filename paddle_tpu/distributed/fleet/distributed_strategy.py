@@ -148,7 +148,7 @@ class DistributedStrategy:
         self.auto_search = False
         # sequence-parallel attention flavor: "ulysses" (head-scatter
         # all-to-all) or "ring" (KV rotation via ppermute). Ulysses is the
-        # default on the XLA cost model (BASELINE.md ring-vs-Ulysses table:
+        # default on the XLA cost model (tools/sp_cost_compare.py:
         # near-dense peak memory and bytes-moved at sp 2-4, all-to-alls ride
         # ICI); ring remains available for the seq >> 100k regime where its
         # O(1) per-step working set wins.

@@ -50,7 +50,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import flags as _flags
 from ..core import monitor as _monitor
-from ..core.jax_compat import shard_map
 
 # grad_comm.* observability: steps through this subsystem, microbatches
 # executed, and the collective payload bytes per device (analytic — the
@@ -278,8 +277,8 @@ def make_accum_step(*, compute_loss: Callable, update: Callable, clip,
                 return _local(params, key, rest[0], *rest[1:])
             return _local(params, key, None, *rest)
 
-        fn = shard_map(region, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
+        fn = jax.shard_map(region, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         if residual is not None:
             return fn(params, key, residual, *batch)
         return fn(params, key, *batch)
@@ -551,8 +550,8 @@ def make_zero_accum_step(*, compute_loss: Callable, flat_update: Callable,
                               *rest[2:])
             return _local(params, lr, step_i, key, None, rest[0], *rest[1:])
 
-        fn = shard_map(region, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
+        fn = jax.shard_map(region, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         if use_residual:
             return fn(params, lr, step_i, key, residual, tuple(opt), *batch)
         return fn(params, lr, step_i, key, tuple(opt), *batch)
@@ -993,8 +992,8 @@ def make_fsdp_accum_step(*, compute_loss: Callable, flat_update: Callable,
             return _local(p_shards, lr, step_i, key, None, rest[0],
                           *rest[1:])
 
-        fn = shard_map(region, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
+        fn = jax.shard_map(region, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         if use_residual:
             return fn(tuple(p_shards), lr, step_i, key, residual,
                       tuple(opt), *batch)

@@ -9,6 +9,7 @@ sets the PS env contract (controllers/ps.py:21).
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import socket
@@ -22,6 +23,31 @@ def _free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         return s.getsockname()[1]
+
+
+def tpu_chips_on_host() -> int:
+    """TPU chips this host exposes to a process that is not pinned to the CPU
+    platform, counted from their device nodes — without touching jax, so the
+    caller does not become a holder of the chips it is asking about."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip() == "cpu":
+        return 0
+    return len(glob.glob("/dev/accel[0-9]*")
+               + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def one_controller_per_host(nproc: int, what: str) -> None:
+    """Refuse more than one worker per TPU host. A chip belongs to one
+    process at a time and nothing here partitions the chips between workers,
+    so a second worker fails or hangs when its backend starts; one controller
+    process drives every local chip. CPU-platform jobs (JAX_PLATFORMS=cpu)
+    may start as many workers as they like."""
+    chips = tpu_chips_on_host()
+    if nproc > 1 and chips:
+        raise SystemExit(
+            f"{what}: {nproc} workers asked for on a host with {chips} TPU "
+            f"chip(s). One controller process drives all local chips; start "
+            f"one worker per host (the mesh spans jax.devices()), or set "
+            f"JAX_PLATFORMS=cpu for a CPU-platform job.")
 
 
 def _parse_args(argv=None):
@@ -200,6 +226,7 @@ def launch(argv=None) -> int:
         args.nproc_per_node
 
     nproc = trainers  # trainer processes per node
+    one_controller_per_host(nproc, "paddle_tpu.distributed.launch")
     node_rank, master_addr, master_port, all_endpoints, store = \
         _rendezvous(args, nproc)
     world = args.nnodes * nproc

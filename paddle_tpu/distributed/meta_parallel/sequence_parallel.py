@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ...core.jax_compat import axis_size, shard_map
 
 NEG_INF = -1e30
 
@@ -150,7 +149,7 @@ def _ring_shard(q, k, v, *, axis, causal, sm_scale):
     so the Pallas flash kernel (which returns normalized output + lse) drops
     straight into the loop.
     """
-    p_size = axis_size(axis)
+    p_size = jax.lax.axis_size(axis)
     my_idx = jax.lax.axis_index(axis)
     b, s_loc, h, d = q.shape
     use_flash = _ring_use_flash(s_loc, d)
@@ -204,7 +203,7 @@ def ring_attention(q, k, v, mesh, axis: str = "sp", causal: bool = False,
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     spec = P(None, axis, None, None)
     fn = functools.partial(_ring_shard, axis=axis, causal=causal, sm_scale=sm_scale)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, axis_names={axis},
                          check_vma=False)(q, k, v)
 
@@ -214,7 +213,7 @@ def ring_attention(q, k, v, mesh, axis: str = "sp", causal: bool = False,
 def _ulysses_shard(q, k, v, *, axis, causal, sm_scale):
     """Per-shard Ulysses: seq-sharded [b, s/P, h, d] -> all_to_all ->
     head-sharded [b, s, h/P, d] -> dense local attention -> back."""
-    p_size = axis_size(axis)
+    p_size = jax.lax.axis_size(axis)
 
     def scatter_heads(x):
         # tiled all_to_all: heads scattered across ranks, sequence gathered
@@ -262,6 +261,6 @@ def ulysses_attention(q, k, v, mesh, axis: str = "sp", causal: bool = False,
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     spec = P(None, axis, None, None)
     fn = functools.partial(_ulysses_shard, axis=axis, causal=causal, sm_scale=sm_scale)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, axis_names={axis},
                          check_vma=False)(q, k, v)

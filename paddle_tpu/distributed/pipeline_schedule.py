@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..core.jax_compat import shard_map
 
 
 def spmd_pipeline(body_fn, stage_params, x_mb, mesh, axis: str = "pp"):
@@ -79,7 +78,7 @@ def spmd_pipeline(body_fn, stage_params, x_mb, mesh, axis: str = "pp"):
         # replicate the result over the pp axis (only the last stage holds it)
         return jax.lax.psum(jnp.where(stage == S - 1, out, jnp.zeros_like(out)), axis)
 
-    return shard_map(local, mesh=mesh, in_specs=(param_specs, xspec),
+    return jax.shard_map(local, mesh=mesh, in_specs=(param_specs, xspec),
                          out_specs=xspec, axis_names={axis},
                          check_vma=False)(stage_params, x_mb)
 
@@ -260,7 +259,7 @@ def spmd_pipeline_interleaved(body_fn, stage_params, x_mb, mesh,
     sch_args = tuple(jnp.asarray(sched[k]) for k in
                      ("v_sel", "ingest", "buf_read", "buf_write",
                       "out_write", "valid"))
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(param_specs, xspec) + (sspec,) * 6,
         out_specs=xspec, axis_names={axis},

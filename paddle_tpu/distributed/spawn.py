@@ -1,8 +1,10 @@
 """paddle.distributed.spawn — single-node multiprocess entry (reference spawn.py).
 
-On TPU, a single controller already drives all local chips, so nprocs>1 maps to
-multi-host multi-controller launches (one process per host) via the launcher CLI;
-spawn with nprocs=1 (or default) simply runs the function.
+On a TPU host a single controller drives all local chips, and a chip belongs
+to one process at a time: nprocs > 1 is refused there (multi-host jobs start
+one process per host through the launcher CLI). spawn with nprocs=1 (or
+default) simply runs the function; on the CPU platform nprocs > 1 starts
+that many worker processes.
 """
 from __future__ import annotations
 
@@ -20,9 +22,13 @@ def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
         func(*args)
         return None
 
-    # spawn (not fork): the parent has initialized JAX, which is multithreaded —
-    # forking a multithreaded process can deadlock children on PJRT/threadpool
-    # locks. spawn requires func/args to be picklable (same contract as torch).
+    from .launch.main import one_controller_per_host
+
+    one_controller_per_host(nprocs, "paddle_tpu.distributed.spawn")
+    # spawn (not fork): the parent may have initialized JAX, which is
+    # multithreaded — forking a multithreaded process can deadlock children
+    # on PJRT/threadpool locks. spawn requires func/args to be picklable
+    # (same contract as torch).
     ctx = mp.get_context("spawn")
     procs = []
     for rank in range(nprocs):

@@ -200,7 +200,7 @@ class TCPStore(_StoreOps):
                                     timeout=self.timeout if wait else 0.0)
         if wait:
             # wait+get (rather than the server's blocking kGet) so the store's
-            # timeout applies — a never-set key raises instead of wedging the job
+            # timeout applies — a never-set key raises instead of hanging the job
             self.wait([key])
         cap = 1 << 16
         while True:
@@ -345,7 +345,7 @@ class FileStore(_StoreOps):
 
     def wait(self, keys, timeout: Optional[float] = None) -> None:
         """Block until every key exists; raises TimeoutError past the bound
-        (the store timeout by default) instead of wedging the caller — the
+        (the store timeout by default) instead of hanging the caller — the
         same contract as TCPStore.wait."""
         if isinstance(keys, str):
             keys = [keys]

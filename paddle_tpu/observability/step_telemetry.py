@@ -62,9 +62,10 @@ class StepTelemetry:
 
     flops_per_token: model-FLOPs per trained token (see
         flops.transformer_flops_per_token); enables tflops_per_sec and mfu.
-    peak_flops: MFU denominator in FLOP/s; defaults per backend at first
-        record (flops.peak_flops_per_sec), None on backends with no
-        calibrated peak — mfu is then omitted.
+    peak_flops: MFU denominator in FLOP/s; defaults at first record to the
+        peak of the device's kind (flops.peak_flops_per_sec), which raises
+        for an accelerator not in the table. On the CPU platform mfu is
+        omitted.
     """
 
     def __init__(self, sink=None, flops_per_token: Optional[int] = None,
@@ -177,16 +178,19 @@ class StepTelemetry:
 
     # ---- internals ----
     def _resolve_peak(self) -> Optional[float]:
-        if self.peak_flops is not None:
-            return self.peak_flops
-        try:
+        """MFU denominator for the device the step ran on. The host CPU
+        platform has no MFU (None: the field is omitted); an accelerator
+        whose kind is not in the peak table raises from
+        flops.peak_flops_per_sec instead of reporting against a guess."""
+        if self.peak_flops is None:
             import jax
 
             from . import flops as _flops
 
-            self.peak_flops = _flops.peak_flops_per_sec(jax.default_backend())
-        except Exception:
-            self.peak_flops = None
+            dev = jax.devices()[0]
+            if dev.platform == "cpu":
+                return None
+            self.peak_flops = _flops.peak_flops_per_sec(dev.device_kind)
         return self.peak_flops
 
     def _counter_deltas(self) -> Dict[str, Any]:

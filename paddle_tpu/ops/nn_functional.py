@@ -362,8 +362,8 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=N
     n_axes = len(tuple(normalized_shape))
     axes = tuple(range(x.ndim - n_axes, x.ndim))
 
-    # The Pallas LayerNorm kernel is RETIRED from this route (BASELINE.md
-    # round 5: never completed a functional on-chip run across two chip
+    # The Pallas LayerNorm kernel is RETIRED from this route (round 5:
+    # never completed a functional on-chip run across two chip
     # windows, and XLA already fuses this lowering into the surrounding
     # elementwise chain — the kernel remains a direct-call library op in
     # ops/pallas/layer_norm.py, math pinned by tests/test_pallas_layernorm).

@@ -9,11 +9,11 @@ produces dx in one pass; dgamma/dbeta accumulate across the sequential TPU
 grid into one revisited [1, hidden] output block (the Mosaic reduction idiom —
 no atomics, no partials array).
 
-RETIRED from the nn.functional.layer_norm route in round 5 (BASELINE.md
-retirement note: never completed a functional on-chip run across two chip
-windows, and XLA fuses the plain lowering into the surrounding elementwise
-chain, leaving little headroom). Available as a direct-call library kernel;
-math pinned by tests/test_pallas_layernorm.py (interpret mode).
+RETIRED from the nn.functional.layer_norm route in round 5 (never completed
+a functional on-chip run, and XLA fuses the plain lowering into the
+surrounding elementwise chain, leaving little headroom). Available as a
+direct-call library kernel; math pinned by tests/test_pallas_layernorm.py
+(interpret mode). Not compiled on the machine this tree now runs on.
 """
 from __future__ import annotations
 

@@ -471,7 +471,7 @@ class ServingEngine:
     def drain(self, timeout_s: Optional[float] = None) -> List[Request]:
         """Run active slots to completion (admission closed), deregister
         the replica lease, and return the requests completed during the
-        drain. Bounded by FLAGS_elastic_drain_timeout_s — a wedged decode
+        drain. Bounded by FLAGS_elastic_drain_timeout_s — a hung decode
         retires the replica anyway rather than hanging the SIGTERM path.
         Records ``elastic.drain_ms`` in the metrics registry."""
         self.begin_drain()

@@ -1,4 +1,4 @@
-"""BASELINE.md config analogues on the 8-device virtual CPU mesh.
+"""BASELINE.json config analogues on the 8-device virtual CPU mesh.
 
 Config 1 (MNIST LeNet dygraph) lives in test_mnist_e2e; config 4 (GPT hybrid
 dp+mp+pp) in test_pipeline + __graft_entry__.dryrun_multichip; config 5

@@ -40,7 +40,7 @@ def _bench_engine(batch=8):
     from paddle_tpu.distributed import fleet
     from paddle_tpu.models import GPTForPretraining
 
-    cfg, _, seq, _, _ = bench.bench_config("base")
+    cfg, _, seq, _, _ = bench.bench_config()
     paddle.seed(0)
     strategy = dist.DistributedStrategy()
     strategy.hybrid_configs = {"dp_degree": 1, "mp_degree": 1}

@@ -294,8 +294,6 @@ def test_aot_bundle_round_trip_fresh_process(tmp_path):
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "PYTHONPATH": REPO + os.pathsep + os.environ.get(
                "PYTHONPATH", "")}
-    env.pop("PADDLE_TPU_COMPILE_CACHE", None)
-    env.pop("FLAGS_compile_cache_dir", None)
 
     def run(mode):
         res = subprocess.run([sys.executable, "-c", prog, mode, bundle],

@@ -225,7 +225,7 @@ def test_flash_attention_routes_to_pallas_when_flagged():
 
 
 # (the layernorm / lm_loss flag-routing gates were removed in round 5 with
-#  the kernels' retirement from the training path — BASELINE.md; their math
+#  the kernels' retirement from the training path; their math
 #  stays pinned by tests/test_pallas_layernorm.py / test_pallas_lm_loss.py)
 
 
@@ -425,7 +425,7 @@ def test_ring_sequence_parallel_emits_collective_permute():
 
 
 def test_default_sequence_parallel_is_ulysses_all_to_all():
-    """The DEFAULT sp flavor is Ulysses (cost-model-backed, BASELINE.md):
+    """The DEFAULT sp flavor is Ulysses (cost-model-backed):
     sp=2 with no explicit sep_impl must emit all-to-alls, not ppermutes."""
     # non-combining backends also reshard across the dp2/mp2/sp2 mesh with
     # device-order collective-permutes (identity-shuffle source_target_pairs),

@@ -224,7 +224,13 @@ def test_golden_wire_format_pinned(tmp_path, kind):
     (iota_*/const_* initializers: int64 -> int32). Node list, op multiset,
     and initializer names were unchanged and the new export is numerically
     identical to eager (same max-abs-err as the old fixture), so the
-    regeneration pins the new — intentional — layout."""
+    regeneration pins the new — intentional — layout. Regenerated again in
+    PR 21 for jax 0.9.0, the one installation there is: `jnp.tril` now
+    builds the dense path's causal mask from iotas in the default integer
+    dtype, so under x64 the same three initializers (iota_105, iota_108,
+    const_106) went back from int32 to int64 (+516 bytes). Nodes, op
+    multiset, initializer names and every other initializer are unchanged
+    and the export evaluates equal to eager."""
     import os
 
     fixture = os.path.join(os.path.dirname(__file__), "fixtures",

@@ -146,8 +146,9 @@ def test_shape_size_is_empty_copy_to():
     assert int(paddle.numel(x)) == 24
     assert not bool(paddle.is_empty(x))
     assert bool(paddle.is_empty(t(np.zeros((0, 3), np.float32))))
-    # copy_to/Tensor.cuda: a device-placement copy must preserve values
-    y = x.cuda()
+    # a device-placement copy must preserve values (Tensor.cuda/.tpu name
+    # the accelerator and raise on a CPU-only host: tests/test_chip_rules.py)
+    y = x.to(paddle.get_device())
     np.testing.assert_array_equal(y.numpy(), x.numpy())
 
 

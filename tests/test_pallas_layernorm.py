@@ -1,8 +1,8 @@
 """Fused Pallas LayerNorm vs the XLA lowering (values + grads), interpret
 mode on CPU. Reference parity: phi layer_norm_kernel fused path.
 
-Round 5: the kernel is RETIRED from the nn.functional.layer_norm route
-(BASELINE.md retirement note) — these tests call it DIRECTLY
+Round 5: the kernel is RETIRED from the nn.functional.layer_norm route —
+these tests call it DIRECTLY
 (ops/pallas/layer_norm.py), keeping its math pinned as a library kernel.
 """
 import jax

@@ -1,6 +1,6 @@
 """Online Pallas LM-head cross-entropy (ops/pallas/lm_loss.py) vs dense math
 (interpret mode on CPU). Round 5: RETIRED from the fused_linear_cross_entropy
-route (BASELINE.md retirement note) — called DIRECTLY here, keeping the math
+route — called DIRECTLY here, keeping the math
 pinned as a library kernel."""
 import jax
 import jax.numpy as jnp
@@ -105,7 +105,7 @@ def test_small_compute_blocks_match_dense(block_n):
     their 1024-element XLA-tile blocks (revisit sub-slices) — value and both
     grads must match the dense reference at every supported block size.
     (The knob exists because Mosaic compile time grows superlinearly in
-    per-block vector ops — BASELINE.md round 3.)"""
+    per-block vector ops.)"""
     rng = np.random.RandomState(7)
     N, V, H = 2048, 640, 128  # N spans 2 revisit groups at block 256
     h = jnp.asarray(rng.randn(N, H).astype(np.float32))

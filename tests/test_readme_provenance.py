@@ -4,9 +4,7 @@ VERDICT r4 #10: "a reader can reproduce every number in README from
 committed tools". This pins the mechanical half of that promise — the
 quoted tok/s figures are exact `value` / `extra.decode_tokens_per_sec`
 fields of BENCH_HISTORY.jsonl rows, so README cannot drift into
-aspirational numbers without this test failing. (The MFU/bandwidth
-readings live in BASELINE.md tables next to the tool that produced them;
-the tok/s figures are the ones a reader will try to reproduce first.)
+aspirational numbers without this test failing.
 """
 import json
 import os
@@ -34,26 +32,6 @@ def _history_values():
             if isinstance(d, (int, float)):
                 vals.add(round(float(d), 1))
     return vals
-
-
-def test_readme_planner_join_headline_matches_baseline():
-    """VERDICT r5 weak #6/next #4: one planner-join headline across
-    committed documents. README must quote the FINAL 15-pair join (12/15 =
-    80.0% corrected vs 53.3% raw) — the same figures BASELINE.md records —
-    and may reference the mid-round 3/3 snapshot only as superseded."""
-    readme = open(os.path.join(ROOT, "README.md")).read()
-    baseline = open(os.path.join(ROOT, "BASELINE.md")).read()
-    for doc, name in ((readme, "README.md"), (baseline, "BASELINE.md")):
-        assert "12/15" in doc and "80.0%" in doc, (
-            f"{name} no longer quotes the final planner join headline "
-            f"(12/15 = 80.0%)")
-    # the mid-round snapshot may appear in README only labeled as such
-    m = re.search(r"3/3[^.]*", readme)
-    if m:
-        ctx = readme[max(0, m.start() - 400):m.end() + 200]
-        assert "snapshot" in ctx or "superseded" in ctx, (
-            "README quotes the 3/3 mid-round figure without labeling it a "
-            "superseded snapshot of the 15-pair join")
 
 
 @pytest.mark.slow  # spawns a full collection subprocess (~seconds)

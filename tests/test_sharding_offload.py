@@ -73,15 +73,9 @@ def test_engine_offload_places_opt_state_in_host_memory():
         paddle.to_tensor(rs.rand(8, 8).astype(np.float32))).item())
         for _ in range(3)]
     assert np.isfinite(losses).all() and losses[-1] < losses[0]
-    # host kind is backend-dependent: pinned_host on TPU/GPU and newer CPU
-    # clients, unpinned_host on older CPU clients (core.jax_compat); the
-    # offload-vs-resident distinction below is sharp wherever they differ
-    from paddle_tpu.core.jax_compat import host_memory_kind
-
-    host_kind = host_memory_kind()
     for n, st in engine.opt_state.items():
         for leaf in st:
-            assert leaf.sharding.memory_kind == host_kind, (
+            assert leaf.sharding.memory_kind == "pinned_host", (
                 n, leaf.sharding)
 
     # parity vs the non-offloaded engine

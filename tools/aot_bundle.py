@@ -81,6 +81,19 @@ def store_files(bundle_dir: str) -> Tuple[int, int]:
     return n, b
 
 
+def _refuse_outside_cache() -> None:
+    """A bundle IS a compile-cache directory, pointed at through
+    FLAGS_compile_cache_dir while it is built or loaded. A cache placed from
+    outside is never moved by the program (core/compile_cache.py), so the
+    two cannot be combined."""
+    from paddle_tpu.core import compile_cache as _cc
+
+    if _cc.placed_from_outside():
+        raise RuntimeError(
+            f"{_cc.ENV_DIR}={_cc.placed_from_outside()} places the compile "
+            f"cache from outside; unset it to build or load an AOT bundle")
+
+
 def build_bundle(out_dir: str, *, slots: int = 4,
                  ladder: Tuple[int, ...] = (8, 16, 32),
                  max_new_cap: int = 16, max_seq_len: int = 64,
@@ -101,6 +114,7 @@ def build_bundle(out_dir: str, *, slots: int = 4,
     from paddle_tpu.core import compile_cache as _cc
     from paddle_tpu.serving import ServingEngine
 
+    _refuse_outside_cache()
     os.makedirs(out_dir, exist_ok=True)
     engine_kwargs = {
         "slot_count": int(slots), "ladder": tuple(int(x) for x in ladder),
@@ -151,6 +165,7 @@ def load_engine(bundle_dir: str, model=None, *, force: bool = False,
     from paddle_tpu.core import flags as _flags
     from paddle_tpu.serving import ServingEngine
 
+    _refuse_outside_cache()
     manifest = bundle_manifest(bundle_dir)
     if manifest.get("format") != FORMAT:
         raise ValueError(f"bundle format {manifest.get('format')!r} != "

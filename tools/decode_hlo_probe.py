@@ -1,6 +1,6 @@
 """Chip-free triage of the decode-loop slowness via compiled-HLO inspection.
 
-Round-3 on-chip datum (BASELINE.md): generate(batch 16, prompt 128, 64 new
+Round-3 on-chip datum: generate(batch 16, prompt 128, 64 new
 tokens) = 179.8 tok/s total — ~89 ms per decode step for a model whose
 per-step roofline (weights + KV cache, one HBM pass) is ~1 ms. The two
 structural suspects visible WITHOUT a chip, in the compiled while-loop body:

@@ -4,7 +4,7 @@ Reference: paddle/fluid/API.spec + tools/check_api_compatible.py — CI diffs
 the committed spec against the live package so accidental signature breaks
 fail a test instead of shipping. Regenerate after an intentional API change:
 
-    python tools/gen_api_spec.py > API.spec
+    PYTHONPATH=. python tools/gen_api_spec.py > API.spec
 """
 from __future__ import annotations
 

@@ -113,7 +113,7 @@ def score_variant(v, seq, quick):
 
 def apply_replay_correction(rows, seq):
     """Remat-replay corrected score (round 5, POST-HOC — the pre-registered
-    table in BASELINE.md stands as committed; this corrected model's
+    table stands as committed; this corrected model's
     falsifiable content is for configs measured after it). The round-5
     on-chip rows showed selective remat costing ~15% measured throughput
     while the AOT score separated the variants by only ~1.5%: XLA's
@@ -152,8 +152,8 @@ def measured_tokens(path, seq):
     round 3's b32 only ran WITH recompute, which is the point: the
     predicted-fastest config was the one that couldn't run plain), wrong
     seq, and multi-device rows. Autotuned-flash rows ARE admitted (round
-    5): the committed .autotune_cache.json makes tuned blocks the default
-    program every bench run executes."""
+    5): their tuned [512,512] blocks equal the heuristic's choice, so they
+    ran the default program."""
     out = {}
     with open(path) as f:
         for ln in f:
@@ -172,12 +172,10 @@ def measured_tokens(path, seq):
                 continue  # a medium-model row must not join base predictions
             # bench.py treats ANY non-empty env value as knob-ON (even "0"),
             # so any recorded value disqualifies the row as a plain variant.
-            # autotune rows are NOT excluded (round 5): the tuned flash
-            # blocks are the committed-default program now that
-            # .autotune_cache.json ships with the repo — every future bench
-            # row loads it, and excluding them would freeze the measured
-            # join at the pre-cache rows. Structurally different programs
-            # (scan trainer, pallas kernel variants) stay out.
+            # autotune rows are NOT excluded (round 5): their tuned flash
+            # blocks equal the heuristic's, the same program as a plain
+            # row. Structurally different programs (scan trainer, pallas
+            # kernel variants) stay out.
             # prefetch rows are excluded like scan: input-staging overlap is
             # dispatch-level, invisible to a per-program cost model.
             # microbatch-accumulation rows (PADDLE_TPU_BENCH_ACCUM) are a

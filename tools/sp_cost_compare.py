@@ -1,8 +1,7 @@
 """Ring vs Ulysses vs dense sequence parallelism — XLA cost-model comparison.
 
-The BASELINE.md on-chip ring-vs-Ulysses sweep needs multiple real chips
-(sp>1 on one chip is degenerate), which this sandbox does not have. This is
-the chip-independent half: compile the FULL GPT train step at each (impl,
+An on-chip ring-vs-Ulysses sweep needs multiple real chips (sp>1 on one chip
+is degenerate). This is the chip-independent half: compile the FULL GPT train step at each (impl,
 sp_degree, seq) on the virtual 8-device CPU mesh and report what the XLA
 cost model and the compiled HLO say —
 
@@ -15,7 +14,7 @@ Ring should show collective-permutes with per-shard peak memory ~1/sp of
 dense attention's; Ulysses shows all-to-alls with head-sharded compute.
 Run: XLA_FLAGS=--xla_force_host_platform_device_count=8 \
      JAX_PLATFORMS=cpu python tools/sp_cost_compare.py
-One JSON line per config; paste the table into BASELINE.md.
+One JSON line per config.
 """
 from __future__ import annotations
 
