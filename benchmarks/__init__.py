@@ -1,0 +1,1 @@
+"""The benchmark: BENCHMARK.json at the root names what is here."""
