@@ -22,11 +22,14 @@ suite: cache-SERVED multi-device CPU executables can produce
 nondeterministic collective results on this jax). The min-compile-time /
 min-entry-size thresholds are zeroed so even sub-second programs cache.
 
-Cold/warm accounting: `entries()` counts serialized executables; the train
-engines snapshot it around a dispatch that compiled — if the persistent
-store grew, the compile was COLD (paid XLA), otherwise it was WARM (served
-from the cache). Counters land in core.monitor (`engine.compile_cold` /
-`engine.compile_warm` and their _ms twins) and ride into StepTelemetry.
+Cold/warm accounting: jax reports every compile request that consults the
+persistent cache and every hit (`jax.monitoring` events); `misses()` is their
+difference. The engines snapshot it around a dispatch that compiled: if a
+request missed, the compile was COLD (paid XLA), otherwise it was WARM
+(served from the cache). Counting the directory's entries instead misread a
+compile as warm when an evicted entry was rebuilt under a size cap, because
+the count did not grow. Counters land in core.monitor (`engine.compile_cold`
+/ `engine.compile_warm` and their _ms twins) and ride into StepTelemetry.
 """
 from __future__ import annotations
 
@@ -40,6 +43,10 @@ ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 
 # what configure() last applied: None = nothing yet, "" = off, else the dir
 _applied: Optional[str] = None
+
+# compile requests that consulted the persistent cache, and those it served
+_requests = _hits = 0
+_listening = False
 
 _COLD = _monitor.stat("engine.compile_cold")
 _WARM = _monitor.stat("engine.compile_warm")
@@ -91,7 +98,28 @@ def configure() -> Optional[str]:
 
     _jcc.reset_cache()
     _applied = d
+    global _listening
+    if d and not _listening:
+        jax.monitoring.register_event_listener(_on_jax_event)
+        _listening = True
     return cache_dir()
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    global _requests, _hits
+    if event == "/jax/compilation_cache/compile_requests_use_cache":
+        _requests += 1
+    elif event == "/jax/compilation_cache/cache_hits":
+        _hits += 1
+
+
+def misses() -> int:
+    """Compile requests of this process that consulted the persistent cache
+    and were not served from it (-1 when off). Snapshot it round a dispatch
+    that compiles: growth means XLA was paid."""
+    if not _applied:
+        return -1
+    return _requests - _hits
 
 
 def entries() -> int:
@@ -108,11 +136,13 @@ def entries() -> int:
 
 def note_compile(wall_ms: int, persistent_before: int,
                  persistent_after: int) -> Optional[str]:
-    """Classify one observed executable-cache compile as cold/warm.
+    """Classify one observed executable-cache compile as cold/warm from two
+    snapshots of `misses()`.
 
-    Only meaningful when the persistent cache is on: a compile that left no
-    new serialized entry was served FROM the store (warm — deserialization
-    cost only); one that wrote an entry paid XLA (cold). Returns
+    Only meaningful when the persistent cache is on: a compile whose
+    requests were all served FROM the store was warm (deserialization cost
+    only); one with a request the store missed paid XLA (cold), whether or
+    not the store then held more entries than before. Returns
     "cold" / "warm" / None (cache off)."""
     if persistent_before < 0 or persistent_after < 0:
         return None

@@ -436,13 +436,12 @@ class ExecutableRegistry:
 
     # --------------------------------------------------- compile telemetry
     def persistent_before(self, entry: ExecEntry) -> int:
-        """Snapshot of the persistent store to classify the NEXT dispatch's
-        compile, taken only when this entry has never compiled (-1 after:
-        entries() costs a readdir, first-dispatch-only keeps it off the
-        steady-state path)."""
+        """Snapshot of the persistent cache's misses to classify the NEXT
+        dispatch's compile, taken only when this entry has never compiled
+        (-1 after)."""
         if entry._counted_once or entry._seen_cache_size > 0:
             return -1
-        return _compile_cache.entries()
+        return _compile_cache.misses()
 
     def note_compiles(self, entry: ExecEntry,
                       n_before: Optional[int] = None,
@@ -496,7 +495,7 @@ class ExecutableRegistry:
         _COMPILE_MS.increase(int(wall_ms))
         self._compile_ms.append(wall_ms)
         kind = _compile_cache.note_compile(int(wall_ms), persistent_before,
-                                           _compile_cache.entries())
+                                           _compile_cache.misses())
         self._observe_compile(kind, wall_ms)
         return grew
 
@@ -521,7 +520,7 @@ class ExecutableRegistry:
         warm-start bundle path), classifying cold/warm like any compile."""
         avals = abstract_args(call_args, aval_fn)
         entry.avals = avals
-        p0 = _compile_cache.entries()
+        p0 = _compile_cache.misses()
         t0 = time.perf_counter()
         entry.aot = entry.fn.lower(*avals).compile()
         wall_ms = (time.perf_counter() - t0) * 1000.0
@@ -529,7 +528,7 @@ class ExecutableRegistry:
         _COMPILE_MS.increase(int(wall_ms))
         self._compile_ms.append(wall_ms)
         kind = _compile_cache.note_compile(int(wall_ms), p0,
-                                           _compile_cache.entries())
+                                           _compile_cache.misses())
         self._observe_compile(kind, wall_ms)
         if _flags.flag("exec_introspect"):
             try:

@@ -873,14 +873,18 @@ class TrainStepEngine:
                 #    a composable subdivide that lowers to
                 #    reduce-scatter/dynamic-slice, after which the update
                 #    runs on the shard and only new params all-gather.
-                grads = {n: jax.lax.with_sharding_constraint(
-                    g, NamedSharding(mesh, param_specs_c[n]))
-                    for n, g in grads.items()}
-                grads = {n: jax.lax.with_sharding_constraint(
-                    g, NamedSharding(mesh, zero_specs[n]))
-                    for n, g in grads.items()}
-            grads = opt_funct.clip_grads(grads, clip)
-            new_params, new_opt = update(params, grads, opt_state, lr, step_i)
+                with jax.named_scope("grad_sync"):
+                    grads = {n: jax.lax.with_sharding_constraint(
+                        g, NamedSharding(mesh, param_specs_c[n]))
+                        for n, g in grads.items()}
+                    grads = {n: jax.lax.with_sharding_constraint(
+                        g, NamedSharding(mesh, zero_specs[n]))
+                        for n, g in grads.items()}
+            with jax.named_scope("grad_clip"):
+                grads = opt_funct.clip_grads(grads, clip)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = update(params, grads, opt_state, lr,
+                                             step_i)
             if health_stats is None:
                 return loss, new_params, new_opt
             return loss, new_params, new_opt, health_stats(
@@ -1511,10 +1515,12 @@ class TrainStepEngine:
             donate_argnums=donate if self._donate else (),
         )
 
-    def _accum_step(self, arrays) -> Tensor:
+    def _accum_step(self, arrays, span) -> Tensor:
         """One optimizer step over K in-program microbatches: the grad_comm
         twin of step() (same plumbing contract: telemetry, compile
-        accounting, donation-safe rebind of params/opt state)."""
+        accounting, donation-safe rebind of params/opt state). `span` is
+        step()'s open `engine.step`, ended here when the enqueue returns."""
+        tr = _obs_tracer.get_tracer()
         k, dtype, use_residual, chunk, zero = self._grad_comm_config()
         self._check_batch(arrays)
         nrep = _gc.replica_count(self.mesh, self._batch_axes())
@@ -1548,9 +1554,10 @@ class TrainStepEngine:
             label=label, pin=True)
         fn = entry.fn
         staged, self._pending_h2d = self._pending_h2d, None
-        arrays, h2d_ms = self._place_batch(
-            arrays, self._batch_shardings,
-            timed=self.telemetry is not None and staged is None)
+        with tr.boundary("engine.place_batch"):
+            arrays, h2d_ms = self._place_batch(
+                arrays, self._batch_shardings,
+                timed=self.telemetry is not None and staged is None)
         prefetch_depth = None
         if staged is not None:
             h2d_ms, prefetch_depth = staged
@@ -1565,34 +1572,35 @@ class TrainStepEngine:
         fr = _obs_flight.get()
         mreg = _obs_metrics.active_registry()
         n0 = _jit_cache_size(fn)
-        p0 = _compile_cache.entries() if n0 == 0 else -1
+        p0 = _compile_cache.misses() if n0 == 0 else -1
         t0 = time.perf_counter()
         try:
-            if fsdp:
-                p_in, opt_in = self._ensure_fsdp_state()
-            else:
-                p_in = self.params
-                opt_in = (self._ensure_zero_opt() if zero
-                          else self._opt_to_hbm(self.opt_state))
-            if use_residual:
-                call_args = (p_in, opt_in,
-                             self._ensure_residual(), lr,
-                             jnp.int32(self._step_count), sub) + tuple(arrays)
+            with tr.boundary("engine.dispatch"):
+                if fsdp:
+                    p_in, opt_in = self._ensure_fsdp_state()
+                else:
+                    p_in = self.params
+                    opt_in = (self._ensure_zero_opt() if zero
+                              else self._opt_to_hbm(self.opt_state))
+                call_args = (p_in, opt_in) + (
+                    (self._ensure_residual(),) if use_residual else ()) + (
+                    lr, jnp.int32(self._step_count), sub) + tuple(arrays)
                 self._stash_exec(label, fn, call_args)
                 outs = fn(*call_args)
+            if use_residual:
                 loss, new_p, new_opt, self._grad_residual = outs[:4]
             else:
-                call_args = (p_in, opt_in,
-                             lr, jnp.int32(self._step_count),
-                             sub) + tuple(arrays)
-                self._stash_exec(label, fn, call_args)
-                outs = fn(*call_args)
                 loss, new_p, new_opt = outs[:3]
             if fsdp:
                 self._fsdp_params = tuple(new_p)
             else:
                 self.params = new_p
             hbuf = outs[-1] if health_on else None
+            n1 = _jit_cache_size(fn)
+            span.args = {"step": self._step_count, "compiled": n1 > n0 >= 0,
+                         "microbatches": k, "grad_comm_dtype": dtype,
+                         "zero_update": zero, "fsdp": fsdp}
+            span.end()          # enqueue returned: the span never syncs
             if tele is not None or fr is not None or mreg is not None:
                 jax.block_until_ready(loss)
         except Exception as e:
@@ -1602,7 +1610,7 @@ class TrainStepEngine:
             raise
         t1 = time.perf_counter()
         compiled = self._execs.note_compiles(
-            entry, n_before=n0, n_after=_jit_cache_size(fn), wall_s=t1 - t0,
+            entry, n_before=n0, n_after=n1, wall_s=t1 - t0,
             persistent_before=p0, engine_counters=True) > 0
         if fsdp:
             # L per-bucket weight gathers + one grad reduce-scatter; the
@@ -1628,12 +1636,6 @@ class TrainStepEngine:
         _gc.BYTES_MOVED.increase(comm_bytes)
         if dtype != "f32":
             _gc.LOWP_STEPS.increase()
-        tr = _obs_tracer.get_tracer()
-        if tr.enabled:
-            tr.record_complete("engine.accum_step", t0, t1,
-                               {"step": self._step_count, "compiled": compiled,
-                                "microbatches": k, "grad_comm_dtype": dtype,
-                                "zero_update": zero, "fsdp": fsdp})
         if fsdp:
             self._fsdp_opt = tuple(tuple(col) for col in new_opt)
         elif zero:
@@ -1802,7 +1804,7 @@ class TrainStepEngine:
         fr = _obs_flight.get()
         mreg = _obs_metrics.active_registry()
         n0 = _jit_cache_size(fn)
-        p0 = _compile_cache.entries() if n0 == 0 else -1
+        p0 = _compile_cache.misses() if n0 == 0 else -1
         t0 = time.perf_counter()
         try:
             call_args = (self.params, self._opt_to_hbm(self.opt_state), lrs,
@@ -1868,6 +1870,20 @@ class TrainStepEngine:
             self.telemetry = tele
 
     def step(self, *batch) -> Tensor:
+        """One optimizer step. The span `engine.step` runs from here to the
+        return of the enqueue, never over a sync (the loss is a future),
+        with children `engine.place_batch` (host to device) and
+        `engine.dispatch` (arguments and the call); all three are
+        engine-boundary spans (observability/tracer.py `boundary`), always
+        recorded. `compiled` in its arguments tells a step that compiled
+        or loaded its program from one that only dispatched."""
+        with _obs_tracer.get_tracer().boundary("engine.step") as span:
+            # _step ends the span itself, when the enqueue returns; leaving
+            # the block ends it only if the step raised before that
+            return self._step(batch, span)
+
+    def _step(self, batch, span) -> Tensor:
+        tr = _obs_tracer.get_tracer()
         arrays = self._to_arrays(batch)
         if (self.microbatches > 1 or _gc.comm_dtype() != "f32"
                 or self._zero_on() or self._fsdp_on()):
@@ -1876,7 +1892,7 @@ class TrainStepEngine:
             # the ZeRO weight-update sharding). The default (K=1, f32, no
             # zero_update) stays below on the original step program —
             # bit-identical to pre-grad_comm behavior.
-            return self._accum_step(arrays)
+            return self._accum_step(arrays, span)
         self._check_batch(arrays)
         from ..core import autotune
         autotune.set_step(self._step_count + 1)
@@ -1887,9 +1903,10 @@ class TrainStepEngine:
         # sharding); arrays staged by prefetch() arrive already placed and
         # skip the put — their H2D stats were captured at issue time
         staged, self._pending_h2d = self._pending_h2d, None
-        arrays, h2d_ms = self._place_batch(
-            arrays, self._batch_shardings,
-            timed=self.telemetry is not None and staged is None)
+        with tr.boundary("engine.place_batch"):
+            arrays, h2d_ms = self._place_batch(
+                arrays, self._batch_shardings,
+                timed=self.telemetry is not None and staged is None)
         if staged is not None:
             h2d_ms, prefetch_depth = staged
         else:
@@ -1906,19 +1923,24 @@ class TrainStepEngine:
         fr = _obs_flight.get()
         mreg = _obs_metrics.active_registry()
         n0 = _jit_cache_size(fn)
-        # persistent-store snapshot only around a first compile: one readdir,
-        # and only when the fn has no executable yet (recompiles from shape
-        # churn stay unclassified rather than taxing every steady-state step)
-        p0 = _compile_cache.entries() if n0 == 0 else -1
+        # persistent-cache snapshot only around a first compile, when the fn
+        # has no executable yet (recompiles from shape churn stay
+        # unclassified)
+        p0 = _compile_cache.misses() if n0 == 0 else -1
         health_on = self._health is not None
         t0 = time.perf_counter()
         try:
-            call_args = (self.params, self._opt_to_hbm(self.opt_state), lr,
-                         jnp.int32(self._step_count), sub) + tuple(arrays)
-            self._stash_exec("train.step", fn, call_args)
-            outs = fn(*call_args)
+            with tr.boundary("engine.dispatch"):
+                call_args = (self.params, self._opt_to_hbm(self.opt_state),
+                             lr, jnp.int32(self._step_count),
+                             sub) + tuple(arrays)
+                self._stash_exec("train.step", fn, call_args)
+                outs = fn(*call_args)
             loss, self.params, new_opt = outs[:3]
             hbuf = outs[-1] if health_on else None
+            n1 = _jit_cache_size(fn)
+            span.args = {"step": self._step_count, "compiled": n1 > n0 >= 0}
+            span.end()          # enqueue returned: the span never syncs
             if tele is not None or fr is not None or mreg is not None:
                 jax.block_until_ready(loss)  # honest wall over async dispatch
         except Exception as e:
@@ -1928,13 +1950,8 @@ class TrainStepEngine:
             raise
         t1 = time.perf_counter()
         compiled = self._execs.note_compiles(
-            step_entry, n_before=n0, n_after=_jit_cache_size(fn),
+            step_entry, n_before=n0, n_after=n1,
             wall_s=t1 - t0, persistent_before=p0, engine_counters=True) > 0
-        tr = _obs_tracer.get_tracer()
-        if tr.enabled:
-            tr.record_complete("engine.step", t0, t1,
-                               {"step": self._step_count,
-                                "compiled": compiled})
         self.opt_state = self._opt_to_home(new_opt)
         if hbuf is not None:
             self._health.on_step(self._step_count, hbuf)

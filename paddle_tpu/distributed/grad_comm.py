@@ -257,8 +257,9 @@ def make_accum_step(*, compute_loss: Callable, update: Callable, clip,
 
         (acc, _), losses = jax.lax.scan(body, (zero_flat, jnp.int32(0)), mbs)
         res_in = residual[0] if residual is not None else None
-        red, loss, res_out = _reduce_local(acc / k, losses.mean(), axes,
-                                           dtype, chunk, res_in)
+        with jax.named_scope("grad_sync"):
+            red, loss, res_out = _reduce_local(acc / k, losses.mean(), axes,
+                                               dtype, chunk, res_in)
         if residual is not None:
             return unravel(red), loss, res_out[None]
         return unravel(red), loss
@@ -297,8 +298,11 @@ def make_accum_step(*, compute_loss: Callable, update: Callable, clip,
                 for n, g in grads.items()}
         from ..optimizer import functional as opt_funct
 
-        grads = opt_funct.clip_grads(grads, clip)
-        new_params, new_opt = update(params, grads, opt_state, lr, step_i)
+        with jax.named_scope("grad_clip"):
+            grads = opt_funct.clip_grads(grads, clip)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = update(params, grads, opt_state, lr,
+                                         step_i)
         if health_stats is None:
             return new_params, new_opt, None
         return new_params, new_opt, health_stats(raw_grads, params,
@@ -477,7 +481,8 @@ def make_zero_accum_step(*, compute_loss: Callable, flat_update: Callable,
             # bit-identical to the replicated path's psum'd loss. int8 must
             # not quantize it; there it rides the gather slab in f32.
             buf = buf.at[n].set(losses.mean())
-        g_shard, new_res = _scatter(buf)
+        with jax.named_scope("grad_sync"):
+            g_shard, new_res = _scatter(buf)
         # own-shard offset: row-major replica index over the batch axes —
         # the order psum_scatter/all_gather tile in (pinned by tests)
         r = jnp.int32(0)
@@ -497,9 +502,11 @@ def make_zero_accum_step(*, compute_loss: Callable, flat_update: Callable,
             jnp.pad(_flatten(params), (0, n_pad - n)),
             (r * jnp.int32(shard),), (shard,))
         raw_g = g_shard                     # pre-clip: health attribution
-        g_shard = _clip_shard(g_shard, clip, axes)
-        new_p_shard, new_opt = flat_update(p_shard, g_shard, tuple(opt),
-                                           lr, step_i)
+        with jax.named_scope("grad_clip"):
+            g_shard = _clip_shard(g_shard, clip, axes)
+        with jax.named_scope("optimizer"):
+            new_p_shard, new_opt = flat_update(p_shard, g_shard, tuple(opt),
+                                               lr, step_i)
         extras = [loss_part[None]]
         if health_partial is not None:
             ids_shard = jax.lax.dynamic_slice(
@@ -512,8 +519,9 @@ def make_zero_accum_step(*, compute_loss: Callable, flat_update: Callable,
         # elsewhere (summing is exact); int8 rows carry local mean losses.
         slab = jnp.concatenate([new_p_shard] + extras)
         if axes:
-            rows = jax.lax.all_gather(slab, axes, tiled=True).reshape(
-                (nrep, slab.shape[0]))
+            with jax.named_scope("fsdp_gather"):
+                rows = jax.lax.all_gather(slab, axes, tiled=True).reshape(
+                    (nrep, slab.shape[0]))
             new_flat = rows[:, :shard].reshape(-1)[:n]
             loss = jnp.sum(rows[:, shard])
             if not ride_loss:
@@ -906,7 +914,8 @@ def make_fsdp_accum_step(*, compute_loss: Callable, flat_update: Callable,
         return g / nrep, jnp.sum(ss[:, -1]) / nrep, res
 
     def _local(p_shards, lr, step_i, key, residual, opt, *lbatch):
-        params, window_hold = _gather_params(p_shards, step_i)
+        with jax.named_scope("fsdp_gather"):
+            params, window_hold = _gather_params(p_shards, step_i)
         mbs = tuple(b.reshape((k, b.shape[0] // k) + b.shape[1:])
                     for b in lbatch)
         zero_flat, _ = ravel_pytree(
@@ -931,7 +940,8 @@ def make_fsdp_accum_step(*, compute_loss: Callable, flat_update: Callable,
         flat = acc / k
         if residual is not None:
             flat = flat + residual[0]
-        g_all, loss, new_res = _scatter(flat, losses.mean())
+        with jax.named_scope("grad_sync"):
+            g_all, loss, new_res = _scatter(flat, losses.mean())
         if window_hold:
             # keep the window's ahead-gathered buffers resident across the
             # microbatch scan: the dead select branch reads each buffer at
@@ -946,14 +956,16 @@ def make_fsdp_accum_step(*, compute_loss: Callable, flat_update: Callable,
             loss = jnp.where(step_i >= jnp.int32(-2 ** 31), loss,
                              probe.astype(loss.dtype))
         raw_g = g_all                       # pre-clip: health attribution
-        g_all = _clip_shard(g_all, clip, axes)
+        with jax.named_scope("grad_clip"):
+            g_all = _clip_shard(g_all, clip, axes)
         new_ps = []
         new_opt_cols = [[] for _ in opt]
         for i, b in enumerate(buckets):
             g_b = g_all[soffs[i]:soffs[i + 1]]
             opt_b = tuple(slot[i] for slot in opt)
-            new_p_b, new_opt_b = flat_update(p_shards[i], g_b, opt_b,
-                                             lr, step_i)
+            with jax.named_scope("optimizer"):
+                new_p_b, new_opt_b = flat_update(p_shards[i], g_b, opt_b,
+                                                 lr, step_i)
             new_ps.append(new_p_b)
             for j, col in enumerate(new_opt_b):
                 new_opt_cols[j].append(col)
@@ -1056,8 +1068,11 @@ def make_accum_step_gspmd(*, compute_loss: Callable, update: Callable, clip,
                 for n, g in grads.items()}
         from ..optimizer import functional as opt_funct
 
-        grads = opt_funct.clip_grads(grads, clip)
-        new_params, new_opt = update(params, grads, opt_state, lr, step_i)
+        with jax.named_scope("grad_clip"):
+            grads = opt_funct.clip_grads(grads, clip)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = update(params, grads, opt_state, lr,
+                                         step_i)
         if health_stats is None:
             return losses.mean(), new_params, new_opt
         return losses.mean(), new_params, new_opt, health_stats(

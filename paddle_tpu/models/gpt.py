@@ -13,6 +13,8 @@ from __future__ import annotations
 
 import math
 
+import jax
+
 from .. import nn
 from ..core.tensor import Tensor
 from ..distributed.fleet.utils import recompute
@@ -76,25 +78,28 @@ class GPTAttention(nn.Layer):
         self.attn_dropout = config.attention_dropout
 
     def forward(self, x, cache=None):
+        # named scopes (here and below) are the fixed, unnumbered vocabulary
+        # observability/device_trace.py reads device time by: they are
+        # trace-time metadata and leave the compiled program as it was
         b, s = x.shape[0], x.shape[1]
-        qkv = self.qkv_proj(x)  # [b, s, 3h] (h sharded over mp)
-        qkv = P.reshape(qkv, (b, s, 3, self.num_heads, self.head_dim))
-        q, k, v = P.unbind(qkv, axis=2)  # heads dim sharded over mp under pjit
+        with jax.named_scope("qkv"):
+            qkv = self.qkv_proj(x)  # [b, s, 3h] (h sharded over mp)
+            qkv = P.reshape(qkv, (b, s, 3, self.num_heads, self.head_dim))
+            q, k, v = P.unbind(qkv, axis=2)  # heads dim sharded over mp under pjit
         if cache is None:
-            out = F.scaled_dot_product_attention(
-                q, k, v, is_causal=True, dropout_p=self.attn_dropout,
-                training=self.training)
-            out = P.reshape(out, (b, s, self.hidden_size))
-            return self.out_proj(out)
+            with jax.named_scope("core"):
+                out = F.scaled_dot_product_attention(
+                    q, k, v, is_causal=True, dropout_p=self.attn_dropout,
+                    training=self.training)
+                out = P.reshape(out, (b, s, self.hidden_size))
+            with jax.named_scope("out"):
+                return self.out_proj(out)
 
         # KV-cache decode (TPU-native: fixed [b, T, nh, hd] buffers updated
         # with dynamic_update_slice, so the whole decode loop is one static-
         # shape scan). cache = (k_cache, v_cache, offset): offset is the count
         # of already-cached positions; the new chunk writes [offset, offset+s).
-        import jax
         import jax.numpy as jnp
-
-        from ..core.tensor import Tensor
 
         if hasattr(cache, "page_table"):
             # paged serving cache (serving/kv_pages.py): scatter this
@@ -105,17 +110,21 @@ class GPTAttention(nn.Layer):
             # contiguous cache bit for bit.
             from ..serving import kv_pages as _kvp
 
-            kc, vc, new_cache = _kvp.update_and_read(cache, k._data, v._data)
-            total = kc.shape[1]
-            off = cache.offset
-            qpos = off[:, None] + jnp.arange(s)[None, :]      # [b, s]
-            mask = (jnp.arange(total)[None, None, :]
-                    <= qpos[:, :, None])[:, None]             # [b, 1, s, T]
-            out = F.scaled_dot_product_attention(
-                q, Tensor(kc), Tensor(vc), attn_mask=Tensor(mask),
-                dropout_p=0.0, training=False)
-            out = P.reshape(out, (b, s, self.hidden_size))
-            return self.out_proj(out), new_cache
+            with jax.named_scope("cache_write"):
+                kc, vc, new_cache = _kvp.update_and_read(cache, k._data,
+                                                         v._data)
+            with jax.named_scope("core"):
+                total = kc.shape[1]
+                off = cache.offset
+                qpos = off[:, None] + jnp.arange(s)[None, :]      # [b, s]
+                mask = (jnp.arange(total)[None, None, :]
+                        <= qpos[:, :, None])[:, None]             # [b, 1, s, T]
+                out = F.scaled_dot_product_attention(
+                    q, Tensor(kc), Tensor(vc), attn_mask=Tensor(mask),
+                    dropout_p=0.0, training=False)
+                out = P.reshape(out, (b, s, self.hidden_size))
+            with jax.named_scope("out"):
+                return self.out_proj(out), new_cache
 
         k_cache, v_cache, offset = cache
         kc, vc = k_cache._data, v_cache._data
@@ -127,27 +136,32 @@ class GPTAttention(nn.Layer):
             # chunk at its own position. Rows past a row's offset are never
             # attended (mask below), so retired/short slots stay inert and
             # one batched step can serve slots at arbitrary depths.
-            rows = jnp.arange(b)[:, None]                     # [b, 1]
-            pos = jnp.clip(off[:, None] + jnp.arange(s)[None, :], 0, total - 1)
-            kc = kc.at[rows, pos].set(k._data.astype(kc.dtype))
-            vc = vc.at[rows, pos].set(v._data.astype(vc.dtype))
+            with jax.named_scope("cache_write"):
+                rows = jnp.arange(b)[:, None]                     # [b, 1]
+                pos = jnp.clip(off[:, None] + jnp.arange(s)[None, :], 0,
+                               total - 1)
+                kc = kc.at[rows, pos].set(k._data.astype(kc.dtype))
+                vc = vc.at[rows, pos].set(v._data.astype(vc.dtype))
             qpos = off[:, None] + jnp.arange(s)[None, :]      # [b, s]
             mask = (jnp.arange(total)[None, None, :]
                     <= qpos[:, :, None])[:, None]             # [b, 1, s, T]
         else:
-            zero = jnp.int32(0)
-            kc = jax.lax.dynamic_update_slice(
-                kc, k._data.astype(kc.dtype), (zero, off, zero, zero))
-            vc = jax.lax.dynamic_update_slice(
-                vc, v._data.astype(vc.dtype), (zero, off, zero, zero))
+            with jax.named_scope("cache_write"):
+                zero = jnp.int32(0)
+                kc = jax.lax.dynamic_update_slice(
+                    kc, k._data.astype(kc.dtype), (zero, off, zero, zero))
+                vc = jax.lax.dynamic_update_slice(
+                    vc, v._data.astype(vc.dtype), (zero, off, zero, zero))
             qpos = off + jnp.arange(s)                       # [s]
             mask = jnp.arange(total)[None, :] <= qpos[:, None]  # [s, T]
-        out = F.scaled_dot_product_attention(
-            q, Tensor(kc), Tensor(vc), attn_mask=Tensor(mask),
-            dropout_p=0.0, training=False)
-        out = P.reshape(out, (b, s, self.hidden_size))
-        return self.out_proj(out), (Tensor(kc), Tensor(vc),
-                                    Tensor(off + jnp.int32(s)))
+        with jax.named_scope("core"):
+            out = F.scaled_dot_product_attention(
+                q, Tensor(kc), Tensor(vc), attn_mask=Tensor(mask),
+                dropout_p=0.0, training=False)
+            out = P.reshape(out, (b, s, self.hidden_size))
+        with jax.named_scope("out"):
+            return self.out_proj(out), (Tensor(kc), Tensor(vc),
+                                        Tensor(off + jnp.int32(s)))
 
 
 class GPTMLP(nn.Layer):
@@ -175,14 +189,21 @@ class GPTBlock(nn.Layer):
                                              "full")
 
     def _forward(self, x):
-        h = x + F.dropout(self.attn(self.ln1(x)), self.dropout, training=self.training)
-        return h + F.dropout(self.mlp(self.ln2(h)), self.dropout, training=self.training)
+        # each scope takes the LayerNorm in front of it and its residual add
+        with jax.named_scope("attn"):
+            h = x + F.dropout(self.attn(self.ln1(x)), self.dropout,
+                              training=self.training)
+        with jax.named_scope("mlp"):
+            return h + F.dropout(self.mlp(self.ln2(h)), self.dropout,
+                                 training=self.training)
 
     def forward(self, x, cache=None):
         if cache is not None:
-            a, new_cache = self.attn(self.ln1(x), cache=cache)
-            h = x + a
-            return h + self.mlp(self.ln2(h)), new_cache
+            with jax.named_scope("attn"):
+                a, new_cache = self.attn(self.ln1(x), cache=cache)
+                h = x + a
+            with jax.named_scope("mlp"):
+                return h + self.mlp(self.ln2(h)), new_cache
         if self.use_recompute and self.training:
             return recompute(self._forward, x,
                              policy=self.recompute_granularity)
@@ -200,10 +221,23 @@ class GPTModel(nn.Layer):
         self.ln_f = nn.LayerNorm(config.hidden_size)
 
     def forward(self, input_ids, caches=None):
+        with jax.named_scope("embed"):
+            x = self._embed(input_ids, caches)
+        if caches is not None:
+            new_caches = []
+            for blk, cache in zip(self.blocks, caches):
+                x, c = blk(x, cache=cache)
+                new_caches.append(c)
+            with jax.named_scope("final_norm"):
+                return self.ln_f(x), new_caches
+        for blk in self.blocks:
+            x = blk(x)
+        with jax.named_scope("final_norm"):
+            return self.ln_f(x)
+
+    def _embed(self, input_ids, caches):
         s = input_ids.shape[1]
         if caches is not None:
-            from ..core.tensor import Tensor
-
             off = caches[0][2]
             off_arr = off._data if isinstance(off, Tensor) else off
             import jax.numpy as jnp
@@ -216,16 +250,7 @@ class GPTModel(nn.Layer):
         else:
             pos = C.arange(0, s, dtype="int64")
         x = self.wte(input_ids) + self.wpe(pos)
-        x = self.drop(x)
-        if caches is not None:
-            new_caches = []
-            for blk, cache in zip(self.blocks, caches):
-                x, c = blk(x, cache=cache)
-                new_caches.append(c)
-            return self.ln_f(x), new_caches
-        for blk in self.blocks:
-            x = blk(x)
-        return self.ln_f(x)
+        return self.drop(x)
 
     @staticmethod
     def fsdp_layer_key(name: str) -> str:
@@ -487,31 +512,27 @@ class GPTForPretraining(nn.Layer):
         self.loss_fn = ParallelCrossEntropy()
 
     def logits(self, input_ids):
-        h = self.gpt(input_ids)
-        if self.lm_head is None:
-            from ..ops import linalg as L
-
-            return L.matmul(h, self.gpt.wte.weight, transpose_y=True)
-        return self.lm_head(h)
+        return self._head_logits(self.gpt(input_ids))
 
     def forward(self, input_ids, labels=None):
         from ..ops import reduction as R
 
-        if labels is not None and self._can_fuse_loss():
-            # chunked LM-head+CE (ops/fused.py): skips the [b, s, vocab] f32
-            # logits materialization — the dominant activation of the step
-            from ..ops.fused import fused_linear_cross_entropy
-
-            h = self.gpt(input_ids)
-            loss = fused_linear_cross_entropy(h, self.gpt.wte.weight, labels,
-                                              transpose_y=True,
-                                              ignore_index=self.loss_fn.ignore_index)
-            return R.mean(loss)
-        logits = self.logits(input_ids)
+        h = self.gpt(input_ids)
         if labels is None:
-            return logits
-        loss = self.loss_fn(logits, labels)
-        return R.mean(loss)
+            return self._head_logits(h)
+        with jax.named_scope("lm_head_loss"):
+            if self._can_fuse_loss():
+                # chunked LM-head+CE (ops/fused.py): skips the [b, s, vocab]
+                # f32 logits materialization — the dominant activation of
+                # the step
+                from ..ops.fused import fused_linear_cross_entropy
+
+                loss = fused_linear_cross_entropy(
+                    h, self.gpt.wte.weight, labels, transpose_y=True,
+                    ignore_index=self.loss_fn.ignore_index)
+            else:
+                loss = self.loss_fn(self._head_logits(h), labels)
+            return R.mean(loss)
 
     # param names here are 'gpt.blocks.N.*' / 'gpt.wte.*' / 'lm_head.*';
     # the prefix-insensitive key delegates cleanly
@@ -528,11 +549,12 @@ class GPTForPretraining(nn.Layer):
 
     def _head_logits(self, h):
         """Hidden states -> vocab logits (shared by forward and decode)."""
-        if self.lm_head is None:
-            from ..ops import linalg as L
+        with jax.named_scope("lm_head"):
+            if self.lm_head is None:
+                from ..ops import linalg as L
 
-            return L.matmul(h, self.gpt.wte.weight, transpose_y=True)
-        return self.lm_head(h)
+                return L.matmul(h, self.gpt.wte.weight, transpose_y=True)
+            return self.lm_head(h)
 
     def decode_exec_registry(self):
         """This model's decode ExecutableRegistry (generate/generate_beam

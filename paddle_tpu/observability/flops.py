@@ -1,6 +1,6 @@
 """Shared model-FLOPs accounting for throughput/MFU telemetry.
 
-One home for the convention bench.py and tools/mxu_roofline.py already use
+One home for the convention bench.py already uses
 (PaLM appendix B): 6*N parameter FLOPs per token plus the full causal
 attention matmul term 12*L*h*s. StepTelemetry, bench, and the offline tools
 must all divide by the same number or cross-checking them is meaningless.

@@ -1,7 +1,6 @@
 """Per-step training telemetry: one structured record per optimizer step.
 
-The run-time complement of the offline probes in tools/ (step_breakdown,
-mxu_roofline): instead of re-deriving throughput after the fact, the
+Instead of re-deriving throughput after the fact, the
 training loop itself emits a JSONL stream of step records — wall time,
 tokens/s, achieved TFLOP/s, estimated MFU (flops.py model, the bench.py
 convention), device-memory high-water, and the compile/dispatch counters

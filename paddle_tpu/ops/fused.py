@@ -125,6 +125,11 @@ def fused_linear_cross_entropy(hidden, weight, label, transpose_y=True,
     hdim = hidden.shape[-1]
 
     def kernel(h, w, lb):
+        # the custom backward inherits the scope open round the forward
+        with jax.named_scope("lm_head_loss"):
+            return _chunked(h, w, lb)
+
+    def _chunked(h, w, lb):
         n = int(np.prod(lead_shape)) if lead_shape else 1
         h2 = h.reshape(n, hdim)
         lb1 = lb.reshape(n).astype(jnp.int32)

@@ -133,6 +133,10 @@ def _fwd(q, k, v, sm_scale, causal, blocks=None):
         ],
         scratch_shapes=[_vmem((bq, 128)), _vmem((bq, 128)), _vmem((bq, d))],
         interpret=_interpret(),
+        # the name reaches the device trace twice: as an element of the op
+        # path (observability/device_trace.py reads kernels by it) and as
+        # the instruction's name (recorded on the chip, PR 26)
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -264,6 +268,7 @@ def _bwd(res, g, sm_scale, causal, blocks=None, g_lse=None):
         ],
         scratch_shapes=[_vmem((bk, d)), _vmem((bk, d))],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     dq_kernel = functools.partial(
@@ -284,6 +289,7 @@ def _bwd(res, g, sm_scale, causal, blocks=None, g_lse=None):
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[_vmem((bq, d))],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

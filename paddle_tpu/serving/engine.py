@@ -233,6 +233,11 @@ class ServingEngine:
         self._queue: deque[Request] = deque()
         self._completed: List[Request] = []
         self._steps = 0
+        # what step() hands the serve_step sink record: the admission's
+        # span times, and where the last decode fetch ended (host_gap_ms)
+        self._admit_ms = None
+        self._prefill_ms = []
+        self._fetch_end = None
         # elastic drain state (distributed/membership.py protocol): once
         # draining, submit() refuses and _admit() stops pulling the queue —
         # active slots run to completion, then the replica retires
@@ -418,10 +423,22 @@ class ServingEngine:
     def step(self) -> int:
         """Admit queued requests into free slots (bucketed prefill), then
         run ONE decode step for all slots. Returns the number of live
-        slots after the step (0 = fully drained)."""
-        self._admit()
-        if self._active.any():
-            self._advance_step()
+        slots after the step (0 = fully drained).
+
+        The spans below are engine-boundary spans (observability/tracer.py
+        `boundary`): always recorded, a handful a dispatch, each mirrored
+        as a TraceAnnotation so a device trace's idle gaps can be put down
+        to what the engine was doing. serve.step > serve.admit > per
+        request serve.prefill.dispatch + serve.prefill.sync; then
+        serve.decode.dispatch, serve.decode.fetch, serve.emit."""
+        tr = _obs_tracer.get_tracer()
+        with tr.boundary("serve.step"):
+            self._prefill_ms = []      # (dispatch, sync) of this step's prefills
+            with tr.boundary("serve.admit") as admit:
+                self._admit()
+            self._admit_ms = admit.ms
+            if self._active.any():
+                self._advance_step()
         return int(self._active.sum())
 
     def run(self, max_steps: Optional[int] = None) -> List[Request]:
@@ -887,7 +904,8 @@ class ServingEngine:
                     big_v, layer[1]._data.astype(big_v.dtype), start))
             return new_kcs, new_vcs, tok
 
-        return jax.jit(prefill, donate_argnums=(1, 2))
+        return jax.jit(jax.named_scope("prefill")(prefill),
+                       donate_argnums=(1, 2))
 
     def _build_prefill_paged(self, bucket: int):
         """Paged tail-prefill, one executable per TAIL rung: the unshared
@@ -938,7 +956,8 @@ class ServingEngine:
             }
             return new_state, tok
 
-        return jax.jit(prefill, donate_argnums=(1,))
+        return jax.jit(jax.named_scope("prefill")(prefill),
+                       donate_argnums=(1,))
 
     # ---- speculative decoding: draft prefill ---------------------------
     def _build_draft_prefill(self, bucket: int):
@@ -978,7 +997,8 @@ class ServingEngine:
                     big_v, layer[1]._data.astype(big_v.dtype), start))
             return new_kcs, new_vcs
 
-        return jax.jit(prefill, donate_argnums=(1, 2))
+        return jax.jit(jax.named_scope("prefill")(prefill),
+                       donate_argnums=(1, 2))
 
     def _seat_spec(self, req: Request, slot: int) -> None:
         """Per-seat speculative setup, called at every seating site (slot
@@ -1044,30 +1064,39 @@ class ServingEngine:
             bucket = req.bucket
             plen = len(req.prompt_ids)
             req.admit_ts = time.perf_counter()    # queue wait ends here
-            entry = self._execs.get_or_build(
-                ("serve.prefill", bucket),
-                lambda: self._build_prefill(bucket),
-                label=f"serve.prefill_b{bucket}", donate=(1, 2), pin=True)
-            padded = np.zeros((1, bucket), np.int64)
-            padded[0, :plen] = req.prompt_ids
-            call_args = (self._params, self._kcs, self._vcs,
-                         jnp.asarray(padded), jnp.int32(plen),
-                         jnp.int32(slot), jnp.float32(req.temperature),
-                         jnp.int32(req.top_k), jnp.float32(req.top_p),
-                         jnp.int32(req.seed))
-            self._stash_exec(f"serve.prefill_b{bucket}", entry.fn, call_args)
-            from ..core import monitor
-
-            monitor.stat("serving.prefill_dispatches").increase()
-            p0 = self._execs.persistent_before(entry)
-            t0 = time.perf_counter()
+            tr = _obs_tracer.get_tracer()
+            span_args = req.trace_args(bucket=bucket, slot=slot)
             try:
-                self._kcs, self._vcs, tok = entry(*call_args)
-                self._execs.note_compiles(
-                    entry, wall_s=time.perf_counter() - t0,
-                    persistent_before=p0,
-                    counter="serving.prefill_compiles")
-                first = int(tok)                  # device sync = first token
+                with tr.boundary("serve.prefill.dispatch",
+                                 **span_args) as dispatch:
+                    entry = self._execs.get_or_build(
+                        ("serve.prefill", bucket),
+                        lambda: self._build_prefill(bucket),
+                        label=f"serve.prefill_b{bucket}", donate=(1, 2),
+                        pin=True)
+                    padded = np.zeros((1, bucket), np.int64)
+                    padded[0, :plen] = req.prompt_ids
+                    call_args = (self._params, self._kcs, self._vcs,
+                                 jnp.asarray(padded), jnp.int32(plen),
+                                 jnp.int32(slot),
+                                 jnp.float32(req.temperature),
+                                 jnp.int32(req.top_k),
+                                 jnp.float32(req.top_p), jnp.int32(req.seed))
+                    self._stash_exec(f"serve.prefill_b{bucket}", entry.fn,
+                                     call_args)
+                    from ..core import monitor
+
+                    monitor.stat("serving.prefill_dispatches").increase()
+                    p0 = self._execs.persistent_before(entry)
+                    t0 = time.perf_counter()
+                    self._kcs, self._vcs, tok = entry(*call_args)
+                    self._execs.note_compiles(
+                        entry, wall_s=time.perf_counter() - t0,
+                        persistent_before=p0,
+                        counter="serving.prefill_compiles")
+                with tr.boundary("serve.prefill.sync", **span_args) as sync:
+                    first = int(tok)              # device sync = first token
+                self._prefill_ms.append((dispatch.ms, sync.ms))
             except Exception as e:
                 fr = _obs_flight.get()
                 if fr is not None:
@@ -1077,7 +1106,6 @@ class ServingEngine:
                 self._finish(req, outcome="error")
                 raise
             req.first_token_ts = time.perf_counter()
-            tr = _obs_tracer.get_tracer()
             if tr.enabled:
                 tr.record_complete("serve.queue_wait", req.submit_ts,
                                    req.admit_ts, req.trace_args())
@@ -1236,28 +1264,36 @@ class ServingEngine:
             page = self._pool.alloc()
             self._tables[slot, pi] = page
             self._slot_pages[slot].append(page)
-        entry = self._execs.get_or_build(
-            ("serve.prefill", tbucket),
-            lambda: self._build_prefill_paged(tbucket),
-            label=f"serve.prefill_b{tbucket}", donate=(1,), pin=True)
-        padded = np.zeros((1, tbucket), np.int64)
-        padded[0, :tail] = req.prompt_ids[base:]
-        state = dict(self._pool_state, tables=jnp.asarray(self._tables))
-        call_args = (self._params, state, jnp.asarray(padded),
-                     jnp.int32(tail), jnp.int32(base), jnp.int32(slot),
-                     jnp.float32(req.temperature), jnp.int32(req.top_k),
-                     jnp.float32(req.top_p), jnp.int32(req.seed))
-        self._stash_exec(f"serve.prefill_b{tbucket}", entry.fn, call_args,
-                         donate=(1,))
-        monitor.stat("serving.prefill_dispatches").increase()
-        p0 = self._execs.persistent_before(entry)
-        t0 = time.perf_counter()
+        span_args = req.trace_args(bucket=tbucket, base=base, slot=slot)
         try:
-            new_state, tok = entry(*call_args)
-            self._execs.note_compiles(
-                entry, wall_s=time.perf_counter() - t0, persistent_before=p0,
-                counter="serving.prefill_compiles")
-            first = int(tok)                  # device sync = first token
+            with tr.boundary("serve.prefill.dispatch",
+                             **span_args) as dispatch:
+                entry = self._execs.get_or_build(
+                    ("serve.prefill", tbucket),
+                    lambda: self._build_prefill_paged(tbucket),
+                    label=f"serve.prefill_b{tbucket}", donate=(1,), pin=True)
+                padded = np.zeros((1, tbucket), np.int64)
+                padded[0, :tail] = req.prompt_ids[base:]
+                state = dict(self._pool_state,
+                             tables=jnp.asarray(self._tables))
+                call_args = (self._params, state, jnp.asarray(padded),
+                             jnp.int32(tail), jnp.int32(base),
+                             jnp.int32(slot), jnp.float32(req.temperature),
+                             jnp.int32(req.top_k), jnp.float32(req.top_p),
+                             jnp.int32(req.seed))
+                self._stash_exec(f"serve.prefill_b{tbucket}", entry.fn,
+                                 call_args, donate=(1,))
+                monitor.stat("serving.prefill_dispatches").increase()
+                p0 = self._execs.persistent_before(entry)
+                t0 = time.perf_counter()
+                new_state, tok = entry(*call_args)
+                self._execs.note_compiles(
+                    entry, wall_s=time.perf_counter() - t0,
+                    persistent_before=p0,
+                    counter="serving.prefill_compiles")
+            with tr.boundary("serve.prefill.sync", **span_args) as sync:
+                first = int(tok)                  # device sync = first token
+            self._prefill_ms.append((dispatch.ms, sync.ms))
         except Exception as e:
             fr = _obs_flight.get()
             if fr is not None:
@@ -1270,9 +1306,7 @@ class ServingEngine:
         req.first_token_ts = time.perf_counter()
         if tr.enabled:
             tr.record_complete("serve.prefill", req.admit_ts,
-                               req.first_token_ts,
-                               req.trace_args(bucket=tbucket, base=base,
-                                              slot=slot))
+                               req.first_token_ts, span_args)
         if mreg is not None:
             mreg.histogram("serve.prefill_ms").observe(
                 (req.first_token_ts - req.admit_ts) * 1e3)
@@ -1360,7 +1394,8 @@ class ServingEngine:
             return (kcs, vcs, off, tok, active, remaining, toks, was_active,
                     hits)
 
-        return jax.jit(step_chunk, donate_argnums=(1, 2))
+        return jax.jit(jax.named_scope("decode")(step_chunk),
+                       donate_argnums=(1, 2))
 
     def _build_decode_paged(self, family: str):
         """Paged decode chunk: same continuous-batching scan as the dense
@@ -1436,7 +1471,8 @@ class ServingEngine:
             return (new_state, off, tok, active, replay, remaining, toks,
                     was_active, hits)
 
-        return jax.jit(step_chunk, donate_argnums=(1,))
+        return jax.jit(jax.named_scope("decode")(step_chunk),
+                       donate_argnums=(1,))
 
     def _prealloc_decode_pages(self) -> None:
         """Host-side, between dispatches: make sure every active slot's
@@ -1641,7 +1677,8 @@ class ServingEngine:
             return (kcs, vcs, dkcs, dvcs, new_off, new_tok, new_active,
                     new_remaining, emit, m, a, hit_eos)
 
-        return jax.jit(verify, donate_argnums=(2, 3, 4, 5))
+        return jax.jit(jax.named_scope("decode")(verify),
+                       donate_argnums=(2, 3, 4, 5))
 
     def _build_verify_paged(self, family: str, k: int):
         """Paged-layout verify: target K/V flows through the donated pool
@@ -1737,7 +1774,8 @@ class ServingEngine:
             return (new_state, dkcs, dvcs, new_off, new_tok, new_active,
                     new_replay, new_remaining, emit, m, a, hit_eos)
 
-        return jax.jit(verify, donate_argnums=(2, 3, 4))
+        return jax.jit(jax.named_scope("decode")(verify),
+                       donate_argnums=(2, 3, 4))
 
     def _spec_dispatch_rung(self) -> int:
         """Window rung for the next dispatch: the max ladder rung among
@@ -1983,64 +2021,83 @@ class ServingEngine:
         import jax.numpy as jnp
         import numpy as np
 
+        tr = _obs_tracer.get_tracer()
+        paged = self.kv_layout == "paged"
         # per-dispatch family pick: an all-greedy slot set runs the slim
         # executable; any sampling slot routes to the full one. Two decode
         # executables max, regardless of traffic mix.
         family = ("greedy"
                   if not self._temps[self._active].any() else "sample")
-        paged = self.kv_layout == "paged"
-        entry = self._execs.get_or_build(
-            ("serve.decode", family),
-            lambda: (self._build_decode_paged(family) if paged
-                     else self._build_decode(family)),
-            label=f"serve.decode_{family}",
-            donate=(1,) if paged else (1, 2), pin=True)
-        if paged:
-            self._prealloc_decode_pages()
-            state = dict(self._pool_state,
-                         tables=jnp.asarray(self._tables))
-            call_args = (self._params, state, jnp.asarray(self._offsets),
-                         jnp.asarray(self._last_tok),
-                         jnp.asarray(self._active),
-                         jnp.asarray(self._replay),
-                         jnp.asarray(self._temps), jnp.asarray(self._topk),
-                         jnp.asarray(self._topp), jnp.asarray(self._eos),
-                         jnp.asarray(self._remaining),
-                         jnp.asarray(self._seeds))
-            self._stash_exec(f"serve.decode_{family}", entry.fn, call_args,
-                             donate=(1,))
-        else:
-            call_args = (self._params, self._kcs, self._vcs,
-                         jnp.asarray(self._offsets),
-                         jnp.asarray(self._last_tok),
-                         jnp.asarray(self._active),
-                         jnp.asarray(self._temps), jnp.asarray(self._topk),
-                         jnp.asarray(self._topp), jnp.asarray(self._eos),
-                         jnp.asarray(self._remaining),
-                         jnp.asarray(self._seeds))
-            self._stash_exec(f"serve.decode_{family}", entry.fn, call_args)
-        p0 = self._execs.persistent_before(entry)
-        t0 = time.perf_counter()
         try:
-            if paged:
-                (self._pool_state, off, tok, active, replay, remaining,
-                 toks, was_active, hits) = entry(*call_args)
-                self._replay = np.array(replay)
-            else:
-                (self._kcs, self._vcs, off, tok, active, remaining, toks,
-                 was_active, hits) = entry(*call_args)
-            self._execs.note_compiles(
-                entry, wall_s=time.perf_counter() - t0, persistent_before=p0,
-                counter="serving.decode_compiles")
-            # np.array (copy): zero-copy views of jax buffers are read-only,
-            # and _admit mutates these in place when it seats the next request
-            self._offsets = np.array(off)
-            self._last_tok = np.array(tok)
-            self._active = np.array(active)
-            self._remaining = np.array(remaining)
-            toks = np.asarray(toks)           # [n_inner, S]
-            was_active = np.asarray(was_active)
-            hits = np.asarray(hits)
+            # family's executable, arguments, and the call's return: the
+            # device has the dispatch enqueued when this span ends
+            with tr.boundary(
+                    "serve.decode.dispatch", family=family,
+                    step=self._steps,
+                    requests=[r.id for r in self._slot_req
+                              if r is not None]) as dispatch:
+                entry = self._execs.get_or_build(
+                    ("serve.decode", family),
+                    lambda: (self._build_decode_paged(family) if paged
+                             else self._build_decode(family)),
+                    label=f"serve.decode_{family}",
+                    donate=(1,) if paged else (1, 2), pin=True)
+                if paged:
+                    self._prealloc_decode_pages()
+                    state = dict(self._pool_state,
+                                 tables=jnp.asarray(self._tables))
+                    call_args = (
+                        self._params, state, jnp.asarray(self._offsets),
+                        jnp.asarray(self._last_tok),
+                        jnp.asarray(self._active),
+                        jnp.asarray(self._replay),
+                        jnp.asarray(self._temps), jnp.asarray(self._topk),
+                        jnp.asarray(self._topp), jnp.asarray(self._eos),
+                        jnp.asarray(self._remaining),
+                        jnp.asarray(self._seeds))
+                    self._stash_exec(f"serve.decode_{family}", entry.fn,
+                                     call_args, donate=(1,))
+                else:
+                    call_args = (
+                        self._params, self._kcs, self._vcs,
+                        jnp.asarray(self._offsets),
+                        jnp.asarray(self._last_tok),
+                        jnp.asarray(self._active),
+                        jnp.asarray(self._temps), jnp.asarray(self._topk),
+                        jnp.asarray(self._topp), jnp.asarray(self._eos),
+                        jnp.asarray(self._remaining),
+                        jnp.asarray(self._seeds))
+                    self._stash_exec(f"serve.decode_{family}", entry.fn,
+                                     call_args)
+                p0 = self._execs.persistent_before(entry)
+                t0 = time.perf_counter()
+                if paged:
+                    (self._pool_state, off, tok, active, replay, remaining,
+                     toks, was_active, hits) = entry(*call_args)
+                else:
+                    (self._kcs, self._vcs, off, tok, active, remaining, toks,
+                     was_active, hits) = entry(*call_args)
+                self._execs.note_compiles(
+                    entry, wall_s=time.perf_counter() - t0,
+                    persistent_before=p0, counter="serving.decode_compiles")
+            # the time the decode program had nothing enqueued: from the end
+            # of the last dispatch's fetch to this dispatch's enqueue
+            host_gap_ms = (None if self._fetch_end is None
+                           else (dispatch.t1 - self._fetch_end) * 1e3)
+            with tr.boundary("serve.decode.fetch") as fetch:   # blocks
+                # np.array (copy): zero-copy views of jax buffers are
+                # read-only, and _admit mutates these in place when it
+                # seats the next request
+                if paged:
+                    self._replay = np.array(replay)
+                self._offsets = np.array(off)
+                self._last_tok = np.array(tok)
+                self._active = np.array(active)
+                self._remaining = np.array(remaining)
+                toks = np.asarray(toks)           # [n_inner, S]
+                was_active = np.asarray(was_active)
+                hits = np.asarray(hits)
+            self._fetch_end = fetch.t1
         except Exception as e:
             fr = _obs_flight.get()
             if fr is not None:
@@ -2055,11 +2112,22 @@ class ServingEngine:
                 if req is not None and req.done_ts is None:
                     self._finish(req, outcome="error")
             raise
-        t1 = time.perf_counter()
-        tr = _obs_tracer.get_tracer()
-        if tr.enabled:
-            tr.record_complete("serve.decode_step", t0, t1,
-                               {"step": self._steps, "family": family})
+        spans_ms = {"admit": self._admit_ms,
+                    "prefill_dispatch": [d for d, _ in self._prefill_ms],
+                    "prefill_sync": [s for _, s in self._prefill_ms],
+                    "decode_dispatch": dispatch.ms, "decode_fetch": fetch.ms}
+        self._admit_ms, self._prefill_ms = None, []   # told once (drain()
+        #                               dispatches without a step() before it)
+        with tr.boundary("serve.emit") as emit:
+            self._emit_decoded(toks, was_active, hits, paged, spans_ms,
+                               host_gap_ms, emit)
+
+    def _emit_decoded(self, toks, was_active, hits, paged, spans_ms,
+                      host_gap_ms, emit) -> None:
+        """Hand a fetched dispatch's tokens to their requests, retire the
+        finished, count, and write the `serve_step` sink record."""
+        import numpy as np
+
         n_inner = toks.shape[0]
         self._steps += n_inner
         now = time.perf_counter()
@@ -2085,7 +2153,8 @@ class ServingEngine:
         occupancy = float(was_active.mean())
         mreg = _obs_metrics.active_registry()
         if mreg is not None:
-            mreg.histogram("serve.decode_step_ms").observe((t1 - t0) * 1e3)
+            mreg.histogram("serve.decode_step_ms").observe(
+                spans_ms["decode_dispatch"] + spans_ms["decode_fetch"])
             mreg.histogram("serve.occupancy",
                            boundaries=_OCCUPANCY_BUCKETS).observe(occupancy)
             mreg.gauge("serve.queue_depth").set(len(self._queue))
@@ -2097,6 +2166,9 @@ class ServingEngine:
                     self._prefix.hit_rate)
         fr = _obs_flight.get()
         if self.sink is not None or fr is not None:
+            # `emit` is still open: its time up to here, the record's own
+            # write left out
+            spans_ms["emit"] = (time.perf_counter() - emit.t0) * 1e3
             rec = {
                 "event": "serve_step", "step": self._steps, "ts": time.time(),
                 "steps_per_dispatch": n_inner,
@@ -2107,6 +2179,12 @@ class ServingEngine:
                 "occupancy": round(occupancy, 4),
                 "queue_depth": len(self._queue),
                 "tokens": emitted,
+                # host milliseconds of this dispatch's spans (serve.admit,
+                # serve.prefill.*, serve.decode.*, serve.emit), and the gap
+                # in which the decode program had nothing enqueued (None
+                # for the engine's first dispatch)
+                "spans_ms": spans_ms,
+                "host_gap_ms": host_gap_ms,
             }
             if paged:
                 rec["pages_in_use"] = self._pool.in_use

@@ -51,13 +51,15 @@ def sample_tokens(logits, keys, temperature, top_k, top_p):
     traced temperature/top_k/top_p. Returns int32 [n]. temperature == 0
     selects greedy argmax for that row (the sampling branch still traces,
     its result is discarded by the select)."""
-    logits = jnp.asarray(logits, jnp.float32)
-    temperature = jnp.asarray(temperature, jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    filtered = filter_topk_topp(scaled, top_k, top_p)
-    sampled = jax.vmap(jax.random.categorical)(keys, filtered)
-    return jnp.where(temperature == 0.0, greedy, sampled.astype(jnp.int32))
+    with jax.named_scope("sample"):
+        logits = jnp.asarray(logits, jnp.float32)
+        temperature = jnp.asarray(temperature, jnp.float32)
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        filtered = filter_topk_topp(scaled, top_k, top_p)
+        sampled = jax.vmap(jax.random.categorical)(keys, filtered)
+        return jnp.where(temperature == 0.0, greedy,
+                         sampled.astype(jnp.int32))
 
 
 def request_key(seed, position, base=None):

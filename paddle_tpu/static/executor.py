@@ -101,14 +101,14 @@ class Executor:
         """Dispatch a lowered program, recording compile wall time (and the
         persistent-cache cold/warm split) when this call compiled."""
         n0 = _jit_cache_size(fn)
-        p0 = _compile_cache.entries() if n0 == 0 else -1
+        p0 = _compile_cache.misses() if n0 == 0 else -1
         t0 = time.perf_counter()
         out = fn(*operands)
         if _jit_cache_size(fn) > n0 >= 0:
             ms = int((time.perf_counter() - t0) * 1000)
             _EXEC_COMPILES.increase()
             _EXEC_COMPILE_MS.increase(ms)
-            _compile_cache.note_compile(ms, p0, _compile_cache.entries())
+            _compile_cache.note_compile(ms, p0, _compile_cache.misses())
         return out
 
     # ---- public API (executor.py:1103 signature subset) ----

@@ -310,13 +310,15 @@ def test_serve_span_lifecycle_ordering(model):
 
     assert {e["name"] for e in events} >= {
         "serve.enqueue", "serve.queue_wait", "serve.prefill", "serve.decode",
-        "serve.request", "serve.retire", "serve.decode_step"}
+        "serve.request", "serve.retire", "serve.step", "serve.admit",
+        "serve.decode.dispatch", "serve.decode.fetch", "serve.emit"}
     for req in reqs:
         evs = {e["name"]: e for e in events
                if (e.get("args") or {}).get("request") == req.id}
         assert set(evs) == {"serve.enqueue", "serve.queue_wait",
                             "serve.prefill", "serve.decode", "serve.request",
-                            "serve.retire"}
+                            "serve.retire", "serve.prefill.dispatch",
+                            "serve.prefill.sync"}
 
         def end(e):
             return e["ts"] + e["dur"]

@@ -2,15 +2,14 @@
 JSON, or a metrics-registry snapshot.
 
 The offline half of paddle_tpu/observability: point it at what a run wrote
-and get per-region/per-step tables, so `tools/step_breakdown.py` (fresh
-synthetic probe runs) and the in-process tracer (what the REAL run did)
-can be compared region by region.
+and get per-region/per-step tables of what the REAL run did.
 
   python tools/trace_summary.py /tmp/tele/step_telemetry.jsonl
   python tools/trace_summary.py /tmp/serve/serve.jsonl      # serve_request
   python tools/trace_summary.py /tmp/slo/alerts.jsonl       # alert timeline
   python tools/trace_summary.py /tmp/paddle_tpu_profile/host_1234.json
   python tools/trace_summary.py /tmp/paddle_tpu_profile/   # merged dir
+  python tools/trace_summary.py <profile dir>/.../host.xplane.pb  # by scope
   python tools/trace_summary.py snapshot.json  # exporter /metrics.json dump
   python tools/trace_summary.py /tmp/w0 /tmp/w1     # fleet: merged report
   python tools/trace_summary.py '/tmp/workers/w*'   # fleet: glob of dirs
@@ -22,7 +21,15 @@ snapshots losslessly via the fleet histogram-merge (bucket counts add,
 percentiles recomputed), mirroring what the live FleetCollector serves
 at /fleet/metrics.
 
-Format is auto-detected: a JSONL stream of step records gets the per-step
+A device trace (`*.xplane.pb`, what `paddle.profiler.Profiler` and
+`jax.profiler` write; a directory is searched for the newest) gets the
+program's table by scope (observability/device_trace.py): device seconds by
+named scope with forward and backward apart, by kernel, by executable, the
+unnamed and metadata-less remainder, collectives by opcode, and every idle
+gap of chip 0 put down to the `serve.*` / `engine.*` span open on the host
+then. `--window NAME` clips to a host annotation of that name.
+
+Otherwise the format is auto-detected: a JSONL stream of step records gets the per-step
 throughput table (plus a TTFT/TPOT/step-time p50/p90/p99 percentile table
 when serve_request records are present); a JSON object with "histograms"
 (the exporter's /metrics.json shape, also written into flight-recorder
@@ -770,29 +777,56 @@ def summarize_fleet(paths):
     return summary
 
 
-def main():
+def summarize_device(path, window=None):
+    """The by-scope table of one device trace, then its JSON line."""
+    from paddle_tpu.observability import device_trace
+
+    reduced = device_trace.reduce(path, window=window)
+    print(device_trace.format_table(reduced))
+    print(json.dumps({"summary": "device_trace", **reduced}))
+
+
+def _holds_xplane(path):
+    if os.path.isfile(path):
+        return path.endswith(".xplane.pb")
+    return any(f.endswith(".xplane.pb")
+               for _, _, fs in os.walk(path) for f in fs)
+
+
+def main(argv=None):
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("paths", nargs="+",
                     help="StepTelemetry .jsonl, chrome-trace .json, a "
-                         "directory of traces, or several of these (or a "
-                         "quoted glob) for one merged fleet report")
-    args = ap.parse_args()
+                         "device trace .xplane.pb, a directory of traces, "
+                         "or several of these (or a quoted glob) for one "
+                         "merged fleet report")
+    ap.add_argument("--window", default=None,
+                    help="device trace only: clip to the host annotation "
+                         "of this name")
+    args = ap.parse_args(argv)
     paths = _expand_paths(args.paths)
     if len(paths) > 1:
         summarize_fleet(paths)
-        return
+        return 0
     path = paths[0]
     if not os.path.exists(path):
         sys.exit(f"no such path: {path}")
-    if os.path.isfile(path) and _is_snapshot(path):
+    if _holds_xplane(path):
+        summarize_device(path, args.window)
+        # a Profiler directory holds the host spans beside the device trace
+        if os.path.isdir(path) and any(
+                f.endswith(".json") for f in os.listdir(path)):
+            summarize_trace(path)
+    elif os.path.isfile(path) and _is_snapshot(path):
         summarize_snapshot(path)
     elif os.path.isfile(path) and _is_jsonl(path):
         summarize_steps(path)
     else:
         summarize_trace(path)
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())
