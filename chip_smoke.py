@@ -54,30 +54,32 @@ def device_facts() -> dict:
             "count": len(devs)}
 
 
-def kernel_phase(bh: int, seq: int, head_dim: int) -> dict:
-    """Flash kernel vs dense attention on bf16 inputs, causal: forward and
-    dq/dk/dv. On a TPU this is the compiled Mosaic kernel; on the CPU
+def kernel_phase(batch: int, heads: int, seq: int, head_dim: int) -> dict:
+    """The flash-attention entry the model calls, on [b, s, h, d] bf16
+    inputs, causal, vs dense attention: forward and dq/dk/dv. On a TPU these
+    are the compiled Mosaic kernels of the path the shape takes; on the CPU
     rehearsal, Pallas interpret mode."""
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.ops.pallas.flash_attention import _flash_bhsd, supported
+    from paddle_tpu.ops.pallas.flash_attention import (
+        _path, flash_attention, supported)
 
     require(supported(seq, seq, head_dim),
             f"flash kernel does not take seq={seq} head_dim={head_dim}")
     rng = np.random.RandomState(0)
-    q, k, v, g = (jnp.asarray(rng.randn(bh, seq, head_dim), jnp.bfloat16)
-                  for _ in range(4))
+    q, k, v, g = (jnp.asarray(rng.randn(batch, seq, heads, head_dim),
+                              jnp.bfloat16) for _ in range(4))
     scale = 1.0 / math.sqrt(head_dim)
 
     def flash(q, k, v):
-        return _flash_bhsd(q, k, v, scale, True, None)
+        return flash_attention(q, k, v, causal=True, sm_scale=scale)
 
     def dense(q, k, v):
         q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
-        s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
         s = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), s, -jnp.inf)
-        return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, axis=-1), v)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
 
     def fwd_and_grads(fn):
         out, vjp = jax.vjp(fn, q, k, v)
@@ -101,7 +103,9 @@ def kernel_phase(bh: int, seq: int, head_dim: int) -> dict:
         require(errs[name] <= 2e-2,
                 f"flash {name} differs from dense attention by "
                 f"{errs[name]:.3e} of max|ref| (bound 2e-2)")
-    return {"shape": [bh, seq, head_dim], "rel_err_vs_dense": errs}
+    return {"shape": [batch, seq, heads, head_dim],
+            "path": _path(heads, head_dim, seq, seq, q.dtype)[0],
+            "rel_err_vs_dense": errs}
 
 
 def train_phase(cfg, batch_per_chip: int, seq: int, *, warmup: int = 2,
@@ -370,7 +374,7 @@ def main() -> int:
     say("start", {"compile_cache_dir": compile_cache.cache_dir(),
                   "cache_entries_at_start": compile_cache.entries(),
                   "jax_ready_s": round(time.perf_counter() - _T0, 1)})
-    say("kernel", kernel_phase(batch * cfg.num_heads, seq,
+    say("kernel", kernel_phase(batch, cfg.num_heads, seq,
                                cfg.hidden_size // cfg.num_heads))
     say("train", train_phase(cfg, batch, seq))
     say("serve", serve_phase(cfg))

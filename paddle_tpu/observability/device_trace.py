@@ -29,7 +29,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 # ops/pallas/flash_attention.py, distributed/engine.py, grad_comm.py,
 # serving/engine.py, serving/sampling.py) -------------------------------
 ROOTS = ("prefill", "decode")                       # the serving programs
-KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
+KERNELS = ("flash_fwd", "flash_bwd", "flash_bwd_dkv", "flash_bwd_dq")
 SCOPES = frozenset(ROOTS + KERNELS + (
     "embed", "attn", "qkv", "core", "out", "cache_write", "mlp",
     "final_norm", "lm_head_loss", "lm_head", "sample", "grad_clip",

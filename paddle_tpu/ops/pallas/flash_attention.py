@@ -1,18 +1,42 @@
-"""Pallas TPU flash-attention kernel (forward + backward).
+"""Pallas TPU flash-attention kernels (forward + backward).
 
 This is the TPU-native replacement for the reference's fused CUDA attention
 (`paddle/fluid/operators/fused/fused_attention_op.cu`, `fmha` kernels): an
 online-softmax tiled attention that never materializes the [s, s] score matrix,
-keeping the working set in VMEM and the two matmuls per tile on the MXU.
+keeping the working set in VMEM and the matmuls on the MXU.
 
-Layout: [b, h, s, d] inside the kernels (batch*heads collapsed into one grid
-dim). The public entry `flash_attention` takes paddle's [b, s, h, d].
+The public entries take paddle's [b, s, h, d]. Which kernels a call runs
+depends only on its (local) shapes, `_path`:
 
-Backward follows the FlashAttention-2 scheme: forward saves per-row
-logsumexp; backward recomputes P tile-by-tile, with one kernel producing
-dK/dV (kv-block outer loop) and one producing dQ (q-block outer loop).
+- `packed` (d == 64, even head count) and `head128` (d % 128 == 0): the
+  kernels read q, k, v straight from `[b, s, h*d]` — a reshape, no
+  transpose — in blocks `(1, rows, W)` of W = 128 lanes (two heads of 64) or
+  W = d (one head). (What XLA still copies round the call is its own choice
+  of layouts: PERF.md section 5.) The two heads of a block
+  are separate softmaxes: head g's scores come from a q (or k, v) whose other
+  head's lanes are zeroed, so no product mixes them, and each head keeps an
+  accumulator of its own whose other half is dropped at the end.
+  Everything is computed transposed, S^T = K Q^T of shape [kv rows, q rows],
+  so that a row statistic (max, sum, lse, delta) is a lane-dense [1, q rows]
+  vector that broadcasts over sublanes, and `lse` / `delta` are stored
+  `[b, h, s]` f32, never 128 wide.
+  Forward: grid (b, h*d/W, q blocks); a head's K and V for the whole sequence
+  stay in VMEM and a `fori_loop` walks the kv sub-blocks up to the q block's
+  diagonal and stops; only sub-blocks that touch the diagonal build a mask;
+  q is scaled once.
+  Backward: ONE kernel, grid (b, h*d/W, kv blocks), q sub-blocks from the
+  diagonal down in a `fori_loop`; S, P and dP are computed once a tile, dK
+  and dV accumulate in VMEM for the kv block, dQ in an f32 VMEM scratch of
+  the whole [sq, W] that is written once.
+- `legacy` (any other shape `supported()` admits: d = 32/80/96, an odd head
+  count, a sequence with no 128-row divisor above 1024, or one so long that
+  the whole-sequence blocks above do not fit the VMEM budget): layout
+  [b*h, s, d] with the transposes round the call, a grid over all (q, kv)
+  blocks, and the FlashAttention-2 backward in two kernels (dK/dV with the
+  kv block outer, dQ with the q block outer).
 
-On CPU (tests) the kernels run in Pallas interpret mode.
+`flash.calls.<path>` (observability/metrics.py) counts the choice at trace
+time. On CPU (tests) the kernels run in Pallas interpret mode.
 """
 from __future__ import annotations
 
@@ -23,7 +47,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ...observability import metrics
 from ._common import I0 as _I0, NEG_INF, attention_partition, \
     interpret as _interpret, pick_block as _pick_block, vmem as _vmem
 
@@ -48,7 +74,7 @@ def supported(seq_q: int, seq_k: int, head_dim: int) -> bool:
     )
 
 
-# ---------------------------------------------------------------- forward ----
+# ------------------------------------------- legacy kernels, [b*h, s, d] ----
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, sm_scale, causal, block_q, block_k, kv_blocks):
@@ -140,8 +166,6 @@ def _fwd(q, k, v, sm_scale, causal, blocks=None):
     )(q, k, v)
     return o, lse
 
-
-# --------------------------------------------------------------- backward ----
 
 def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      dk_ref, dv_ref, dk_scr, dv_scr,
@@ -294,7 +318,8 @@ def _bwd(res, g, sm_scale, causal, blocks=None, g_lse=None):
     return dq, dk, dv
 
 
-# ------------------------------------------------------------- public API ----
+# ----------------------------------------------------- legacy custom_vjp ----
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_bhsd(q, k, v, sm_scale, causal, blocks):
@@ -336,34 +361,364 @@ def _flash_lse_bwd_rule(sm_scale, causal, blocks, res, g):
 _flash_bhsd_lse.defvjp(_flash_lse_fwd_rule, _flash_lse_bwd_rule)
 
 
-def _attend(kernel, finish, out_specs, q, k, v, causal, sm_scale):
-    """Run a [b*h, s, d] kernel entry on [b, s, h, d] operands — once per
-    device under a scoped mesh (_common.mesh_scope): GSPMD cannot partition
-    a Mosaic call, so each device gets its own batch and head shard through
-    a shard_map. finish(kernel outputs, b, h, sq, d) restores the paddle
-    layout; out_specs(q's PartitionSpec) gives the outputs' specs."""
+# --------------------------------- packed kernels, [b, s, h*d], transposed ----
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+
+# What the whole-sequence blocks and scratch of a packed call may hold in VMEM
+# (v5e has 128 MiB; the default scoped limit of 16 MiB is too small at s=4096),
+# and the limit the calls ask for: the rest is for the score-sized temporaries.
+_VMEM_RESIDENT = 32 << 20
+_VMEM_LIMIT = 48 << 20
+
+
+def _dot(a, b, dims):
+    # operands stay in their storage dtype (bf16 under amp): the MXU runs at
+    # its bf16 rate and accumulates in f32
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _div(a, b: int):
+    # lax.div on i32: `//` would promote through i64 under jax_enable_x64,
+    # which Mosaic cannot lower
+    return jax.lax.div(a, jnp.int32(b))
+
+
+def _head_masks(heads, hd, rows):
+    """One [rows, heads*hd] lane mask a head of the block; [None] for one."""
+    if heads == 1:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, heads * hd), 1)
+    return [(lane >= g * hd) & (lane < (g + 1) * hd) for g in range(heads)]
+
+
+def _own_lanes(x, mask):
+    """x with the other heads' lanes zeroed: a contraction over all W lanes
+    of it is this head's contraction alone."""
+    return x if mask is None else jnp.where(mask, x, jnp.zeros_like(x))
+
+
+def _merge_heads(parts, masks):
+    """Each head's lanes from its own [rows, W] result."""
+    out = parts[0]
+    for part, mask in zip(parts[1:], masks[1:]):
+        out = jnp.where(mask, part, out)
+    return out
+
+
+def _scaled(x, scale):
+    return (x.astype(jnp.float32) * jnp.float32(scale)).astype(x.dtype)
+
+
+def _causal_mask(s, k0, q0):
+    """s is S^T [kv rows, q rows]; k0 / q0 the tile's first positions."""
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(qpos >= kpos, s, jnp.float32(NEG_INF))
+
+
+def _packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_scr,
+                       *, scale, causal, bq, bk, heads, hd, nk):
+    i = pl.program_id(2)
+    masks = _head_masks(heads, hd, bq)
+    q = _scaled(q_ref[0], scale)                          # [bq, W], once
+    qz = [_own_lanes(q, mask) for mask in masks]
+    if causal:
+        # kv sub-blocks [0, clear) lie wholly under the q block's diagonal,
+        # [clear, stop) touch it, the rest is never visited
+        clear = jnp.minimum(jnp.int32(nk), _div(i * bq, bk))
+        stop = jnp.minimum(jnp.int32(nk), _div((i + 1) * bq + (bk - 1), bk))
+    else:
+        clear = stop = nk
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def step(j, stats, masked):
+        start = pl.multiple_of(j * bk, bk)
+        k = k_ref[0, pl.ds(start, bk), :]                 # [bk, W]
+        v = v_ref[0, pl.ds(start, bk), :]
+        out = []
+        for g in range(heads):
+            m, l = stats[g]                               # [1, bq] f32
+            s = _dot(k, qz[g], _NT)                       # S^T [bk, bq]
+            if masked:
+                s = _causal_mask(s, start, i * bq)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = alpha * l + jnp.sum(p, axis=0, keepdims=True)
+            # O^T [W, bq] with every head's V: the other heads' rows are
+            # dropped by _merge_heads below
+            acc_scr[g] = acc_scr[g] * alpha + _dot(v, p.astype(v.dtype), _TN)
+            out.append((m_new, l))
+        return tuple(out)
+
+    stats = tuple((jnp.full((1, bq), NEG_INF, jnp.float32),
+                   jnp.zeros((1, bq), jnp.float32)) for _ in range(heads))
+    stats = jax.lax.fori_loop(0, clear, functools.partial(step, masked=False),
+                              stats)
+    if causal:
+        stats = jax.lax.fori_loop(clear, stop,
+                                  functools.partial(step, masked=True), stats)
+    outs = []
+    for g in range(heads):
+        m, l = stats[g]
+        # fully-masked rows -> zeros, not NaN. ones_like (not a python 1.0
+        # literal): under jax_enable_x64 the weak literal promotes through
+        # f64 and Mosaic has no f64->f32 cast — caught by the TPU-export gate
+        l = jnp.where(l == 0.0, jnp.ones_like(l), l)
+        outs.append((acc_scr[g] / l).T)                   # [bq, W]
+        lse_ref[0, 0, g:g + 1, :] = m + jnp.log(l)
+    o_ref[0] = _merge_heads(outs, masks).astype(o_ref.dtype)
+
+
+def _packed_call(kernel, grid, in_specs, out_specs, out_shape, scratch, name):
+    return pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(), name=name)
+
+
+def _packed_fwd(q, k, v, scale, causal, heads, hd, blocks):
+    """q [b, sq, H], k, v [b, sk, H] -> o [b, sq, H] and lse
+    [b, H/W, heads, sq] f32, a free reshape of [b, h, sq]."""
+    b, sq, H = q.shape
+    sk = k.shape[1]
+    W = heads * hd
+    bq, bk = blocks
+    kernel = functools.partial(
+        _packed_fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
+        heads=heads, hd=hd, nk=sk // bk)
+    whole_kv = pl.BlockSpec((1, sk, W), lambda b_, h, i: (b_, _I0, h))
+    rows_q = pl.BlockSpec((1, bq, W), lambda b_, h, i: (b_, i, h))
+    return _packed_call(
+        kernel, (b, H // W, sq // bq), [rows_q, whole_kv, whole_kv],
+        [rows_q,
+         pl.BlockSpec((1, 1, heads, bq), lambda b_, h, i: (b_, h, _I0, i))],
+        [jax.ShapeDtypeStruct((b, sq, H), q.dtype),
+         jax.ShapeDtypeStruct((b, H // W, heads, sq), jnp.float32)],
+        [_vmem((heads, W, bq))],
+        # the name reaches the device trace twice: as an element of the op
+        # path (observability/device_trace.py reads kernels by it) and as
+        # the instruction's name (recorded on the chip, PR 26)
+        name="flash_fwd")(q, k, v)
+
+
+def _packed_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dq_ref, dk_ref, dv_ref, qs_scr, dq_scr, dk_scr, dv_scr,
+                       *, scale, causal, bq, bk, heads, hd, nq, nk):
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _first_kv_block():
+        qs_scr[...] = _scaled(q_ref[0], scale)            # q scaled once
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    dk_scr[...] = jnp.zeros_like(dk_scr)
+    dv_scr[...] = jnp.zeros_like(dv_scr)
+    masks = _head_masks(heads, hd, bk)
+    kz = [_own_lanes(k_ref[0], mask) for mask in masks]   # [bk, W]
+    vz = [_own_lanes(v_ref[0], mask) for mask in masks]
+    if causal:
+        # q sub-blocks [0, first) lie wholly above the kv block's diagonal
+        # and are never visited, [first, clear) touch it, the rest is clear
+        first = jnp.minimum(jnp.int32(nq), _div(j * bk, bq))
+        clear = jnp.minimum(jnp.int32(nq), _div((j + 1) * bk + (bq - 1), bq))
+    else:
+        first = clear = 0
+
+    def step(i, carry, masked):
+        start = pl.multiple_of(i * bq, bq)
+        qs = qs_scr[pl.ds(start, bq), :]                  # [bq, W]
+        do = do_ref[0, pl.ds(start, bq), :]
+        for g in range(heads):
+            lse = lse_ref[0, 0, g, pl.ds(i, 1), :]        # [1, bq]
+            delta = delta_ref[0, 0, g, pl.ds(i, 1), :]
+            s = _dot(kz[g], qs, _NT)                      # S^T [bk, bq]
+            if masked:
+                s = _causal_mask(s, j * bk, start)
+            p = jnp.exp(s - lse)
+            # with every head's dO / q: the other heads' lanes of dv_scr[g]
+            # and dk_scr[g] are dropped by _merge_heads below
+            dv_scr[g] += _dot(p.astype(do.dtype), do, _NN)
+            dp = _dot(vz[g], do, _NT)                     # dP^T [bk, bq]
+            ds = (p * (dp - delta)).astype(qs.dtype)
+            dk_scr[g] += _dot(ds, qs, _NN)
+            dq_scr[pl.ds(start, bq), :] += _dot(ds, kz[g], _TN)
+        return carry
+
+    if causal:
+        jax.lax.fori_loop(first, clear, functools.partial(step, masked=True),
+                          None)
+    jax.lax.fori_loop(clear, nq, functools.partial(step, masked=False), None)
+    dk_ref[0] = _merge_heads([dk_scr[g] for g in range(heads)], masks
+                             ).astype(dk_ref.dtype)
+    dv_ref[0] = _merge_heads([dv_scr[g] for g in range(heads)], masks
+                             ).astype(dv_ref.dtype)
+
+    @pl.when(j == nk - 1)
+    def _last_kv_block():
+        # S = (scale * q) k^T, so dq = scale * dS k
+        dq_ref[0] = (dq_scr[...] * jnp.float32(scale)).astype(dq_ref.dtype)
+
+
+def _packed_bwd(res, g_o, g_lse, scale, causal, heads, hd, blocks):
+    q, k, v, o, lse = res
+    b, sq, H = q.shape
+    sk = k.shape[1]
+    W = heads * hd
+    bq, bk = blocks
+    nq, nk = sq // bq, sk // bk
+    delta = jnp.sum((g_o.astype(jnp.float32) * o.astype(jnp.float32)
+                     ).reshape(b, sq, H // hd, hd), axis=-1)     # [b, sq, h]
+    # lse cotangent folds into delta: dS = P*(dP - delta) + P*g_lse
+    #                                    = P*(dP - (delta - g_lse))
+    delta = (jnp.swapaxes(delta, 1, 2).reshape(lse.shape)
+             - g_lse.astype(jnp.float32))
+    stats_shape = (b, H // W, heads, nq, bq)       # a q sub-block a row
+    kernel = functools.partial(
+        _packed_bwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
+        heads=heads, hd=hd, nq=nq, nk=nk)
+    whole_q = pl.BlockSpec((1, sq, W), lambda b_, h, j: (b_, _I0, h))
+    rows_kv = pl.BlockSpec((1, bk, W), lambda b_, h, j: (b_, j, h))
+    stats = pl.BlockSpec((1, 1, heads, nq, bq),
+                         lambda b_, h, j: (b_, h, _I0, _I0, _I0))
+    return _packed_call(
+        kernel, (b, H // W, nk),
+        [whole_q, rows_kv, rows_kv, whole_q, stats, stats],
+        [whole_q, rows_kv, rows_kv],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [_vmem((sq, W), q.dtype), _vmem((sq, W)),
+         _vmem((heads, bk, W)), _vmem((heads, bk, W))],
+        name="flash_bwd")(q, k, v, g_o, lse.reshape(stats_shape),
+                          delta.reshape(stats_shape))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_packed(q, k, v, sm_scale, causal, heads, hd, blocks):
+    """[b, s, h*d] operands -> (o, lse [b, h*d/W, heads, sq]); both carry
+    cotangents (lse's folds into delta in _packed_bwd)."""
+    return _packed_fwd(q, k, v, sm_scale, causal, heads, hd, blocks)
+
+
+def _flash_packed_fwd_rule(q, k, v, sm_scale, causal, heads, hd, blocks):
+    o, lse = _packed_fwd(q, k, v, sm_scale, causal, heads, hd, blocks)
+    return (o, lse), (q, k, v, o, lse)
+
+
+def _flash_packed_bwd_rule(sm_scale, causal, heads, hd, blocks, res, g):
+    return _packed_bwd(res, g[0], g[1], sm_scale, causal, heads, hd, blocks)
+
+
+_flash_packed.defvjp(_flash_packed_fwd_rule, _flash_packed_bwd_rule)
+
+
+def _packed_rows(n: int, preferred: int = 512):
+    """Row counts a packed block may take along a sequence of n: the
+    128-multiples that divide it, largest first, or the whole of a short
+    one (a block equal to the dimension needs no alignment)."""
+    rows = [r for r in (1024, 512, 256, 128) if r <= preferred and n % r == 0]
+    if not rows and n % 8 == 0 and n <= 1024:
+        rows = [n]
+    return rows
+
+
+def _packed_fits(sq, sk, W, heads, itemsize, blocks) -> bool:
+    """Whether the blocks and scratch the two packed kernels keep in VMEM
+    (inputs and outputs twice: the pipeline double-buffers them) stay
+    within _VMEM_RESIDENT."""
+    bq, bk = blocks
+    # forward: q, o blocks and whole k, v, twice; a head's f32 accumulator
+    fwd = (4 * bq + 4 * sk) * W * itemsize + heads * W * bq * 4
+    # backward: whole q, do, dq twice and scaled q once; k, v, dk, dv
+    # blocks twice; f32 dq of the sequence and a head's dk, dv
+    bwd = ((7 * sq + 8 * bk) * W * itemsize
+           + (sq + 2 * heads * bk) * W * 4)
+    return max(fwd, bwd) <= _VMEM_RESIDENT
+
+
+def _path(h, d, sq, sk, dtype):
+    """(path, heads a block) of a call, from what it can see of its local
+    operands alone."""
+    if d == 64 and h % 2 == 0:
+        path, heads = "packed", 2
+    elif d % 128 == 0:
+        path, heads = "head128", 1
+    else:
+        return "legacy", 0
+    if not (_packed_rows(sq) and _packed_rows(sk)) or not _packed_fits(
+            sq, sk, heads * d, heads, jnp.dtype(dtype).itemsize,
+            _static_blocks(path, sq, sk)):
+        return "legacy", 0
+    return path, heads
+
+
+def _static_blocks(path, sq, sk):
+    """The default (q rows, kv rows) of every kernel of the path. On the
+    packed paths they are the forward's q block and kv sub-block and the
+    backward's q sub-block and kv block: 512 x 512 where the sequence
+    divides, the best of the space {128..1024}^2 for both kernels at
+    (b*h, s, d) = (128, 1024, 64), (160, 1024, 64), (64, 1024, 128) and at
+    s = 2048 and 4096 (TPU v5e, PR 27; PERF.md section 6)."""
+    if path == "legacy":
+        return (_pick_block(sq), _pick_block(sk))
+    return (_packed_rows(sq)[0], _packed_rows(sk)[0])
+
+
+# ------------------------------------------------------------- public API ----
+
+def _attend(q, k, v, causal, sm_scale, with_lse):
+    """Attention over [b, s, h, d] operands -> o [b, sq, h, d], and lse
+    [b, h, sq] f32 with it if asked — once per device under a scoped mesh
+    (_common.mesh_scope): GSPMD cannot partition a Mosaic call, so each
+    device gets its own batch and head shard through a shard_map, and the
+    path (module docstring) is chosen on that LOCAL shard's shapes."""
     scale = float(1.0 / math.sqrt(q.shape[-1]) if sm_scale is None
                   else sm_scale)
     causal = bool(causal)
 
     def local(q, k, v):
         b, sq, h, d = q.shape
+        sk = k.shape[1]
+        path, heads = _path(h, d, sq, sk, q.dtype)
+        metrics.default_registry().counter(
+            "flash.calls." + path,
+            "flash-attention calls traced, by the kernels they chose").inc()
+        blocks = tuple(_tuned_blocks(path, heads, b, h, sq, sk, d, q.dtype,
+                                     scale, causal))
+        if path != "legacy":      # [b, s, h, d] -> [b, s, h*d]: no transpose
+            o, lse = _flash_packed(
+                q.reshape(b, sq, h * d), k.reshape(b, sk, h * d),
+                v.reshape(b, sk, h * d), scale, causal, heads, d, blocks)
+            o = o.reshape(b, sq, h, d)
+            return (o, lse.reshape(b, h, sq)) if with_lse else o
 
         def to_bhsd(x):  # [b, s, h, d] -> [b*h, s, d]
             return jnp.swapaxes(x, 1, 2).reshape(b * h, x.shape[1], d)
 
-        blocks = _tuned_blocks(b * h, sq, k.shape[1], d, q.dtype, scale,
-                               causal)
-        return finish(kernel(to_bhsd(q), to_bhsd(k), to_bhsd(v), scale,
-                             causal, tuple(blocks)), b, h, sq, d)
+        def to_bshd(o):
+            return jnp.swapaxes(o.reshape(b, h, sq, d), 1, 2)
+
+        operands = (to_bhsd(q), to_bhsd(k), to_bhsd(v), scale, causal, blocks)
+        if not with_lse:
+            return to_bshd(_flash_bhsd(*operands))
+        o, lse = _flash_bhsd_lse(*operands)
+        return to_bshd(o), lse.reshape(b, h, sq)
 
     part = attention_partition()
     if part is None:
         return local(q, k, v)
     mesh, spec, auto = part
+    lse_spec = jax.sharding.PartitionSpec(spec[0], spec[2], None)
     return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=out_specs(spec), axis_names=auto,
-                         check_vma=False)(q, k, v)
+                         out_specs=(spec, lse_spec) if with_lse else spec,
+                         axis_names=auto, check_vma=False)(q, k, v)
 
 
 def flash_attention_with_lse(q, k, v, causal=False, sm_scale=None):
@@ -373,25 +728,20 @@ def flash_attention_with_lse(q, k, v, causal=False, sm_scale=None):
     KV-block results with online softmax (SURVEY §5.7); both are
     differentiable through the Pallas backward kernels.
     """
-    return _attend(
-        _flash_bhsd_lse,
-        lambda out, b, h, sq, d: (
-            jnp.swapaxes(out[0].reshape(b, h, sq, d), 1, 2),
-            out[1].reshape(b, h, sq)),
-        lambda spec: (spec, jax.sharding.PartitionSpec(spec[0], spec[2], None)),
-        q, k, v, causal, sm_scale)
+    return _attend(q, k, v, causal, sm_scale, with_lse=True)
 
 
-def _tuned_blocks(bh, sq, sk, d, dtype, sm_scale, causal):
+def _tuned_blocks(path, heads, b, h, sq, sk, d, dtype, sm_scale, causal):
     """Block-size choice via the kernel autotune cache (core/autotune.py — the
-    phi AlgorithmsCache analogue). Tuning runs the forward kernel out-of-band
+    phi AlgorithmsCache analogue). Tuning runs forward + backward out-of-band
     on materialized random inputs, so it is legal mid-trace; when autotune is
-    off this collapses to the static heuristic."""
+    off this collapses to the static choice. A tuned pair is (q rows, kv
+    rows) of every kernel of the path."""
     from ...core import autotune
 
-    default = (_pick_block(sq), _pick_block(sk))
-    key = (int(bh), int(sq), int(sk), int(d), str(dtype), bool(causal),
-           jax.default_backend())
+    default = _static_blocks(path, sq, sk)
+    key = (int(b * h), int(sq), int(sk), int(d), str(dtype), bool(causal),
+           jax.default_backend(), path)
     if not autotune.enabled():
         # peek (non-counting): a disabled run must not skew hit-rate stats
         cached = autotune.cache().peek("flash_attention", key)
@@ -401,58 +751,65 @@ def _tuned_blocks(bh, sq, sk, d, dtype, sm_scale, causal):
         return cached
     if not autotune.should_tune():  # closed window / multi-controller: no timing
         return default
-    # 1024 joins the space only where the BACKWARD working set fits: the
-    # tuned choice is shared with the bwd kernels (which the tuner also
-    # compiles + times, see below), whose bodies hold ~4 score-sized f32 intermediates
-    # (s/p/dp/ds) — so the guard budgets 4 * bq * bk * 4 B <= 8 MB of
-    # v5e's 16 MB VMEM, admitting (512,1024)/(1024,512) but not
-    # (1024,1024), whose ~16 MB bwd set would spill or fail Mosaic. At
-    # the bench shape (seq 1024) the {128,256,512} space degenerated to
-    # the heuristic's own choice — the tuned [512,512] equaled
-    # pick_block's default, so the round-5 "autotune win" was run-to-run
-    # variance; the 1024-rect blocks are the first candidates the
-    # heuristic cannot reach.
-    candidates = sorted({(q_, k_)
-                         for q_ in (1024, 512, 256, 128)
-                         for k_ in (1024, 512, 256, 128)
-                         if sq % q_ == 0 and sk % k_ == 0
-                         and 4 * q_ * k_ * 4 <= (8 << 20)}) or [default]
-    if len(candidates) == 1:
-        return candidates[0]
+    if path == "legacy":
+        # 1024 joins the space only where the BACKWARD working set fits: the
+        # bodies hold ~4 score-sized f32 intermediates (s/p/dp/ds), so the
+        # guard budgets 4 * bq * bk * 4 B <= 8 MB, admitting (512,1024) and
+        # (1024,512) but not (1024,1024), whose ~16 MB would spill or fail
+        # Mosaic.
+        candidates = sorted({(q_, k_)
+                             for q_ in (1024, 512, 256, 128)
+                             for k_ in (1024, 512, 256, 128)
+                             if sq % q_ == 0 and sk % k_ == 0
+                             and 4 * q_ * k_ * 4 <= (8 << 20)})
+    else:
+        # what the packed working set admits: rows the sequence divides by
+        # and whole-sequence blocks within _VMEM_RESIDENT
+        candidates = sorted(
+            (q_, k_) for q_ in _packed_rows(sq, 1024)
+            for k_ in _packed_rows(sk, 1024)
+            if _packed_fits(sq, sk, heads * d, heads,
+                            jnp.dtype(dtype).itemsize, (q_, k_)))
+    if len(candidates) < 2:
+        return candidates[0] if candidates else default
 
     rng = np.random.RandomState(0)
-    qa = jnp.asarray(rng.randn(bh, sq, d), dtype=dtype)
-    ka = jnp.asarray(rng.randn(bh, sk, d), dtype=dtype)
-    va = jnp.asarray(rng.randn(bh, sk, d), dtype=dtype)
+    if path == "legacy":
+        shape_q, shape_k = (b * h, sq, d), (b * h, sk, d)
+    else:
+        shape_q, shape_k = (b, sq, h * d), (b, sk, h * d)
+    qa = jnp.asarray(rng.randn(*shape_q), dtype=dtype)
+    ka = jnp.asarray(rng.randn(*shape_k), dtype=dtype)
+    va = jnp.asarray(rng.randn(*shape_k), dtype=dtype)
 
     # one jitted executable per candidate, shared by the warmup and timed calls
     # (a fresh lambda per call would re-compile and time the compiler instead).
-    # The tuned choice binds the FA2 BACKWARD kernels too (the pick is reused
-    # at training time), so each candidate is compiled AND timed through
-    # value_and_grad: fwd + both bwd kernels. A block pair whose backward
-    # fails Mosaic compile raises here and is skipped by pick() — it can no
-    # longer win on forward time and then fail only at training time
-    # (ADVICE r5 #1), and the argmin now optimizes the full train-step cost.
+    # The tuned choice binds the BACKWARD too (the pick is reused at training
+    # time), so each candidate is compiled AND timed through grad: a block
+    # pair whose backward fails Mosaic compile raises here and is skipped by
+    # pick() — it cannot win on forward time and then fail only at training
+    # time (ADVICE r5 #1), and the argmin optimizes the full train-step cost.
     def _make_fb(blocks):
-        def loss(a, b, c):
-            return jnp.sum(
-                _flash_bhsd(a, b, c, sm_scale, causal, blocks)
-                .astype(jnp.float32))
+        def loss(a, b_, c):
+            if path == "legacy":
+                o = _flash_bhsd(a, b_, c, sm_scale, causal, blocks)
+            else:
+                o = _flash_packed(a, b_, c, sm_scale, causal, heads, d,
+                                  blocks)[0]
+            return jnp.sum(o.astype(jnp.float32))
 
         return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
 
     compiled = {blocks: _make_fb(blocks) for blocks in candidates}
 
     def run(blocks):
-        # the grads drain both backward kernels
+        # the grads drain the backward kernels
         jax.block_until_ready(compiled[blocks](qa, ka, va))
 
-    return autotune.pick("flash_attention", key, candidates, run, default=default)
+    return autotune.pick("flash_attention", key, candidates, run,
+                         default=default)
 
 
 def flash_attention(q, k, v, causal: bool = False, sm_scale: float | None = None):
     """q,k,v: [b, s, h, d] (paddle layout). Returns [b, sq, h, d]."""
-    return _attend(
-        _flash_bhsd,
-        lambda o, b, h, sq, d: jnp.swapaxes(o.reshape(b, h, sq, d), 1, 2),
-        lambda spec: spec, q, k, v, causal, sm_scale)
+    return _attend(q, k, v, causal, sm_scale, with_lse=False)

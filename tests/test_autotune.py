@@ -3,6 +3,7 @@
 Reference analogue: phi AlgorithmsCache / switch_autotune step-window tests.
 """
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.core import autotune
@@ -81,30 +82,38 @@ def test_persistence_roundtrip(tmp_path):
     assert c2.get("flash_attention", (96, 1024, 1024)) == (512, 512)
 
 
-def test_flash_attention_uses_tuned_blocks():
+@pytest.mark.parametrize("head_dim", [32, 64])   # legacy, packed kernels
+def test_flash_attention_uses_tuned_blocks(head_dim):
     """End-to-end: tuning picks a block pair and the kernel still matches the
     dense reference (CPU interpret mode; timing is meaningless there but the
-    mechanism must produce a valid, cached choice)."""
+    mechanism must produce a valid, cached choice), forward and backward —
+    the tuned pair binds every kernel of the path."""
+    import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
     autotune.set_config({"kernel": {"enable": True}})
     rng = np.random.RandomState(0)
-    q, k, v = [jnp.asarray(rng.randn(1, 256, 2, 32).astype(np.float32))
+    q, k, v = [jnp.asarray(rng.randn(1, 256, 2, head_dim).astype(np.float32))
                for _ in range(3)]
     out = flash_attention(q, k, v, causal=True)
     assert autotune.cache().size() == 1
     (choice,) = [vv for sub in autotune.cache()._map.values() for vv in sub.values()]
     assert tuple(choice)[0] in (128, 256) and tuple(choice)[1] in (128, 256)
 
-    # dense reference
-    import jax
-    qt, kt, vt = [jnp.swapaxes(x, 1, 2) for x in (q, k, v)]
-    s = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) / np.sqrt(32)
-    m = jnp.tril(jnp.ones(s.shape[-2:], bool))
-    p = jax.nn.softmax(jnp.where(m, s, -1e30), axis=-1)
-    ref = jnp.swapaxes(jnp.einsum("bhqk,bhkd->bhqd", p, vt), 1, 2)
-    np.testing.assert_allclose(out, ref, atol=2e-5)
+    def dense(q, k, v):
+        qt, kt, vt = [jnp.swapaxes(x, 1, 2) for x in (q, k, v)]
+        s = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) / np.sqrt(head_dim)
+        m = jnp.tril(jnp.ones(s.shape[-2:], bool))
+        p = jax.nn.softmax(jnp.where(m, s, -1e30), axis=-1)
+        return jnp.swapaxes(jnp.einsum("bhqk,bhkd->bhqd", p, vt), 1, 2)
+
+    np.testing.assert_allclose(out, dense(q, k, v), atol=2e-5)
+    # a second call reads the cached pair, and the backward takes it too
+    got = jax.grad(lambda q: flash_attention(q, k, v, causal=True).sum())(q)
+    want = jax.grad(lambda q: dense(q, k, v).sum())(q)
+    np.testing.assert_allclose(got, want, atol=5e-5)
+    assert autotune.cache().size() == 1
 
 
 def test_incubate_surface():

@@ -37,8 +37,9 @@ def test_smoke_phases_run_at_tiny_size():
     import chip_smoke
     from paddle_tpu.models import gpt_tiny
 
-    k = chip_smoke.kernel_phase(4, 128, 32)
+    k = chip_smoke.kernel_phase(1, 4, 128, 64)
     assert set(k["rel_err_vs_dense"]) == {"out", "dq", "dk", "dv"}
+    assert k["path"] == "packed"
 
     t = chip_smoke.train_phase(gpt_tiny(), 1, 64, warmup=1, steps=2)
     assert t["dp"] == jax.device_count() and t["batch"] == t["dp"]

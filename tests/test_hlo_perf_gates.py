@@ -238,23 +238,39 @@ def _export_tpu(fn, *avals):
 
 
 @pytest.mark.slow
-def test_flash_attention_mosaic_compiles_for_tpu(monkeypatch):
-    """Lower fwd+bwd for the REAL TPU target (Mosaic) from the CPU host.
+@pytest.mark.parametrize("shape, dtype, kernels", [
+    # the shape the gate always had: f32, 4 heads of 64 -> packed
+    ((2, 256, 4, 64), jnp.float32, ("flash_fwd", "flash_bwd")),
+    # the train cell's call (GPT-2 medium, 8 x 1024, 16 heads of 64)
+    ((8, 1024, 16, 64), jnp.bfloat16, ("flash_fwd", "flash_bwd")),
+    # one head a 128-lane block
+    ((4, 1024, 8, 128), jnp.bfloat16, ("flash_fwd", "flash_bwd")),
+    # an odd head count keeps the [b*h, s, d] kernels and their two backwards
+    ((2, 256, 3, 64), jnp.float32,
+     ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")),
+])
+def test_flash_attention_mosaic_compiles_for_tpu(monkeypatch, shape, dtype,
+                                                 kernels):
+    """Lower fwd+bwd for the REAL TPU target (Mosaic) from the CPU host, every
+    path of flash_attention._path.
 
     Interpret-mode tests verify numerics but not Mosaic legality; this caught
     an f64 weak-literal cast in the masked-row fix that would have failed on
-    chip (flash_attention.py:_finalize)."""
+    chip (flash_attention.py:_finalize), and an i64 floor-divide in the
+    packed kernels' loop bounds (jax_enable_x64 promotes `//`)."""
     monkeypatch.setattr(_FA, "_interpret", lambda: False)
     paddle.set_flags({"use_flash_attention": True, "pallas_interpret_ok": True})
     from paddle_tpu.ops import nn_functional as F
 
     def att_loss(qd):
         t = Tensor(qd)
-        return F.scaled_dot_product_attention(t, t, t, is_causal=True)._data.sum()
+        out = F.scaled_dot_product_attention(t, t, t, is_causal=True)._data
+        return out.astype(jnp.float32).sum()
 
-    mod = _export_tpu(jax.grad(att_loss),
-                      jax.ShapeDtypeStruct((2, 256, 4, 64), jnp.float32))
-    assert "tpu_custom_call" in mod
+    mod = _export_tpu(jax.grad(att_loss), jax.ShapeDtypeStruct(shape, dtype))
+    assert mod.count("tpu_custom_call") >= len(kernels)
+    for name in ("flash_fwd", "flash_bwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert (f'kernel_name = "{name}"' in mod) == (name in kernels), name
 
 
 @pytest.mark.slow

@@ -232,6 +232,8 @@ def test_decode_program_stablehlo_identical_without_scopes(tiny_gpt,
      ("attn/core", False, "flash_fwd")),
     ("jit(step)/transpose(jvp(attn))/core/flash_bwd_dkv/pallas_call",
      ("attn/core", True, "flash_bwd_dkv")),
+    ("jit(step)/transpose(jvp(attn))/core/flash_bwd/pallas_call",
+     ("attn/core", True, "flash_bwd")),
     ("jit(step)/transpose(jvp(lm_head_loss))/jit(fwd)/lm_head_loss/while/"
      "body/closed_call/dot_general", ("lm_head_loss", True, None)),
     ("jit(step_chunk)/decode/while/body/closed_call/attn/cache_write/"
@@ -580,7 +582,10 @@ def test_scopes_sum_to_busy_time(reduced):
 
 @needs_probe
 def test_kernels_are_found_by_name(reduced):
-    assert set(reduced["by_kernel"]) == set(device_trace.KERNELS)
+    # the probe was recorded from PR 26's program, whose d=64 model ran the
+    # [b*h, s, d] kernels: the packed paths' `flash_bwd` is not in it
+    assert set(reduced["by_kernel"]) == set(device_trace.KERNELS) - {
+        "flash_bwd"}
     assert all(v > 0 for v in reduced["by_kernel"].values())
     core = reduced["by_scope"]["attn/core"]
     assert reduced["by_kernel"]["flash_fwd"] <= core["fwd"]
