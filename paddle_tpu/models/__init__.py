@@ -2,6 +2,9 @@ from .gpt import (  # noqa: F401
     GPTConfig, GPTForPretraining, GPTForPretrainingPipe, GPTModel, gpt_tiny,
     gpt_1p3b, gpt_345m,
 )
+from .afmoe import (  # noqa: F401
+    AfmoeConfig, AfmoeForCausalLM, AfmoeModel, afmoe_tiny,
+)
 from .ernie import (  # noqa: F401
     BertConfig, BertForPretraining, BertModel, ErnieConfig, ErnieForPretraining,
     ErnieModel, bert_base, bert_large, ernie_base, ernie_large, ernie_tiny,

@@ -556,6 +556,24 @@ class GPTForPretraining(nn.Layer):
                 return L.matmul(h, self.gpt.wte.weight, transpose_y=True)
             return self.lm_head(h)
 
+    # ---- what ServingEngine asks of a model (serving/kv_state.py) ------
+    # a decode step reports nothing beside its tokens
+    serving_step_stats = {}
+
+    def serving_backbone(self):
+        """(the layer called with (ids, caches=...), its prefix in
+        state_dict)."""
+        return self.gpt, "gpt."
+
+    def kv_cache_spec(self, max_seq_len: int):
+        """Every layer keeps every position of a slot."""
+        from ..serving.kv_state import KVLayerSpec
+
+        cfg = self.config
+        return [KVLayerSpec("full", max_seq_len, cfg.num_heads,
+                            cfg.hidden_size // cfg.num_heads)
+                ] * cfg.num_layers
+
     def decode_exec_registry(self):
         """This model's decode ExecutableRegistry (generate/generate_beam
         executables, LRU-bounded by FLAGS_decode_jit_cache_size). Public so

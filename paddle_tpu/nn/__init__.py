@@ -32,6 +32,7 @@ from .layers.norm import (  # noqa: F401
     InstanceNorm2D, InstanceNorm3D, LayerNorm, LocalResponseNorm, RMSNorm,
     SyncBatchNorm,
 )
+from .layers.routed_experts import RoutedExperts  # noqa: F401
 from .layers.transformer import (  # noqa: F401
     MultiHeadAttention, Transformer, TransformerDecoder, TransformerDecoderLayer,
     TransformerEncoder, TransformerEncoderLayer,

@@ -25,15 +25,20 @@ import os
 import re
 from typing import Dict, Iterator, List, Optional, Tuple
 
-# ---- the vocabulary (paddle_tpu/models/gpt.py, ops/fused.py,
-# ops/pallas/flash_attention.py, distributed/engine.py, grad_comm.py,
-# serving/engine.py, serving/sampling.py) -------------------------------
+# ---- the vocabulary (paddle_tpu/models/gpt.py, models/afmoe.py,
+# nn/layers/routed_experts.py, ops/fused.py, ops/pallas/flash_attention.py,
+# distributed/engine.py, grad_comm.py, serving/engine.py,
+# serving/sampling.py) ---------------------------------------------------
 ROOTS = ("prefill", "decode")                       # the serving programs
 KERNELS = ("flash_fwd", "flash_bwd", "flash_bwd_dkv", "flash_bwd_dq")
 SCOPES = frozenset(ROOTS + KERNELS + (
     "embed", "attn", "qkv", "core", "out", "cache_write", "mlp",
     "final_norm", "lm_head_loss", "lm_head", "sample", "grad_clip",
-    "optimizer", "fsdp_gather", "grad_sync"))
+    "optimizer", "fsdp_gather", "grad_sync",
+    # the afmoe block: attn > qk_norm, rope, gate; moe > router, dispatch,
+    # experts, shared, combine
+    "qk_norm", "rope", "gate", "moe", "router", "dispatch", "experts",
+    "shared", "combine"))
 SPAN_PREFIXES = ("serve.", "engine.")               # the engines' spans
 
 UNNAMED = "unnamed"           # an op_name, and no scope of the vocabulary

@@ -14,7 +14,8 @@ resident-program philosophy of MPK, arxiv 2512.22219):
   true prompt length, target slot, sampling params, and seed are all
   traced, so a whole traffic distribution shares O(#rungs) executables.
 - **a single-token decode step**, ONE executable total: operates on the
-  fixed [slots, max_seq_len, nh, hd] donated KV cache with per-slot write
+  fixed donated slot KV cache (one [slots, rows, kv_heads, head_dim] pair a
+  layer, as the model declares it: kv_state.py) with per-slot write
   offsets, per-slot sampling params (traced — mixed greedy/top-k/top-p
   share the program), per-slot EOS/budget masks, and per-slot RNG streams.
 
@@ -43,6 +44,7 @@ from ..observability import exporter as _obs_exporter
 from ..observability import flight_recorder as _obs_flight
 from ..observability import metrics as _obs_metrics
 from ..observability import tracer as _obs_tracer
+from . import kv_state as _kvs
 from .bucketing import DEFAULT_LADDER, bucket_for, clip_ladder
 
 _NO_EOS = -1
@@ -154,9 +156,15 @@ class Request:
 
 
 class ServingEngine:
-    """Continuous-batching GPT serving over a slot-based KV cache.
+    """Continuous-batching decoder serving over a slot-based KV cache.
 
-    model: a GPTForPretraining (eval mode is forced). slot_count fixes the
+    model: a causal LM that says what the engine needs of it and nothing of
+    its architecture (GPTForPretraining, AfmoeForCausalLM): `config`
+    (`vocab_size`, `max_seq_len`), `serving_backbone()` (the layer called
+    with `(ids, caches=...)` and its prefix in `state_dict`),
+    `kv_cache_spec(max_seq_len)` (what each layer keeps a slot: kv_state.py),
+    `_head_logits(h)`, and `serving_step_stats` (what a decode step reports
+    beside its tokens). Eval mode is forced. slot_count fixes the
     decode batch; ladder the prefill rungs (clipped to what fits
     max_seq_len with max_new_cap headroom). Weights are snapshotted (and
     pre-cast to the active AMP compute dtype) at construction — call
@@ -251,14 +259,26 @@ class ServingEngine:
 
         self.refresh_params()
 
-        nh = cfg.num_heads
-        hd = cfg.hidden_size // cfg.num_heads
         S, T = self.slot_count, self.max_seq_len
         if kv_layout not in ("contiguous", "paged"):
             raise ValueError(
                 f"kv_layout must be 'contiguous' or 'paged', got {kv_layout!r}")
         self.kv_layout = kv_layout
+        self._kv_spec = _kvs.spec_of(model, T)
+        windows = _kvs.window_layers(self._kv_spec)
+        if windows and kv_layout == "paged":
+            raise ValueError(
+                f"kv_layout='paged' cannot hold this model: layers {windows} "
+                "keep a window of rows, and kv_pages.py has one page table "
+                "for all layers and no ring of pages; use 'contiguous'")
+        if windows and draft_model is not None:
+            raise ValueError(
+                f"speculative decoding cannot run this model: layers "
+                f"{windows} keep a window of rows as a ring, and the verify "
+                "program rewinds rejected rows by offset, which a ring "
+                "overwrites; construct the engine without draft_model")
         if kv_layout == "paged":
+            nh, hd = self._kv_spec[0].kv_heads, self._kv_spec[0].head_dim
             # paged KV: per-layer page pools + ONE [slots, max_pages] page
             # table traced into prefill/decode as a gather index
             # (kv_pages.py). Shapes stay static so the two-executable
@@ -286,30 +306,27 @@ class ServingEngine:
             self._pool = _kvp.PagePool(self.num_pages)
             self._prefix = RadixPrefixCache(self._pool, pt)
             self._pool_state = _kvp.make_pool_state(
-                cfg.num_layers, self.num_pages, pt, nh, hd, S,
+                len(self._kv_spec), self.num_pages, pt, nh, hd, S,
                 self.max_pages, self._store_dtype, self._kv_quantized)
             self._tables = np.zeros((S, self.max_pages), np.int32)
             self._slot_pages: List[List[int]] = [[] for _ in range(S)]
             self._replay = np.zeros(S, bool)
             self._kcs = self._vcs = None
         else:
-            self._kcs = [jnp.zeros((S, T, nh, hd), self._cache_dtype)
-                         for _ in range(cfg.num_layers)]
-            self._vcs = [jnp.zeros((S, T, nh, hd), self._cache_dtype)
-                         for _ in range(cfg.num_layers)]
+            self._kcs, self._vcs = _kvs.allocate(self._kv_spec, S,
+                                                 self._cache_dtype)
 
         # draft KV cache: always slot-contiguous (draft rows rewind by
         # offset alone — rejected rows go stale-but-inert under the causal
         # mask, so the draft never needs page bookkeeping even when the
         # target cache is paged)
         if draft_model is not None:
-            dcfg = draft_model.config
-            dnh = dcfg.num_heads
-            dhd = dcfg.hidden_size // dcfg.num_heads
-            self._dkcs = [jnp.zeros((S, T, dnh, dhd), self._cache_dtype)
-                          for _ in range(dcfg.num_layers)]
-            self._dvcs = [jnp.zeros((S, T, dnh, dhd), self._cache_dtype)
-                          for _ in range(dcfg.num_layers)]
+            self._dkv_spec = _kvs.spec_of(draft_model, T)
+            if _kvs.window_layers(self._dkv_spec):
+                raise ValueError("a draft model with window layers cannot "
+                                 "rewind its cache by offset")
+            self._dkcs, self._dvcs = _kvs.allocate(self._dkv_spec, S,
+                                                   self._cache_dtype)
         else:
             self._dkcs = self._dvcs = None
 
@@ -358,16 +375,19 @@ class ServingEngine:
         state = self.model.state_dict(include_non_persistable_buffer=True)
         params = {k: v._data for k, v in state.items()}
         mm_dtype = _autocast_dtype_for("attention", ())
+        backbone, _ = self.model.serving_backbone()
         self._cache_dtype = (mm_dtype if mm_dtype is not None
-                             else self.model.gpt.wte.weight._data.dtype)
+                             else backbone.parameters()[0]._data.dtype)
         w_dtype = _autocast_dtype_for("matmul", ())
 
         def _cast(params):
+            # an array that already has the compute dtype is kept as it is:
+            # the snapshot then adds no second copy of it (D16)
             if w_dtype is None:
                 return params
             return {k: (v.astype(w_dtype)
-                        if v.ndim >= 2 and jnp.issubdtype(
-                            v.dtype, jnp.floating) else v)
+                        if v.ndim >= 2 and v.dtype != w_dtype
+                        and jnp.issubdtype(v.dtype, jnp.floating) else v)
                     for k, v in params.items()}
 
         self._params = _cast(params)
@@ -398,6 +418,12 @@ class ServingEngine:
             if speculate_k < 0:
                 raise ValueError(
                     f"speculate_k must be >= 0, got {speculate_k}")
+            windows = _kvs.window_layers(self._kv_spec)
+            if windows:
+                raise ValueError(
+                    f"speculate_k > 0 cannot be served: layers {windows} "
+                    "keep a window of rows as a ring, which the verify "
+                    "program's rewind by offset would overwrite")
             if self.draft_model is None:
                 raise ValueError(
                     "speculate_k > 0 needs a draft model: construct the "
@@ -583,8 +609,7 @@ class ServingEngine:
             from . import kv_pages as _kvp
 
             return _kvp.pool_state_bytes(self._pool_state)
-        return sum(int(a.size) * a.dtype.itemsize
-                   for a in (*self._kcs, *self._vcs))
+        return _kvs.cache_bytes((*self._kcs, *self._vcs))
 
     def prefix_match_len(self, prompt_ids) -> int:
         """Tokens of this prompt already cached as shared pages (0 on the
@@ -862,33 +887,36 @@ class ServingEngine:
                 no_grad():
             return self.draft_model._head_logits(Tensor(h_arr))._data
 
+    @staticmethod
+    def _backbone(model, params, ids, caches):
+        """`model`'s backbone over `ids` with weights from traced params ->
+        (hidden states, new caches, what the step reports of itself: {} for
+        a model that reports nothing)."""
+        from ..core.tensor import Tensor
+        from ..jit import functional_call
+
+        layer, prefix = model.serving_backbone()
+        own = {k[len(prefix):]: v for k, v in params.items()
+               if k.startswith(prefix)}
+        out = functional_call(layer, own, Tensor(ids), caches=caches)
+        return out[0]._data, out[1], (out[2] if len(out) > 2 else {})
+
     # ---- prefill -------------------------------------------------------
     def _build_prefill(self, bucket: int):
         import jax
-        import jax.numpy as jnp
 
-        from ..core.tensor import Tensor
-        from ..jit import functional_call
         from .sampling import request_key, sample_tokens
 
-        cfg = self.model.config
-        nh = cfg.num_heads
-        hd = cfg.hidden_size // cfg.num_heads
+        spec = self._kv_spec
         cache_dtype = self._cache_dtype
-        gpt = self.model.gpt
 
         def prefill(params, kcs, vcs, ids, plen, slot, temp, top_k, top_p,
                     seed):
-            gpt_params = {k[len("gpt."):]: v for k, v in params.items()
-                          if k.startswith("gpt.")}
             # fresh request-local cache sized to the rung; causal masking
             # makes the right-pad inert (queries past plen are discarded)
-            caches = [(Tensor(jnp.zeros((1, bucket, nh, hd), cache_dtype)),
-                       Tensor(jnp.zeros((1, bucket, nh, hd), cache_dtype)),
-                       Tensor(jnp.int32(0))) for _ in range(cfg.num_layers)]
-            h, caches = functional_call(gpt, gpt_params, Tensor(ids),
-                                        caches=caches)
-            last_h = jax.lax.dynamic_index_in_dim(h._data, plen - 1, 1,
+            caches = _kvs.request_local(spec, bucket, cache_dtype)
+            h, caches, _ = self._backbone(self.model, params, ids, caches)
+            last_h = jax.lax.dynamic_index_in_dim(h, plen - 1, 1,
                                                   keepdims=False)
             logits = self._head_traced(params, last_h)       # [1, V]
             key = request_key(seed, plen)  # first token sits at position plen
@@ -896,12 +924,11 @@ class ServingEngine:
                                 top_p[None])[0]
             # scatter this request's K/V into its slot row of the big cache
             new_kcs, new_vcs = [], []
-            start = (slot, jnp.int32(0), jnp.int32(0), jnp.int32(0))
-            for big_k, big_v, layer in zip(kcs, vcs, caches):
-                new_kcs.append(jax.lax.dynamic_update_slice(
-                    big_k, layer[0]._data.astype(big_k.dtype), start))
-                new_vcs.append(jax.lax.dynamic_update_slice(
-                    big_v, layer[1]._data.astype(big_v.dtype), start))
+            for kind, big_k, big_v, layer in zip(spec, kcs, vcs, caches):
+                new_kcs.append(_kvs.scatter_prefill(
+                    kind, big_k, layer[0]._data, slot, plen))
+                new_vcs.append(_kvs.scatter_prefill(
+                    kind, big_v, layer[1]._data, slot, plen))
             return new_kcs, new_vcs, tok
 
         return jax.jit(jax.named_scope("prefill")(prefill),
@@ -916,20 +943,15 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..core.tensor import Tensor
-        from ..jit import functional_call
         from . import kv_pages as _kvp
         from .sampling import request_key, sample_tokens
 
-        gpt = self.model.gpt
         pt = self.page_tokens
         quant = self._kv_quantized
         compute_dtype = self._cache_dtype
 
         def prefill(params, state, ids, tail_len, base, slot, temp, top_k,
                     top_p, seed):
-            gpt_params = {k[len("gpt."):]: v for k, v in params.items()
-                          if k.startswith("gpt.")}
             table_row = jax.lax.dynamic_slice_in_dim(
                 state["tables"], slot, 1, 0)                 # [1, max_pages]
             # pad positions past the tail redirect to the scratch page:
@@ -939,9 +961,8 @@ class ServingEngine:
                      < tail_len)                             # [1, bucket]
             caches = _kvp.layer_views(state, table_row, base[None], wmask,
                                       pt, compute_dtype)
-            h, caches = functional_call(gpt, gpt_params, Tensor(ids),
-                                        caches=caches)
-            last_h = jax.lax.dynamic_index_in_dim(h._data, tail_len - 1, 1,
+            h, caches, _ = self._backbone(self.model, params, ids, caches)
+            last_h = jax.lax.dynamic_index_in_dim(h, tail_len - 1, 1,
                                                   keepdims=False)
             logits = self._head_traced(params, last_h)       # [1, V]
             key = request_key(seed, base + tail_len)  # abs first-token pos
@@ -971,30 +992,20 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..core.tensor import Tensor
-        from ..jit import functional_call
-
-        dcfg = self.draft_model.config
-        nh = dcfg.num_heads
-        hd = dcfg.hidden_size // dcfg.num_heads
+        spec = self._dkv_spec
         cache_dtype = self._cache_dtype
-        dgpt = self.draft_model.gpt
 
         def prefill(dparams, dkcs, dvcs, ids, slot):
-            dgpt_params = {k[len("gpt."):]: v for k, v in dparams.items()
-                           if k.startswith("gpt.")}
-            caches = [(Tensor(jnp.zeros((1, bucket, nh, hd), cache_dtype)),
-                       Tensor(jnp.zeros((1, bucket, nh, hd), cache_dtype)),
-                       Tensor(jnp.int32(0))) for _ in range(dcfg.num_layers)]
-            _h, caches = functional_call(dgpt, dgpt_params, Tensor(ids),
-                                         caches=caches)
-            new_kcs, new_vcs = [], []
-            start = (slot, jnp.int32(0), jnp.int32(0), jnp.int32(0))
-            for big_k, big_v, layer in zip(dkcs, dvcs, caches):
-                new_kcs.append(jax.lax.dynamic_update_slice(
-                    big_k, layer[0]._data.astype(big_k.dtype), start))
-                new_vcs.append(jax.lax.dynamic_update_slice(
-                    big_v, layer[1]._data.astype(big_v.dtype), start))
+            caches = _kvs.request_local(spec, bucket, cache_dtype)
+            _h, caches, _ = self._backbone(self.draft_model, dparams, ids,
+                                           caches)
+            plen = jnp.int32(bucket)      # every layer is `full`: not read
+            new_kcs = [_kvs.scatter_prefill(kind, big, layer[0]._data, slot,
+                                            plen)
+                       for kind, big, layer in zip(spec, dkcs, caches)]
+            new_vcs = [_kvs.scatter_prefill(kind, big, layer[1]._data, slot,
+                                            plen)
+                       for kind, big, layer in zip(spec, dvcs, caches)]
             return new_kcs, new_vcs
 
         return jax.jit(jax.named_scope("prefill")(prefill),
@@ -1345,19 +1356,14 @@ class ServingEngine:
         import jax.numpy as jnp
 
         from ..core.tensor import Tensor
-        from ..jit import functional_call
         from .sampling import request_key, sample_tokens
 
-        gpt = self.model.gpt
         T = self.max_seq_len
         n_inner = self.steps_per_dispatch
         greedy_only = family == "greedy"
 
         def step_chunk(params, kcs, vcs, off, tok, active, temps, top_k,
                        top_p, eos, remaining, seeds):
-            gpt_params = {k[len("gpt."):]: v for k, v in params.items()
-                          if k.startswith("gpt.")}
-
             def one(carry, _):
                 kcs, vcs, off, tok, active, remaining = carry
                 # idle slots keep writing their (ignored) tip row; clamp so
@@ -1365,10 +1371,10 @@ class ServingEngine:
                 off_m = jnp.minimum(off, jnp.int32(T - 1))
                 caches = [(Tensor(kc), Tensor(vc), Tensor(off_m))
                           for kc, vc in zip(kcs, vcs)]
-                h, caches = functional_call(
-                    gpt, gpt_params, Tensor(tok[:, None].astype(jnp.int64)),
-                    caches=caches)
-                logits = self._head_traced(params, h._data[:, 0])  # [S, V]
+                h, caches, stats = self._backbone(
+                    self.model, params, tok[:, None].astype(jnp.int64),
+                    caches)
+                logits = self._head_traced(params, h[:, 0])  # [S, V]
                 act = active.astype(jnp.int32)
                 new_off = off + act         # the sampled token's position
                 if greedy_only:
@@ -1384,15 +1390,16 @@ class ServingEngine:
                 new_kcs = [c[0]._data for c in caches]
                 new_vcs = [c[1]._data for c in caches]
                 return ((new_kcs, new_vcs, new_off, nxt, new_active,
-                         new_remaining), (nxt, active, hit_eos))
+                         new_remaining), (nxt, active, hit_eos, stats))
 
             carry = (kcs, vcs, off, tok, active, remaining)
-            (kcs, vcs, off, tok, active, remaining), (toks, was_active,
-                                                      hits) = jax.lax.scan(
+            (kcs, vcs, off, tok, active, remaining), (
+                toks, was_active, hits, stats) = jax.lax.scan(
                 one, carry, None, length=n_inner)
-            # toks/was_active/hits: [n_inner, S]
+            # toks/was_active/hits: [n_inner, S]; stats: what the model
+            # reports of a step ({} for most), folded over the fused steps
             return (kcs, vcs, off, tok, active, remaining, toks, was_active,
-                    hits)
+                    hits, self._fold_step_stats(stats))
 
         return jax.jit(jax.named_scope("decode")(step_chunk),
                        donate_argnums=(1, 2))
@@ -1408,12 +1415,9 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..core.tensor import Tensor
-        from ..jit import functional_call
         from . import kv_pages as _kvp
         from .sampling import request_key, sample_tokens
 
-        gpt = self.model.gpt
         T = self.max_seq_len
         t_eff = self._t_eff
         n_inner = self.steps_per_dispatch
@@ -1424,8 +1428,6 @@ class ServingEngine:
 
         def step_chunk(params, state, off, tok, active, replay, temps,
                        top_k, top_p, eos, remaining, seeds):
-            gpt_params = {k[len("gpt."):]: v for k, v in params.items()
-                          if k.startswith("gpt.")}
             tables = state["tables"]
 
             def one(carry, _):
@@ -1436,10 +1438,10 @@ class ServingEngine:
                 caches = _kvp.layer_views(st, tables, off_m,
                                           active & ~replay, pt,
                                           compute_dtype)
-                h, caches = functional_call(
-                    gpt, gpt_params, Tensor(tok[:, None].astype(jnp.int64)),
-                    caches=caches)
-                logits = self._head_traced(params, h._data[:, 0])  # [S, V]
+                h, caches, _ = self._backbone(
+                    self.model, params, tok[:, None].astype(jnp.int64),
+                    caches)
+                logits = self._head_traced(params, h[:, 0])  # [S, V]
                 act = active.astype(jnp.int32)
                 new_off = off + act         # the sampled token's position
                 if greedy_only:
@@ -1609,28 +1611,20 @@ class ServingEngine:
         import jax.numpy as jnp
 
         from ..core.tensor import Tensor
-        from ..jit import functional_call
         from .sampling import DRAFT_SALT, sample_tokens, spec_key
 
-        gpt = self.model.gpt
-        dgpt = self.draft_model.gpt
         greedy_only = family == "greedy"
 
         def verify(params, dparams, kcs, vcs, dkcs, dvcs, off, tok, active,
                    n_draft, temps, top_k, top_p, eos, remaining, seeds):
-            gpt_params = {n[len("gpt."):]: v for n, v in params.items()
-                          if n.startswith("gpt.")}
-            dgpt_params = {n[len("gpt."):]: v for n, v in dparams.items()
-                           if n.startswith("gpt.")}
-
             def dstep(carry, i):
                 dkcs, dvcs, cur = carry
                 caches = [(Tensor(kc), Tensor(vc), Tensor(off + i))
                           for kc, vc in zip(dkcs, dvcs)]
-                h, caches = functional_call(
-                    dgpt, dgpt_params,
-                    Tensor(cur[:, None].astype(jnp.int64)), caches=caches)
-                dlogits = self._draft_head_traced(dparams, h._data[:, 0])
+                h, caches, _ = self._backbone(
+                    self.draft_model, dparams,
+                    cur[:, None].astype(jnp.int64), caches)
+                dlogits = self._draft_head_traced(dparams, h[:, 0])
                 if greedy_only:
                     d = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
                     out = d
@@ -1660,12 +1654,11 @@ class ServingEngine:
             win = jnp.concatenate([tok[:, None], props], axis=1)
             caches = [(Tensor(kc), Tensor(vc), Tensor(off))
                       for kc, vc in zip(kcs, vcs)]
-            h, caches = functional_call(gpt, gpt_params,
-                                        Tensor(win.astype(jnp.int64)),
-                                        caches=caches)
+            h, caches, _ = self._backbone(self.model, params,
+                                          win.astype(jnp.int64), caches)
             S = win.shape[0]
             logits = self._head_traced(
-                params, h._data.reshape((S * (k + 1), -1))
+                params, h.reshape((S * (k + 1), -1))
             ).reshape((S, k + 1, -1))
             kcs = [c[0]._data for c in caches]
             vcs = [c[1]._data for c in caches]
@@ -1692,12 +1685,9 @@ class ServingEngine:
         import jax.numpy as jnp
 
         from ..core.tensor import Tensor
-        from ..jit import functional_call
         from . import kv_pages as _kvp
         from .sampling import DRAFT_SALT, sample_tokens, spec_key
 
-        gpt = self.model.gpt
-        dgpt = self.draft_model.gpt
         greedy_only = family == "greedy"
         pt = self.page_tokens
         quant = self._kv_quantized
@@ -1706,20 +1696,16 @@ class ServingEngine:
         def verify(params, dparams, state, dkcs, dvcs, off, tok, active,
                    replay, n_draft, temps, top_k, top_p, eos, remaining,
                    seeds):
-            gpt_params = {n[len("gpt."):]: v for n, v in params.items()
-                          if n.startswith("gpt.")}
-            dgpt_params = {n[len("gpt."):]: v for n, v in dparams.items()
-                           if n.startswith("gpt.")}
             tables = state["tables"]
 
             def dstep(carry, i):
                 dkcs, dvcs, cur = carry
                 caches = [(Tensor(kc), Tensor(vc), Tensor(off + i))
                           for kc, vc in zip(dkcs, dvcs)]
-                h, caches = functional_call(
-                    dgpt, dgpt_params,
-                    Tensor(cur[:, None].astype(jnp.int64)), caches=caches)
-                dlogits = self._draft_head_traced(dparams, h._data[:, 0])
+                h, caches, _ = self._backbone(
+                    self.draft_model, dparams,
+                    cur[:, None].astype(jnp.int64), caches)
+                dlogits = self._draft_head_traced(dparams, h[:, 0])
                 if greedy_only:
                     d = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
                     out = d
@@ -1751,12 +1737,11 @@ class ServingEngine:
                   "vs": state["vs"]}
             caches = _kvp.layer_views(st, tables, off, wmask, pt,
                                       compute_dtype)
-            h, caches = functional_call(gpt, gpt_params,
-                                        Tensor(win.astype(jnp.int64)),
-                                        caches=caches)
+            h, caches, _ = self._backbone(self.model, params,
+                                          win.astype(jnp.int64), caches)
             S = win.shape[0]
             logits = self._head_traced(
-                params, h._data.reshape((S * (k + 1), -1))
+                params, h.reshape((S * (k + 1), -1))
             ).reshape((S, k + 1, -1))
             new_state = {
                 "k": [c.k_pool for c in caches],
@@ -2074,9 +2059,10 @@ class ServingEngine:
                 if paged:
                     (self._pool_state, off, tok, active, replay, remaining,
                      toks, was_active, hits) = entry(*call_args)
+                    stats = {}
                 else:
                     (self._kcs, self._vcs, off, tok, active, remaining, toks,
-                     was_active, hits) = entry(*call_args)
+                     was_active, hits, stats) = entry(*call_args)
                 self._execs.note_compiles(
                     entry, wall_s=time.perf_counter() - t0,
                     persistent_before=p0, counter="serving.decode_compiles")
@@ -2097,6 +2083,7 @@ class ServingEngine:
                 toks = np.asarray(toks)           # [n_inner, S]
                 was_active = np.asarray(was_active)
                 hits = np.asarray(hits)
+                stats = {name: float(v) for name, v in stats.items()}
             self._fetch_end = fetch.t1
         except Exception as e:
             fr = _obs_flight.get()
@@ -2120,12 +2107,23 @@ class ServingEngine:
         #                               dispatches without a step() before it)
         with tr.boundary("serve.emit") as emit:
             self._emit_decoded(toks, was_active, hits, paged, spans_ms,
-                               host_gap_ms, emit)
+                               host_gap_ms, emit, stats)
+
+    def _fold_step_stats(self, stats):
+        """What the model reported at each fused step -> one value a
+        dispatch, folded as `model.serving_step_stats` says ("mean" or
+        "max"). Runs inside the decode program."""
+        how = self.model.serving_step_stats
+        return {name: v.max() if how[name] == "max" else v.mean()
+                for name, v in stats.items()}
 
     def _emit_decoded(self, toks, was_active, hits, paged, spans_ms,
-                      host_gap_ms, emit) -> None:
+                      host_gap_ms, emit, stats) -> None:
         """Hand a fetched dispatch's tokens to their requests, retire the
-        finished, count, and write the `serve_step` sink record."""
+        finished, count, and write the `serve_step` sink record. `stats` is
+        what the model reported of the dispatch (`_fold_step_stats`): it goes
+        to `serving.<name>` counters (the last dispatch's value; `peak()`
+        keeps the highest) and into the record."""
         import numpy as np
 
         n_inner = toks.shape[0]
@@ -2150,6 +2148,8 @@ class ServingEngine:
         from ..core import monitor
 
         monitor.stat("serving.steps").increase(n_inner)
+        for name, value in stats.items():
+            monitor.stat("serving." + name).set(value)
         occupancy = float(was_active.mean())
         mreg = _obs_metrics.active_registry()
         if mreg is not None:
@@ -2185,6 +2185,10 @@ class ServingEngine:
                 # for the engine's first dispatch)
                 "spans_ms": spans_ms,
                 "host_gap_ms": host_gap_ms,
+                # positions held by the slots still live after the dispatch:
+                # what the next step's attention reads
+                "contexts": self._offsets[self._active].tolist(),
+                **stats,
             }
             if paged:
                 rec["pages_in_use"] = self._pool.in_use

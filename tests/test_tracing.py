@@ -225,6 +225,64 @@ def test_decode_program_stablehlo_identical_without_scopes(tiny_gpt,
                       "decode/lm_head", "decode/sample"}
 
 
+def test_afmoe_decode_program_stablehlo_identical_without_scopes(
+        monkeypatch):
+    """The routed-expert decoder's scopes (attn > qk_norm, rope, gate; moe >
+    router, dispatch, experts, shared, combine) are metadata too."""
+    import jax
+
+    from paddle_tpu.models import AfmoeForCausalLM, afmoe_tiny
+    from paddle_tpu.serving import ServingEngine
+
+    paddle.seed(0)
+    model = AfmoeForCausalLM(afmoe_tiny())
+    eng = ServingEngine(model, slot_count=2, ladder=(8, 16), max_seq_len=32,
+                        max_new_cap=8, steps_per_dispatch=2)
+    scoped = _lower_decode(eng)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope", _NullScope)
+        bare = _lower_decode(eng)
+    assert scoped.as_text() == bare.as_text()
+    scopes = {device_trace.scope_of(n)[0] for n in _op_names(scoped)}
+    assert scopes >= {"decode/embed", "decode/attn/qkv",
+                      "decode/attn/qk_norm", "decode/attn/rope",
+                      "decode/attn/cache_write", "decode/attn/core",
+                      "decode/attn/gate", "decode/attn/out", "decode/mlp",
+                      "decode/moe/router", "decode/moe/dispatch",
+                      "decode/moe/experts", "decode/moe/shared",
+                      "decode/moe/combine", "decode/final_norm",
+                      "decode/lm_head", "decode/sample"}
+
+
+def test_afmoe_serve_step_record_carries_the_experts_load():
+    """`moe_touched` and `moe_max_load` leave the decode program with its
+    tokens and reach the `serve_step` sink record and `serving.*` counters;
+    a GPT record has neither."""
+    from paddle_tpu.core import monitor
+    from paddle_tpu.models import AfmoeForCausalLM, afmoe_tiny
+    from paddle_tpu.observability import InMemorySink
+    from paddle_tpu.serving import ServingEngine
+
+    paddle.seed(0)
+    sink = InMemorySink()
+    eng = ServingEngine(AfmoeForCausalLM(afmoe_tiny()), slot_count=3,
+                        ladder=(8,), max_seq_len=32, max_new_cap=8,
+                        steps_per_dispatch=2, sink=sink)
+    for n in (3, 5, 7):
+        eng.submit(list(range(1, n + 1)), max_new_tokens=6)
+    eng.run()
+    steps = [r for r in sink.records if r["event"] == "serve_step"]
+    assert steps
+    for rec in steps:
+        # 3 rows x top-2 of 8 experts: 2 to 6 of them see a row, and one
+        # expert sees 1 to 3
+        assert 2.0 <= rec["moe_touched"] <= 6.0
+        assert 1.0 <= rec["moe_max_load"] <= 3.0
+    assert monitor.stat("serving.moe_touched").get() == steps[-1]["moe_touched"]
+    assert monitor.stat("serving.moe_max_load").peak() >= max(
+        r["moe_max_load"] for r in steps)
+
+
 @pytest.mark.parametrize("path, want", [
     ("jit(step)/transpose(jvp(attn))/qkv/jit(fwd)/dot_general",
      ("attn/qkv", True, None)),
