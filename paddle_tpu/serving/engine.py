@@ -1381,7 +1381,10 @@ class ServingEngine:
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 else:
                     keys = jax.vmap(request_key)(seeds, new_off)
-                    nxt = sample_tokens(logits, keys, temps, top_k, top_p)
+                    # a retired slot draws as a greedy row: no search
+                    nxt = sample_tokens(logits, keys,
+                                        jnp.where(active, temps, 0.0),
+                                        top_k, top_p)
                 nxt = jnp.where(active, nxt, tok)
                 new_remaining = remaining - act
                 hit_eos = active & (eos != _NO_EOS) & (nxt == eos)
@@ -1448,7 +1451,10 @@ class ServingEngine:
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 else:
                     keys = jax.vmap(request_key)(seeds, new_off)
-                    nxt = sample_tokens(logits, keys, temps, top_k, top_p)
+                    # a retired slot draws as a greedy row: no search
+                    nxt = sample_tokens(logits, keys,
+                                        jnp.where(active, temps, 0.0),
+                                        top_k, top_p)
                 nxt = jnp.where(active, nxt, tok)
                 new_remaining = remaining - act
                 hit_eos = active & (eos != _NO_EOS) & (nxt == eos)

@@ -670,3 +670,67 @@ def test_flash_attention_memory_scales_linearly_with_seq():
     assert b4 < 6 * b1, (
         f"flash temp memory grew {b4 / max(b1, 1):.1f}x for 4x seq — "
         f"attention is materializing O(s^2) state again")
+
+
+def _sort_operand_shapes(stablehlo_text):
+    """The operand shapes of every `stablehlo.sort` in a lowered program:
+    a list of lists of dimension tuples."""
+    found = []
+    for m in re.finditer(r'"?stablehlo\.sort"?\(', stablehlo_text):
+        sig = re.search(r"\}\)\s*:\s*\(([^)]*)\)\s*->",
+                        stablehlo_text[m.end():])
+        assert sig, "a stablehlo.sort whose signature this gate cannot read"
+        found.append([tuple(int(d) for d in t.split("x")[:-1])
+                      for t in re.findall(r"tensor<([^>]*)>", sig.group(1))])
+    return found
+
+
+@pytest.mark.parametrize("family", ["gpt", "afmoe"])
+def test_serving_programs_sort_nothing_as_wide_as_the_vocabulary(family):
+    """The sampler finds its top-k and nucleus thresholds by selection
+    (serving/sampling.py): neither the `sample` decode program nor a prefill
+    program sorts an array as wide as the vocabulary (two such sorts were
+    44% of the Trinity decode step's device time at 16 x 200,192: ISSUE 29).
+    The `sample` scope the trace reducer reads is still there."""
+    from paddle_tpu.distributed.mesh import set_hybrid_communicate_group
+    from paddle_tpu.observability import device_trace
+    from paddle_tpu.serving import ServingEngine
+
+    set_hybrid_communicate_group(None)
+    paddle.seed(0)
+    if family == "gpt":
+        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+
+        model, vocab = GPTForPretraining(gpt_tiny()), gpt_tiny().vocab_size
+    else:
+        from paddle_tpu.models import AfmoeForCausalLM, afmoe_tiny
+
+        model, vocab = AfmoeForCausalLM(afmoe_tiny()), afmoe_tiny().vocab_size
+    model.eval()
+    eng = ServingEngine(model, slot_count=2, ladder=(8, 16), max_seq_len=32,
+                        max_new_cap=8, steps_per_dispatch=2)
+
+    def vec(dtype):
+        return jnp.zeros((eng.slot_count,), dtype)
+
+    decode = eng._build_decode("sample").lower(
+        eng._params, eng._kcs, eng._vcs, vec(jnp.int32), vec(jnp.int32),
+        vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32), vec(jnp.float32),
+        vec(jnp.int32), vec(jnp.int32), vec(jnp.int32))
+    prefill = eng._build_prefill(8).lower(
+        eng._params, eng._kcs, eng._vcs, jnp.zeros((1, 8), jnp.int64),
+        jnp.int32(0), jnp.int32(0), jnp.float32(0.0), jnp.int32(0),
+        jnp.float32(1.0), jnp.int32(0))
+    for name, lowered in (("decode", decode), ("prefill", prefill)):
+        text = lowered.as_text()
+        assert re.search(rf"tensor<\d+x{vocab}xf32>", text), \
+            f"{name}: no [rows, {vocab}] logits to sample from"
+        wide = [s for s in _sort_operand_shapes(text)
+                if any(vocab in shape for shape in s)]
+        assert not wide, (
+            f"{name}: a sort over the whole vocabulary is back in the "
+            f"program: {wide}")
+        scopes = {device_trace.scope_of(op)[0] for op in re.findall(
+            r'op_name="([^"]+)"', lowered.compile().as_text())}
+        assert f"{name}/sample" in scopes, \
+            f"{name}: the `sample` scope is gone: {sorted(scopes)}"

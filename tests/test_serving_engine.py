@@ -179,39 +179,199 @@ def test_decode_families_bounded(model):
 
 
 # ----------------------------------------------- sampling shared semantics
-def test_filter_topk_topp_matches_legacy_reference():
-    """Combined top-k+top-p support equivalence between the traced per-slot
-    filter (shared by prefill and decode-step programs) and legacy
-    sample()'s static filtering."""
+def sorted_filter_topk_topp(logits, top_k, top_p):
+    """The filter as it was up to PR 28: two sorts of the whole row. The
+    oracle of the selection that took its place (float32, in jax, so that
+    it can stand in for the program's own filter inside an engine)."""
+    import jax
     import jax.numpy as jnp
 
-    rng = np.random.RandomState(5)
-    logits = rng.randn(4, 50).astype(np.float32) * 3
+    vocab = logits.shape[-1]
+    top_k = jnp.asarray(top_k, jnp.int32)
+    top_p = jnp.asarray(top_p, jnp.float32)
+    sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
+    k_eff = jnp.clip(top_k, 1, vocab)
+    kth = jnp.take_along_axis(sorted_desc, (k_eff - 1)[:, None], axis=-1)
+    logits = jnp.where((top_k[:, None] > 0) & (logits < kth),
+                       -jnp.inf, logits)
+    sorted_f = jnp.sort(logits, axis=-1)[:, ::-1]
+    probs = jax.nn.softmax(sorted_f, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    cutoff_idx = jnp.sum(cum < top_p[:, None], axis=-1)
+    cutoff = jnp.take_along_axis(
+        sorted_f, jnp.clip(cutoff_idx, 0, vocab - 1)[:, None], axis=-1)
+    return jnp.where((top_p[:, None] < 1.0) & (logits < cutoff),
+                     -jnp.inf, logits)
 
-    def legacy_mask(row, top_k, top_p):
-        row = row.copy()
-        if top_k and top_k > 0:
-            k_eff = min(int(top_k), row.shape[-1])
-            kth = np.sort(row)[-k_eff]
-            row = np.where(row < kth, -np.inf, row)
-        if top_p < 1.0:
-            srt = np.sort(row)[::-1]
-            e = np.exp(srt - srt[0])
-            probs = e / e.sum()
-            cum = np.cumsum(probs)
-            cutoff_idx = int((cum < top_p).sum())
-            cutoff = srt[min(cutoff_idx, row.shape[-1] - 1)]
-            row = np.where(row < cutoff, -np.inf, row)
-        return np.isinf(row)
 
-    cases = [(0, 1.0), (10, 1.0), (0, 0.7), (10, 0.7)]
-    top_k = jnp.asarray([c[0] for c in cases], jnp.int32)
-    top_p = jnp.asarray([c[1] for c in cases], jnp.float32)
-    got = np.asarray(filter_topk_topp(jnp.asarray(logits), top_k, top_p))
-    for i, (k, p) in enumerate(cases):
-        np.testing.assert_array_equal(
-            np.isinf(got[i]), legacy_mask(logits[i], k, p),
-            err_msg=f"case top_k={k} top_p={p}")
+def legacy_mask(row, top_k, top_p):
+    """Legacy sample()'s static filtering of one row, in float64 ->
+    (masked, near): what is excluded, and where the mass above a value
+    lies within 1e-5 of top_p, so that a float32 sum may fall either way."""
+    row = row.astype(np.float64)
+    near = np.zeros(row.shape, bool)
+    if top_k and top_k > 0:
+        k_eff = min(int(top_k), row.shape[-1])
+        kth = np.sort(row)[-k_eff]
+        row = np.where(row < kth, -np.inf, row)
+    if top_p < 1.0:
+        srt = np.sort(row)[::-1]
+        e = np.exp(srt - srt[0])
+        probs = e / e.sum()
+        cum = np.cumsum(probs)
+        cutoff_idx = int((cum < top_p).sum())
+        cutoff = srt[min(cutoff_idx, row.shape[-1] - 1)]
+        # the mass strictly above each value: cum just before its first tie
+        first = np.searchsorted(-srt, -row, side="left")
+        above = np.where(first > 0, cum[np.maximum(first, 1) - 1], 0.0)
+        near = (np.abs(above - top_p) <= 1e-5) & (row < srt[0])
+        row = np.where(row < cutoff, -np.inf, row)
+    return np.isinf(row), near
+
+
+_WIDTHS = (50, 50_304, 200_192)
+
+
+def _filter_rows():
+    """(id, vocab, kind, top_k, top_p): the four cases this test always
+    had, the grid of ISSUE 29 at three widths, and the rows a selection
+    could get wrong where a sort cannot."""
+    rows = [(f"v50-randn-k{k}-p{p}", 50, "randn", k, p)
+            for k, p in ((0, 1.0), (10, 1.0), (0, 0.7), (10, 0.7))]
+    for v in _WIDTHS:
+        for k in (0, 1, 7, 50, 1000, v + 5):
+            for p in (0.0, 0.5, 0.9, 1.0):
+                rows.append((f"v{v}-randn-k{k}-p{p}", v, "randn", k, p))
+        rows += [
+            (f"v{v}-ties_at_kth", v, "ties_at_kth", 7, 1.0),
+            (f"v{v}-ties_at_kth-p0.9", v, "ties_at_kth", 7, 0.9),
+            (f"v{v}-ties_at_nucleus", v, "ties_at_nucleus", 0, 0.55),
+            (f"v{v}-ties_at_nucleus-k50", v, "ties_at_nucleus", 50, 0.55),
+            (f"v{v}-padded-k50-p0.9", v, "padded", 50, 0.9),
+            (f"v{v}-padded-kall-p0.5", v, "padded", v + 5, 0.5),
+            (f"v{v}-one_hot", v, "one_hot", 50, 0.9),
+            (f"v{v}-constant", v, "constant", 7, 0.5),
+        ]
+    return rows
+
+
+def _filter_row(vocab, kind):
+    rng = np.random.RandomState(5 + vocab % 97)
+    row = (rng.randn(vocab) * 3).astype(np.float32)
+    if kind == "ties_at_kth":       # five more columns at the 7th value
+        row[rng.choice(vocab, 5, replace=False)] = np.sort(row)[-7]
+    elif kind == "ties_at_nucleus":
+        # 0.4, then four columns of 0.1 each, the rest sharing 0.2: at
+        # top_p 0.55 the cut falls among the four, and all four stay
+        probs = np.full(vocab, 0.2 / (vocab - 5))
+        probs[:5] = (0.4, 0.1, 0.1, 0.1, 0.1)
+        row = np.log(probs).astype(np.float32)
+        row[1:5] = row[1]
+        row = row[rng.permutation(vocab)]
+    elif kind == "padded":          # a padded vocabulary's columns
+        row[-47 if vocab > 100 else -7:] = -np.inf
+    elif kind == "one_hot":
+        row[:] = -np.inf
+        row[vocab // 3] = 0.0
+    elif kind == "constant":
+        row[:] = 1.25
+    return row
+
+
+@pytest.mark.parametrize("vocab,kind,top_k,top_p",
+                         [pytest.param(*r[1:], id=r[0])
+                          for r in _filter_rows()])
+def test_filter_topk_topp_matches_legacy_reference(vocab, kind, top_k,
+                                                   top_p):
+    """Combined top-k+top-p support equivalence between the traced per-slot
+    filter (shared by prefill and decode-step programs) and legacy
+    sample()'s static filtering, and the sort-based filter it replaced:
+    the same support, but where the float64 mass at the boundary lies
+    within 1e-5 of top_p."""
+    import jax.numpy as jnp
+
+    row = _filter_row(vocab, kind)
+    k = jnp.asarray([top_k], jnp.int32)
+    p = jnp.asarray([top_p], jnp.float32)
+    got = np.asarray(filter_topk_topp(jnp.asarray(row[None]), k, p))[0]
+    masked, near = legacy_mask(row, top_k, top_p)
+    kept = ~np.isinf(got)
+    np.testing.assert_array_equal(got[kept], row[kept])
+    np.testing.assert_array_equal(np.isinf(got)[~near], masked[~near])
+    assert near.sum() <= 4            # the exemption is a few columns
+    assert kept.any()
+    was = np.asarray(sorted_filter_topk_topp(jnp.asarray(row[None]), k, p))
+    np.testing.assert_array_equal(np.isinf(got)[~near],
+                                  np.isinf(was[0])[~near])
+    if kind == "ties_at_kth" and top_p >= 1.0:
+        assert kept.sum() == 12         # 7 and the five ties: all stay
+    if kind == "ties_at_nucleus":
+        assert kept.sum() == 5
+    if kind in ("one_hot", "constant"):
+        assert kept.sum() == (1 if kind == "one_hot" else vocab)
+
+
+@pytest.mark.parametrize("vocab", _WIDTHS)
+def test_sample_tokens_draws_what_the_sorted_filter_drew(vocab):
+    """16 rows of mixed greedy / top-k / top-p / plain parameters with
+    fixed seeds: the token drawn through the selection is the token
+    `categorical` draws over the sort-filtered row with the same key."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving import request_key
+
+    rng = np.random.RandomState(11)
+    logits = jnp.asarray((rng.randn(16, vocab) * 3).astype(np.float32))
+    temps = jnp.asarray([0.0, 0.8, 1.0, 1.3] * 4, jnp.float32)
+    top_k = jnp.asarray([50, 50, 0, 0, 7, 1, 1000, vocab + 5] * 2, jnp.int32)
+    top_p = jnp.asarray([0.9] * 4 + [1.0] * 4 + [0.5] * 4 + [0.0, 0.3, 0.9,
+                                                             1.0],
+                        jnp.float32)
+    keys = jax.vmap(request_key)(jnp.arange(16, dtype=jnp.int32) * 7 + 1,
+                                 jnp.arange(16, dtype=jnp.int32) + 3)
+    got = np.asarray(sample_tokens(logits, keys, temps, top_k, top_p))
+    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+    want = np.asarray(jax.vmap(jax.random.categorical)(
+        keys, sorted_filter_topk_topp(scaled, top_k, top_p)))
+    greedy = np.asarray(jnp.argmax(logits, axis=-1))
+    want = np.where(np.asarray(temps) == 0.0, greedy, want)
+    np.testing.assert_array_equal(got, want)
+    # the draws are not all the argmax: the test would see a wrong support
+    assert (got != greedy).sum() >= 4
+
+
+def test_engine_tokens_equal_the_sorted_filters(model, monkeypatch):
+    """An engine run of mixed requests emits the tokens of the same run
+    with the sort-based oracle patched in for `filter_topk_topp`, from the
+    prefill program (first token) and the decode program (the rest)."""
+    from paddle_tpu.serving import sampling
+
+    rng = np.random.RandomState(12)
+    prompts = [rng.randint(0, 1024, (n,)).astype(np.int64)
+               for n in (5, 9, 12, 7, 14, 6)]
+    configs = [dict(temperature=0.8, top_k=50, top_p=0.9, seed=1),
+               dict(temperature=0.0),
+               dict(temperature=1.2, top_k=5, seed=2),
+               dict(temperature=1.0, top_p=0.5, seed=3),
+               dict(temperature=0.7, seed=4),
+               dict(temperature=0.9, top_k=2000, top_p=0.0, seed=5)]
+
+    def run():
+        eng = ServingEngine(model, slot_count=3, ladder=(8, 16),
+                            max_new_cap=16, steps_per_dispatch=4)
+        reqs = [eng.submit(p, max_new_tokens=10, **kw)
+                for p, kw in zip(prompts, configs)]
+        eng.run()
+        assert all(r.done for r in reqs)
+        return [r.tokens for r in reqs]
+
+    got = run()
+    with monkeypatch.context() as m:
+        m.setattr(sampling, "filter_topk_topp", sorted_filter_topk_topp)
+        want = run()
+    assert got == want
+    assert len({tuple(t) for t in got}) == len(got)
 
 
 def test_sample_tokens_traced_params():
