@@ -20,6 +20,13 @@ host-random     Python ``random.*`` or ``np.random.*`` (not ``jax.random``)
 mutable-default ``def f(x, acc=[])`` / ``={}`` / ``=set()`` in any public
                 function (all files, not just traced code)
 bare-lock       ``lock.acquire()`` outside a ``with`` statement (all files)
+model-serving   a file under ``paddle_tpu/models/`` imports from
+                ``paddle_tpu.serving``: the arrow points up
+model-cache     a file under ``paddle_tpu/models/`` writes an array in
+                place, ``x.at[...]`` or ``dynamic_update_slice(x, ...)``,
+                whatever ``x`` is called: where a cache's row lives is
+                nn/kv_cache.py's to know, and a write that is not a cache's
+                (beam search's token table) is named in the baseline
 =============== ==========================================================
 
 Tracedness is syntactic: a function is traced when it is decorated with
@@ -41,6 +48,7 @@ from __future__ import annotations
 
 import ast
 import os
+import re
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Set, Tuple
 
@@ -59,6 +67,7 @@ _NP_SYNC_FUNCS = {"asarray", "array"}
 _MUTABLE_LITERALS = (ast.List, ast.Dict, ast.Set, ast.ListComp,
                      ast.DictComp, ast.SetComp)
 _MUTABLE_CTORS = {"list", "dict", "set", "defaultdict", "OrderedDict"}
+_MODELS_DIR = "paddle_tpu/models/"
 
 
 @dataclass
@@ -112,6 +121,7 @@ class _FileLinter(ast.NodeVisitor):
         self._stack: List[str] = []          # qualname parts
         self._traced_depth = 0               # >0 → inside a traced body
         self._with_calls: Set[ast.Call] = set()
+        self._in_models = relpath.replace(os.sep, "/").startswith(_MODELS_DIR)
 
     # -- sweep 1: which local functions get traced? -------------------------
     # Traced names are collected PER ENCLOSING SCOPE as "scope::name": the
@@ -199,6 +209,42 @@ class _FileLinter(ast.NodeVisitor):
                     f"public API {node.name!r} has mutable default for "
                     f"{a.arg!r} — shared across calls; use None + init"))
 
+    # -- the models' seam: no import of serving, no cache written by hand ----
+    def _check_import(self, node, modules) -> None:
+        for mod in modules:
+            parts = mod.split(".")
+            if "serving" in parts[:2] and (parts[0] in ("serving",
+                                                        "paddle_tpu")):
+                self._emit(node, "model-serving", mod,
+                           f"a model imports {mod}: models sit below "
+                           f"serving; what both need lives in nn/ or core/")
+
+    def visit_Import(self, node: ast.Import) -> None:
+        if self._in_models:
+            self._check_import(node, [a.name for a in node.names])
+
+    def visit_ImportFrom(self, node: ast.ImportFrom) -> None:
+        if self._in_models and (node.level == 2 or (
+                node.level == 0 and (node.module or "").startswith(
+                    "paddle_tpu"))):
+            base = node.module or ""
+            self._check_import(node, [base] if base and base != "paddle_tpu"
+                               else [f"{base}.{a.name}".lstrip(".")
+                                     for a in node.names])
+
+    def _check_cache_write(self, node, target: ast.AST, how: str) -> None:
+        if self._in_models:
+            name = _attr_chain(target).split(".")[-1] or "<expr>"
+            self._emit(node, "model-cache", f"{name}{how}",
+                       f"{name}{how}: a model writes an array in place; a "
+                       f"cache's rows go to the handle's update() "
+                       f"(nn/kv_cache.py), anything else into the baseline")
+
+    def visit_Subscript(self, node: ast.Subscript) -> None:
+        if isinstance(node.value, ast.Attribute) and node.value.attr == "at":
+            self._check_cache_write(node, node.value.value, ".at[")
+        self.generic_visit(node)
+
     def visit_With(self, node: ast.With) -> None:
         for item in node.items:
             if isinstance(item.context_expr, ast.Call):
@@ -219,6 +265,9 @@ class _FileLinter(ast.NodeVisitor):
                        f"bare {callee}() — leaks the lock on exception; "
                        f"use `with`")
 
+        if last == "dynamic_update_slice" and node.args:
+            self._check_cache_write(node, node.args[0],
+                                    " in dynamic_update_slice(")
         if self._traced_depth > 0:
             self._check_traced_call(node, callee, last)
         self.generic_visit(node)

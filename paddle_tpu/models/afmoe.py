@@ -23,10 +23,11 @@ so a bf16 model never has a float32 twin. Norms, softmax, RoPE and the router
 run in float32. `forward(ids)` gives logits; training is not written.
 
 Serving: `kv_cache_spec` declares a `window` cache of `sliding_window` rows
-for a sliding layer and a `full` one for the rest (serving/kv_state.py), and
-the attention layers take the engine's `(k, v, offset)` caches: a scalar
-offset is a prefill over a fresh cache, a per-row offset a decode step that
-writes row `position % rows` and masks every row by the position it holds.
+for a sliding layer and a `full` one for the rest, and each attention layer
+hands its new keys and values to the cache handle it is given
+(nn/kv_cache.py) and masks what comes back by the position each row holds:
+causal, and the window on a sliding layer. A prefill over a fresh cache
+attends the chunk's own keys blockwise instead.
 """
 from __future__ import annotations
 
@@ -37,6 +38,7 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..core.tensor import Tensor
+from ..nn.kv_cache import KVLayerSpec
 from ..nn.layers.routed_experts import NormalInto
 
 _QUERY_BLOCK = 512     # prefill attention runs this many queries at a time
@@ -228,16 +230,14 @@ class AfmoeAttention(nn.Layer):
 
     def forward(self, a, cache=None):
         """a [b, s, hidden], already normalised -> [b, s, hidden], and the
-        new cache when one was given."""
+        new cache when one was given (a handle of nn/kv_cache.py)."""
         b, s = a.shape[0], a.shape[1]
         groups = self.num_heads // self.kv_heads
-        off = None
-        if cache is not None:
-            off = cache[2]._data if isinstance(cache[2], Tensor) else cache[2]
-            off = off.astype(jnp.int32)
-        per_row = off is not None and off.ndim == 1
-        first = 0 if off is None else off[:, None] if per_row else off
-        pos = first + jnp.arange(s, dtype=jnp.int32)[None, :]     # [b|1, s]
+        # nothing held before this chunk (no cache, or a fresh one that
+        # starts at position 0): its own keys are all there is to see
+        alone = cache is None or cache.fresh
+        pos = (jnp.arange(s, dtype=jnp.int32)[None, :] if cache is None
+               else cache.positions(s))                           # [b|1, s]
         with jax.named_scope("qkv"):
             q = self.q_proj(a).reshape(b, s, self.num_heads, self.head_dim)
             k = self.k_proj(a).reshape(b, s, self.kv_heads, self.head_dim)
@@ -249,47 +249,24 @@ class AfmoeAttention(nn.Layer):
                 q = _rope(q, pos, self.rope_theta)
                 k = _rope(k, pos, self.rope_theta)
         q = q.reshape(b, s, self.kv_heads, groups, self.head_dim)
-        new_cache = None
-        if not per_row:
-            # no cache, or a fresh request-local one (prefill from position
-            # 0): the chunk's own keys are all there is to see
-            with jax.named_scope("core"):
-                o = self._prefill_core(q, k, v)
-            if cache is not None:
-                with jax.named_scope("cache_write"):
-                    kc, vc = cache[0]._data, cache[1]._data
-                    zero = jnp.int32(0)
-                    kc = jax.lax.dynamic_update_slice(
-                        kc, k.astype(kc.dtype), (zero, off, zero, zero))
-                    vc = jax.lax.dynamic_update_slice(
-                        vc, v.astype(vc.dtype), (zero, off, zero, zero))
-                new_cache = (Tensor(kc), Tensor(vc),
-                             Tensor(off + jnp.int32(s)))
-        else:
-            # a step over the slot cache: row r of a slot holds the last
-            # position p <= the query's with p % rows == r (rows is the
-            # window on a sliding layer, the slot's length on a full one)
-            kc, vc = cache[0]._data, cache[1]._data
-            rows = kc.shape[1]
+        if cache is not None:
             with jax.named_scope("cache_write"):
-                at = jnp.arange(b)[:, None]
-                kc = kc.at[at, pos % rows].set(k.astype(kc.dtype))
-                vc = vc.at[at, pos % rows].set(v.astype(vc.dtype))
-            with jax.named_scope("core"):
-                r = jnp.arange(rows, dtype=jnp.int32)[None, None, :]
-                held = pos[:, :, None] - (pos[:, :, None] - r) % rows
-                mask = held >= 0
+                kc, vc, held, cache = cache.update(k, v)
+        with jax.named_scope("core"):
+            if alone:
+                o = self._prefill_core(q, k, v)
+            else:
+                mask = held <= pos[:, :, None]
                 if self.window is not None:
                     mask = mask & (held > pos[:, :, None] - self.window)
                 o = _attend(q, kc, vc, mask)
-            new_cache = (Tensor(kc), Tensor(vc), Tensor(off + jnp.int32(s)))
         with jax.named_scope("gate"):
             o = o.reshape(b, s, self.num_heads * self.head_dim)
             o = o * jax.nn.sigmoid(
                 self.gate_proj(a).astype(jnp.float32)).astype(o.dtype)
         with jax.named_scope("out"):
             out = self.o_proj(o)
-        return out if cache is None else (out, new_cache)
+        return out if cache is None else (out, cache)
 
 
 class AfmoeMLP(nn.Layer):
@@ -433,8 +410,6 @@ class AfmoeForCausalLM(nn.Layer):
         return self.model, "model."
 
     def kv_cache_spec(self, max_seq_len: int):
-        from ..serving.kv_state import KVLayerSpec
-
         c = self.config
         return [KVLayerSpec("window", min(c.sliding_window, max_seq_len),
                             c.num_key_value_heads, c.head_dim)

@@ -25,6 +25,7 @@ from ..distributed.meta_parallel.mp_layers import (
 from ..ops import creation as C
 from ..ops import manipulation as P
 from ..nn import functional as F
+from ..nn.kv_cache import ChunkKV, KVLayerSpec
 
 
 class GPTConfig:
@@ -95,73 +96,22 @@ class GPTAttention(nn.Layer):
             with jax.named_scope("out"):
                 return self.out_proj(out)
 
-        # KV-cache decode (TPU-native: fixed [b, T, nh, hd] buffers updated
-        # with dynamic_update_slice, so the whole decode loop is one static-
-        # shape scan). cache = (k_cache, v_cache, offset): offset is the count
-        # of already-cached positions; the new chunk writes [offset, offset+s).
-        import jax.numpy as jnp
-
-        if hasattr(cache, "page_table"):
-            # paged serving cache (serving/kv_pages.py): scatter this
-            # chunk's K/V through the slot page table, gather the logical
-            # cache back (dequantizing int8 pages), and mask exactly like
-            # the per-row dense path — unallocated table entries alias the
-            # zero page, so the gathered values match a zero-initialized
-            # contiguous cache bit for bit.
-            from ..serving import kv_pages as _kvp
-
-            with jax.named_scope("cache_write"):
-                kc, vc, new_cache = _kvp.update_and_read(cache, k._data,
-                                                         v._data)
-            with jax.named_scope("core"):
-                total = kc.shape[1]
-                off = cache.offset
-                qpos = off[:, None] + jnp.arange(s)[None, :]      # [b, s]
-                mask = (jnp.arange(total)[None, None, :]
-                        <= qpos[:, :, None])[:, None]             # [b, 1, s, T]
-                out = F.scaled_dot_product_attention(
-                    q, Tensor(kc), Tensor(vc), attn_mask=Tensor(mask),
-                    dropout_p=0.0, training=False)
-                out = P.reshape(out, (b, s, self.hidden_size))
-            with jax.named_scope("out"):
-                return self.out_proj(out), new_cache
-
-        k_cache, v_cache, offset = cache
-        kc, vc = k_cache._data, v_cache._data
-        off = offset._data if isinstance(offset, Tensor) else offset
-        off = off.astype(jnp.int32)
-        total = kc.shape[1]
-        if getattr(off, "ndim", 0) == 1:
-            # per-row offsets (serving slot cache): each row writes its new
-            # chunk at its own position. Rows past a row's offset are never
-            # attended (mask below), so retired/short slots stay inert and
-            # one batched step can serve slots at arbitrary depths.
-            with jax.named_scope("cache_write"):
-                rows = jnp.arange(b)[:, None]                     # [b, 1]
-                pos = jnp.clip(off[:, None] + jnp.arange(s)[None, :], 0,
-                               total - 1)
-                kc = kc.at[rows, pos].set(k._data.astype(kc.dtype))
-                vc = vc.at[rows, pos].set(v._data.astype(vc.dtype))
-            qpos = off[:, None] + jnp.arange(s)[None, :]      # [b, s]
-            mask = (jnp.arange(total)[None, None, :]
-                    <= qpos[:, :, None])[:, None]             # [b, 1, s, T]
-        else:
-            with jax.named_scope("cache_write"):
-                zero = jnp.int32(0)
-                kc = jax.lax.dynamic_update_slice(
-                    kc, k._data.astype(kc.dtype), (zero, off, zero, zero))
-                vc = jax.lax.dynamic_update_slice(
-                    vc, v._data.astype(vc.dtype), (zero, off, zero, zero))
-            qpos = off + jnp.arange(s)                       # [s]
-            mask = jnp.arange(total)[None, :] <= qpos[:, None]  # [s, T]
+        # KV-cache decode: `cache` is one layer's handle (nn/kv_cache.py). It
+        # writes this chunk's rows and hands back what the queries may read
+        # with the position each row holds; where the rows live is its
+        # business. Fixed shapes throughout, so a whole decode loop is one
+        # static-shape scan.
+        with jax.named_scope("cache_write"):
+            kc, vc, held, new_cache = cache.update(k._data, v._data)
         with jax.named_scope("core"):
+            qpos = cache.positions(s)                         # [b|1, s]
+            mask = (held <= qpos[:, :, None])[:, None]        # [b|1, 1, s, T]
             out = F.scaled_dot_product_attention(
                 q, Tensor(kc), Tensor(vc), attn_mask=Tensor(mask),
                 dropout_p=0.0, training=False)
             out = P.reshape(out, (b, s, self.hidden_size))
         with jax.named_scope("out"):
-            return self.out_proj(out), (Tensor(kc), Tensor(vc),
-                                        Tensor(off + jnp.int32(s)))
+            return self.out_proj(out), new_cache
 
 
 class GPTMLP(nn.Layer):
@@ -238,15 +188,9 @@ class GPTModel(nn.Layer):
     def _embed(self, input_ids, caches):
         s = input_ids.shape[1]
         if caches is not None:
-            off = caches[0][2]
-            off_arr = off._data if isinstance(off, Tensor) else off
             import jax.numpy as jnp
 
-            if getattr(off_arr, "ndim", 0) == 1:  # per-row offsets -> [b, s]
-                pos = Tensor(off_arr[:, None].astype(jnp.int64)
-                             + jnp.arange(s, dtype=jnp.int64)[None, :])
-            else:
-                pos = Tensor(off_arr + jnp.arange(s, dtype=jnp.int64))
+            pos = Tensor(caches[0].positions(s).astype(jnp.int64))
         else:
             pos = C.arange(0, s, dtype="int64")
         x = self.wte(input_ids) + self.wpe(pos)
@@ -556,7 +500,7 @@ class GPTForPretraining(nn.Layer):
                 return L.matmul(h, self.gpt.wte.weight, transpose_y=True)
             return self.lm_head(h)
 
-    # ---- what ServingEngine asks of a model (serving/kv_state.py) ------
+    # ---- what ServingEngine asks of a model (nn/kv_cache.py) -----------
     # a decode step reports nothing beside its tokens
     serving_step_stats = {}
 
@@ -567,8 +511,6 @@ class GPTForPretraining(nn.Layer):
 
     def kv_cache_spec(self, max_seq_len: int):
         """Every layer keeps every position of a slot."""
-        from ..serving.kv_state import KVLayerSpec
-
         cfg = self.config
         return [KVLayerSpec("full", max_seq_len, cfg.num_heads,
                             cfg.hidden_size // cfg.num_heads)
@@ -642,7 +584,7 @@ class GPTForPretraining(nn.Layer):
         b, prompt = ids.shape
         bucketed = prompt_bucket is not None
         if bucketed:
-            from ..serving.bucketing import resolve_bucket
+            from ..core.bucketing import resolve_bucket
 
             padded_len = resolve_bucket(prompt, prompt_bucket)
             ids = jnp.pad(ids, ((0, 0), (0, padded_len - prompt)))
@@ -723,9 +665,8 @@ class GPTForPretraining(nn.Layer):
             # the executable as a constant
             gpt_params = {k[len("gpt."):]: v for k, v in params.items()
                           if k.startswith("gpt.")}
-            caches = [(Tensor(jnp.zeros((b, total, nh, hd), cache_dtype)),
-                       Tensor(jnp.zeros((b, total, nh, hd), cache_dtype)),
-                       Tensor(jnp.int32(0))) for _ in range(cfg.num_layers)]
+            caches = [ChunkKV.zeros(b, total, nh, hd, cache_dtype)
+                      for _ in range(cfg.num_layers)]
             h, caches = functional_call(self.gpt, gpt_params, Tensor(ids),
                                         caches=caches)
             if bucketed:
@@ -736,8 +677,7 @@ class GPTForPretraining(nn.Layer):
                 # ever attended (causal mask) — numerics match unpadded.
                 last_h = jax.lax.dynamic_index_in_dim(h._data, plen - 1, 1,
                                                       keepdims=False)
-                caches = [(kc2, vc2, Tensor(plen)) for (kc2, vc2, _o)
-                          in caches]
+                caches = [c.rewound(plen) for c in caches]
             else:
                 last_h = h._data[:, -1]
             logits = head(params, last_h)
@@ -745,12 +685,9 @@ class GPTForPretraining(nn.Layer):
             tok = sample(logits, sub).astype(ids.dtype)
             done = (jnp.zeros((b,), bool) if eos_token_id is None
                     else tok == eos_token_id)
-            flat = jax.tree_util.tree_map(lambda t: t._data, caches,
-                                          is_leaf=lambda t: isinstance(t, Tensor))
 
             def step(carry, _):
-                flat_caches, tok, key, done = carry
-                caches = jax.tree_util.tree_map(Tensor, flat_caches)
+                caches, tok, key, done = carry
                 h, caches = functional_call(self.gpt, gpt_params,
                                             Tensor(tok[:, None]),
                                             caches=caches)
@@ -760,13 +697,10 @@ class GPTForPretraining(nn.Layer):
                 if eos_token_id is not None:
                     nxt = jnp.where(done, eos_token_id, nxt)
                     done = done | (nxt == eos_token_id)
-                flat_caches = jax.tree_util.tree_map(
-                    lambda t: t._data, caches,
-                    is_leaf=lambda t: isinstance(t, Tensor))
-                return (flat_caches, nxt, key, done), nxt
+                return (caches, nxt, key, done), nxt
 
             if max_new_tokens > 1:
-                _, toks = jax.lax.scan(step, (flat, tok, key, done), None,
+                _, toks = jax.lax.scan(step, (caches, tok, key, done), None,
                                        length=max_new_tokens - 1)
                 out = jnp.concatenate([ids, tok[:, None], toks.T], axis=1)
             else:
@@ -863,9 +797,8 @@ class GPTForPretraining(nn.Layer):
             gpt_params = {k[len("gpt."):]: v for k, v in params.items()
                           if k.startswith("gpt.")}
             # ---- prefill on the raw batch, then tile everything to beams
-            caches = [(Tensor(jnp.zeros((b, total, nh, hd), cache_dtype)),
-                       Tensor(jnp.zeros((b, total, nh, hd), cache_dtype)),
-                       Tensor(jnp.int32(0))) for _ in range(cfg.num_layers)]
+            caches = [ChunkKV.zeros(b, total, nh, hd, cache_dtype)
+                      for _ in range(cfg.num_layers)]
             h, caches = functional_call(self.gpt, gpt_params, Tensor(ids),
                                         caches=caches)
             logp0 = jax.nn.log_softmax(
@@ -878,22 +811,22 @@ class GPTForPretraining(nn.Layer):
                         else tok0 == eos_token_id)
             lengths = jnp.ones((b, K), jnp.float32)  # emitted per beam
 
-            def tile(t):
-                a = t._data if isinstance(t, Tensor) else t
-                if a.ndim == 0:
-                    return a
-                return jnp.repeat(a, K, axis=0)  # row i -> beams i*K..i*K+K-1
+            def per_beam(caches, reorder):
+                """`reorder` on every leaf that has a batch axis (the scalar
+                offset has none)."""
+                return jax.tree_util.tree_map(
+                    lambda a: a if a.ndim == 0 else reorder(a), caches)
 
-            flat = [tuple(tile(c) for c in layer) for layer in caches]
+            # row i -> beams i*K..i*K+K-1
+            caches = per_beam(caches, lambda a: jnp.repeat(a, K, axis=0))
 
             def step(carry, t):
-                flat, toks, scores, finished, lengths = carry
+                caches, toks, scores, finished, lengths = carry
                 # each beam continues from its last emitted token
                 prev = jnp.reshape(
                     jax.lax.dynamic_index_in_dim(
                         jnp.moveaxis(toks, 2, 0), t - 1, 0, keepdims=False),
                     (b * K,))
-                caches = [tuple(Tensor(c) for c in layer) for layer in flat]
                 h, caches = functional_call(self.gpt, gpt_params,
                                             Tensor(prev[:, None]),
                                             caches=caches)
@@ -923,15 +856,12 @@ class GPTForPretraining(nn.Layer):
                 # beam order; gather AFTER the append so each child inherits
                 # its parent's cache including the new row
                 rows = (jnp.arange(b)[:, None] * K + beam_idx).reshape(-1)
-                new_flat = []
-                for layer in caches:
-                    kc, vc, off = (x._data for x in layer)
-                    new_flat.append((kc[rows], vc[rows], off))
-                return (new_flat, toks, scores, finished, lengths), None
+                caches = per_beam(caches, lambda a: a[rows])
+                return (caches, toks, scores, finished, lengths), None
 
             if max_new_tokens > 1:
-                (flat, toks, scores, finished, lengths), _ = jax.lax.scan(
-                    step, (flat, toks, scores, finished, lengths),
+                (caches, toks, scores, finished, lengths), _ = jax.lax.scan(
+                    step, (caches, toks, scores, finished, lengths),
                     jnp.arange(1, max_new_tokens))
             # GNMT length penalty; pick the best beam per row
             norm = scores / jnp.power(lengths, jnp.float32(length_penalty))

@@ -1,0 +1,172 @@
+"""Where position p of a sequence lives in a key/value cache, and how it is
+written and read back: the one module that knows.
+
+A model declares what each layer keeps and never looks inside a cache:
+
+    model.kv_cache_spec(max_seq_len) -> [KVLayerSpec(kind, rows, kv_heads,
+                                                     head_dim), ...]
+
+- `full`: every position is kept, row p holds position p.
+- `window`: the last `rows` positions are kept as a ring, position p in row
+  `p % rows`, however long the context.
+
+Its attention is handed one handle a layer and calls it once:
+
+    positions = cache.positions(s)            # [b or 1, s]: the chunk's own
+    keys, values, held, cache = cache.update(k_new, v_new)
+
+`update` writes the chunk's rows and returns what the queries may read:
+`keys` / `values` [b, t, kv_heads, head_dim] and `held` (broadcasts against
+[b, s, t]), the absolute position row t holds for each query. A row that holds
+nothing yet reports the first position that will land on it, which is after
+every query of the chunk, so ONE causal test `held <= position` hides unwritten
+rows, stale rows past a rewound offset and later rows alike; a model with a
+window adds `held > position - window`. `cache.fresh` (static) says that
+nothing was held before this chunk, so the chunk's own keys are all there is
+to see.
+
+The handles are pytrees (`lax.scan` carries them, `tree_map` reorders beams):
+`ChunkKV` here for a whole batch at one offset (generate(), beam search, a
+request's prefill alone), `SlotKV` and `RingKV` for the serving engine's slot
+cache at per-row offsets (serving/kv_state.py builds them), and the paged
+pool's handle with the same two operations in serving/kv_pages.py. Latent rows
+and recurrent state would be further kinds (ROADMAP.md).
+"""
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax.tree_util import register_pytree_node_class
+
+KINDS = ("full", "window")
+
+
+class KVLayerSpec(NamedTuple):
+    kind: str
+    rows: int
+    kv_heads: int
+    head_dim: int
+
+
+def ring_row(pos, rows: int):
+    """The row of a ring of `rows` rows that holds position `pos`."""
+    return pos % rows
+
+
+def ring_held(tip, rows: int):
+    """[..., rows]: the position each row of a ring holds once position `tip`
+    is written, the last p <= tip with `ring_row(p) == r`; a row no position
+    has reached reports r itself, the first that will land there."""
+    r = jnp.arange(rows, dtype=jnp.int32)
+    tip = tip[..., None]
+    return jnp.maximum(tip - (tip - r) % rows, r)
+
+
+class _KV:
+    """k, v [b, rows, kv_heads, head_dim] and `offset`, the count of positions
+    already held (int32: a scalar for the batch or one a row)."""
+
+    fresh = False
+
+    def __init__(self, k, v, offset):
+        self.k, self.v, self.offset = k, v, offset
+
+    def tree_flatten(self):
+        return (self.k, self.v, self.offset), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(*leaves)
+
+    def _advanced(self, k, v, s: int):
+        return type(self)(k, v, self.offset + jnp.int32(s))
+
+
+@register_pytree_node_class
+class ChunkKV(_KV):
+    """Every sequence of the batch at one scalar offset; row p holds
+    position p. The new chunk lands at [offset, offset + s)."""
+
+    def __init__(self, k, v, offset, fresh=False):
+        super().__init__(k, v, offset)
+        self.fresh = fresh
+
+    @classmethod
+    def zeros(cls, batch: int, rows: int, kv_heads: int, head_dim: int, dtype):
+        def make():
+            return jnp.zeros((batch, rows, kv_heads, head_dim), dtype)
+
+        return cls(make(), make(), jnp.int32(0), fresh=True)
+
+    def tree_flatten(self):
+        return (self.k, self.v, self.offset), self.fresh
+
+    @classmethod
+    def tree_unflatten(cls, fresh, leaves):
+        return cls(*leaves, fresh=fresh)
+
+    def _advanced(self, k, v, s):
+        return ChunkKV(k, v, self.offset + jnp.int32(s))
+
+    def rewound(self, offset):
+        """The same rows with `offset` positions counted as held (a bucketed
+        prompt resumes at its true length: the pad's rows are overwritten
+        before any query sees them)."""
+        return ChunkKV(self.k, self.v, offset)
+
+    def positions(self, s: int):
+        return self.offset + jnp.arange(s, dtype=jnp.int32)[None, :]
+
+    def update(self, k_new, v_new):
+        zero = jnp.int32(0)
+        at = (zero, self.offset, zero, zero)
+        k = jax.lax.dynamic_update_slice(self.k, k_new.astype(self.k.dtype), at)
+        v = jax.lax.dynamic_update_slice(self.v, v_new.astype(self.v.dtype), at)
+        held = jnp.arange(k.shape[1], dtype=jnp.int32)[None, None, :]
+        return k, v, held, self._advanced(k, v, k_new.shape[1])
+
+
+@register_pytree_node_class
+class SlotKV(_KV):
+    """A `full` layer of the slot cache: each row of the batch is a slot at
+    its own offset, row p of a slot holds position p. Rows past a slot's
+    offset are never seen (the causal test), so retired and short slots stay
+    inert and one batched step serves slots at any depth. Positions are in
+    jnp's default integer width, the form GPT-2's decode programs have been
+    compiled and measured in."""
+
+    def positions(self, s: int):
+        return self.offset[:, None] + jnp.arange(s)[None, :]
+
+    def update(self, k_new, v_new):
+        b, s = k_new.shape[0], k_new.shape[1]
+        total = self.k.shape[1]
+        slots = jnp.arange(b)[:, None]
+        pos = jnp.clip(self.offset[:, None] + jnp.arange(s)[None, :], 0,
+                       total - 1)
+        k = self.k.at[slots, pos].set(k_new.astype(self.k.dtype))
+        v = self.v.at[slots, pos].set(v_new.astype(self.v.dtype))
+        held = jnp.arange(total)[None, None, :]
+        return k, v, held, self._advanced(k, v, s)
+
+
+@register_pytree_node_class
+class RingKV(_KV):
+    """A `window` layer of the slot cache: each slot keeps its last `rows`
+    positions, position p in row `ring_row(p)`."""
+
+    def positions(self, s: int):
+        return self.offset[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+
+    def update(self, k_new, v_new):
+        b, s = k_new.shape[0], k_new.shape[1]
+        rows = self.k.shape[1]
+        pos = self.positions(s)
+        slots = jnp.arange(b)[:, None]
+        k = self.k.at[slots, ring_row(pos, rows)].set(
+            k_new.astype(self.k.dtype))
+        v = self.v.at[slots, ring_row(pos, rows)].set(
+            v_new.astype(self.v.dtype))
+        return k, v, ring_held(pos, rows), self._advanced(k, v, s)

@@ -20,7 +20,7 @@ serving.prefix_lookups, serving.prefix_hits, serving.prefill_skips;
 legacy generate() adds decode.jit_compiles / decode.cache_evictions
 (LRU-bounded executable cache).
 """
-from .bucketing import (  # noqa: F401
+from ..core.bucketing import (  # noqa: F401
     DEFAULT_LADDER, bucket_for, clip_ladder, resolve_bucket,
 )
 from .engine import Request, ServingEngine  # noqa: F401

@@ -14,9 +14,9 @@ resident-program philosophy of MPK, arxiv 2512.22219):
   true prompt length, target slot, sampling params, and seed are all
   traced, so a whole traffic distribution shares O(#rungs) executables.
 - **a single-token decode step**, ONE executable total: operates on the
-  fixed donated slot KV cache (one [slots, rows, kv_heads, head_dim] pair a
-  layer, as the model declares it: kv_state.py) with per-slot write
-  offsets, per-slot sampling params (traced — mixed greedy/top-k/top-p
+  fixed donated slot KV cache (kv_state.py: one object built from what the
+  model declares it keeps a layer and from `kv_layout`, over which every
+  program here is written once) with per-slot write offsets, per-slot sampling params (traced — mixed greedy/top-k/top-p
   share the program), per-slot EOS/budget masks, and per-slot RNG streams.
 
 On top sits continuous batching: finished sequences retire their slot
@@ -45,13 +45,24 @@ from ..observability import flight_recorder as _obs_flight
 from ..observability import metrics as _obs_metrics
 from ..observability import tracer as _obs_tracer
 from . import kv_state as _kvs
-from .bucketing import DEFAULT_LADDER, bucket_for, clip_ladder
+from ..core.bucketing import DEFAULT_LADDER, bucket_for, clip_ladder
 
 _NO_EOS = -1
 
 # slot-occupancy fractions live in (0, 1]: linear buckets, not the default
 # log-spaced latency boundaries
 _OCCUPANCY_BUCKETS = tuple(round(0.1 * i, 1) for i in range(1, 11))
+
+
+def _split(args, *counts):
+    """A program's positional arguments after its weights -> each cache's
+    own (`counts[i]` of them, in order: `n_args` of kv_state.py), then the
+    program's."""
+    out, i = [], 0
+    for n in counts:
+        out.append(tuple(args[i:i + n]))
+        i += n
+    return (*out, args[i:])
 
 
 class Request:
@@ -264,8 +275,8 @@ class ServingEngine:
             raise ValueError(
                 f"kv_layout must be 'contiguous' or 'paged', got {kv_layout!r}")
         self.kv_layout = kv_layout
-        self._kv_spec = _kvs.spec_of(model, T)
-        windows = _kvs.window_layers(self._kv_spec)
+        spec = _kvs.spec_of(model, T)
+        windows = _kvs.window_layers(spec)
         if windows and kv_layout == "paged":
             raise ValueError(
                 f"kv_layout='paged' cannot hold this model: layers {windows} "
@@ -277,58 +288,36 @@ class ServingEngine:
                 f"{windows} keep a window of rows as a ring, and the verify "
                 "program rewinds rejected rows by offset, which a ring "
                 "overwrites; construct the engine without draft_model")
+        # the slot cache (kv_state.py): ONE object knows where a slot's rows
+        # live; the programs below are written over it. Paged: per-layer
+        # page pools + one page table traced as a gather index, and the
+        # radix prefix cache sharing whole prompt pages across requests
+        # (kv_pages.py). Shapes stay static either way, so the executables
+        # and their donation are the same design.
         if kv_layout == "paged":
-            nh, hd = self._kv_spec[0].kv_heads, self._kv_spec[0].head_dim
-            # paged KV: per-layer page pools + ONE [slots, max_pages] page
-            # table traced into prefill/decode as a gather index
-            # (kv_pages.py). Shapes stay static so the two-executable
-            # design and donation survive; the radix prefix cache
-            # (prefix_cache.py) shares whole prompt pages across requests.
-            from . import kv_pages as _kvp
-            from .prefix_cache import RadixPrefixCache
+            from .kv_pages import PagedSlotCache
 
-            pt = int(kv_page_tokens if kv_page_tokens is not None
-                     else _flags.flag("kv_page_tokens"))
-            if pt < 1:
-                raise ValueError(f"kv_page_tokens must be >= 1, got {pt}")
-            self.page_tokens = pt
-            self.max_pages = -(-T // pt)                  # ceil(T / pt)
-            self._t_eff = self.max_pages * pt
-            mode = (kv_cache_dtype if kv_cache_dtype is not None
-                    else _flags.flag("kv_cache_dtype"))
-            self._store_dtype, self._kv_quantized = _kvp.resolve_store_dtype(
-                mode, self._cache_dtype)
-            # default pool covers the contiguous worst case (every slot at
-            # max_seq_len) so it can never exhaust; pass kv_num_pages to
-            # trade bytes for admission-time eviction pressure
-            self.num_pages = int(kv_num_pages if kv_num_pages is not None
-                                 else S * self.max_pages + _kvp.RESERVED_PAGES)
-            self._pool = _kvp.PagePool(self.num_pages)
-            self._prefix = RadixPrefixCache(self._pool, pt)
-            self._pool_state = _kvp.make_pool_state(
-                len(self._kv_spec), self.num_pages, pt, nh, hd, S,
-                self.max_pages, self._store_dtype, self._kv_quantized)
-            self._tables = np.zeros((S, self.max_pages), np.int32)
-            self._slot_pages: List[List[int]] = [[] for _ in range(S)]
-            self._replay = np.zeros(S, bool)
-            self._kcs = self._vcs = None
+            self._kv = PagedSlotCache(
+                spec, S, T, self._cache_dtype,
+                int(kv_page_tokens if kv_page_tokens is not None
+                    else _flags.flag("kv_page_tokens")),
+                kv_num_pages,
+                (kv_cache_dtype if kv_cache_dtype is not None
+                 else _flags.flag("kv_cache_dtype")))
         else:
-            self._kcs, self._vcs = _kvs.allocate(self._kv_spec, S,
-                                                 self._cache_dtype)
+            self._kv = _kvs.SlotCache(spec, S, T, self._cache_dtype)
 
-        # draft KV cache: always slot-contiguous (draft rows rewind by
+        # the draft's cache is always contiguous (draft rows rewind by
         # offset alone — rejected rows go stale-but-inert under the causal
         # mask, so the draft never needs page bookkeeping even when the
         # target cache is paged)
+        self._dkv = None
         if draft_model is not None:
-            self._dkv_spec = _kvs.spec_of(draft_model, T)
-            if _kvs.window_layers(self._dkv_spec):
+            dspec = _kvs.spec_of(draft_model, T)
+            if _kvs.window_layers(dspec):
                 raise ValueError("a draft model with window layers cannot "
                                  "rewind its cache by offset")
-            self._dkcs, self._dvcs = _kvs.allocate(self._dkv_spec, S,
-                                                   self._cache_dtype)
-        else:
-            self._dkcs = self._dvcs = None
+            self._dkv = _kvs.SlotCache(dspec, S, T, self._cache_dtype)
 
         # host-side per-slot state (tiny arrays, re-staged every step)
         self._offsets = np.zeros(S, np.int32)
@@ -418,7 +407,7 @@ class ServingEngine:
             if speculate_k < 0:
                 raise ValueError(
                     f"speculate_k must be >= 0, got {speculate_k}")
-            windows = _kvs.window_layers(self._kv_spec)
+            windows = _kvs.window_layers(self._kv.spec)
             if windows:
                 raise ValueError(
                     f"speculate_k > 0 cannot be served: layers {windows} "
@@ -533,8 +522,7 @@ class ServingEngine:
                     req = self._slot_req[slot]
                     self._active[slot] = False
                     self._slot_req[slot] = None
-                    if self.kv_layout == "paged":
-                        self._release_slot(slot)
+                    self._kv.release(slot)
                     if req is not None and req.done_ts is None:
                         self._finish(req, outcome="drained")
                 break
@@ -592,11 +580,11 @@ class ServingEngine:
             })
         if self.kv_layout == "paged":
             out.update({
-                "page_tokens": self.page_tokens,
-                "num_pages": self.num_pages,
-                "pages_in_use": self._pool.in_use,
-                "pages_cached": self._pool.cached,
-                "prefix": self._prefix.stats(),
+                "page_tokens": self._kv.page_tokens,
+                "num_pages": self._kv.num_pages,
+                "pages_in_use": self._kv.pool.in_use,
+                "pages_cached": self._kv.pool.cached,
+                "prefix": self._kv.prefix.stats(),
             })
         return out
 
@@ -605,11 +593,7 @@ class ServingEngine:
         """Device bytes held by the KV cache: per-slot rows (contiguous)
         or pools + scales + page tables (paged) — the denominator of
         serve_bench's concurrent-requests-per-MB datum."""
-        if self.kv_layout == "paged":
-            from . import kv_pages as _kvp
-
-            return _kvp.pool_state_bytes(self._pool_state)
-        return _kvs.cache_bytes((*self._kcs, *self._vcs))
+        return self._kv.nbytes()
 
     def prefix_match_len(self, prompt_ids) -> int:
         """Tokens of this prompt already cached as shared pages (0 on the
@@ -617,8 +601,7 @@ class ServingEngine:
         refcount side effects."""
         if self.kv_layout != "paged":
             return 0
-        return self._prefix.peek(
-            [int(t) for t in prompt_ids])
+        return self._kv.prefix.peek([int(t) for t in prompt_ids])
 
     def flush_prefix_cache(self) -> int:
         """Evict every refcount-zero cached prefix page; returns the count
@@ -626,7 +609,7 @@ class ServingEngine:
         executables."""
         if self.kv_layout != "paged":
             return 0
-        return self._prefix.flush()
+        return self._kv.prefix.flush()
 
     def occupancy(self) -> float:
         return float(self._active.sum()) / self.slot_count
@@ -635,6 +618,23 @@ class ServingEngine:
         return len(self._queue)
 
     # ---------------------------------------------------------- internals
+    @property
+    def _kcs(self):
+        """The contiguous cache's key arrays, one [slots, rows, kv_heads,
+        head_dim] a layer (None on the paged layout): read-only, for the
+        benchmark's row check and rehearsals until they get public names."""
+        return getattr(self._kv, "k", None)
+
+    @property
+    def _vcs(self):
+        return getattr(self._kv, "v", None)
+
+    @staticmethod
+    def _donate(first: int, *caches) -> tuple:
+        """donate_argnums of a program whose cache arguments start at
+        `first`: every argument of the caches, in order."""
+        return tuple(range(first, first + sum(c.n_args for c in caches)))
+
     @property
     def _exec_stash(self):
         """label -> (jitted fn, abstract args), now owned by the registry
@@ -758,7 +758,7 @@ class ServingEngine:
             return {"precompiled": 0, "skipped": reason,
                     "cold": 0, "warm": 0, "wall_ms": 0.0}
         self.aot_skip_reason = None
-        paged = self.kv_layout == "paged"
+        kv, dkv = self._kv, self._dkv
         S = self.slot_count
 
         def slot_vecs():
@@ -770,69 +770,41 @@ class ServingEngine:
                     jnp.asarray(self._topp), jnp.asarray(self._eos),
                     jnp.asarray(self._remaining), jnp.asarray(self._seeds))
 
-        def pool_state():
-            return dict(self._pool_state, tables=jnp.asarray(self._tables))
-
         plan = []  # (key, build, label, donate, call_args)
         for bucket in self.ladder:
             padded = jnp.asarray(np.zeros((1, bucket), np.int64))
-            if paged:
-                args = (self._params, pool_state(), padded, jnp.int32(0),
-                        jnp.int32(0), jnp.int32(0), jnp.float32(0.0),
-                        jnp.int32(0), jnp.float32(1.0), jnp.int32(0))
-                plan.append((("serve.prefill", bucket),
-                             (lambda b=bucket:
-                              self._build_prefill_paged(b)),
-                             f"serve.prefill_b{bucket}", (1,), args))
-            else:
-                args = (self._params, self._kcs, self._vcs, padded,
-                        jnp.int32(0), jnp.int32(0), jnp.float32(0.0),
-                        jnp.int32(0), jnp.float32(1.0), jnp.int32(0))
-                plan.append((("serve.prefill", bucket),
-                             (lambda b=bucket: self._build_prefill(b)),
-                             f"serve.prefill_b{bucket}", (1, 2), args))
-            if self.draft_model is not None:
-                dargs = (self._dparams, self._dkcs, self._dvcs, padded,
+            at = tuple(jnp.int32(0) for _ in kv.prefill_at)
+            args = (self._params, *kv.args(), padded, jnp.int32(0), *at,
+                    jnp.float32(0.0), jnp.int32(0), jnp.float32(1.0),
+                    jnp.int32(0))
+            plan.append((("serve.prefill", bucket),
+                         (lambda b=bucket: self._build_prefill(b)),
+                         f"serve.prefill_b{bucket}", self._donate(1, kv),
+                         args))
+            if dkv is not None:
+                dargs = (self._dparams, *dkv.args(), padded, jnp.int32(0),
                          jnp.int32(0))
                 plan.append((("serve.dprefill", bucket),
                              (lambda b=bucket:
                               self._build_draft_prefill(b)),
-                             f"serve.dprefill_b{bucket}", (1, 2), dargs))
+                             f"serve.dprefill_b{bucket}",
+                             self._donate(1, dkv), dargs))
         for family in families:
-            if paged:
-                args = (self._params, pool_state(), *slot_vecs(),
-                        jnp.asarray(self._replay), *sampling_vecs())
-                plan.append((("serve.decode", family),
-                             (lambda f=family:
-                              self._build_decode_paged(f)),
-                             f"serve.decode_{family}", (1,), args))
-            else:
-                args = (self._params, self._kcs, self._vcs, *slot_vecs(),
-                        *sampling_vecs())
-                plan.append((("serve.decode", family),
-                             (lambda f=family: self._build_decode(f)),
-                             f"serve.decode_{family}", (1, 2), args))
-            if self.draft_model is None:
+            args = (self._params, *kv.args(), *slot_vecs(), *sampling_vecs())
+            plan.append((("serve.decode", family),
+                         (lambda f=family: self._build_decode(f)),
+                         f"serve.decode_{family}", self._donate(1, kv), args))
+            if dkv is None:
                 continue
             for k in self.spec_ladder:
                 n_draft = jnp.asarray(np.zeros(S, np.int32))
-                if paged:
-                    args = (self._params, self._dparams, pool_state(),
-                            self._dkcs, self._dvcs, *slot_vecs(),
-                            jnp.asarray(self._replay), n_draft,
-                            *sampling_vecs())
-                    donate = (2, 3, 4)
-                    build = (lambda f=family, kk=k:
-                             self._build_verify_paged(f, kk))
-                else:
-                    args = (self._params, self._dparams, self._kcs,
-                            self._vcs, self._dkcs, self._dvcs,
-                            *slot_vecs(), n_draft, *sampling_vecs())
-                    donate = (2, 3, 4, 5)
-                    build = (lambda f=family, kk=k:
-                             self._build_verify(f, kk))
-                plan.append((("serve.verify", family, k), build,
-                             f"serve.verify_{family}_k{k}", donate, args))
+                args = (self._params, self._dparams, *kv.args(), *dkv.args(),
+                        *slot_vecs(), n_draft, *sampling_vecs())
+                plan.append((("serve.verify", family, k),
+                             (lambda f=family, kk=k:
+                              self._build_verify(f, kk)),
+                             f"serve.verify_{family}_k{k}",
+                             self._donate(2, kv, dkv), args))
 
         from ..core import compile_cache as _compile_cache
 
@@ -868,24 +840,16 @@ class ServingEngine:
         if fr is not None:
             fr.record(rec)
 
-    def _head_traced(self, params, h_arr):
-        """last-position hidden -> logits with weights from traced params."""
+    @staticmethod
+    def _head_traced(model, params, h_arr):
+        """`model`'s hidden states -> logits with weights from traced
+        params."""
         from ..core.autograd import no_grad
         from ..core.tensor import Tensor
         from ..jit import _swapped_state, _tracing
 
-        with _swapped_state(self.model, params), _tracing(), no_grad():
-            return self.model._head_logits(Tensor(h_arr))._data
-
-    def _draft_head_traced(self, dparams, h_arr):
-        """Draft-model hidden -> logits with weights from traced params."""
-        from ..core.autograd import no_grad
-        from ..core.tensor import Tensor
-        from ..jit import _swapped_state, _tracing
-
-        with _swapped_state(self.draft_model, dparams), _tracing(), \
-                no_grad():
-            return self.draft_model._head_logits(Tensor(h_arr))._data
+        with _swapped_state(model, params), _tracing(), no_grad():
+            return model._head_logits(Tensor(h_arr))._data
 
     @staticmethod
     def _backbone(model, params, ids, caches):
@@ -903,82 +867,35 @@ class ServingEngine:
 
     # ---- prefill -------------------------------------------------------
     def _build_prefill(self, bucket: int):
+        """One executable per prompt rung: `length` tokens (the prompt, or on
+        the paged layout its unshared tail from a traced `base`) right-padded
+        to the rung run through the model, and the cache commits their rows
+        to the slot. Where the request goes (`at`: the cache's `prefill_at`),
+        its length, sampling params and seed are all traced, so a whole
+        traffic distribution, prefix hits of any depth included, shares
+        O(#rungs) executables."""
         import jax
 
         from .sampling import request_key, sample_tokens
 
-        spec = self._kv_spec
-        cache_dtype = self._cache_dtype
+        kv = self._kv
 
-        def prefill(params, kcs, vcs, ids, plen, slot, temp, top_k, top_p,
-                    seed):
-            # fresh request-local cache sized to the rung; causal masking
-            # makes the right-pad inert (queries past plen are discarded)
-            caches = _kvs.request_local(spec, bucket, cache_dtype)
+        def prefill(params, *args):
+            cache, (ids, length, *at, temp, top_k, top_p, seed) = _split(
+                args, kv.n_args)
+            caches = kv.prefill_views(cache, bucket, length, *at)
             h, caches, _ = self._backbone(self.model, params, ids, caches)
-            last_h = jax.lax.dynamic_index_in_dim(h, plen - 1, 1,
+            # queries past `length` are the pad's: discarded
+            last_h = jax.lax.dynamic_index_in_dim(h, length - 1, 1,
                                                   keepdims=False)
-            logits = self._head_traced(params, last_h)       # [1, V]
-            key = request_key(seed, plen)  # first token sits at position plen
+            logits = self._head_traced(self.model, params, last_h)  # [1, V]
+            key = request_key(seed, kv.first_position(length, *at))
             tok = sample_tokens(logits, key[None], temp[None], top_k[None],
                                 top_p[None])[0]
-            # scatter this request's K/V into its slot row of the big cache
-            new_kcs, new_vcs = [], []
-            for kind, big_k, big_v, layer in zip(spec, kcs, vcs, caches):
-                new_kcs.append(_kvs.scatter_prefill(
-                    kind, big_k, layer[0]._data, slot, plen))
-                new_vcs.append(_kvs.scatter_prefill(
-                    kind, big_v, layer[1]._data, slot, plen))
-            return new_kcs, new_vcs, tok
+            return (*kv.commit_prefill(cache, caches, length, *at), tok)
 
         return jax.jit(jax.named_scope("prefill")(prefill),
-                       donate_argnums=(1, 2))
-
-    def _build_prefill_paged(self, bucket: int):
-        """Paged tail-prefill, one executable per TAIL rung: the unshared
-        suffix of the prompt (the whole prompt on a trie miss) runs with a
-        traced base offset and writes K/V through this slot's page-table
-        row. base/tail_len/slot/sampling/seed are all traced, so prefix
-        hits of any depth share the same rung executables."""
-        import jax
-        import jax.numpy as jnp
-
-        from . import kv_pages as _kvp
-        from .sampling import request_key, sample_tokens
-
-        pt = self.page_tokens
-        quant = self._kv_quantized
-        compute_dtype = self._cache_dtype
-
-        def prefill(params, state, ids, tail_len, base, slot, temp, top_k,
-                    top_p, seed):
-            table_row = jax.lax.dynamic_slice_in_dim(
-                state["tables"], slot, 1, 0)                 # [1, max_pages]
-            # pad positions past the tail redirect to the scratch page:
-            # their page-table entries may be unallocated (the zero page
-            # must never be written)
-            wmask = (jnp.arange(bucket, dtype=jnp.int32)[None, :]
-                     < tail_len)                             # [1, bucket]
-            caches = _kvp.layer_views(state, table_row, base[None], wmask,
-                                      pt, compute_dtype)
-            h, caches, _ = self._backbone(self.model, params, ids, caches)
-            last_h = jax.lax.dynamic_index_in_dim(h, tail_len - 1, 1,
-                                                  keepdims=False)
-            logits = self._head_traced(params, last_h)       # [1, V]
-            key = request_key(seed, base + tail_len)  # abs first-token pos
-            tok = sample_tokens(logits, key[None], temp[None], top_k[None],
-                                top_p[None])[0]
-            new_state = {
-                "k": [c.k_pool for c in caches],
-                "v": [c.v_pool for c in caches],
-                "ks": [c.k_scale for c in caches] if quant else [],
-                "vs": [c.v_scale for c in caches] if quant else [],
-                "tables": state["tables"],
-            }
-            return new_state, tok
-
-        return jax.jit(jax.named_scope("prefill")(prefill),
-                       donate_argnums=(1,))
+                       donate_argnums=self._donate(1, kv))
 
     # ---- speculative decoding: draft prefill ---------------------------
     def _build_draft_prefill(self, bucket: int):
@@ -992,24 +909,17 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
 
-        spec = self._dkv_spec
-        cache_dtype = self._cache_dtype
+        dkv = self._dkv
 
-        def prefill(dparams, dkcs, dvcs, ids, slot):
-            caches = _kvs.request_local(spec, bucket, cache_dtype)
+        def prefill(dparams, *args):
+            cache, (ids, length, slot) = _split(args, dkv.n_args)
+            caches = dkv.prefill_views(cache, bucket, length, slot)
             _h, caches, _ = self._backbone(self.draft_model, dparams, ids,
                                            caches)
-            plen = jnp.int32(bucket)      # every layer is `full`: not read
-            new_kcs = [_kvs.scatter_prefill(kind, big, layer[0]._data, slot,
-                                            plen)
-                       for kind, big, layer in zip(spec, dkcs, caches)]
-            new_vcs = [_kvs.scatter_prefill(kind, big, layer[1]._data, slot,
-                                            plen)
-                       for kind, big, layer in zip(spec, dvcs, caches)]
-            return new_kcs, new_vcs
+            return dkv.commit_prefill(cache, caches, length, slot)
 
         return jax.jit(jax.named_scope("prefill")(prefill),
-                       donate_argnums=(1, 2))
+                       donate_argnums=self._donate(1, dkv))
 
     def _seat_spec(self, req: Request, slot: int) -> None:
         """Per-seat speculative setup, called at every seating site (slot
@@ -1035,27 +945,129 @@ class ServingEngine:
         self._spec_k[slot] = rung
         bucket = req.bucket
         plen = len(req.prompt_ids)
+        dkv = self._dkv
+        label, donate = f"serve.dprefill_b{bucket}", self._donate(1, dkv)
         entry = self._execs.get_or_build(
             ("serve.dprefill", bucket),
             lambda: self._build_draft_prefill(bucket),
-            label=f"serve.dprefill_b{bucket}", donate=(1, 2), pin=True)
+            label=label, donate=donate, pin=True)
         padded = np.zeros((1, bucket), np.int64)
         padded[0, :plen] = req.prompt_ids
-        call_args = (self._dparams, self._dkcs, self._dvcs,
-                     jnp.asarray(padded), jnp.int32(slot))
-        self._stash_exec(f"serve.dprefill_b{bucket}", entry.fn, call_args)
+        call_args = (self._dparams, *dkv.args(), jnp.asarray(padded),
+                     jnp.int32(plen), jnp.int32(slot))
+        self._stash_exec(label, entry.fn, call_args, donate=donate)
         monitor.stat("serving.draft_prefill_dispatches").increase()
         p0 = self._execs.persistent_before(entry)
         t0 = time.perf_counter()
-        self._dkcs, self._dvcs = entry(*call_args)
+        dkv.take(entry(*call_args))
         self._execs.note_compiles(
             entry, wall_s=time.perf_counter() - t0, persistent_before=p0,
             counter="serving.draft_prefill_compiles")
 
-    def _admit(self) -> None:
+    def _run_prefill(self, req: Request, bucket: int, prompt, at,
+                     span_args: dict) -> int:
+        """Dispatch the rung's prefill program over `prompt` (the whole
+        prompt, or its unshared tail) at `at` and sync on the first token.
+        A failure dumps to the flight recorder, finishes the request as an
+        error and re-raises."""
         import jax.numpy as jnp
         import numpy as np
 
+        from ..core import monitor
+
+        kv = self._kv
+        tr = _obs_tracer.get_tracer()
+        try:
+            with tr.boundary("serve.prefill.dispatch",
+                             **span_args) as dispatch:
+                label, donate = f"serve.prefill_b{bucket}", self._donate(1, kv)
+                entry = self._execs.get_or_build(
+                    ("serve.prefill", bucket),
+                    lambda: self._build_prefill(bucket),
+                    label=label, donate=donate, pin=True)
+                padded = np.zeros((1, bucket), np.int64)
+                padded[0, :len(prompt)] = prompt
+                call_args = (self._params, *kv.args(), jnp.asarray(padded),
+                             jnp.int32(len(prompt)),
+                             *(jnp.int32(a) for a in at),
+                             jnp.float32(req.temperature),
+                             jnp.int32(req.top_k), jnp.float32(req.top_p),
+                             jnp.int32(req.seed))
+                self._stash_exec(label, entry.fn, call_args, donate=donate)
+                monitor.stat("serving.prefill_dispatches").increase()
+                p0 = self._execs.persistent_before(entry)
+                t0 = time.perf_counter()
+                *cache, tok = entry(*call_args)
+                kv.take(cache)
+                self._execs.note_compiles(
+                    entry, wall_s=time.perf_counter() - t0,
+                    persistent_before=p0, counter="serving.prefill_compiles")
+            with tr.boundary("serve.prefill.sync", **span_args) as sync:
+                first = int(tok)                  # device sync = first token
+            self._prefill_ms.append((dispatch.ms, sync.ms))
+        except Exception as e:
+            fr = _obs_flight.get()
+            if fr is not None:
+                fr.dump("serve_prefill_exception",
+                        {"request": req.id, "bucket": bucket,
+                         "at": [int(a) for a in at], "error": repr(e)})
+            self._finish(req, outcome="error")
+            raise
+        req.first_token_ts = time.perf_counter()
+        if tr.enabled:
+            tr.record_complete("serve.prefill", req.admit_ts,
+                               req.first_token_ts, span_args)
+        mreg = _obs_metrics.active_registry()
+        if mreg is not None:
+            mreg.histogram("serve.prefill_ms").observe(
+                (req.first_token_ts - req.admit_ts) * 1e3)
+        return first
+
+    def _note_queue_wait(self, req: Request) -> None:
+        tr = _obs_tracer.get_tracer()
+        if tr.enabled:
+            tr.record_complete("serve.queue_wait", req.submit_ts,
+                               req.admit_ts, req.trace_args())
+        mreg = _obs_metrics.active_registry()
+        if mreg is not None:
+            mreg.histogram("serve.queue_wait_ms").observe(
+                req.queue_wait_s * 1e3)
+
+    def _seat(self, req: Request, slot: int, offset: int, last_tok: int,
+              remaining: int) -> None:
+        """Hand `slot` to `req` for decode: `offset` positions held,
+        `last_tok` the next step's input, `remaining` tokens still owed."""
+        eos = req.eos_token_id
+        req.slot = slot
+        self._offsets[slot] = offset
+        self._last_tok[slot] = last_tok
+        self._active[slot] = True
+        self._temps[slot] = req.temperature
+        self._topk[slot] = req.top_k
+        self._topp[slot] = req.top_p
+        self._eos[slot] = eos if eos is not None else _NO_EOS
+        self._remaining[slot] = remaining
+        self._seeds[slot] = req.seed
+        self._slot_req[slot] = req
+        self._seat_spec(req, slot)
+
+    def _seat_after_prefill(self, req: Request, slot: int,
+                            first: int) -> None:
+        """The prefill's first token is the request's; seat it for decode
+        unless that token already ends it."""
+        req.slot = slot
+        req.tokens.append(first)
+        self._count_tokens(1)
+        hit_eos = req.eos_token_id is not None and first == req.eos_token_id
+        if hit_eos or req.max_new_tokens <= 1:
+            req.finish_reason = "eos" if hit_eos else "length"
+            self._kv.release(slot)
+            self._finish(req)
+            return
+        self._seat(req, slot, len(req.prompt_ids), first,
+                   req.max_new_tokens - 1)
+
+    def _admit(self) -> None:
         if self._draining:
             return
         while True:
@@ -1072,83 +1084,12 @@ class ServingEngine:
                 if not self._admit_paged(req, slot):
                     return
                 continue
-            bucket = req.bucket
-            plen = len(req.prompt_ids)
             req.admit_ts = time.perf_counter()    # queue wait ends here
-            tr = _obs_tracer.get_tracer()
-            span_args = req.trace_args(bucket=bucket, slot=slot)
-            try:
-                with tr.boundary("serve.prefill.dispatch",
-                                 **span_args) as dispatch:
-                    entry = self._execs.get_or_build(
-                        ("serve.prefill", bucket),
-                        lambda: self._build_prefill(bucket),
-                        label=f"serve.prefill_b{bucket}", donate=(1, 2),
-                        pin=True)
-                    padded = np.zeros((1, bucket), np.int64)
-                    padded[0, :plen] = req.prompt_ids
-                    call_args = (self._params, self._kcs, self._vcs,
-                                 jnp.asarray(padded), jnp.int32(plen),
-                                 jnp.int32(slot),
-                                 jnp.float32(req.temperature),
-                                 jnp.int32(req.top_k),
-                                 jnp.float32(req.top_p), jnp.int32(req.seed))
-                    self._stash_exec(f"serve.prefill_b{bucket}", entry.fn,
-                                     call_args)
-                    from ..core import monitor
-
-                    monitor.stat("serving.prefill_dispatches").increase()
-                    p0 = self._execs.persistent_before(entry)
-                    t0 = time.perf_counter()
-                    self._kcs, self._vcs, tok = entry(*call_args)
-                    self._execs.note_compiles(
-                        entry, wall_s=time.perf_counter() - t0,
-                        persistent_before=p0,
-                        counter="serving.prefill_compiles")
-                with tr.boundary("serve.prefill.sync", **span_args) as sync:
-                    first = int(tok)              # device sync = first token
-                self._prefill_ms.append((dispatch.ms, sync.ms))
-            except Exception as e:
-                fr = _obs_flight.get()
-                if fr is not None:
-                    fr.dump("serve_prefill_exception",
-                            {"request": req.id, "bucket": bucket,
-                             "error": repr(e)})
-                self._finish(req, outcome="error")
-                raise
-            req.first_token_ts = time.perf_counter()
-            if tr.enabled:
-                tr.record_complete("serve.queue_wait", req.submit_ts,
-                                   req.admit_ts, req.trace_args())
-                tr.record_complete("serve.prefill", req.admit_ts,
-                                   req.first_token_ts,
-                                   req.trace_args(bucket=bucket, slot=slot))
-            mreg = _obs_metrics.active_registry()
-            if mreg is not None:
-                mreg.histogram("serve.queue_wait_ms").observe(
-                    req.queue_wait_s * 1e3)
-                mreg.histogram("serve.prefill_ms").observe(
-                    (req.first_token_ts - req.admit_ts) * 1e3)
-            req.slot = slot
-            req.tokens.append(first)
-            self._count_tokens(1)
-            eos = req.eos_token_id if req.eos_token_id is not None else _NO_EOS
-            if (eos != _NO_EOS and first == eos) or req.max_new_tokens <= 1:
-                req.finish_reason = ("eos" if eos != _NO_EOS and first == eos
-                                     else "length")
-                self._finish(req)
-                continue
-            self._offsets[slot] = plen
-            self._last_tok[slot] = first
-            self._active[slot] = True
-            self._temps[slot] = req.temperature
-            self._topk[slot] = req.top_k
-            self._topp[slot] = req.top_p
-            self._eos[slot] = eos
-            self._remaining[slot] = req.max_new_tokens - 1
-            self._seeds[slot] = req.seed
-            self._slot_req[slot] = req
-            self._seat_spec(req, slot)
+            self._note_queue_wait(req)
+            first = self._run_prefill(
+                req, req.bucket, req.prompt_ids, (slot,),
+                req.trace_args(bucket=req.bucket, slot=slot))
+            self._seat_after_prefill(req, slot, first)
 
     # ---- paged admission ----------------------------------------------
     def _pages_reserved_inflight(self) -> int:
@@ -1157,24 +1098,14 @@ class ServingEngine:
         already in its table row don't count)."""
         import numpy as np
 
-        pt = self.page_tokens
+        kv = self._kv
         total = 0
         for i in np.nonzero(self._active)[0]:
             end = min(int(self._offsets[i]) + int(self._remaining[i]),
                       self.max_seq_len)
-            need = -(-end // pt) - int((self._tables[i] != 0).sum())
+            need = -(-end // kv.page_tokens) - int((kv.tables[i] != 0).sum())
             total += max(0, need)
         return total
-
-    def _release_slot(self, slot: int) -> None:
-        """Drop the slot's page references (shared pages decref; own pages
-        free or park for prefix reuse) and reset its table row to the zero
-        page."""
-        for p in self._slot_pages[slot]:
-            self._prefix.release(int(p))
-        self._slot_pages[slot] = []
-        self._tables[slot, :] = 0
-        self._replay[slot] = False
 
     def _admit_paged(self, req: Request, slot: int) -> bool:
         """Seat a request on the paged cache. Three admission shapes:
@@ -1194,28 +1125,26 @@ class ServingEngine:
         Returns False (request requeued) when the pool can't cover this
         request's worst case plus in-flight reservations — admission
         retries once decode retires a slot and frees pages."""
-        import jax.numpy as jnp
-        import numpy as np
-
         from ..core import monitor
-        from . import kv_pages as _kvp
+        from .kv_pages import PoolExhausted
 
-        pt = self.page_tokens
+        kv = self._kv
+        pt = kv.page_tokens
         plen = len(req.prompt_ids)
         req.admit_ts = time.perf_counter()    # queue wait ends here
-        shared = self._prefix.match(req.prompt_ids)
+        shared = kv.prefix.match(req.prompt_ids)
         k_shared = len(shared)
         monitor.stat("serving.prefix_lookups").increase()
         # reservation check: this request's unshared worst case on top of
         # what active slots may still allocate must fit free + evictable
         need_new = -(-(plen + req.max_new_tokens) // pt) - k_shared
-        avail = self._pool.available
+        avail = kv.pool.available
         if avail < self._pages_reserved_inflight() + need_new:
             for p in shared:
-                self._prefix.release(int(p))
+                kv.prefix.release(int(p))
             if not self._active.any():
-                raise _kvp.PoolExhausted(
-                    f"pool of {self.num_pages} pages cannot fit one request "
+                raise PoolExhausted(
+                    f"pool of {kv.num_pages} pages cannot fit one request "
                     f"needing {need_new} fresh pages ({avail} available) — "
                     "raise kv_num_pages or lower max_new_cap")
             req.admit_ts = None
@@ -1226,155 +1155,76 @@ class ServingEngine:
             monitor.stat("serving.prefix_hits").increase()
             req.prefix_hit = True
             req.shared_tokens = k_shared * pt
-        self._tables[slot, :] = 0
-        self._tables[slot, :k_shared] = shared
-        self._slot_pages[slot] = [int(p) for p in shared]
-        eos = req.eos_token_id if req.eos_token_id is not None else _NO_EOS
-        tr = _obs_tracer.get_tracer()
-        mreg = _obs_metrics.active_registry()
-        if tr.enabled:
-            tr.record_complete("serve.queue_wait", req.submit_ts,
-                               req.admit_ts, req.trace_args())
-        if mreg is not None:
-            mreg.histogram("serve.queue_wait_ms").observe(
-                req.queue_wait_s * 1e3)
+        kv.tables[slot, :] = 0
+        kv.tables[slot, :k_shared] = shared
+        kv.slot_pages[slot] = [int(p) for p in shared]
+        self._note_queue_wait(req)
 
         if k_shared * pt >= plen:
             # full hit: replay seat, zero prefill dispatches
             monitor.stat("serving.prefill_skips").increase()
             req.tail_bucket = 0
-            req.slot = slot
+            tr = _obs_tracer.get_tracer()
             if tr.enabled:
                 tr.instant("serve.prefix_replay", **req.trace_args(
                     slot=slot, shared_tokens=req.shared_tokens))
-            self._offsets[slot] = plen - 1
-            self._last_tok[slot] = int(req.prompt_ids[-1])
-            self._active[slot] = True
-            self._replay[slot] = True
-            self._temps[slot] = req.temperature
-            self._topk[slot] = req.top_k
-            self._topp[slot] = req.top_p
-            self._eos[slot] = eos
-            self._remaining[slot] = req.max_new_tokens
-            self._seeds[slot] = req.seed
-            self._slot_req[slot] = req
-            self._seat_spec(req, slot)
+            self._seat(req, slot, plen - 1, int(req.prompt_ids[-1]),
+                       req.max_new_tokens)
+            kv.replay[slot] = True
             return True
 
         # partial hit / miss: allocate the prompt's unshared pages and
         # prefill the tail rung at base = shared tokens
         base = k_shared * pt
-        tail = plen - base
-        tbucket = bucket_for(tail, self.ladder)
+        tbucket = bucket_for(plen - base, self.ladder)
         req.tail_bucket = tbucket
         npages_prompt = -(-plen // pt)
-        if not self._prefix.ensure_free(npages_prompt - k_shared):
-            raise _kvp.PoolExhausted(     # reservation check above makes
+        if not kv.prefix.ensure_free(npages_prompt - k_shared):
+            raise PoolExhausted(          # reservation check above makes
                 "page reservation accounting violated")  # this unreachable
         for pi in range(k_shared, npages_prompt):
-            page = self._pool.alloc()
-            self._tables[slot, pi] = page
-            self._slot_pages[slot].append(page)
-        span_args = req.trace_args(bucket=tbucket, base=base, slot=slot)
-        try:
-            with tr.boundary("serve.prefill.dispatch",
-                             **span_args) as dispatch:
-                entry = self._execs.get_or_build(
-                    ("serve.prefill", tbucket),
-                    lambda: self._build_prefill_paged(tbucket),
-                    label=f"serve.prefill_b{tbucket}", donate=(1,), pin=True)
-                padded = np.zeros((1, tbucket), np.int64)
-                padded[0, :tail] = req.prompt_ids[base:]
-                state = dict(self._pool_state,
-                             tables=jnp.asarray(self._tables))
-                call_args = (self._params, state, jnp.asarray(padded),
-                             jnp.int32(tail), jnp.int32(base),
-                             jnp.int32(slot), jnp.float32(req.temperature),
-                             jnp.int32(req.top_k), jnp.float32(req.top_p),
-                             jnp.int32(req.seed))
-                self._stash_exec(f"serve.prefill_b{tbucket}", entry.fn,
-                                 call_args, donate=(1,))
-                monitor.stat("serving.prefill_dispatches").increase()
-                p0 = self._execs.persistent_before(entry)
-                t0 = time.perf_counter()
-                new_state, tok = entry(*call_args)
-                self._execs.note_compiles(
-                    entry, wall_s=time.perf_counter() - t0,
-                    persistent_before=p0,
-                    counter="serving.prefill_compiles")
-            with tr.boundary("serve.prefill.sync", **span_args) as sync:
-                first = int(tok)                  # device sync = first token
-            self._prefill_ms.append((dispatch.ms, sync.ms))
-        except Exception as e:
-            fr = _obs_flight.get()
-            if fr is not None:
-                fr.dump("serve_prefill_exception",
-                        {"request": req.id, "bucket": tbucket,
-                         "base": base, "error": repr(e)})
-            self._finish(req, outcome="error")
-            raise
-        self._pool_state = new_state
-        req.first_token_ts = time.perf_counter()
-        if tr.enabled:
-            tr.record_complete("serve.prefill", req.admit_ts,
-                               req.first_token_ts, span_args)
-        if mreg is not None:
-            mreg.histogram("serve.prefill_ms").observe(
-                (req.first_token_ts - req.admit_ts) * 1e3)
+            page = kv.pool.alloc()
+            kv.tables[slot, pi] = page
+            kv.slot_pages[slot].append(page)
+        first = self._run_prefill(
+            req, tbucket, req.prompt_ids[base:], (base, slot),
+            req.trace_args(bucket=tbucket, base=base, slot=slot))
         # publish this prompt's fully-written pages for future sharers
         full_pages = plen // pt
         if full_pages > k_shared:
-            self._prefix.insert(
+            kv.prefix.insert(
                 req.prompt_ids[:full_pages * pt],
-                [int(p) for p in self._tables[slot, :full_pages]])
-        req.slot = slot
-        req.tokens.append(first)
-        self._count_tokens(1)
-        if (eos != _NO_EOS and first == eos) or req.max_new_tokens <= 1:
-            req.finish_reason = ("eos" if eos != _NO_EOS and first == eos
-                                 else "length")
-            self._release_slot(slot)
-            self._finish(req)
-            return True
-        self._offsets[slot] = plen
-        self._last_tok[slot] = first
-        self._active[slot] = True
-        self._replay[slot] = False
-        self._temps[slot] = req.temperature
-        self._topk[slot] = req.top_k
-        self._topp[slot] = req.top_p
-        self._eos[slot] = eos
-        self._remaining[slot] = req.max_new_tokens - 1
-        self._seeds[slot] = req.seed
-        self._slot_req[slot] = req
-        self._seat_spec(req, slot)
+                [int(p) for p in kv.tables[slot, :full_pages]])
+        self._seat_after_prefill(req, slot, first)
         return True
 
     # ---- decode --------------------------------------------------------
     def _build_decode(self, family: str):
+        """The continuous-batching decode chunk, ONE executable a sampling
+        family on either layout: `steps_per_dispatch` single-token steps in
+        a scan over the donated slot cache."""
         import jax
         import jax.numpy as jnp
 
-        from ..core.tensor import Tensor
         from .sampling import request_key, sample_tokens
 
         T = self.max_seq_len
         n_inner = self.steps_per_dispatch
         greedy_only = family == "greedy"
+        kv = self._kv
 
-        def step_chunk(params, kcs, vcs, off, tok, active, temps, top_k,
-                       top_p, eos, remaining, seeds):
+        def step_chunk(params, *args):
+            cache, (off, tok, active, temps, top_k, top_p, eos, remaining,
+                    seeds) = _split(args, kv.n_args)
+
             def one(carry, _):
-                kcs, vcs, off, tok, active, remaining = carry
-                # idle slots keep writing their (ignored) tip row; clamp so
-                # a full slot can never index past the cache
-                off_m = jnp.minimum(off, jnp.int32(T - 1))
-                caches = [(Tensor(kc), Tensor(vc), Tensor(off_m))
-                          for kc, vc in zip(kcs, vcs)]
+                cache, off, tok, active, remaining = carry
+                caches = kv.views(cache, kv.tip(off), active)
                 h, caches, stats = self._backbone(
                     self.model, params, tok[:, None].astype(jnp.int64),
                     caches)
-                logits = self._head_traced(params, h[:, 0])  # [S, V]
+                logits = self._head_traced(self.model, params,
+                                           h[:, 0])  # [S, V]
                 act = active.astype(jnp.int32)
                 new_off = off + act         # the sampled token's position
                 if greedy_only:
@@ -1390,122 +1240,21 @@ class ServingEngine:
                 hit_eos = active & (eos != _NO_EOS) & (nxt == eos)
                 new_active = (active & ~hit_eos & (new_remaining > 0)
                               & (new_off < T))
-                new_kcs = [c[0]._data for c in caches]
-                new_vcs = [c[1]._data for c in caches]
-                return ((new_kcs, new_vcs, new_off, nxt, new_active,
-                         new_remaining), (nxt, active, hit_eos, stats))
+                return ((kv.absorb(cache, caches, active), new_off, nxt,
+                         new_active, new_remaining),
+                        (nxt, active, hit_eos, stats))
 
-            carry = (kcs, vcs, off, tok, active, remaining)
-            (kcs, vcs, off, tok, active, remaining), (
+            carry = (cache, off, tok, active, remaining)
+            (cache, off, tok, active, remaining), (
                 toks, was_active, hits, stats) = jax.lax.scan(
                 one, carry, None, length=n_inner)
             # toks/was_active/hits: [n_inner, S]; stats: what the model
             # reports of a step ({} for most), folded over the fused steps
-            return (kcs, vcs, off, tok, active, remaining, toks, was_active,
+            return (*cache, off, tok, active, remaining, toks, was_active,
                     hits, self._fold_step_stats(stats))
 
         return jax.jit(jax.named_scope("decode")(step_chunk),
-                       donate_argnums=(1, 2))
-
-    def _build_decode_paged(self, family: str):
-        """Paged decode chunk: same continuous-batching scan as the dense
-        decode, but K/V flows through the donated pool state (per-layer
-        pools + scales + the page table). Extra per-row ``replay`` flag:
-        a full-prefix-hit slot's first step re-derives a position whose
-        K/V already sits in a shared page, so its write is redirected to
-        the scratch page; the flag clears after the row's first active
-        step and the row behaves like any other from then on."""
-        import jax
-        import jax.numpy as jnp
-
-        from . import kv_pages as _kvp
-        from .sampling import request_key, sample_tokens
-
-        T = self.max_seq_len
-        t_eff = self._t_eff
-        n_inner = self.steps_per_dispatch
-        greedy_only = family == "greedy"
-        pt = self.page_tokens
-        quant = self._kv_quantized
-        compute_dtype = self._cache_dtype
-
-        def step_chunk(params, state, off, tok, active, replay, temps,
-                       top_k, top_p, eos, remaining, seeds):
-            tables = state["tables"]
-
-            def one(carry, _):
-                ks, vs, kss, vss, off, tok, active, replay, remaining = carry
-                off_m = jnp.clip(off, 0, jnp.int32(t_eff - 1))
-                st = {"k": ks, "v": vs, "ks": kss, "vs": vss}
-                # idle rows and replaying rows write to the scratch page
-                caches = _kvp.layer_views(st, tables, off_m,
-                                          active & ~replay, pt,
-                                          compute_dtype)
-                h, caches, _ = self._backbone(
-                    self.model, params, tok[:, None].astype(jnp.int64),
-                    caches)
-                logits = self._head_traced(params, h[:, 0])  # [S, V]
-                act = active.astype(jnp.int32)
-                new_off = off + act         # the sampled token's position
-                if greedy_only:
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                else:
-                    keys = jax.vmap(request_key)(seeds, new_off)
-                    # a retired slot draws as a greedy row: no search
-                    nxt = sample_tokens(logits, keys,
-                                        jnp.where(active, temps, 0.0),
-                                        top_k, top_p)
-                nxt = jnp.where(active, nxt, tok)
-                new_remaining = remaining - act
-                hit_eos = active & (eos != _NO_EOS) & (nxt == eos)
-                new_active = (active & ~hit_eos & (new_remaining > 0)
-                              & (new_off < T))
-                new_replay = replay & ~active
-                new_ks = [c.k_pool for c in caches]
-                new_vs = [c.v_pool for c in caches]
-                new_kss = [c.k_scale for c in caches] if quant else []
-                new_vss = [c.v_scale for c in caches] if quant else []
-                return ((new_ks, new_vs, new_kss, new_vss, new_off, nxt,
-                         new_active, new_replay, new_remaining),
-                        (nxt, active, hit_eos))
-
-            carry = (state["k"], state["v"], state["ks"], state["vs"], off,
-                     tok, active, replay, remaining)
-            ((ks, vs, kss, vss, off, tok, active, replay, remaining),
-             (toks, was_active, hits)) = jax.lax.scan(
-                one, carry, None, length=n_inner)
-            new_state = {"k": ks, "v": vs, "ks": kss, "vs": vss,
-                         "tables": tables}
-            return (new_state, off, tok, active, replay, remaining, toks,
-                    was_active, hits)
-
-        return jax.jit(jax.named_scope("decode")(step_chunk),
-                       donate_argnums=(1,))
-
-    def _prealloc_decode_pages(self) -> None:
-        """Host-side, between dispatches: make sure every active slot's
-        table row covers the positions the next chunk may write (the
-        table is static within a dispatch). Evicts LRU cached prefixes
-        under pressure; admission reservations guarantee success."""
-        import numpy as np
-
-        from . import kv_pages as _kvp
-
-        pt = self.page_tokens
-        for i in np.nonzero(self._active)[0]:
-            first = int(self._offsets[i]) + (1 if self._replay[i] else 0)
-            last = min(int(self._offsets[i]) + self.steps_per_dispatch,
-                       self.max_seq_len) - 1
-            for pi in range(first // pt, last // pt + 1):
-                if self._tables[i, pi] == 0:
-                    if not self._prefix.ensure_free(1):
-                        raise _kvp.PoolExhausted(
-                            f"decode needs a page for slot {i} and none is "
-                            "free or evictable (reservation accounting "
-                            "violated)")
-                    page = self._pool.alloc()
-                    self._tables[i, pi] = page
-                    self._slot_pages[i].append(page)
+                       donate_argnums=self._donate(1, kv))
 
     # ---- speculative decoding: verify ----------------------------------
     def _spec_commit(self, jax, jnp, logits, dlogits_sk, props, off, tok,
@@ -1605,32 +1354,39 @@ class ServingEngine:
                 hit_eos)
 
     def _build_verify(self, family: str, k: int):
-        """Contiguous-layout verify program, one executable per (sampling
-        family, ladder rung k): a draft scan proposes k tokens, then
-        ONE [S, k+1] window forward through the target scores every
-        proposal plus the bonus position, and the commit math accepts the
-        longest agreeing prefix. Rejected rows need no cache surgery —
-        the offset rewind leaves them as inert stale rows (causal masking
-        hides them, and they are rewritten before any query attends them,
-        the same argument decode's idle-row tip writes rely on)."""
+        """The verify program, one executable per (sampling family, ladder
+        rung k) on either layout: a draft scan proposes k tokens, then ONE
+        [S, k+1] window forward through the target scores every proposal
+        plus the bonus position, and the commit math accepts the longest
+        agreeing prefix. The window's write mask is the active rows' columns
+        up to their n_draft: on the paged layout later columns have no pages
+        and go to the scratch page. Rejected rows need no cache surgery on
+        the device — the offset rewind leaves them as inert stale rows
+        (causal masking hides them, and they are rewritten before any query
+        attends them, the same argument decode's idle-row tip writes rely
+        on); the host truncates a paged slot's table past the accepted
+        frontier."""
         import jax
         import jax.numpy as jnp
 
-        from ..core.tensor import Tensor
         from .sampling import DRAFT_SALT, sample_tokens, spec_key
 
         greedy_only = family == "greedy"
+        kv, dkv = self._kv, self._dkv
 
-        def verify(params, dparams, kcs, vcs, dkcs, dvcs, off, tok, active,
-                   n_draft, temps, top_k, top_p, eos, remaining, seeds):
+        def verify(params, dparams, *args):
+            cache, dcache, (off, tok, active, n_draft, temps, top_k, top_p,
+                            eos, remaining, seeds) = _split(
+                args, kv.n_args, dkv.n_args)
+
             def dstep(carry, i):
-                dkcs, dvcs, cur = carry
-                caches = [(Tensor(kc), Tensor(vc), Tensor(off + i))
-                          for kc, vc in zip(dkcs, dvcs)]
+                dcache, cur = carry
+                caches = dkv.views(dcache, off + i, active)
                 h, caches, _ = self._backbone(
                     self.draft_model, dparams,
                     cur[:, None].astype(jnp.int64), caches)
-                dlogits = self._draft_head_traced(dparams, h[:, 0])
+                dlogits = self._head_traced(self.draft_model, dparams,
+                                            h[:, 0])
                 if greedy_only:
                     d = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
                     out = d
@@ -1639,17 +1395,15 @@ class ServingEngine:
                         seeds, off + i + 1, DRAFT_SALT)
                     d = sample_tokens(dlogits, keys, temps, top_k, top_p)
                     out = (d, dlogits)
-                return ([c[0]._data for c in caches],
-                        [c[1]._data for c in caches], d), out
+                return (dkv.absorb(dcache, caches, active), d), out
 
             # k+1 steps, last proposal discarded: the extra step feeds d_k
             # so the draft cache stays dense through position off+k — a
             # fully-accepted window advances the frontier past off+k, and
             # a hole there would poison every later window's draft
             # attention (accept-rate collapse, not a correctness bug)
-            (dkcs, dvcs, _), outs = jax.lax.scan(
-                dstep, (dkcs, dvcs, tok),
-                jnp.arange(k + 1, dtype=jnp.int32))
+            (dcache, _), outs = jax.lax.scan(
+                dstep, (dcache, tok), jnp.arange(k + 1, dtype=jnp.int32))
             if greedy_only:
                 props = outs.T[:, :k]                            # [S, k]
                 dlogits_sk = None
@@ -1658,124 +1412,35 @@ class ServingEngine:
                 dlogits_sk = jnp.moveaxis(outs[1], 0, 1)[:, :k]  # [S, k, V]
 
             win = jnp.concatenate([tok[:, None], props], axis=1)
-            caches = [(Tensor(kc), Tensor(vc), Tensor(off))
-                      for kc, vc in zip(kcs, vcs)]
+            cols = jnp.arange(k + 1, dtype=jnp.int32)[None, :]
+            caches = kv.views(
+                cache, off, active[:, None] & (cols <= n_draft[:, None]))
             h, caches, _ = self._backbone(self.model, params,
                                           win.astype(jnp.int64), caches)
             S = win.shape[0]
             logits = self._head_traced(
-                params, h.reshape((S * (k + 1), -1))
+                self.model, params, h.reshape((S * (k + 1), -1))
             ).reshape((S, k + 1, -1))
-            kcs = [c[0]._data for c in caches]
-            vcs = [c[1]._data for c in caches]
+            cache = kv.absorb(cache, caches, active)
             (new_off, new_tok, new_active, new_remaining, emit, m, a,
              hit_eos) = self._spec_commit(
                 jax, jnp, logits, dlogits_sk, props, off, tok, active,
                 n_draft, temps, top_k, top_p, eos, remaining, seeds, k,
                 greedy_only)
-            return (kcs, vcs, dkcs, dvcs, new_off, new_tok, new_active,
+            return (*cache, *dcache, new_off, new_tok, new_active,
                     new_remaining, emit, m, a, hit_eos)
 
         return jax.jit(jax.named_scope("decode")(verify),
-                       donate_argnums=(2, 3, 4, 5))
-
-    def _build_verify_paged(self, family: str, k: int):
-        """Paged-layout verify: target K/V flows through the donated pool
-        state with a 2-D [S, k+1] write mask — columns past a row's
-        n_draft have no pages allocated and redirect to the scratch page,
-        and a prefix-replay row's column 0 (position plen-1, living in a
-        SHARED page) takes the same scratch redirect the decode replay
-        seat uses. The draft cache stays contiguous. Rollback beyond the
-        accepted frontier is host-side page-table truncation."""
-        import jax
-        import jax.numpy as jnp
-
-        from ..core.tensor import Tensor
-        from . import kv_pages as _kvp
-        from .sampling import DRAFT_SALT, sample_tokens, spec_key
-
-        greedy_only = family == "greedy"
-        pt = self.page_tokens
-        quant = self._kv_quantized
-        compute_dtype = self._cache_dtype
-
-        def verify(params, dparams, state, dkcs, dvcs, off, tok, active,
-                   replay, n_draft, temps, top_k, top_p, eos, remaining,
-                   seeds):
-            tables = state["tables"]
-
-            def dstep(carry, i):
-                dkcs, dvcs, cur = carry
-                caches = [(Tensor(kc), Tensor(vc), Tensor(off + i))
-                          for kc, vc in zip(dkcs, dvcs)]
-                h, caches, _ = self._backbone(
-                    self.draft_model, dparams,
-                    cur[:, None].astype(jnp.int64), caches)
-                dlogits = self._draft_head_traced(dparams, h[:, 0])
-                if greedy_only:
-                    d = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
-                    out = d
-                else:
-                    keys = jax.vmap(spec_key, in_axes=(0, 0, None))(
-                        seeds, off + i + 1, DRAFT_SALT)
-                    d = sample_tokens(dlogits, keys, temps, top_k, top_p)
-                    out = (d, dlogits)
-                return ([c[0]._data for c in caches],
-                        [c[1]._data for c in caches], d), out
-
-            # k+1 steps, last proposal discarded — keeps the draft cache
-            # dense through off+k (see the contiguous builder)
-            (dkcs, dvcs, _), outs = jax.lax.scan(
-                dstep, (dkcs, dvcs, tok),
-                jnp.arange(k + 1, dtype=jnp.int32))
-            if greedy_only:
-                props = outs.T[:, :k]
-                dlogits_sk = None
-            else:
-                props = outs[0].T[:, :k]
-                dlogits_sk = jnp.moveaxis(outs[1], 0, 1)[:, :k]
-
-            win = jnp.concatenate([tok[:, None], props], axis=1)
-            cols = jnp.arange(k + 1, dtype=jnp.int32)[None, :]
-            wmask = (active[:, None] & (cols <= n_draft[:, None])
-                     & ~(replay[:, None] & (cols == 0)))
-            st = {"k": state["k"], "v": state["v"], "ks": state["ks"],
-                  "vs": state["vs"]}
-            caches = _kvp.layer_views(st, tables, off, wmask, pt,
-                                      compute_dtype)
-            h, caches, _ = self._backbone(self.model, params,
-                                          win.astype(jnp.int64), caches)
-            S = win.shape[0]
-            logits = self._head_traced(
-                params, h.reshape((S * (k + 1), -1))
-            ).reshape((S, k + 1, -1))
-            new_state = {
-                "k": [c.k_pool for c in caches],
-                "v": [c.v_pool for c in caches],
-                "ks": [c.k_scale for c in caches] if quant else [],
-                "vs": [c.v_scale for c in caches] if quant else [],
-                "tables": tables,
-            }
-            (new_off, new_tok, new_active, new_remaining, emit, m, a,
-             hit_eos) = self._spec_commit(
-                jax, jnp, logits, dlogits_sk, props, off, tok, active,
-                n_draft, temps, top_k, top_p, eos, remaining, seeds, k,
-                greedy_only)
-            new_replay = replay & ~active
-            return (new_state, dkcs, dvcs, new_off, new_tok, new_active,
-                    new_replay, new_remaining, emit, m, a, hit_eos)
-
-        return jax.jit(jax.named_scope("decode")(verify),
-                       donate_argnums=(2, 3, 4))
+                       donate_argnums=self._donate(2, kv, dkv))
 
     def _spec_dispatch_rung(self) -> int:
         """Window rung for the next dispatch: the max ladder rung among
         active speculating slots, or 0 when the dispatch must fall back to
-        plain decode. Contiguous layout falls back while any active slot
-        sits on the last cache row — the window's unmasked per-row writes
-        would collapse onto row T-1 and corrupt the position the bonus
-        column reads (bounded: only the final token of a max-length
-        sequence takes the slow path)."""
+        plain decode. A cache that cannot mask a write (contiguous) falls back
+        while any active slot sits on the last cache row — the window's
+        unmasked per-row writes would collapse onto row T-1 and corrupt the
+        position the bonus column reads (bounded: only the final token of a
+        max-length sequence takes the slow path)."""
         import numpy as np
 
         if self.draft_model is None or not self._active.any():
@@ -1783,7 +1448,7 @@ class ServingEngine:
         rungs = self._spec_k[self._active]
         if not rungs.any():
             return 0
-        if (self.kv_layout != "paged"
+        if (not self._kv.masks_writes
                 and int(self._offsets[self._active].max())
                 >= self.max_seq_len - 1):
             return 0
@@ -1800,32 +1465,6 @@ class ServingEngine:
         else:
             self._decode_step()
 
-    def _prealloc_verify_pages(self, n_draft) -> None:
-        """Paged pre-verify: cover every position the window may write —
-        off..off+n_draft per active slot (a replay slot's column 0 is
-        scratch-redirected, so its coverage starts at off+1). n_draft is
-        clamped to remaining-1 on the host, so this never exceeds the
-        admission reservation (end = off + remaining)."""
-        import numpy as np
-
-        from . import kv_pages as _kvp
-
-        pt = self.page_tokens
-        for i in np.nonzero(self._active)[0]:
-            first = int(self._offsets[i]) + (1 if self._replay[i] else 0)
-            last = min(int(self._offsets[i]) + int(n_draft[i]),
-                       self.max_seq_len - 1)
-            for pi in range(first // pt, last // pt + 1):
-                if self._tables[i, pi] == 0:
-                    if not self._prefix.ensure_free(1):
-                        raise _kvp.PoolExhausted(
-                            f"verify needs a page for slot {i} and none is "
-                            "free or evictable (reservation accounting "
-                            "violated)")
-                    page = self._pool.alloc()
-                    self._tables[i, pi] = page
-                    self._slot_pages[i].append(page)
-
     def _verify_step(self, k: int) -> None:
         """Host driver for one speculative verify dispatch: draft scan +
         [S, k+1] target window + accept/commit on device, then per-slot
@@ -1835,17 +1474,16 @@ class ServingEngine:
         import numpy as np
 
         from ..core import monitor
-        from . import kv_pages as _kvp
 
         family = ("greedy"
                   if not self._temps[self._active].any() else "sample")
-        paged = self.kv_layout == "paged"
+        kv, dkv = self._kv, self._dkv
+        label, donate = (f"serve.verify_{family}_k{k}",
+                         self._donate(2, kv, dkv))
         entry = self._execs.get_or_build(
             ("serve.verify", family, k),
-            lambda: (self._build_verify_paged(family, k) if paged
-                     else self._build_verify(family, k)),
-            label=f"serve.verify_{family}_k{k}",
-            donate=(2, 3, 4) if paged else (2, 3, 4, 5), pin=True)
+            lambda: self._build_verify(family, k),
+            label=label, donate=donate, pin=True)
         # per-slot draft window: the request's rung, clamped so the window
         # never outruns the token budget (keeps paged writes inside the
         # admission reservation) or the cache end, and zero on non-spec
@@ -1855,44 +1493,27 @@ class ServingEngine:
         n_draft = np.minimum(
             n_draft, np.maximum(self.max_seq_len - 2 - self._offsets, 0))
         n_draft = np.where(self._active, n_draft, 0).astype(np.int32)
-        if paged:
-            self._prealloc_verify_pages(n_draft)
-            state = dict(self._pool_state,
-                         tables=jnp.asarray(self._tables))
-            call_args = (self._params, self._dparams, state, self._dkcs,
-                         self._dvcs, jnp.asarray(self._offsets),
-                         jnp.asarray(self._last_tok),
-                         jnp.asarray(self._active),
-                         jnp.asarray(self._replay), jnp.asarray(n_draft),
-                         jnp.asarray(self._temps), jnp.asarray(self._topk),
-                         jnp.asarray(self._topp), jnp.asarray(self._eos),
-                         jnp.asarray(self._remaining),
-                         jnp.asarray(self._seeds))
-            self._stash_exec(f"serve.verify_{family}_k{k}", entry.fn,
-                             call_args, donate=(2, 3, 4))
-        else:
-            call_args = (self._params, self._dparams, self._kcs, self._vcs,
-                         self._dkcs, self._dvcs,
-                         jnp.asarray(self._offsets),
-                         jnp.asarray(self._last_tok),
-                         jnp.asarray(self._active), jnp.asarray(n_draft),
-                         jnp.asarray(self._temps), jnp.asarray(self._topk),
-                         jnp.asarray(self._topp), jnp.asarray(self._eos),
-                         jnp.asarray(self._remaining),
-                         jnp.asarray(self._seeds))
-            self._stash_exec(f"serve.verify_{family}_k{k}", entry.fn,
-                             call_args, donate=(2, 3, 4, 5))
+        # cover every position the window may write, off..off+n_draft a
+        # slot: n_draft is clamped to remaining-1 above, so this never
+        # exceeds the admission reservation (end = off + remaining)
+        kv.cover(self._active, self._offsets,
+                 np.minimum(self._offsets + n_draft, self.max_seq_len - 1))
+        call_args = (self._params, self._dparams, *kv.args(), *dkv.args(),
+                     jnp.asarray(self._offsets), jnp.asarray(self._last_tok),
+                     jnp.asarray(self._active), jnp.asarray(n_draft),
+                     jnp.asarray(self._temps), jnp.asarray(self._topk),
+                     jnp.asarray(self._topp), jnp.asarray(self._eos),
+                     jnp.asarray(self._remaining), jnp.asarray(self._seeds))
+        self._stash_exec(label, entry.fn, call_args, donate=donate)
         active_before = self._active.copy()
         p0 = self._execs.persistent_before(entry)
         t0 = time.perf_counter()
         try:
-            if paged:
-                (self._pool_state, self._dkcs, self._dvcs, off, tok, active,
-                 replay, remaining, emit, m, a, hits) = entry(*call_args)
-                self._replay = np.array(replay)
-            else:
-                (self._kcs, self._vcs, self._dkcs, self._dvcs, off, tok,
-                 active, remaining, emit, m, a, hits) = entry(*call_args)
+            cache, dcache, (off, tok, active, remaining, emit, m, a,
+                            hits) = _split(entry(*call_args), kv.n_args,
+                                           dkv.n_args)
+            kv.take(cache, active_before)
+            dkv.take(dcache, active_before)
             self._execs.note_compiles(
                 entry, wall_s=time.perf_counter() - t0, persistent_before=p0,
                 counter="serving.verify_compiles")
@@ -1946,20 +1567,15 @@ class ServingEngine:
                 mreg.histogram("spec.accept_rate",
                                boundaries=_OCCUPANCY_BUCKETS).observe(
                     acc / nd)
-            if paged:
-                # rollback: any page whose positions lie wholly past the
-                # accepted frontier was only touched by rejected draft
-                # rows — truncate it out of the table and free it (always
-                # slot-private: shared prompt pages sit below the frontier)
-                _kvp.truncate_row(
-                    self._tables, self._slot_pages[slot],
-                    self._prefix.release, slot,
-                    int(self._offsets[slot]) // self.page_tokens + 1)
+            # rollback: any page whose positions lie wholly past the
+            # accepted frontier was only touched by rejected draft rows —
+            # the cache drops it (always slot-private: shared prompt pages
+            # sit below the frontier)
+            kv.truncate(slot, int(self._offsets[slot]))
             if not self._active[slot]:
                 req.finish_reason = "eos" if hits[slot] else "length"
                 self._slot_req[slot] = None
-                if paged:
-                    self._release_slot(slot)
+                kv.release(slot)
                 self._finish(req, now)
         self._count_tokens(emitted)
         monitor.stat("serving.steps").increase()
@@ -1977,11 +1593,8 @@ class ServingEngine:
                            boundaries=_OCCUPANCY_BUCKETS).observe(occupancy)
             mreg.gauge("serve.queue_depth").set(len(self._queue))
             mreg.gauge("serve.active_slots").set(int(self._active.sum()))
-            if paged:
-                mreg.gauge("serve.pages_in_use").set(self._pool.in_use)
-                mreg.gauge("serve.pages_cached").set(self._pool.cached)
-                mreg.gauge("serve.prefix_hit_rate").set(
-                    self._prefix.hit_rate)
+            for name, value in kv.gauges().items():
+                mreg.gauge("serve." + name).set(value)
         fr = _obs_flight.get()
         if self.sink is not None or fr is not None:
             rec = {
@@ -1998,11 +1611,8 @@ class ServingEngine:
                 "spec": True, "spec_window": k,
                 "spec_proposed": proposed, "spec_accepted": accepted,
                 "spec_bonus": bonus,
+                **{name: round(v, 4) for name, v in kv.gauges().items()},
             }
-            if paged:
-                rec["pages_in_use"] = self._pool.in_use
-                rec["pages_cached"] = self._pool.cached
-                rec["prefix_hit_rate"] = round(self._prefix.hit_rate, 4)
             if self.sink is not None:
                 self.sink.write(rec)
             if fr is not None:
@@ -2013,7 +1623,7 @@ class ServingEngine:
         import numpy as np
 
         tr = _obs_tracer.get_tracer()
-        paged = self.kv_layout == "paged"
+        kv = self._kv
         # per-dispatch family pick: an all-greedy slot set runs the slim
         # executable; any sampling slot routes to the full one. Two decode
         # executables max, regardless of traffic mix.
@@ -2027,48 +1637,28 @@ class ServingEngine:
                     step=self._steps,
                     requests=[r.id for r in self._slot_req
                               if r is not None]) as dispatch:
+                label, donate = f"serve.decode_{family}", self._donate(1, kv)
                 entry = self._execs.get_or_build(
                     ("serve.decode", family),
-                    lambda: (self._build_decode_paged(family) if paged
-                             else self._build_decode(family)),
-                    label=f"serve.decode_{family}",
-                    donate=(1,) if paged else (1, 2), pin=True)
-                if paged:
-                    self._prealloc_decode_pages()
-                    state = dict(self._pool_state,
-                                 tables=jnp.asarray(self._tables))
-                    call_args = (
-                        self._params, state, jnp.asarray(self._offsets),
-                        jnp.asarray(self._last_tok),
-                        jnp.asarray(self._active),
-                        jnp.asarray(self._replay),
-                        jnp.asarray(self._temps), jnp.asarray(self._topk),
-                        jnp.asarray(self._topp), jnp.asarray(self._eos),
-                        jnp.asarray(self._remaining),
-                        jnp.asarray(self._seeds))
-                    self._stash_exec(f"serve.decode_{family}", entry.fn,
-                                     call_args, donate=(1,))
-                else:
-                    call_args = (
-                        self._params, self._kcs, self._vcs,
-                        jnp.asarray(self._offsets),
-                        jnp.asarray(self._last_tok),
-                        jnp.asarray(self._active),
-                        jnp.asarray(self._temps), jnp.asarray(self._topk),
-                        jnp.asarray(self._topp), jnp.asarray(self._eos),
-                        jnp.asarray(self._remaining),
-                        jnp.asarray(self._seeds))
-                    self._stash_exec(f"serve.decode_{family}", entry.fn,
-                                     call_args)
+                    lambda: self._build_decode(family),
+                    label=label, donate=donate, pin=True)
+                # the positions this chunk may write (a slot's table row is
+                # static within a dispatch)
+                kv.cover(self._active, self._offsets,
+                         np.minimum(self._offsets + self.steps_per_dispatch,
+                                    self.max_seq_len) - 1)
+                call_args = (
+                    self._params, *kv.args(), jnp.asarray(self._offsets),
+                    jnp.asarray(self._last_tok), jnp.asarray(self._active),
+                    jnp.asarray(self._temps), jnp.asarray(self._topk),
+                    jnp.asarray(self._topp), jnp.asarray(self._eos),
+                    jnp.asarray(self._remaining), jnp.asarray(self._seeds))
+                self._stash_exec(label, entry.fn, call_args, donate=donate)
                 p0 = self._execs.persistent_before(entry)
                 t0 = time.perf_counter()
-                if paged:
-                    (self._pool_state, off, tok, active, replay, remaining,
-                     toks, was_active, hits) = entry(*call_args)
-                    stats = {}
-                else:
-                    (self._kcs, self._vcs, off, tok, active, remaining, toks,
-                     was_active, hits, stats) = entry(*call_args)
+                cache, (off, tok, active, remaining, toks, was_active, hits,
+                        stats) = _split(entry(*call_args), kv.n_args)
+                kv.take(cache, self._active)
                 self._execs.note_compiles(
                     entry, wall_s=time.perf_counter() - t0,
                     persistent_before=p0, counter="serving.decode_compiles")
@@ -2080,8 +1670,6 @@ class ServingEngine:
                 # np.array (copy): zero-copy views of jax buffers are
                 # read-only, and _admit mutates these in place when it
                 # seats the next request
-                if paged:
-                    self._replay = np.array(replay)
                 self._offsets = np.array(off)
                 self._last_tok = np.array(tok)
                 self._active = np.array(active)
@@ -2112,8 +1700,8 @@ class ServingEngine:
         self._admit_ms, self._prefill_ms = None, []   # told once (drain()
         #                               dispatches without a step() before it)
         with tr.boundary("serve.emit") as emit:
-            self._emit_decoded(toks, was_active, hits, paged, spans_ms,
-                               host_gap_ms, emit, stats)
+            self._emit_decoded(toks, was_active, hits, spans_ms, host_gap_ms,
+                               emit, stats)
 
     def _fold_step_stats(self, stats):
         """What the model reported at each fused step -> one value a
@@ -2123,8 +1711,8 @@ class ServingEngine:
         return {name: v.max() if how[name] == "max" else v.mean()
                 for name, v in stats.items()}
 
-    def _emit_decoded(self, toks, was_active, hits, paged, spans_ms,
-                      host_gap_ms, emit, stats) -> None:
+    def _emit_decoded(self, toks, was_active, hits, spans_ms, host_gap_ms,
+                      emit, stats) -> None:
         """Hand a fetched dispatch's tokens to their requests, retire the
         finished, count, and write the `serve_step` sink record. `stats` is
         what the model reported of the dispatch (`_fold_step_stats`): it goes
@@ -2146,8 +1734,7 @@ class ServingEngine:
                 if not alive_after[slot]:     # retired at this inner step
                     req.finish_reason = "eos" if hits[j, slot] else "length"
                     self._slot_req[slot] = None
-                    if paged:
-                        self._release_slot(slot)
+                    self._kv.release(slot)
                     self._finish(req, now)
         emitted = int(was_active.sum())
         self._count_tokens(emitted)
@@ -2165,11 +1752,8 @@ class ServingEngine:
                            boundaries=_OCCUPANCY_BUCKETS).observe(occupancy)
             mreg.gauge("serve.queue_depth").set(len(self._queue))
             mreg.gauge("serve.active_slots").set(int(self._active.sum()))
-            if paged:
-                mreg.gauge("serve.pages_in_use").set(self._pool.in_use)
-                mreg.gauge("serve.pages_cached").set(self._pool.cached)
-                mreg.gauge("serve.prefix_hit_rate").set(
-                    self._prefix.hit_rate)
+            for name, value in self._kv.gauges().items():
+                mreg.gauge("serve." + name).set(value)
         fr = _obs_flight.get()
         if self.sink is not None or fr is not None:
             # `emit` is still open: its time up to here, the record's own
@@ -2195,11 +1779,9 @@ class ServingEngine:
                 # what the next step's attention reads
                 "contexts": self._offsets[self._active].tolist(),
                 **stats,
+                **{name: round(v, 4)
+                   for name, v in self._kv.gauges().items()},
             }
-            if paged:
-                rec["pages_in_use"] = self._pool.in_use
-                rec["pages_cached"] = self._pool.cached
-                rec["prefix_hit_rate"] = round(self._prefix.hit_rate, 4)
             if self.sink is not None:
                 self.sink.write(rec)
             if fr is not None:

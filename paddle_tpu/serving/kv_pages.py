@@ -42,7 +42,9 @@ prefix cache — see prefix_cache.py).
 from __future__ import annotations
 
 from collections import OrderedDict, deque
-from typing import Dict, List, Optional, Sequence
+from typing import List, Optional
+
+from jax.tree_util import register_pytree_node_class
 
 ZERO_PAGE = 0
 SCRATCH_PAGE = 1
@@ -163,10 +165,10 @@ def quantize_kv_int8(x):
     return q.astype(jnp.int8), scale
 
 
+@register_pytree_node_class
 class PagedLayerCache:
-    """Traced per-layer view of the paged KV state, duck-compatible with
-    the dense ``(k_cache, v_cache, offset)`` cache tuple GPTModel indexes
-    (``cache[2]`` -> per-row offsets). Built fresh inside each traced
+    """One layer's handle onto the paged pool (the interface of
+    nn/kv_cache.py: `positions`, `update`), built fresh inside each traced
     prefill/decode step from the donated pool-state operands.
 
     offset: int32 [b] — count of already-cached positions per row (the
@@ -174,6 +176,8 @@ class PagedLayerCache:
     write_mask: bool [b] or [b, s] — rows/positions whose scatter goes to
     a real page; everything else is redirected to the scratch page.
     """
+
+    fresh = False
 
     def __init__(self, k_pool, v_pool, page_table, offset, write_mask,
                  page_tokens: int, compute_dtype, k_scale=None, v_scale=None):
@@ -187,145 +191,277 @@ class PagedLayerCache:
         self.k_scale = k_scale          # [P, pt, nh] f32 (int8 mode only)
         self.v_scale = v_scale
 
+    def tree_flatten(self):
+        return ((self.k_pool, self.v_pool, self.page_table, self.offset,
+                 self.write_mask, self.k_scale, self.v_scale),
+                (self.page_tokens, self.compute_dtype))
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(*leaves[:5], *aux, *leaves[5:])
+
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
 
-    def __getitem__(self, i):
-        # GPTModel reads caches[0][2] for position embeddings
-        if i == 2:
-            from ..core.tensor import Tensor
+    def positions(self, s: int):
+        import jax.numpy as jnp
 
-            return Tensor(self.offset)
-        raise IndexError(f"PagedLayerCache exposes only [2] (offset), "
-                         f"got [{i}]")
+        return self.offset[:, None] + jnp.arange(s)[None, :]
 
+    def update(self, k, v):
+        """Scatter this step's K/V into the pools through the page table,
+        then gather the full logical cache back out in compute dtype.
 
-def update_and_read(cache: PagedLayerCache, k, v):
-    """Scatter this step's K/V into the pools through the page table, then
-    gather the full logical cache back out in compute dtype.
+        k, v: [b, s, nh, hd]. Returns (kc, vc, held, new_cache): kc/vc are
+        the dense [b, max_pages * page_tokens, nh, hd] views attention
+        consumes, row p of a slot holding position p (an unallocated table
+        entry aliases the zero page, so what the causal test hides reads as
+        a zero-initialized contiguous cache does, bit for bit), and
+        new_cache carries the updated pools with offset advanced by s.
+        """
+        import jax.numpy as jnp
 
-    k, v: [b, s, nh, hd]. Returns (kc, vc, new_cache) where kc/vc are the
-    dense [b, max_pages * page_tokens, nh, hd] views attention consumes
-    and new_cache carries the updated pools with offset advanced by s.
-    """
-    import jax.numpy as jnp
+        b, s = k.shape[0], k.shape[1]
+        pt = self.page_tokens
+        table = self.page_table
+        max_pages = table.shape[1]
+        t_eff = max_pages * pt
 
-    b, s = k.shape[0], k.shape[1]
-    pt = cache.page_tokens
-    table = cache.page_table
-    max_pages = table.shape[1]
-    t_eff = max_pages * pt
+        pos = self.offset[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+        pos_c = jnp.clip(pos, 0, t_eff - 1)                       # [b, s]
+        pidx = pos_c // pt
+        within = pos_c % pt
+        gpage = jnp.take_along_axis(table, pidx, axis=1)          # [b, s]
+        wm = self.write_mask
+        if wm.ndim == 1:
+            wm = wm[:, None]
+        # out-of-range positions (idle slot at the cache tip) always redirect
+        wm = wm & (pos < t_eff)
+        target = jnp.where(wm, gpage, jnp.int32(SCRATCH_PAGE))    # [b, s]
 
-    pos = cache.offset[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-    pos_c = jnp.clip(pos, 0, t_eff - 1)                       # [b, s]
-    pidx = pos_c // pt
-    within = pos_c % pt
-    gpage = jnp.take_along_axis(table, pidx, axis=1)          # [b, s]
-    wm = cache.write_mask
-    if wm.ndim == 1:
-        wm = wm[:, None]
-    # out-of-range positions (idle slot at the cache tip) always redirect
-    wm = wm & (pos < t_eff)
-    target = jnp.where(wm, gpage, jnp.int32(SCRATCH_PAGE))    # [b, s]
+        k_pool, v_pool = self.k_pool, self.v_pool
+        k_scale, v_scale = self.k_scale, self.v_scale
+        if self.quantized:
+            qk, sk = quantize_kv_int8(k)                  # [b,s,nh,hd]/[b,s,nh]
+            qv, sv = quantize_kv_int8(v)
+            k_pool = k_pool.at[target, within].set(qk)
+            v_pool = v_pool.at[target, within].set(qv)
+            k_scale = k_scale.at[target, within].set(sk)
+            v_scale = v_scale.at[target, within].set(sv)
+        else:
+            k_pool = k_pool.at[target, within].set(k.astype(k_pool.dtype))
+            v_pool = v_pool.at[target, within].set(v.astype(v_pool.dtype))
 
-    k_pool, v_pool = cache.k_pool, cache.v_pool
-    k_scale, v_scale = cache.k_scale, cache.v_scale
-    if cache.quantized:
-        qk, sk = quantize_kv_int8(k)                          # [b,s,nh,hd]/[b,s,nh]
-        qv, sv = quantize_kv_int8(v)
-        k_pool = k_pool.at[target, within].set(qk)
-        v_pool = v_pool.at[target, within].set(qv)
-        k_scale = k_scale.at[target, within].set(sk)
-        v_scale = v_scale.at[target, within].set(sv)
-    else:
-        k_pool = k_pool.at[target, within].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[target, within].set(v.astype(v_pool.dtype))
+        # gather: [b, max_pages, pt, nh, hd] -> [b, t_eff, nh, hd]
+        def _gather(pool, scale):
+            g = pool[table]
+            if scale is not None:
+                g = g.astype(jnp.float32) * scale[table][..., None]
+            g = g.reshape((b, t_eff) + g.shape[3:])
+            return g.astype(self.compute_dtype)
 
-    # gather: [b, max_pages, pt, nh, hd] -> [b, t_eff, nh, hd]
-    def _gather(pool, scale):
-        g = pool[table]
-        if scale is not None:
-            g = g.astype(jnp.float32) * scale[table][..., None]
-        g = g.reshape((b, t_eff) + g.shape[3:])
-        return g.astype(cache.compute_dtype)
-
-    kc = _gather(k_pool, k_scale)
-    vc = _gather(v_pool, v_scale)
-    new_cache = PagedLayerCache(
-        k_pool, v_pool, table, cache.offset + jnp.int32(s), cache.write_mask,
-        pt, cache.compute_dtype, k_scale, v_scale)
-    return kc, vc, new_cache
+        kc = _gather(k_pool, k_scale)
+        vc = _gather(v_pool, v_scale)
+        new_cache = PagedLayerCache(
+            k_pool, v_pool, table, self.offset + jnp.int32(s),
+            self.write_mask, pt, self.compute_dtype, k_scale, v_scale)
+        return kc, vc, jnp.arange(t_eff)[None, None, :], new_cache
 
 
-def truncate_row(tables, slot_pages: List[int], release, slot: int,
-                 keep_pages: int) -> int:
-    """Speculative-decode rollback for a paged slot: drop the page-table
-    entries past ``keep_pages`` and return their pages to the pool.
+class PagedSlotCache:
+    """The slot cache over a page pool (the interface of kv_state.py): device
+    side the per-layer K/V pools, optional per-layer scale pools, ONE
+    [slots, max_pages] page table for all layers and the per-row `replay`
+    flag, as one donated pytree; host side the page accounting (`pool`,
+    `prefix`, each slot's `slot_pages`, the `tables` the next dispatch
+    uploads).
 
-    After a verify window is partially rejected the slot's offset rewinds
-    to the accepted frontier; pages past ``keep_pages`` (the page holding
-    the next write position) hold only rejected rows. They are always
-    slot-private — shared prefix pages and trie-published prompt pages all
-    sit at indices below ``new_off // page_tokens`` because generation
-    positions start at the prompt length — so releasing them through the
-    prefix cache frees them outright (no trie node, refcount hits zero).
+    `replay`: a full-prefix-hit slot's first step re-derives a position whose
+    K/V already sits in a shared page, so its write goes to the scratch page;
+    the flag clears after the row's first active step."""
 
-    tables: host [slots, max_pages] int32; slot_pages: the slot's owned/
-    shared page list (mutated); release: RadixPrefixCache.release.
-    Returns the number of pages freed.
-    """
-    freed = 0
-    for pi in range(keep_pages, tables.shape[1]):
-        page = int(tables[slot, pi])
-        if page == ZERO_PAGE:
-            continue
-        tables[slot, pi] = ZERO_PAGE
-        slot_pages.remove(page)
-        release(page)
-        freed += 1
-    return freed
+    n_args = 1
+    masks_writes = True
+    prefill_at = ("base", "slot")
 
+    def __init__(self, spec, slots: int, max_seq_len: int, compute_dtype,
+                 page_tokens: int, num_pages: Optional[int], mode):
+        import jax.numpy as jnp
+        import numpy as np
 
-def make_pool_state(num_layers: int, num_pages: int, page_tokens: int,
-                    num_heads: int, head_dim: int, slots: int,
-                    max_pages: int, store_dtype, quantized: bool) -> Dict:
-    """Device-side paged state as one donated pytree: per-layer K/V pools,
-    optional per-layer scale pools, and the shared page table."""
-    import jax.numpy as jnp
+        from .prefix_cache import RadixPrefixCache
 
-    shape = (num_pages, page_tokens, num_heads, head_dim)
-    state = {
-        "k": [jnp.zeros(shape, store_dtype) for _ in range(num_layers)],
-        "v": [jnp.zeros(shape, store_dtype) for _ in range(num_layers)],
-        "ks": [], "vs": [],
-        "tables": jnp.zeros((slots, max_pages), jnp.int32),
-    }
-    if quantized:
-        sshape = (num_pages, page_tokens, num_heads)
-        state["ks"] = [jnp.zeros(sshape, jnp.float32)
-                       for _ in range(num_layers)]
-        state["vs"] = [jnp.zeros(sshape, jnp.float32)
-                       for _ in range(num_layers)]
-    return state
+        if page_tokens < 1:
+            raise ValueError(f"kv_page_tokens must be >= 1, got {page_tokens}")
+        self.spec = list(spec)
+        self.compute_dtype = compute_dtype
+        self.page_tokens = pt = int(page_tokens)
+        self.max_seq_len = int(max_seq_len)
+        self.max_pages = -(-self.max_seq_len // pt)       # ceil(T / pt)
+        self.store_dtype, self.quantized = resolve_store_dtype(
+            mode, compute_dtype)
+        # default pool covers the contiguous worst case (every slot at
+        # max_seq_len) so it can never exhaust; pass kv_num_pages to
+        # trade bytes for admission-time eviction pressure
+        self.num_pages = int(num_pages if num_pages is not None
+                             else slots * self.max_pages + RESERVED_PAGES)
+        self.pool = PagePool(self.num_pages)
+        self.prefix = RadixPrefixCache(self.pool, pt)
+        self.tables = np.zeros((slots, self.max_pages), np.int32)
+        self.slot_pages: List[List[int]] = [[] for _ in range(slots)]
+        self.replay = np.zeros(slots, bool)
+        shape = (self.num_pages, pt, spec[0].kv_heads, spec[0].head_dim)
 
+        def pools(shape, dtype, n):
+            return [jnp.zeros(shape, dtype) for _ in range(n)]
 
-def pool_state_bytes(state: Dict) -> int:
-    """Total device bytes of pools + scales + tables (the paged engine's
-    KV-cache footprint, what serve_bench's per-MB concurrency divides by)."""
-    import jax
+        n, nq = len(spec), len(spec) if self.quantized else 0
+        self.state = {"k": pools(shape, self.store_dtype, n),
+                      "v": pools(shape, self.store_dtype, n),
+                      "ks": pools(shape[:3], jnp.float32, nq),
+                      "vs": pools(shape[:3], jnp.float32, nq)}
 
-    return sum(a.size * a.dtype.itemsize
-               for a in jax.tree_util.tree_leaves(state))
+    # ---- between dispatches -------------------------------------------
+    def args(self):
+        import jax.numpy as jnp
 
+        return (dict(self.state, tables=jnp.asarray(self.tables),
+                     replay=jnp.asarray(self.replay)),)
 
-def layer_views(state: Dict, table, offset, write_mask, page_tokens: int,
-                compute_dtype) -> List[PagedLayerCache]:
-    """One PagedLayerCache per layer over a (possibly sliced) table."""
-    n = len(state["k"])
-    ks = state["ks"] or [None] * n
-    vs = state["vs"] or [None] * n
-    return [PagedLayerCache(state["k"][i], state["v"][i], table, offset,
-                            write_mask, page_tokens, compute_dtype,
-                            ks[i], vs[i])
-            for i in range(n)]
+    def take(self, results, stepped=None) -> None:
+        """`stepped`: the rows a decode or verify dispatch ran as active. The
+        device cleared their flags (`absorb`), so the host's copy follows
+        without a fetch. A prefill steps no seated row: a slot seated for
+        replay earlier in the same admission round keeps its flag."""
+        (state,) = results
+        self.state = {name: state[name] for name in ("k", "v", "ks", "vs")}
+        if stepped is not None:
+            self.replay &= ~stepped
+
+    def nbytes(self) -> int:
+        """Pools + scales + tables: the paged cache's footprint."""
+        import jax
+
+        return self.tables.nbytes + sum(
+            a.size * a.dtype.itemsize
+            for a in jax.tree_util.tree_leaves(self.state))
+
+    def cover(self, active, offsets, last) -> None:
+        """Make sure every active slot's table row covers the positions the
+        next dispatch may write, its offset up to `last[slot]` (the table
+        is static within a dispatch; a replaying slot's first write goes to
+        the scratch page). Evicts LRU cached prefixes under pressure;
+        admission reservations guarantee success."""
+        import numpy as np
+
+        pt = self.page_tokens
+        for i in np.nonzero(active)[0]:
+            first = int(offsets[i]) + (1 if self.replay[i] else 0)
+            for pi in range(first // pt, int(last[i]) // pt + 1):
+                if self.tables[i, pi] == 0:
+                    if not self.prefix.ensure_free(1):
+                        raise PoolExhausted(
+                            f"slot {i} needs a page and none is free or "
+                            "evictable (reservation accounting violated)")
+                    page = self.pool.alloc()
+                    self.tables[i, pi] = page
+                    self.slot_pages[i].append(page)
+
+    def release(self, slot: int) -> None:
+        """Drop the slot's page references (shared pages decref; own pages
+        free or park for prefix reuse) and reset its table row to the zero
+        page."""
+        for p in self.slot_pages[slot]:
+            self.prefix.release(int(p))
+        self.slot_pages[slot] = []
+        self.tables[slot, :] = 0
+        self.replay[slot] = False
+
+    def truncate(self, slot: int, keep: int) -> None:
+        """Speculative rollback: drop the slot's table entries past the page
+        that holds position `keep` (the next to be written) and free their
+        pages. After a verify window is partially rejected the slot's offset
+        rewinds to the accepted frontier and those pages hold only rejected
+        rows. They are always slot-private — shared prefix pages and
+        trie-published prompt pages all sit below `keep // page_tokens`
+        because generation positions start at the prompt length — so
+        releasing them through the prefix cache frees them outright."""
+        for pi in range(keep // self.page_tokens + 1, self.max_pages):
+            page = int(self.tables[slot, pi])
+            if page != ZERO_PAGE:
+                self.tables[slot, pi] = ZERO_PAGE
+                self.slot_pages[slot].remove(page)
+                self.prefix.release(page)
+
+    def gauges(self) -> dict:
+        return {"pages_in_use": self.pool.in_use,
+                "pages_cached": self.pool.cached,
+                "prefix_hit_rate": self.prefix.hit_rate}
+
+    # ---- inside a traced program --------------------------------------
+    def tip(self, offsets):
+        import jax.numpy as jnp
+
+        return jnp.clip(offsets, 0,
+                        jnp.int32(self.max_pages * self.page_tokens - 1))
+
+    def _handles(self, state, table, offsets, write_mask):
+        n = len(state["k"])
+        ks = state["ks"] or [None] * n
+        vs = state["vs"] or [None] * n
+        return [PagedLayerCache(state["k"][i], state["v"][i], table, offsets,
+                                write_mask, self.page_tokens,
+                                self.compute_dtype, ks[i], vs[i])
+                for i in range(n)]
+
+    def views(self, args, offsets, write_mask):
+        """write_mask: bool [b], or [b, s] for a window of s positions whose
+        first is the row's offset; idle rows and a replaying row's first
+        position write to the scratch page."""
+        import jax.numpy as jnp
+
+        (state,) = args
+        replay = state["replay"]
+        if write_mask.ndim == 2:
+            first = jnp.arange(write_mask.shape[1], dtype=jnp.int32) == 0
+            replay = replay[:, None] & first[None, :]
+        return self._handles(state, state["tables"],
+                             offsets.astype(jnp.int32), write_mask & ~replay)
+
+    def absorb(self, args, handles, active):
+        (state,) = args
+        return (self._absorbed(state, handles,
+                               state["replay"] & ~active),)
+
+    def _absorbed(self, state, handles, replay):
+        q = self.quantized
+        return {"k": [c.k_pool for c in handles],
+                "v": [c.v_pool for c in handles],
+                "ks": [c.k_scale for c in handles] if q else [],
+                "vs": [c.v_scale for c in handles] if q else [],
+                "tables": state["tables"], "replay": replay}
+
+    def prefill_views(self, args, bucket: int, length, base, slot):
+        """The unshared tail of a prompt (`length` tokens from position
+        `base`) writes through the slot's page-table row. Pad positions past
+        the tail go to the scratch page: their table entries may be
+        unallocated, and the zero page must never be written."""
+        import jax
+        import jax.numpy as jnp
+
+        (state,) = args
+        table_row = jax.lax.dynamic_slice_in_dim(
+            state["tables"], slot, 1, 0)                     # [1, max_pages]
+        wmask = (jnp.arange(bucket, dtype=jnp.int32)[None, :]
+                 < length)                                   # [1, bucket]
+        return self._handles(state, table_row, base[None], wmask)
+
+    def commit_prefill(self, args, handles, length, base, slot):
+        (state,) = args
+        return (self._absorbed(state, handles, state["replay"]),)
+
+    @staticmethod
+    def first_position(length, base, slot):
+        """The position of the request's first generated token."""
+        return base + length

@@ -181,6 +181,68 @@ def test_prefix_full_hit_skips_prefill(model):
     assert eng.stats()["prefix"]["full_hits"] >= 1
 
 
+def _trie_pages(eng, prompt):
+    """The pages the trie holds for `prompt`'s full chunks, without the
+    refcounts and statistics a `match` would move."""
+    trie = eng._kv.prefix
+    pages, children = [], trie._root
+    for chunk in trie._chunks(prompt):
+        node = children.get(chunk)
+        if node is None:
+            break
+        pages.append(node.page)
+        children = node.children
+    return pages
+
+
+@pytest.mark.parametrize("dtype", [None, "int8"])
+def test_replay_seat_keeps_its_flag_across_a_prefill(model, dtype):
+    """A full hit and a miss admitted in ONE round: the miss's prefill is
+    dispatched between the replay seat and that slot's first step. The
+    step's re-derived K/V of position plen-1 must still go to the scratch
+    page: the published pages keep their bytes, and the tokens are the
+    dense engine's (for int8 pages: those of the same requests served one
+    at a time)."""
+    rng = np.random.RandomState(11)
+    repeat = rng.randint(0, 1024, (16,)).astype(np.int64)   # 2 full pages
+    fresh = rng.randint(0, 1024, (11,)).astype(np.int64)
+
+    def both(e, together):
+        reqs = []
+        for prompt, seed in ((repeat, 3), (fresh, 4)):
+            reqs.append(e.submit(prompt, max_new_tokens=5, temperature=0.0,
+                                 seed=seed))
+            if not together:
+                e.run()
+        e.run()
+        return [list(r.output_ids()) for r in reqs]
+
+    eng = _paged(model, dtype=dtype)
+    eng.submit(repeat, max_new_tokens=2, temperature=0.0)
+    eng.run()                                  # publishes the two pages
+    pages = np.asarray(_trie_pages(eng, repeat))
+    assert len(pages) == 2
+
+    def shared_bytes():
+        return [np.asarray(a)[pages] for name in ("k", "v", "ks", "vs")
+                for a in eng._kv.state[name]]
+
+    before = shared_bytes()
+    skips = _counter("serving.prefill_skips")
+    got = both(eng, together=True)
+    assert _counter("serving.prefill_skips") == skips + 1
+    for was, now in zip(before, shared_bytes()):
+        np.testing.assert_array_equal(was, now)
+    if dtype is None:
+        want = both(_dense(model), together=True)
+    else:
+        ref = _paged(model, dtype=dtype)
+        ref.submit(repeat, max_new_tokens=2, temperature=0.0)
+        ref.run()
+        want = both(ref, together=False)
+    assert got == want
+
+
 def test_partial_hit_prefills_only_tail(model):
     """Shared prefix + fresh suffix: exactly one prefill dispatch (the
     unshared tail at its small rung), tokens still layout-identical."""
@@ -267,15 +329,13 @@ def test_router_drains_replica_to_zero_admissions(model):
 
 # ----------------------------------------------- contracts + telemetry
 def test_paged_contracts_donate_pool_and_analyze_clean(model):
-    from paddle_tpu.serving.kv_pages import pool_state_bytes
-
     eng = _paged(model)
     _run(eng, _mixed_work(np.random.RandomState(9), n=3))
     contracts = {c.name: c for c in eng.default_contracts()}
     labels = [n for n in contracts if "cache-donation" in n]
     assert any("decode" in n for n in labels)
     assert any("prefill" in n for n in labels)
-    pool_bytes = pool_state_bytes(eng._pool_state)
+    pool_bytes = eng._kv.nbytes()
     for name in labels:
         if "decode" in name:
             # decode donates the whole pool state: pools + scales + tables

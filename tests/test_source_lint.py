@@ -14,6 +14,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 from paddle_tpu.analysis.source_lint import (compare_to_baseline,
                                              lint_source, lint_tree,
                                              load_baseline)
@@ -256,3 +258,60 @@ def test_lint_tracing_cli_exit_codes(tmp_path):
     summary = json.loads(drift.stdout.strip().splitlines()[-1])["summary"]
     assert not summary["ok"]
     assert summary["stale"] == ["gone.py:host-sync:f:float"]
+
+
+# ------------------------------------------- the models' seam (PR 30)
+
+_SEAM_CASES = {
+    # what the parent's models/gpt.py and models/afmoe.py did
+    "relative-import": ("""
+        def kv_cache_spec(self, n):
+            from ..serving.kv_state import KVLayerSpec
+            return [KVLayerSpec("full", n, 4, 32)]
+        """, [("model-serving", "serving.kv_state")]),
+    "relative-import-of-the-package": ("""
+        from .. import serving
+        """, [("model-serving", "serving")]),
+    "absolute-import": ("""
+        import paddle_tpu.serving.kv_pages as kvp
+        from paddle_tpu.serving import kv_pages
+        """, [("model-serving", "paddle_tpu.serving.kv_pages"),
+              ("model-serving", "paddle_tpu.serving")]),
+    "at-set-on-a-cache": ("""
+        def forward(self, k, kc, cache, rows, pos):
+            kc = kc.at[rows, pos].set(k)
+            cache.k_pool = cache.k_pool.at[rows, pos].set(k)
+            return kc
+        """, [("model-cache", "kc.at["), ("model-cache", "k_pool.at[")]),
+    "dynamic-update-slice-on-a-cache": ("""
+        import jax
+        def forward(self, k, v_cache, off):
+            return jax.lax.dynamic_update_slice(v_cache, k, (0, off, 0, 0))
+        """, [("model-cache", "v_cache in dynamic_update_slice(")]),
+    # the rule reads the construct, not the variable's name
+    "a-cache-by-any-other-name": ("""
+        import jax
+        def forward(self, k, state, buf, off):
+            buf = buf.at[:, off].set(k)
+            self.rows().at[off].set(k)
+            return jax.lax.dynamic_update_slice(state.rows, k, (0, off, 0, 0))
+        """, [("model-cache", "buf.at["), ("model-cache", "<expr>.at["),
+              ("model-cache", "rows in dynamic_update_slice(")]),
+    # what a model may do
+    "the-handle-and-other-packages": ("""
+        import jax.numpy as jnp
+        from ..nn.kv_cache import ChunkKV, KVLayerSpec
+        from ..core.bucketing import resolve_bucket
+        def step(toks, beam_idx, cache, k, v):
+            toks = jnp.take_along_axis(toks, beam_idx[..., None], axis=1)
+            return cache.update(k, v)
+        """, []),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SEAM_CASES))
+def test_models_neither_import_serving_nor_write_a_cache(case):
+    src, want = _SEAM_CASES[case]
+    assert _rules(src, "paddle_tpu/models/x.py") == want
+    # the same text anywhere else is not the models' business
+    assert _rules(src, "paddle_tpu/serving/x.py") == []
