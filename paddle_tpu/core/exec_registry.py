@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import threading
 import time
+import types
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
@@ -69,6 +70,25 @@ def _jit_cache_size(fn) -> int:
         return int(fn._cache_size())
     except Exception:
         return -1
+
+
+def _call(fn, args):
+    return fn(*args)
+
+
+# An executable's first call traces and lowers its program in Python, a few
+# hundred frames deep. CPython keeps a thread's frames in chunks of 16 KiB and
+# gives a chunk back to the system the moment its first frame returns: a loop
+# whose own frame is the last that fits its chunk maps and unmaps a chunk with
+# every call it makes. Whether jax's per-equation lowering loop lands on such a
+# boundary depends on how deep the CALLER of the first call stands, so the same
+# program lowered in 0.6 or in 10 s (PERF.md, PR 32: 3,029 equations in the
+# decode scan's body, four calls each). `_call` with a frame of 512 KiB opens
+# one chunk of 1 MiB that holds every frame below it: no boundary, whatever
+# the caller's depth. Used for first calls only (a mapping a call otherwise).
+_call_in_one_chunk = types.FunctionType(
+    _call.__code__.replace(co_stacksize=1 << 16), globals(),
+    "_call_in_one_chunk")
 
 
 def _default_aval(a):
@@ -122,6 +142,8 @@ class ExecEntry:
                 self.aot = None
                 _AOT_FALLBACKS.increase()
         self._via_aot = False
+        if self._seen_cache_size == 0 and not self._counted_once:
+            return _call_in_one_chunk(self.fn, args)    # traces and lowers
         return self.fn(*args)
 
     def cache_size(self) -> int:

@@ -236,6 +236,7 @@ class StepTelemetry:
                            ("serving.decode_compiles",
                             "serving_decode_compiles"),
                            ("serving.steps", "serving_steps"),
+                           ("serving.decode_ahead", "serving_decode_ahead"),
                            ("serving.tokens", "serving_tokens")):
             if key in rep:
                 v = rep[key]["value"]

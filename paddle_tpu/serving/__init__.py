@@ -14,6 +14,7 @@ Quick start::
 
 core.monitor counters: serving.prefill_compiles (bounded by the bucket
 ladder), serving.decode_compiles (one executable), serving.steps,
+serving.decode_ahead (decode dispatches enqueued ahead of a fetch),
 serving.tokens, serving.requests, serving.prefill_dispatches; the paged
 layout (kv_pages.py / prefix_cache.py / router.py) adds
 serving.prefix_lookups, serving.prefix_hits, serving.prefill_skips;

@@ -23,7 +23,9 @@ On top sits continuous batching: finished sequences retire their slot
 mid-flight and queued requests are prefilled into free slots between decode
 steps — the decode loop itself never recompiles and never runs a step for
 work that is already done (only for idle slots while ANY slot is live,
-which is the slot-occupancy metric the telemetry records).
+which is the slot-occupancy metric the telemetry records). While no slot
+can come free, the loop keeps one decode chunk enqueued behind the one it
+is reading (`_may_run_ahead`), so the device does not wait for the host.
 
 CPU-demonstrable (tools/serve_bench.py); the same two executables are what
 a TPU deployment keeps resident.
@@ -257,6 +259,16 @@ class ServingEngine:
         self._admit_ms = None
         self._prefill_ms = []
         self._fetch_end = None
+        # the decode loop's device side (`_decode_step`): the chunk that is
+        # enqueued and not yet fetched, the carry the last enqueued chunk
+        # returned (None once a seat has changed the host's copy) and the
+        # per-slot constants as sent at the last seat; and how many decode
+        # dispatches there were, how many of them enqueued ahead of a fetch
+        self._inflight = None
+        self._carry = None
+        self._consts = None
+        self._decode_dispatches = 0
+        self._decode_ahead = 0
         # elastic drain state (distributed/membership.py protocol): once
         # draining, submit() refuses and _admit() stops pulling the queue —
         # active slots run to completion, then the replica retires
@@ -319,7 +331,8 @@ class ServingEngine:
                                  "rewind its cache by offset")
             self._dkv = _kvs.SlotCache(dspec, S, T, self._cache_dtype)
 
-        # host-side per-slot state (tiny arrays, re-staged every step)
+        # host-side per-slot state (tiny arrays; sent to the device after a
+        # seat, between seats the decode program's own outputs go back in)
         self._offsets = np.zeros(S, np.int32)
         self._last_tok = np.zeros(S, np.int32)
         self._active = np.zeros(S, bool)
@@ -437,8 +450,17 @@ class ServingEngine:
 
     def step(self) -> int:
         """Admit queued requests into free slots (bucketed prefill), then
-        run ONE decode step for all slots. Returns the number of live
-        slots after the step (0 = fully drained).
+        fetch and deliver ONE decode dispatch (`steps_per_dispatch` tokens
+        a live slot). Returns the number of slots live after the delivered
+        dispatch (0 = fully drained, nothing in flight).
+
+        While every slot is live and none can end inside the dispatch in
+        flight, the next dispatch is enqueued before that one is fetched
+        (`_decode_step`), so the device always has a decode chunk queued:
+        a token then reaches its `Request` one `step()` after the device
+        made it, instead of in the same one. Tokens, their order and each
+        request's stream are the same either way; `stats()` gives the share
+        of dispatches enqueued ahead (`decode_ahead_share`).
 
         The spans below are engine-boundary spans (observability/tracer.py
         `boundary`): always recorded, a handful a dispatch, each mirrored
@@ -525,6 +547,8 @@ class ServingEngine:
                     self._kv.release(slot)
                     if req is not None and req.done_ts is None:
                         self._finish(req, outcome="drained")
+                # a chunk still in flight belonged to those requests
+                self._inflight = self._carry = None
                 break
             self._advance_step()
         drain_ms = (time.perf_counter() - t0) * 1000.0
@@ -558,8 +582,18 @@ class ServingEngine:
         self._prev_sigterm = signal.signal(signal.SIGTERM, _on_sigterm)
 
     def stats(self) -> Dict[str, Any]:
+        """Counts of this engine: `steps` (decode steps delivered),
+        `decode_dispatches` and `decode_ahead_share` (plain decode dispatches,
+        and the share of them enqueued before the dispatch ahead of them was
+        fetched: `_decode_step`), `completed`, `queued`, `active_slots`,
+        `draining`, the ladder and executable counts, the cache's layout and
+        bytes; with a draft model the verify counts, on the paged layout the
+        pool's and the prefix cache's."""
         out = {
             "steps": self._steps,
+            "decode_dispatches": self._decode_dispatches,
+            "decode_ahead_share": (self._decode_ahead
+                                   / max(1, self._decode_dispatches)),
             "completed": len(self._completed),
             "queued": len(self._queue),
             "active_slots": int(self._active.sum()),
@@ -1049,6 +1083,7 @@ class ServingEngine:
         self._remaining[slot] = remaining
         self._seeds[slot] = req.seed
         self._slot_req[slot] = req
+        self._carry = self._consts = None     # the device's copies are stale
         self._seat_spec(req, slot)
 
     def _seat_after_prefill(self, req: Request, slot: int,
@@ -1068,7 +1103,10 @@ class ServingEngine:
                    req.max_new_tokens - 1)
 
     def _admit(self) -> None:
-        if self._draining:
+        # a chunk in flight was enqueued with every slot live: a slot it
+        # shows free (an EOS nobody could foresee) is seated once the chunk
+        # is fetched, or the fetch would overwrite the seat
+        if self._draining or self._inflight is not None:
             return
         while True:
             with self._lock:
@@ -1459,7 +1497,7 @@ class ServingEngine:
         any active slot opted in (non-spec slots ride along with a zero
         draft window and emit bit-identically to decode), plain decode
         otherwise."""
-        k = self._spec_dispatch_rung()
+        k = self._spec_dispatch_rung()    # 0 while a chunk is in flight
         if k:
             self._verify_step(k)
         else:
@@ -1521,6 +1559,7 @@ class ServingEngine:
             self._last_tok = np.array(tok)
             self._active = np.array(active)
             self._remaining = np.array(remaining)
+            self._carry = None
             emit = np.asarray(emit)                 # [S, k+1]
             m = np.asarray(m)
             a = np.asarray(a)
@@ -1618,76 +1657,144 @@ class ServingEngine:
             if fr is not None:
                 fr.record(rec)
 
-    def _decode_step(self) -> None:
+    def _may_run_ahead(self) -> bool:
+        """Whether the next chunk can be enqueued behind the one in flight
+        before that one is fetched: only if the host knows already what the
+        fetch will show of the slots. Every slot is live and stays live
+        through the chunk in flight (its budget and its rows last longer
+        than the chunk), so no slot frees for a queued request at that
+        boundary; nobody speculates; admission is open (a draining engine
+        takes today's loop to its end). An EOS is the one thing the host
+        cannot foresee: the device masks that slot in the chunk enqueued
+        ahead, which costs the slot's next request one chunk."""
+        n = self.steps_per_dispatch
+        return bool(not self._draining and self._active.all()
+                    and int(self._remaining.min()) > n
+                    and int(self._offsets.max()) + n < self.max_seq_len
+                    and self._spec_dispatch_rung() == 0)
+
+    def _enqueue_decode(self, ahead: bool) -> dict:
+        """Enqueue one decode chunk and return what `_decode_step` fetches
+        later. `ahead`: the chunk in flight is not fetched yet, so the host's
+        arrays are one chunk old; `_may_run_ahead` has made sure every slot
+        moves `steps_per_dispatch` positions in it.
+
+        Every call has the same form: the carry (`off`, `tok`, `active`,
+        `remaining`) is the last chunk's own output, or made from the host's
+        arrays after a seat changed them, and both are plain uncommitted
+        arrays of the same types, so the decode entry keeps ONE executable a
+        family. The per-slot constants go up once a seat."""
         import jax.numpy as jnp
         import numpy as np
 
+        from ..core import monitor
+
         tr = _obs_tracer.get_tracer()
         kv = self._kv
+        n_inner = self.steps_per_dispatch
+        unseen = n_inner if ahead else 0
         # per-dispatch family pick: an all-greedy slot set runs the slim
         # executable; any sampling slot routes to the full one. Two decode
         # executables max, regardless of traffic mix.
         family = ("greedy"
                   if not self._temps[self._active].any() else "sample")
+        # family's executable, arguments, and the call's return: the
+        # device has the dispatch enqueued when this span ends
+        with tr.boundary(
+                "serve.decode.dispatch", family=family,
+                step=self._steps + unseen,
+                requests=[r.id for r in self._slot_req
+                          if r is not None]) as dispatch:
+            label, donate = f"serve.decode_{family}", self._donate(1, kv)
+            entry = self._execs.get_or_build(
+                ("serve.decode", family),
+                lambda: self._build_decode(family),
+                label=label, donate=donate, pin=True)
+            # the positions this chunk may write (a slot's table row is
+            # static within a dispatch)
+            offsets = self._offsets + unseen
+            kv.cover(self._active, offsets,
+                     np.minimum(offsets + n_inner, self.max_seq_len) - 1)
+            if self._carry is None:
+                self._carry = tuple(jnp.asarray(a) for a in (
+                    self._offsets, self._last_tok, self._active,
+                    self._remaining))
+            if self._consts is None:
+                self._consts = tuple(jnp.asarray(a) for a in (
+                    self._temps, self._topk, self._topp, self._eos,
+                    self._seeds))
+            off, tok, active, remaining = self._carry
+            temps, top_k, top_p, eos, seeds = self._consts
+            call_args = (self._params, *kv.args(), off, tok, active, temps,
+                         top_k, top_p, eos, remaining, seeds)
+            self._stash_exec(label, entry.fn, call_args, donate=donate)
+            p0 = self._execs.persistent_before(entry)
+            t0 = time.perf_counter()
+            cache, (off, tok, active, remaining, toks, was_active, hits,
+                    stats) = _split(entry(*call_args), kv.n_args)
+            kv.take(cache, self._active)
+            self._carry = (off, tok, active, remaining)
+            self._execs.note_compiles(
+                entry, wall_s=time.perf_counter() - t0,
+                persistent_before=p0, counter="serving.decode_compiles")
+        self._decode_dispatches += 1
+        if ahead:
+            self._decode_ahead += 1
+            monitor.stat("serving.decode_ahead").increase()
+        # the time the decode program had nothing enqueued: from the end of
+        # the last dispatch's fetch to this dispatch's enqueue; none when
+        # the chunk before this one was still in flight
+        host_gap_ms = (0.0 if ahead else None if self._fetch_end is None
+                       else (dispatch.t1 - self._fetch_end) * 1e3)
+        return {"carry": self._carry, "toks": toks, "was_active": was_active,
+                "hits": hits, "stats": stats, "family": family,
+                "ahead": ahead, "dispatch_ms": dispatch.ms,
+                "host_gap_ms": host_gap_ms}
+
+    def _decode_step(self) -> None:
+        """Fetch and deliver one decode chunk: the one in flight, or one
+        enqueued now. Before the fetch blocks, the next chunk is enqueued
+        behind it on its device carry whenever `_may_run_ahead` says the
+        fetch cannot change what that chunk has to be sent: the device then
+        goes from one chunk to the next without waiting for the host. In any
+        other state this is enqueue, fetch, deliver, and the next `step()`
+        admits into the freed slots before it enqueues again."""
+        import numpy as np
+
+        tr = _obs_tracer.get_tracer()
+        chunk, self._inflight = self._inflight, None
         try:
-            # family's executable, arguments, and the call's return: the
-            # device has the dispatch enqueued when this span ends
-            with tr.boundary(
-                    "serve.decode.dispatch", family=family,
-                    step=self._steps,
-                    requests=[r.id for r in self._slot_req
-                              if r is not None]) as dispatch:
-                label, donate = f"serve.decode_{family}", self._donate(1, kv)
-                entry = self._execs.get_or_build(
-                    ("serve.decode", family),
-                    lambda: self._build_decode(family),
-                    label=label, donate=donate, pin=True)
-                # the positions this chunk may write (a slot's table row is
-                # static within a dispatch)
-                kv.cover(self._active, self._offsets,
-                         np.minimum(self._offsets + self.steps_per_dispatch,
-                                    self.max_seq_len) - 1)
-                call_args = (
-                    self._params, *kv.args(), jnp.asarray(self._offsets),
-                    jnp.asarray(self._last_tok), jnp.asarray(self._active),
-                    jnp.asarray(self._temps), jnp.asarray(self._topk),
-                    jnp.asarray(self._topp), jnp.asarray(self._eos),
-                    jnp.asarray(self._remaining), jnp.asarray(self._seeds))
-                self._stash_exec(label, entry.fn, call_args, donate=donate)
-                p0 = self._execs.persistent_before(entry)
-                t0 = time.perf_counter()
-                cache, (off, tok, active, remaining, toks, was_active, hits,
-                        stats) = _split(entry(*call_args), kv.n_args)
-                kv.take(cache, self._active)
-                self._execs.note_compiles(
-                    entry, wall_s=time.perf_counter() - t0,
-                    persistent_before=p0, counter="serving.decode_compiles")
-            # the time the decode program had nothing enqueued: from the end
-            # of the last dispatch's fetch to this dispatch's enqueue
-            host_gap_ms = (None if self._fetch_end is None
-                           else (dispatch.t1 - self._fetch_end) * 1e3)
+            if chunk is None:
+                chunk = self._enqueue_decode(ahead=False)
+            if self._may_run_ahead():
+                self._inflight = self._enqueue_decode(ahead=True)
             with tr.boundary("serve.decode.fetch") as fetch:   # blocks
                 # np.array (copy): zero-copy views of jax buffers are
                 # read-only, and _admit mutates these in place when it
                 # seats the next request
+                off, tok, active, remaining = chunk["carry"]
                 self._offsets = np.array(off)
                 self._last_tok = np.array(tok)
                 self._active = np.array(active)
                 self._remaining = np.array(remaining)
-                toks = np.asarray(toks)           # [n_inner, S]
-                was_active = np.asarray(was_active)
-                hits = np.asarray(hits)
-                stats = {name: float(v) for name, v in stats.items()}
+                toks = np.asarray(chunk["toks"])           # [n_inner, S]
+                was_active = np.asarray(chunk["was_active"])
+                hits = np.asarray(chunk["hits"])
+                stats = {name: float(v)
+                         for name, v in chunk["stats"].items()}
             self._fetch_end = fetch.t1
         except Exception as e:
             fr = _obs_flight.get()
             if fr is not None:
                 fr.dump("serve_decode_exception",
-                        {"step": self._steps, "family": family,
+                        {"step": self._steps,
+                         "family": (chunk or {}).get("family"),
                          "error": repr(e)})
             # a failed decode dispatch takes every in-flight request with
-            # it: record each as a terminal error before re-raising so the
-            # availability SLI sees the blast radius
+            # it, and the chunk enqueued ahead: record each request as a
+            # terminal error, once, before re-raising so the availability
+            # SLI sees the blast radius
+            self._inflight = self._carry = None
             for slot in np.nonzero(self._active)[0]:
                 req = self._slot_req[slot]
                 if req is not None and req.done_ts is None:
@@ -1696,12 +1803,19 @@ class ServingEngine:
         spans_ms = {"admit": self._admit_ms,
                     "prefill_dispatch": [d for d, _ in self._prefill_ms],
                     "prefill_sync": [s for _, s in self._prefill_ms],
-                    "decode_dispatch": dispatch.ms, "decode_fetch": fetch.ms}
+                    "decode_dispatch": chunk["dispatch_ms"],
+                    "decode_fetch": fetch.ms}
         self._admit_ms, self._prefill_ms = None, []   # told once (drain()
         #                               dispatches without a step() before it)
         with tr.boundary("serve.emit") as emit:
-            self._emit_decoded(toks, was_active, hits, spans_ms, host_gap_ms,
-                               emit, stats)
+            self._emit_decoded(toks, was_active, hits, spans_ms,
+                               chunk["host_gap_ms"], emit, stats,
+                               chunk["ahead"])
+        if self._inflight is not None and not self._active.any():
+            # every slot ended at an EOS: the chunk enqueued ahead ran with
+            # all of them masked. Fetched now, so that an engine with no
+            # live slot never has a chunk in flight
+            self._decode_step()
 
     def _fold_step_stats(self, stats):
         """What the model reported at each fused step -> one value a
@@ -1712,7 +1826,7 @@ class ServingEngine:
                 for name, v in stats.items()}
 
     def _emit_decoded(self, toks, was_active, hits, spans_ms, host_gap_ms,
-                      emit, stats) -> None:
+                      emit, stats, ahead) -> None:
         """Hand a fetched dispatch's tokens to their requests, retire the
         finished, count, and write the `serve_step` sink record. `stats` is
         what the model reported of the dispatch (`_fold_step_stats`): it goes
@@ -1775,6 +1889,8 @@ class ServingEngine:
                 # for the engine's first dispatch)
                 "spans_ms": spans_ms,
                 "host_gap_ms": host_gap_ms,
+                # enqueued while the dispatch before it was still in flight
+                "ahead": ahead,
                 # positions held by the slots still live after the dispatch:
                 # what the next step's attention reads
                 "contexts": self._offsets[self._active].tolist(),

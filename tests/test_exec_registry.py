@@ -36,6 +36,50 @@ def _mk(tag, log=None):
     return build
 
 
+# ---- an entry's first call stands in one chunk of the frame stack -------
+
+
+def _frames_above():
+    names, f = [], sys._getframe(1)
+    while f is not None:
+        names.append((f.f_code.co_name, f.f_code.co_stacksize))
+        f = f.f_back
+    return names
+
+
+def test_first_call_traces_and_lowers_in_one_stack_chunk():
+    """CPython frees a 16 KiB chunk of a thread's frame stack when its first
+    frame returns, so a loop on a chunk's edge maps memory with every call:
+    jax's lowering loop did, or not, by the depth of whoever made an
+    executable's first call (PERF.md, PR 32). The first call goes through a
+    frame of 512 KiB, which opens one chunk for all frames below it; later
+    calls, which only dispatch, do not pay for that mapping."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.core import exec_registry as X
+
+    assert X._call_in_one_chunk.__code__.co_stacksize >= 1 << 16
+    assert X._call_in_one_chunk(lambda a, b: a - b, (5, 3)) == 2
+
+    seen = []
+
+    def traced(x):
+        seen.append(_frames_above())          # runs while jax traces
+        return x + 1
+
+    reg = ExecutableRegistry(name="t")
+    entry = reg.get_or_build(("prog",), lambda: jax.jit(traced))
+    assert int(entry(jnp.int32(1))) == 2
+    assert reg.note_compiles(entry, wall_s=0.0) == 1
+    assert any(name == "_call" and size >= 1 << 16 for name, size in seen[0])
+
+    roomy = []
+    entry.fn = lambda *a: roomy.append(_frames_above()) or 0
+    entry(jnp.int32(1))
+    assert not any(size >= 1 << 16 for _, size in roomy[0])
+
+
 # ---- claim 1: key uniqueness -------------------------------------------
 
 

@@ -387,7 +387,9 @@ def test_serving_step_span_tree(tiny_gpt, monkeypatch):
 
     tiny_gpt.eval()
     sink = InMemorySink()
-    eng = ServingEngine(tiny_gpt, slot_count=2, ladder=(8, 16),
+    # a slot stays free, so every step is enqueue, fetch, deliver; the tree
+    # of a full engine, which enqueues ahead, is tests/test_decode_ahead.py's
+    eng = ServingEngine(tiny_gpt, slot_count=3, ladder=(8, 16),
                         max_new_cap=8, steps_per_dispatch=2, sink=sink)
     reqs = [eng.submit(np.arange(1, 5 + i, dtype=np.int64), max_new_tokens=6,
                        temperature=0.0) for i in range(2)]
