@@ -5,6 +5,9 @@ from .gpt import (  # noqa: F401
 from .afmoe import (  # noqa: F401
     AfmoeConfig, AfmoeForCausalLM, AfmoeModel, afmoe_tiny,
 )
+from .olmo_hybrid import (  # noqa: F401
+    OlmoHybridConfig, OlmoHybridForCausalLM, OlmoHybridModel, olmo_hybrid_tiny,
+)
 from .ernie import (  # noqa: F401
     BertConfig, BertForPretraining, BertModel, ErnieConfig, ErnieForPretraining,
     ErnieModel, bert_base, bert_large, ernie_base, ernie_large, ernie_tiny,

@@ -1,16 +1,21 @@
-"""Where position p of a sequence lives in a key/value cache, and how it is
-written and read back: the one module that knows.
+"""What a layer keeps of a sequence between two calls, how it is written and
+read back: the one module that knows. Three kinds: two of rows of keys and
+values, where position p lives in a row, and one of state with no position.
 
 A model declares what each layer keeps and never looks inside a cache:
 
     model.kv_cache_spec(max_seq_len) -> [KVLayerSpec(kind, rows, kv_heads,
-                                                     head_dim), ...]
+                                                     head_dim)
+                                         or StateLayerSpec("state", ...), ...]
 
 - `full`: every position is kept, row p holds position p.
 - `window`: the last `rows` positions are kept as a ring, position p in row
   `p % rows`, however long the context.
+- `state`: a recurrent layer's matrix a head and the last inputs of its
+  causal convolution, the same size whatever the context, rewritten whole at
+  every position; nothing in it can be addressed, cut or rewound by position.
 
-Its attention is handed one handle a layer and calls it once:
+An attention layer is handed one handle and calls it once:
 
     positions = cache.positions(s)            # [b or 1, s]: the chunk's own
     keys, values, held, cache = cache.update(k_new, v_new)
@@ -29,8 +34,20 @@ The handles are pytrees (`lax.scan` carries them, `tree_map` reorders beams):
 `ChunkKV` here for a whole batch at one offset (generate(), beam search, a
 request's prefill alone), `SlotKV` and `RingKV` for the serving engine's slot
 cache at per-row offsets (serving/kv_state.py builds them), and the paged
-pool's handle with the same two operations in serving/kv_pages.py. Latent rows
-and recurrent state would be further kinds (ROADMAP.md).
+pool's handle with the same two operations in serving/kv_pages.py.
+
+A `state` layer is handed a `SlotState` and calls it twice:
+
+    state, tail, valid = cache.read()
+    cache = cache.replace(new_state, new_tail)
+
+`state` [b, heads, key_dim, value_dim] float32 and `tail` [b, tail_rows,
+channels] are what each row of the batch held before the chunk; `valid`
+[b, s] says which positions of the chunk are real (a prefill's right-pad and
+a decode step's idle slots are not). The layer must leave the state and the
+tail of a row as they were over the positions that are not real, because the
+cache keeps what the layer returns: `conv_tail` below does it for the tail.
+Latent rows would be a further kind (ROADMAP.md).
 """
 from __future__ import annotations
 
@@ -40,7 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax.tree_util import register_pytree_node_class
 
-KINDS = ("full", "window")
+KINDS = ("full", "window", "state")
 
 
 class KVLayerSpec(NamedTuple):
@@ -48,6 +65,17 @@ class KVLayerSpec(NamedTuple):
     rows: int
     kv_heads: int
     head_dim: int
+
+
+class StateLayerSpec(NamedTuple):
+    """A `state` layer: a float32 matrix [key_dim, value_dim] a head, and the
+    last `tail_rows` inputs of a causal convolution over `channels`."""
+    kind: str
+    heads: int
+    key_dim: int
+    value_dim: int
+    tail_rows: int
+    channels: int
 
 
 def ring_row(pos, rows: int):
@@ -170,3 +198,45 @@ class RingKV(_KV):
         v = self.v.at[slots, ring_row(pos, rows)].set(
             v_new.astype(self.v.dtype))
         return k, v, ring_held(pos, rows), self._advanced(k, v, s)
+
+
+@register_pytree_node_class
+class SlotState:
+    """A `state` layer's handle: what each row of the batch held before the
+    chunk, and which of the chunk's positions are real."""
+
+    fresh = False
+
+    def __init__(self, state, tail, valid):
+        self.state, self.tail, self.valid = state, tail, valid
+
+    def tree_flatten(self):
+        return (self.state, self.tail, self.valid), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(*leaves)
+
+    @classmethod
+    def zeros(cls, batch: int, layer: StateLayerSpec, dtype, valid):
+        return cls(jnp.zeros((batch, layer.heads, layer.key_dim,
+                              layer.value_dim), jnp.float32),
+                   jnp.zeros((batch, layer.tail_rows, layer.channels), dtype),
+                   valid)
+
+    def read(self):
+        return self.state, self.tail, self.valid
+
+    def replace(self, state, tail):
+        return SlotState(state.astype(self.state.dtype),
+                         tail.astype(self.tail.dtype), self.valid)
+
+
+def conv_tail(tail, z, valid):
+    """The last `tail_rows` real inputs of a causal convolution after a
+    chunk: `tail` [b, rows, channels] held before it, `z` [b, s, channels]
+    the chunk's inputs, of which the first `valid.sum(1)` a row are real."""
+    rows = tail.shape[1]
+    seen = jnp.concatenate([tail.astype(z.dtype), z], axis=1)
+    at = valid.sum(1, dtype=jnp.int32)[:, None] + jnp.arange(rows)[None, :]
+    return jnp.take_along_axis(seen, at[:, :, None], axis=1)

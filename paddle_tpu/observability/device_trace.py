@@ -26,9 +26,9 @@ import re
 from typing import Dict, Iterator, List, Optional, Tuple
 
 # ---- the vocabulary (paddle_tpu/models/gpt.py, models/afmoe.py,
-# nn/layers/routed_experts.py, ops/fused.py, ops/pallas/flash_attention.py,
-# distributed/engine.py, grad_comm.py, serving/engine.py,
-# serving/sampling.py) ---------------------------------------------------
+# models/olmo_hybrid.py, nn/layers/routed_experts.py, ops/fused.py,
+# ops/pallas/flash_attention.py, distributed/engine.py, grad_comm.py,
+# serving/engine.py, serving/kv_state.py, serving/sampling.py) ------------
 ROOTS = ("prefill", "decode")                       # the serving programs
 KERNELS = ("flash_fwd", "flash_bwd", "flash_bwd_dkv", "flash_bwd_dq")
 SCOPES = frozenset(ROOTS + KERNELS + (
@@ -38,7 +38,11 @@ SCOPES = frozenset(ROOTS + KERNELS + (
     # the afmoe block: attn > qk_norm, rope, gate; moe > router, dispatch,
     # experts, shared, combine
     "qk_norm", "rope", "gate", "moe", "router", "dispatch", "experts",
-    "shared", "combine"))
+    "shared", "combine",
+    # the olmo_hybrid block: linear_attn > proj, conv, gates, delta_rule,
+    # out_gate, out; the slot's state written back
+    "linear_attn", "proj", "conv", "gates", "delta_rule", "out_gate",
+    "state_write"))
 SPAN_PREFIXES = ("serve.", "engine.")               # the engines' spans
 
 UNNAMED = "unnamed"           # an op_name, and no scope of the vocabulary

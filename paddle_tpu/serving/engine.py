@@ -172,10 +172,12 @@ class ServingEngine:
     """Continuous-batching decoder serving over a slot-based KV cache.
 
     model: a causal LM that says what the engine needs of it and nothing of
-    its architecture (GPTForPretraining, AfmoeForCausalLM): `config`
+    its architecture (GPTForPretraining, AfmoeForCausalLM,
+    OlmoHybridForCausalLM): `config`
     (`vocab_size`, `max_seq_len`), `serving_backbone()` (the layer called
     with `(ids, caches=...)` and its prefix in `state_dict`),
-    `kv_cache_spec(max_seq_len)` (what each layer keeps a slot: kv_state.py),
+    `kv_cache_spec(max_seq_len)` (what each layer keeps a slot, rows of keys
+    and values or a recurrent state: nn/kv_cache.py, kv_state.py),
     `_head_logits(h)`, and `serving_step_stats` (what a decode step reports
     beside its tokens). Eval mode is forced. slot_count fixes the
     decode batch; ladder the prefill rungs (clipped to what fits
@@ -300,6 +302,12 @@ class ServingEngine:
                 f"{windows} keep a window of rows as a ring, and the verify "
                 "program rewinds rejected rows by offset, which a ring "
                 "overwrites; construct the engine without draft_model")
+        if draft_model is not None:
+            _kvs.refuse_state_layers(
+                spec, "speculative decoding",
+                "the verify program rewinds rejected positions by offset, "
+                "which a state cannot be; construct the engine without "
+                "draft_model")
         # the slot cache (kv_state.py): ONE object knows where a slot's rows
         # live; the programs below are written over it. Paged: per-layer
         # page pools + one page table traced as a gather index, and the
@@ -329,6 +337,10 @@ class ServingEngine:
             if _kvs.window_layers(dspec):
                 raise ValueError("a draft model with window layers cannot "
                                  "rewind its cache by offset")
+            _kvs.refuse_state_layers(
+                dspec, "a draft model's cache",
+                "the verify program rewinds the draft's rejected positions "
+                "by offset, which a state cannot be")
             self._dkv = _kvs.SlotCache(dspec, S, T, self._cache_dtype)
 
         # host-side per-slot state (tiny arrays; sent to the device after a
@@ -650,6 +662,12 @@ class ServingEngine:
 
     def queue_depth(self) -> int:
         return len(self._queue)
+
+    @property
+    def slot_cache(self):
+        """The slot cache (kv_state.SlotCache or kv_pages.PagedSlotCache):
+        for checks and rehearsals to read what a slot holds, never to write."""
+        return self._kv
 
     # ---------------------------------------------------------- internals
     @property
@@ -1857,6 +1875,9 @@ class ServingEngine:
         monitor.stat("serving.steps").increase(n_inner)
         for name, value in stats.items():
             monitor.stat("serving." + name).set(value)
+        gauges = self._kv.gauges()
+        if "state_bytes" in gauges:
+            monitor.stat("serving.state_bytes").set(gauges["state_bytes"])
         occupancy = float(was_active.mean())
         mreg = _obs_metrics.active_registry()
         if mreg is not None:
@@ -1866,7 +1887,7 @@ class ServingEngine:
                            boundaries=_OCCUPANCY_BUCKETS).observe(occupancy)
             mreg.gauge("serve.queue_depth").set(len(self._queue))
             mreg.gauge("serve.active_slots").set(int(self._active.sum()))
-            for name, value in self._kv.gauges().items():
+            for name, value in gauges.items():
                 mreg.gauge("serve." + name).set(value)
         fr = _obs_flight.get()
         if self.sink is not None or fr is not None:
@@ -1895,8 +1916,7 @@ class ServingEngine:
                 # what the next step's attention reads
                 "contexts": self._offsets[self._active].tolist(),
                 **stats,
-                **{name: round(v, 4)
-                   for name, v in self._kv.gauges().items()},
+                **{name: round(v, 4) for name, v in gauges.items()},
             }
             if self.sink is not None:
                 self.sink.write(rec)

@@ -46,6 +46,8 @@ from typing import List, Optional
 
 from jax.tree_util import register_pytree_node_class
 
+from .kv_state import refuse_state_layers
+
 ZERO_PAGE = 0
 SCRATCH_PAGE = 1
 RESERVED_PAGES = 2
@@ -294,6 +296,13 @@ class PagedSlotCache:
 
         if page_tokens < 1:
             raise ValueError(f"kv_page_tokens must be >= 1, got {page_tokens}")
+        # this refusal is the prefix cache's too: only this class builds one
+        refuse_state_layers(
+            spec, "kv_layout='paged'",
+            "kv_pages.py's pages hold rows addressed by position through "
+            "one page table for all layers, and its prefix cache shares the "
+            "pages up to a position, where a state cannot be cut; use "
+            "'contiguous'")
         self.spec = list(spec)
         self.compute_dtype = compute_dtype
         self.page_tokens = pt = int(page_tokens)

@@ -28,26 +28,49 @@ Inside a traced program, over the traced `args`:
         `commit_prefill` writes that into the slot's rows: all of it for a
         `full` layer, the last `rows` positions of the prompt for a `window`
         layer.
+
+A `state` layer (nn/kv_cache.py: a matrix a head and a convolution's tail, no
+position) has its arrays in `state` / `tail` beside `k` / `v`, and the cache
+then has four arguments, not two. Its handle tells the layer which positions
+are real (`valid`: below `length` in a prefill, the active rows in a decode
+step) and the layer leaves the rest alone, so an idle slot's state stays as
+it was; a prefill starts from zeros and `commit_prefill` copies its last
+state over the slot's, so nothing of the slot's last request is left.
 """
 from __future__ import annotations
 
 from typing import List, Sequence
 
 from ..nn.kv_cache import (KINDS, ChunkKV, KVLayerSpec, RingKV, SlotKV,
-                           ring_held)
+                           SlotState, StateLayerSpec, ring_held)
 
 
-def spec_of(model, max_seq_len: int) -> List[KVLayerSpec]:
-    spec = [KVLayerSpec(*s) for s in model.kv_cache_spec(int(max_seq_len))]
-    for s in spec:
-        if s.kind not in KINDS:
-            raise ValueError(f"unknown cache kind {s.kind!r} "
+def spec_of(model, max_seq_len: int) -> list:
+    spec = []
+    for s in model.kv_cache_spec(int(max_seq_len)):
+        if s[0] not in KINDS:
+            raise ValueError(f"unknown cache kind {s[0]!r} "
                              f"(expected one of {KINDS})")
+        spec.append((StateLayerSpec if s[0] == "state" else KVLayerSpec)(*s))
     return spec
 
 
-def window_layers(spec: Sequence[KVLayerSpec]) -> List[int]:
+def window_layers(spec: Sequence) -> List[int]:
     return [i for i, s in enumerate(spec) if s.kind == "window"]
+
+
+def state_layers(spec: Sequence) -> List[int]:
+    return [i for i, s in enumerate(spec) if s.kind == "state"]
+
+
+def refuse_state_layers(spec: Sequence, who: str, why: str) -> None:
+    """Raise for a model whose layers `who` cannot hold, by name."""
+    layers = state_layers(spec)
+    if layers:
+        raise ValueError(
+            f"{who} cannot hold this model: layers {layers} keep a "
+            f"recurrent state (a matrix a head and a convolution's tail, "
+            f"rewritten whole at every position), and {why}")
 
 
 def scatter_prefill(layer: KVLayerSpec, big, local, slot, plen):
@@ -70,37 +93,53 @@ def scatter_prefill(layer: KVLayerSpec, big, local, slot, plen):
 
 
 class SlotCache:
-    """One [slots, rows, kv_heads, head_dim] pair of arrays a layer (`k`,
-    `v`): `rows` is `max_seq_len` for a `full` layer, the window for a
-    `window` layer."""
+    """One [slots, rows, kv_heads, head_dim] pair of arrays (`k`, `v`) a
+    `full` or `window` layer: `rows` is `max_seq_len` for a `full` layer, the
+    window for a `window` layer. One [slots, heads, key_dim, value_dim]
+    float32 matrix (`state`) and one [slots, tail_rows, channels] array
+    (`tail`) a `state` layer. Each list holds its own layers in the order of
+    the spec; a spec with no `state` layer has the two arguments `k`, `v`
+    and nothing else."""
 
-    n_args = 2
     masks_writes = False
     prefill_at = ("slot",)
 
-    def __init__(self, spec: Sequence[KVLayerSpec], slots: int,
-                 max_seq_len: int, dtype):
+    def __init__(self, spec: Sequence, slots: int, max_seq_len: int, dtype):
         import jax.numpy as jnp
 
         self.spec = list(spec)
         self.max_seq_len = int(max_seq_len)
         self.dtype = dtype
+        rows = [s for s in self.spec if s.kind != "state"]
+        held = [s for s in self.spec if s.kind == "state"]
 
         def make():
             return [jnp.zeros((slots, s.rows, s.kv_heads, s.head_dim), dtype)
-                    for s in self.spec]
+                    for s in rows]
 
         self.k, self.v = make(), make()
+        self.state = [jnp.zeros((slots, s.heads, s.key_dim, s.value_dim),
+                                jnp.float32) for s in held]
+        self.tail = [jnp.zeros((slots, s.tail_rows, s.channels), dtype)
+                     for s in held]
+        self.n_args = 4 if held else 2
 
     # ---- between dispatches -------------------------------------------
     def args(self):
-        return self.k, self.v
+        return (self.k, self.v, self.state, self.tail)[:self.n_args]
 
     def take(self, results, stepped=None) -> None:
-        self.k, self.v = results
+        self.k, self.v, *held = results
+        if held:
+            self.state, self.tail = held
+
+    def state_bytes(self) -> int:
+        return sum(int(a.size) * a.dtype.itemsize
+                   for a in (*self.state, *self.tail))
 
     def nbytes(self) -> int:
-        return sum(int(a.size) * a.dtype.itemsize for a in (*self.k, *self.v))
+        return self.state_bytes() + sum(
+            int(a.size) * a.dtype.itemsize for a in (*self.k, *self.v))
 
     def cover(self, active, offsets, last) -> None:
         pass
@@ -112,9 +151,26 @@ class SlotCache:
         pass
 
     def gauges(self) -> dict:
-        return {}
+        return {"state_bytes": self.state_bytes()} if self.state else {}
 
     # ---- inside a traced program --------------------------------------
+    def _by_layer(self, args):
+        """The traced `args` as one tuple a layer of the spec: (k, v) or
+        (state, tail)."""
+        rows = zip(args[0], args[1])
+        held = zip(*args[2:]) if self.n_args == 4 else iter(())
+        return [next(held if s.kind == "state" else rows) for s in self.spec]
+
+    def _as_args(self, pairs):
+        """One pair of arrays a layer, (k, v) or (state, tail) -> the
+        arguments, as `args()` orders them."""
+        rows = [p for s, p in zip(self.spec, pairs) if s.kind != "state"]
+        held = [p for s, p in zip(self.spec, pairs) if s.kind == "state"]
+        out = ([a for a, _ in rows], [b for _, b in rows])
+        if self.n_args == 4:
+            out += ([a for a, _ in held], [b for _, b in held])
+        return out
+
     def tip(self, offsets):
         """Idle slots keep writing their (unread) tip row; a full slot must
         not index past the cache."""
@@ -129,25 +185,49 @@ class SlotCache:
         import jax.numpy as jnp
 
         offsets = offsets.astype(jnp.int32)
-        return [(RingKV if s.kind == "window" else SlotKV)(k, v, offsets)
-                for s, k, v in zip(self.spec, *args)]
+        return [SlotState(a, b, write_mask[:, None]) if s.kind == "state"
+                else (RingKV if s.kind == "window" else SlotKV)(a, b, offsets)
+                for s, (a, b) in zip(self.spec, self._by_layer(args))]
 
     def absorb(self, args, handles, active):
-        return [h.k for h in handles], [h.v for h in handles]
+        return self._as_args([(h.state, h.tail) if s.kind == "state"
+                              else (h.k, h.v)
+                              for s, h in zip(self.spec, handles)])
 
     def prefill_views(self, args, bucket: int, length, slot):
         """Fresh caches for one request alone, `bucket` rows each; causal
-        masking makes the right-pad inert. Where the rows go (`length`,
-        `slot`) is `commit_prefill`'s to read."""
-        return [ChunkKV.zeros(1, bucket, s.kv_heads, s.head_dim, self.dtype)
+        masking makes the right-pad inert for rows, and a `state` layer is
+        told that the positions from `length` on are not real. Where the
+        rows go (`slot`) is `commit_prefill`'s to read."""
+        import jax.numpy as jnp
+
+        def held(s):
+            valid = jnp.arange(bucket, dtype=jnp.int32)[None, :] < length
+            return SlotState.zeros(1, s, self.dtype, valid)
+
+        return [held(s) if s.kind == "state"
+                else ChunkKV.zeros(1, bucket, s.kv_heads, s.head_dim,
+                                   self.dtype)
                 for s in self.spec]
 
     def commit_prefill(self, args, handles, length, slot):
-        ks, vs = [], []
-        for s, big_k, big_v, local in zip(self.spec, *args, handles):
-            ks.append(scatter_prefill(s, big_k, local.k, slot, length))
-            vs.append(scatter_prefill(s, big_v, local.v, slot, length))
-        return ks, vs
+        import jax
+        import jax.numpy as jnp
+
+        def put(big, local):
+            at = (slot,) + (jnp.int32(0),) * (big.ndim - 1)
+            return jax.lax.dynamic_update_slice(
+                big, local.astype(big.dtype), at)
+
+        out = []
+        for s, (a, b), local in zip(self.spec, self._by_layer(args), handles):
+            if s.kind == "state":
+                with jax.named_scope("state_write"):
+                    out.append((put(a, local.state), put(b, local.tail)))
+            else:
+                out.append((scatter_prefill(s, a, local.k, slot, length),
+                            scatter_prefill(s, b, local.v, slot, length)))
+        return self._as_args(out)
 
     @staticmethod
     def first_position(length, slot):
