@@ -48,6 +48,22 @@ a decode step's idle slots are not). The layer must leave the state and the
 tail of a row as they were over the positions that are not real, because the
 cache keeps what the layer returns: `conv_tail` below does it for the tail.
 Latent rows would be a further kind (ROADMAP.md).
+
+Where a row lives ON THE DEVICE is decided here too. The slot cache's arrays
+(`SlotKV`, `RingKV`; serving/kv_state.py allocates them) are STORED as
+[slots, rows, heads, head size] with `stored_dims`' pad: the head size to a
+multiple of 128, the heads to a multiple of 8. For such a shape the chip's
+default layout is the descending one, heads x head size tiled (8, 128), which
+is what the decode loop's fusions keep the array in whatever its shape; for
+the unpadded [.., 20, 64] or [.., 30, 128] the default is another (rows on the
+lanes, rows under the heads), and every dispatch converted the whole cache
+on its way into the loop and out of it. Nobody pins a layout: the stored shape
+makes the default the right one (a pinned layout does not survive this jax's
+persistent compile cache, PERF.md PR 34). `update` writes each new row whole,
+zeros in its pad (`padded_rows`), and returns the [kv_heads, head_dim] window
+of the stored rows, so the pad is never read and a model sees [b, t,
+kv_heads, head_dim] as ever; `logical_rows` is that window for the host's
+readers. `ChunkKV` (a request alone) stores exactly what it is given.
 """
 from __future__ import annotations
 
@@ -78,6 +94,49 @@ class StateLayerSpec(NamedTuple):
     channels: int
 
 
+def stored_dims(kv_heads: int, head_dim: int):
+    """(heads, head size) of the array a slot cache STORES for rows of
+    [kv_heads, head_dim]: the head size a multiple of the chip's 128 lanes
+    and the heads a multiple of its 8 sublanes (2 and 4 have tiles of their
+    own; a single head is left alone, its rows tile densely as they are),
+    which is what the tiles of the decode loop pad them to in any case. For
+    such a shape the chip's default layout is the descending one the loop
+    keeps the array in, so the cache crosses every program's boundary as it
+    is; for [.., 20, 64] or [.., 30, 128] the default moves the rows under
+    the heads or onto the lanes and each dispatch converts the whole cache
+    on the way in and out (PERF.md, PR 34)."""
+    heads = kv_heads if kv_heads in (1, 2, 4) else -(-kv_heads // 8) * 8
+    return heads, -(-head_dim // 128) * 128
+
+
+def logical_rows(stored, kv_heads: int, head_dim: int):
+    """[.., kv_heads, head_dim] of a stored [.., heads, head size] array:
+    the array itself where nothing was padded."""
+    if stored.shape[-2:] == (kv_heads, head_dim):
+        return stored
+    return stored[..., :kv_heads, :head_dim]
+
+
+def padded_rows(new, stored):
+    """`new` [.., kv_heads, head_dim] in `stored`'s dtype and as wide as
+    `stored` [.., heads, head size]: zeros in the pad, so that a row is
+    written whole (a write of part of a tile costs a pass of its own)."""
+    new = new.astype(stored.dtype)
+    heads = stored.shape[-2] - new.shape[-2]
+    size = stored.shape[-1] - new.shape[-1]
+    if heads or size:
+        new = jnp.pad(new, [(0, 0)] * (new.ndim - 2) + [(0, heads), (0, size)])
+    return new
+
+
+def _write_rows(stored, slots, rows, new):
+    """`stored` with `new` [b, s, kv_heads, head_dim] at rows `rows` [b, s]
+    of slots `slots` [b, 1], and what a query may read of it: -> (stored,
+    [b, rows, kv_heads, head_dim])."""
+    stored = stored.at[slots, rows].set(padded_rows(new, stored))
+    return stored, logical_rows(stored, *new.shape[2:])
+
+
 def ring_row(pos, rows: int):
     """The row of a ring of `rows` rows that holds position `pos`."""
     return pos % rows
@@ -93,8 +152,9 @@ def ring_held(tip, rows: int):
 
 
 class _KV:
-    """k, v [b, rows, kv_heads, head_dim] and `offset`, the count of positions
-    already held (int32: a scalar for the batch or one a row)."""
+    """k, v [b, rows, kv_heads, head_dim] (a slot layer's: as stored, with
+    `stored_dims`' pad) and `offset`, the count of positions already held
+    (int32: a scalar for the batch or one a row)."""
 
     fresh = False
 
@@ -174,10 +234,10 @@ class SlotKV(_KV):
         slots = jnp.arange(b)[:, None]
         pos = jnp.clip(self.offset[:, None] + jnp.arange(s)[None, :], 0,
                        total - 1)
-        k = self.k.at[slots, pos].set(k_new.astype(self.k.dtype))
-        v = self.v.at[slots, pos].set(v_new.astype(self.v.dtype))
+        k, keys = _write_rows(self.k, slots, pos, k_new)
+        v, values = _write_rows(self.v, slots, pos, v_new)
         held = jnp.arange(total)[None, None, :]
-        return k, v, held, self._advanced(k, v, s)
+        return keys, values, held, self._advanced(k, v, s)
 
 
 @register_pytree_node_class
@@ -193,11 +253,10 @@ class RingKV(_KV):
         rows = self.k.shape[1]
         pos = self.positions(s)
         slots = jnp.arange(b)[:, None]
-        k = self.k.at[slots, ring_row(pos, rows)].set(
-            k_new.astype(self.k.dtype))
-        v = self.v.at[slots, ring_row(pos, rows)].set(
-            v_new.astype(self.v.dtype))
-        return k, v, ring_held(pos, rows), self._advanced(k, v, s)
+        at = ring_row(pos, rows)
+        k, keys = _write_rows(self.k, slots, at, k_new)
+        v, values = _write_rows(self.v, slots, at, v_new)
+        return keys, values, ring_held(pos, rows), self._advanced(k, v, s)
 
 
 @register_pytree_node_class

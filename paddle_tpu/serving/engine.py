@@ -666,7 +666,9 @@ class ServingEngine:
     @property
     def slot_cache(self):
         """The slot cache (kv_state.SlotCache or kv_pages.PagedSlotCache):
-        for checks and rehearsals to read what a slot holds, never to write."""
+        for checks and rehearsals to read what a slot holds (`k[l]`, `v[l]`
+        as [slots, rows, kv_heads, head_dim], `state[l]`, `tail[l]`), never
+        to write."""
         return self._kv
 
     # ---------------------------------------------------------- internals
@@ -674,7 +676,12 @@ class ServingEngine:
     def _kcs(self):
         """The contiguous cache's key arrays, one [slots, rows, kv_heads,
         head_dim] a layer (None on the paged layout): read-only, for the
-        benchmark's row check and rehearsals until they get public names."""
+        benchmark's row check and rehearsals until they get public names.
+        They are `SlotCache.k`, the logical view: the programs' arguments
+        are `k_stored` (kv_state.py), padded to the shape whose default
+        device layout the decode loop keeps, so a rehearsal that builds its
+        argument shapes from these compiles the program without the pad
+        (tools/decode_hlo_probe.py --serving compiles the one served)."""
         return getattr(self._kv, "k", None)
 
     @property

@@ -29,6 +29,15 @@ Inside a traced program, over the traced `args`:
         `full` layer, the last `rows` positions of the prompt for a `window`
         layer.
 
+What the arrays are on the device: `k_stored` / `v_stored`, one [slots, rows,
+heads, head size] a layer with `stored_dims`' pad (nn/kv_cache.py: the shape
+whose default device layout is the one the decode loop keeps, so no program
+converts the cache at its boundary and nothing is pinned), are what `args()`
+hands every program and `take()` receives. `k` / `v` are the same rows as
+[slots, rows, kv_heads, head_dim], a view computed at each read: what the
+benchmark's checks, the rehearsals and the tests index on the host
+(`.shape[1]`, `[slot]`, `[:held]`), never a program's argument.
+
 A `state` layer (nn/kv_cache.py: a matrix a head and a convolution's tail, no
 position) has its arrays in `state` / `tail` beside `k` / `v`, and the cache
 then has four arguments, not two. Its handle tells the layer which positions
@@ -39,10 +48,12 @@ state over the slot's, so nothing of the slot's last request is left.
 """
 from __future__ import annotations
 
-from typing import List, Sequence
+from collections.abc import Sequence
+from typing import List
 
 from ..nn.kv_cache import (KINDS, ChunkKV, KVLayerSpec, RingKV, SlotKV,
-                           SlotState, StateLayerSpec, ring_held)
+                           SlotState, StateLayerSpec, logical_rows,
+                           padded_rows, ring_held, stored_dims)
 
 
 def spec_of(model, max_seq_len: int) -> list:
@@ -74,13 +85,13 @@ def refuse_state_layers(spec: Sequence, who: str, why: str) -> None:
 
 
 def scatter_prefill(layer: KVLayerSpec, big, local, slot, plen):
-    """Write a request's prefilled rows `local` [1, bucket, ...] into row
-    `slot` of the slot cache `big` [slots, rows, ...]. `slot` and `plen` (the
-    true prompt length) are traced."""
+    """Write a request's prefilled rows `local` [1, bucket, kv_heads,
+    head_dim] into row `slot` of the slot cache `big` [slots, rows, ...] as
+    stored (whole rows, zeros in the pad). `slot` and `plen` (the true
+    prompt length) are traced."""
     import jax
     import jax.numpy as jnp
 
-    local = local.astype(big.dtype)
     bucket = local.shape[1]
     if layer.kind == "window" and bucket > layer.rows:
         # each row of the ring takes the position it holds after the prompt;
@@ -89,11 +100,30 @@ def scatter_prefill(layer: KVLayerSpec, big, local, slot, plen):
         held = ring_held(plen - 1, layer.rows)
         local = jnp.take(local, jnp.clip(held, 0, bucket - 1), axis=1)
     return jax.lax.dynamic_update_slice(
-        big, local, (slot, jnp.int32(0), jnp.int32(0), jnp.int32(0)))
+        big, padded_rows(local, big),
+        (slot, jnp.int32(0), jnp.int32(0), jnp.int32(0)))
+
+
+class _Rows(Sequence):
+    """Stored arrays read as one [slots, rows, kv_heads, head_dim] array a
+    layer; a layer's is cut out of its stored array when it is indexed (on
+    the device: a copy of that layer for as long as the reader holds it)."""
+
+    def __init__(self, spec, stored):
+        self._dims = [(s.kv_heads, s.head_dim) for s in spec
+                      if s.kind != "state"]
+        self._stored = stored
+
+    def __len__(self):
+        return len(self._stored)
+
+    def __getitem__(self, layer):
+        return logical_rows(self._stored[layer], *self._dims[layer])
 
 
 class SlotCache:
-    """One [slots, rows, kv_heads, head_dim] pair of arrays (`k`, `v`) a
+    """One [slots, rows, kv_heads, head_dim] pair of arrays (`k`, `v`; stored
+    padded as `k_stored`, `v_stored`, this module's header) a
     `full` or `window` layer: `rows` is `max_seq_len` for a `full` layer, the
     window for a `window` layer. One [slots, heads, key_dim, value_dim]
     float32 matrix (`state`) and one [slots, tail_rows, channels] array
@@ -114,22 +144,35 @@ class SlotCache:
         held = [s for s in self.spec if s.kind == "state"]
 
         def make():
-            return [jnp.zeros((slots, s.rows, s.kv_heads, s.head_dim), dtype)
+            return [jnp.zeros((slots, s.rows)
+                              + stored_dims(s.kv_heads, s.head_dim), dtype)
                     for s in rows]
 
-        self.k, self.v = make(), make()
+        self.k_stored, self.v_stored = make(), make()
         self.state = [jnp.zeros((slots, s.heads, s.key_dim, s.value_dim),
                                 jnp.float32) for s in held]
         self.tail = [jnp.zeros((slots, s.tail_rows, s.channels), dtype)
                      for s in held]
         self.n_args = 4 if held else 2
 
+    @property
+    def k(self):
+        """One [slots, rows, kv_heads, head_dim] array a `full` or `window`
+        layer: what the slots hold, for checks, rehearsals and tests to
+        read."""
+        return _Rows(self.spec, self.k_stored)
+
+    @property
+    def v(self):
+        return _Rows(self.spec, self.v_stored)
+
     # ---- between dispatches -------------------------------------------
     def args(self):
-        return (self.k, self.v, self.state, self.tail)[:self.n_args]
+        return (self.k_stored, self.v_stored, self.state,
+                self.tail)[:self.n_args]
 
     def take(self, results, stepped=None) -> None:
-        self.k, self.v, *held = results
+        self.k_stored, self.v_stored, *held = results
         if held:
             self.state, self.tail = held
 
@@ -139,7 +182,8 @@ class SlotCache:
 
     def nbytes(self) -> int:
         return self.state_bytes() + sum(
-            int(a.size) * a.dtype.itemsize for a in (*self.k, *self.v))
+            int(a.size) * a.dtype.itemsize
+            for a in (*self.k_stored, *self.v_stored))
 
     def cover(self, active, offsets, last) -> None:
         pass

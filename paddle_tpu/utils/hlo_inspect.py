@@ -124,3 +124,114 @@ def bf16_converts_of_min_size(lines: List[str], min_elems: int,
                                or exclude_shape_csv not in ln):
             out.append(ln.strip())
     return out
+
+
+# ---- where arrays cross a loop's boundary (tools/decode_hlo_probe.py) ----
+_ARRAY_RE = re.compile(r"(bf16|f16|f32|s32|s64|u32|pred)\[([\d,]*)\]\{([\d,]*)")
+_ITEM_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s64": 8,
+         "pred": 1}
+
+
+def computations(text):
+    """name -> instruction lines of each computation in optimized HLO text,
+    and the name of the ENTRY one."""
+    comps, entry, name = {}, None, None
+    for line in text.splitlines():
+        m = re.match(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$", line)
+        if m and not line.startswith(" "):
+            name = m.group(2)
+            comps[name] = []
+            entry = name if m.group(1) else entry
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps, entry
+
+
+def reached(comps, roots):
+    """The computations `roots` call, transitively (fusions, bodies,
+    conditions, reducers)."""
+    seen, todo = set(), list(roots)
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                todo += [b.strip().lstrip("%") for b in group.split(",")]
+    return seen
+
+
+def boundary_report(text, cache_shapes):
+    """Where arrays of `cache_shapes` ({(dtype, dims)}) cross the loop of an
+    optimized HLO program: their layouts as ENTRY parameters and as the
+    `while`'s carry, and the `copy` / `copy-start` instructions that produce
+    one, outside and inside the loop (the loop's prefetches apart)."""
+    comps, entry = computations(text)
+    bodies = set()
+    for lines in comps.values():
+        for line in lines:
+            if " while(" in line:
+                bodies.update(re.findall(r"body=%?([\w.\-]+)", line))
+    inside = reached(comps, bodies)
+
+    def result(line):
+        m = _ARRAY_RE.search(line.split("=", 1)[1]) if "=" in line else None
+        if not m:
+            return None
+        key = (m.group(1), tuple(int(d) for d in m.group(2).split(",") if d))
+        return key + (m.group(3),) if key in cache_shapes else None
+
+    def name(r):
+        return f"{r[0]}[{','.join(map(str, r[1]))}]"
+
+    # a fusion's own computation is not a pass over memory: a `copy` in it
+    # is an operand read in another order by the fused consumer. What
+    # counts is a `copy` / `copy-start` instruction of a computation that
+    # runs as written, and a fusion that is nothing but a copy
+    fused = {c for lines in comps.values() for line in lines
+             if " fusion(" in line
+             for c in re.findall(r"calls=%?([\w.\-]+)", line)}
+    bare = {c for c in fused
+            if all(re.search(r"\s(parameter|copy|bitcast)\(", line)
+                   for line in comps.get(c, ()))
+            and any(" copy(" in line for line in comps.get(c, ()))}
+    entry_layouts, loop_layouts = {}, {}
+    # a `copy` changes the layout; a `copy-start` whose two layouts agree
+    # moves the array to another memory space (a prefetch) and changes none
+    copies = {k: [0, 0] for k in ("outside", "inside", "inside_prefetch")}
+    for comp, lines in comps.items():
+        if comp in fused:
+            continue
+        for line in lines:
+            r = result(line)
+            if r is None:
+                continue
+            if comp == entry and " parameter(" in line:
+                by = entry_layouts.setdefault(name(r), {})
+                by[r[2]] = by.get(r[2], 0) + 1
+            if comp in bodies and " get-tuple-element(" in line:
+                by = loop_layouts.setdefault(name(r), {})
+                by[r[2]] = by.get(r[2], 0) + 1
+            op = re.search(r"\s(copy|copy-start|fusion)\(", line)
+            if not op or (op.group(1) == "fusion" and not bare.intersection(
+                    re.findall(r"calls=%?([\w.\-]+)", line))):
+                continue
+            where = "inside" if comp in inside else "outside"
+            both = _ARRAY_RE.findall(line.split(op.group(0))[0])
+            if (op.group(1) == "copy-start" and where == "inside"
+                    and len(both) > 1 and both[0] == both[1]):
+                where = "inside_prefetch"
+            n = _ITEM_BYTES[r[0]]
+            for d in r[1]:
+                n *= d
+            copies[where][0] += 1
+            copies[where][1] += n
+    return {"entry_layouts": entry_layouts, "in_loop_layouts": loop_layouts,
+            "cache_sized_copies": {
+                k: {"count": c, "GiB": round(b / 2 ** 30, 3)}
+                for k, (c, b) in copies.items()}}

@@ -260,7 +260,10 @@ def test_cache_holds_window_rows_on_window_layers(tiny):
     assert [s.rows for s in spec] == [8, 8, 8, 8, 48]
     assert all((s.kv_heads, s.head_dim) == (2, 16) for s in spec)
     assert [k.shape for k in eng._kcs] == [(3, 8, 2, 16)] * 4 + [(3, 48, 2, 16)]
-    per_row = 2 * 2 * 16 * 4                       # k and v, f32
+    # stored with the head size padded to the chip's 128 lanes (ISSUE 34)
+    assert [k.shape for k in eng.slot_cache.k_stored] == (
+        [(3, 8, 2, 128)] * 4 + [(3, 48, 2, 128)])
+    per_row = 2 * 2 * 128 * 4                      # k and v, f32
     assert eng.kv_cache_bytes() == 3 * (4 * 8 + 48) * per_row
     assert eng.stats()["kv_cache_bytes"] == eng.kv_cache_bytes()
 
@@ -310,7 +313,8 @@ def test_gpt_declares_full_layers_and_keeps_its_cache():
     assert spec == [kv_state.KVLayerSpec("full", 64, 4, 32)] * 2
     eng = ServingEngine(model, slot_count=2, ladder=(8, 16), max_seq_len=64,
                         max_new_cap=8)
-    assert eng.kv_cache_bytes() == 2 * 2 * (2 * 64 * 4 * 32) * 4
+    # stored [2, 64, 4, 128]: the head size padded 32 -> 128 (ISSUE 34)
+    assert eng.kv_cache_bytes() == 2 * 2 * (2 * 64 * 4 * 128) * 4
     layer, prefix = model.serving_backbone()
     assert layer is model.gpt and prefix == "gpt."
     assert model.serving_step_stats == {}

@@ -714,11 +714,11 @@ def test_serving_programs_sort_nothing_as_wide_as_the_vocabulary(family):
         return jnp.zeros((eng.slot_count,), dtype)
 
     decode = eng._build_decode("sample").lower(
-        eng._params, eng._kcs, eng._vcs, vec(jnp.int32), vec(jnp.int32),
+        eng._params, *eng.slot_cache.args(), vec(jnp.int32), vec(jnp.int32),
         vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32), vec(jnp.float32),
         vec(jnp.int32), vec(jnp.int32), vec(jnp.int32))
     prefill = eng._build_prefill(8).lower(
-        eng._params, eng._kcs, eng._vcs, jnp.zeros((1, 8), jnp.int64),
+        eng._params, *eng.slot_cache.args(), jnp.zeros((1, 8), jnp.int64),
         jnp.int32(0), jnp.int32(0), jnp.float32(0.0), jnp.int32(0),
         jnp.float32(1.0), jnp.int32(0))
     for name, lowered in (("decode", decode), ("prefill", prefill)):

@@ -24,7 +24,7 @@ from paddle_tpu.models import (GPTConfig, GPTForPretraining,
                                gpt_tiny, olmo_hybrid_tiny)
 from paddle_tpu.models import olmo_hybrid as hybrid
 from paddle_tpu.nn.kv_cache import (KVLayerSpec, SlotState, StateLayerSpec,
-                                    conv_tail)
+                                    conv_tail, stored_dims)
 from paddle_tpu.observability import device_trace, metrics
 from paddle_tpu.ops.gated_delta import gated_delta_chunked, gated_delta_step
 from paddle_tpu.serving import ServingEngine, kv_state
@@ -467,7 +467,8 @@ def test_cache_bytes_are_the_arithmetic(tiny):
     assert [a.shape for a in kv.k] == [(3, 48, 4, 16)] * 2
     assert [a.shape for a in kv.state] == [(3, 4, 8, 16)] * 6
     assert [a.shape for a in kv.tail] == [(3, 3, 128)] * 6
-    rows = 2 * 3 * 48 * 4 * 16 * 4 * 2                    # k and v, f32
+    assert [a.shape for a in kv.k_stored] == [(3, 48, 4, 128)] * 2
+    rows = 2 * 3 * 48 * 4 * 128 * 4 * 2         # k and v as stored, f32
     assert kv.state_bytes() == 6 * 3 * STATE_BYTES
     assert eng.kv_cache_bytes() == rows + 6 * 3 * STATE_BYTES
     assert eng.stats()["kv_cache_bytes"] == eng.kv_cache_bytes()
@@ -562,9 +563,12 @@ def test_slot_cache_without_state_layers_is_as_it_was(dtype):
     kv = kv_state.SlotCache(spec, 3, 32, jnp.dtype(dtype))
     assert kv.n_args == 2
     k, v = kv.args()
-    assert k is kv.k and v is kv.v and kv.state == kv.tail == []
-    assert [a.shape for a in k] == [a.shape for a in v] == [
+    assert k is kv.k_stored and v is kv.v_stored
+    assert kv.state == kv.tail == []
+    assert [a.shape for a in kv.k] == [a.shape for a in kv.v] == [
         (3, 32, s.kv_heads, s.head_dim) for s in spec]
+    assert [a.shape for a in k] == [a.shape for a in v] == [
+        (3, 32) + stored_dims(s.kv_heads, s.head_dim) for s in spec]
     assert {a.dtype for a in k + v} == {jnp.dtype(dtype)}
     assert ServingEngine._donate(1, kv) == (1, 2)
     assert kv.gauges() == {} and kv.state_bytes() == 0
@@ -575,7 +579,7 @@ def test_slot_cache_without_state_layers_is_as_it_was(dtype):
     out = kv.absorb(kv.args(), handles, None)
     assert len(out) == 2 and all(a is b for a, b in zip(out[0], k))
     kv.take(out)
-    assert kv.k is out[0] and kv.v is out[1]
+    assert kv.k_stored is out[0] and kv.v_stored is out[1]
     eng = ServingEngine(model, slot_count=2, ladder=(8,), max_seq_len=32,
                         max_new_cap=8)
     assert eng.slot_cache.n_args == 2 and eng._donate(1, eng.slot_cache) == (1, 2)

@@ -176,7 +176,7 @@ def _lower_decode(eng):
         return jnp.zeros((s,), dtype)
 
     return eng._build_decode("sample").lower(
-        eng._params, eng._kcs, eng._vcs, vec(jnp.int32), vec(jnp.int32),
+        eng._params, *eng.slot_cache.args(), vec(jnp.int32), vec(jnp.int32),
         vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32), vec(jnp.float32),
         vec(jnp.int32), vec(jnp.int32), vec(jnp.int32))
 
