@@ -8,6 +8,10 @@ from .afmoe import (  # noqa: F401
 from .olmo_hybrid import (  # noqa: F401
     OlmoHybridConfig, OlmoHybridForCausalLM, OlmoHybridModel, olmo_hybrid_tiny,
 )
+from .deepseek_v2 import (  # noqa: F401
+    DeepseekV2Config, DeepseekV2ForCausalLM, DeepseekV2Model,
+    deepseek_v2_tiny,
+)
 from .ernie import (  # noqa: F401
     BertConfig, BertForPretraining, BertModel, ErnieConfig, ErnieForPretraining,
     ErnieModel, bert_base, bert_large, ernie_base, ernie_large, ernie_tiny,

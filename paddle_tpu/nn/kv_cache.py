@@ -1,16 +1,23 @@
 """What a layer keeps of a sequence between two calls, how it is written and
-read back: the one module that knows. Three kinds: two of rows of keys and
-values, where position p lives in a row, and one of state with no position.
+read back: the one module that knows. Four kinds: two of rows of keys and
+values, where position p lives in a row, one of latent rows that all heads
+share, and one of state with no position.
 
 A model declares what each layer keeps and never looks inside a cache:
 
     model.kv_cache_spec(max_seq_len) -> [KVLayerSpec(kind, rows, kv_heads,
                                                      head_dim)
+                                         or LatentLayerSpec("latent", ...)
                                          or StateLayerSpec("state", ...), ...]
 
 - `full`: every position is kept, row p holds position p.
 - `window`: the last `rows` positions are kept as a ring, position p in row
   `p % rows`, however long the context.
+- `latent`: every position is kept, row p holds position p, and a row is
+  ONE vector for all heads: the layer's normalised latent (`latent_dim`
+  values, from which keys and values a head are projected) and the one
+  rotated key all heads share (`rope_dim`) behind it. There is no `v` beside
+  it: the values are a projection of the row's first `latent_dim`.
 - `state`: a recurrent layer's matrix a head and the last inputs of its
   causal convolution, the same size whatever the context, rewritten whole at
   every position; nothing in it can be addressed, cut or rewound by position.
@@ -47,7 +54,17 @@ channels] are what each row of the batch held before the chunk; `valid`
 a decode step's idle slots are not). The layer must leave the state and the
 tail of a row as they were over the positions that are not real, because the
 cache keeps what the layer returns: `conv_tail` below does it for the tail.
-Latent rows would be a further kind (ROADMAP.md).
+
+A `latent` layer is handed a `ChunkLatent` (a request alone) or a
+`SlotLatent` (the slot cache, a slot at its own offset) and calls it once:
+
+    positions = cache.positions(s)
+    rows, held, cache = cache.update(new)     # new [b, s, latent + rope]
+
+`rows` [b, t, width] are the rows as stored, `width >= latent_dim + rope_dim`
+with zeros behind what was written, so a query padded with zeros to `width`
+scores against a whole row with no slice of the cache in between; `held` is
+as above and the same causal test hides unwritten rows.
 
 Where a row lives ON THE DEVICE is decided here too. The slot cache's arrays
 (`SlotKV`, `RingKV`; serving/kv_state.py allocates them) are STORED as
@@ -57,8 +74,15 @@ default layout is the descending one, heads x head size tiled (8, 128), which
 is what the decode loop's fusions keep the array in whatever its shape; for
 the unpadded [.., 20, 64] or [.., 30, 128] the default is another (rows on the
 lanes, rows under the heads), and every dispatch converted the whole cache
-on its way into the loop and out of it. Nobody pins a layout: the stored shape
-makes the default the right one (a pinned layout does not survive this jax's
+on its way into the loop and out of it. A `latent` layer's rows are stored as
+ONE [slots, rows, 640] array (`latent_width`: 512 + 64 = 576 is 4.5 vectors
+of 128 lanes, padded to 5): the compiler, asked for a described v5e
+(`tools/decode_hlo_probe.py --serving deepseek-v2`, PERF.md PR 35), gives
+that shape the descending layout with (8, 128) tiles at the program's entry
+and keeps it through the loop, with no cache-sized copy outside or inside
+it; 512 beside 64 padded to 128 is the same 640 a position in two arrays and
+a concatenation at every read, so it was not taken. Nobody pins a layout:
+the stored shape makes the default the right one (a pinned layout does not survive this jax's
 persistent compile cache, PERF.md PR 34). `update` writes each new row whole,
 zeros in its pad (`padded_rows`), and returns the [kv_heads, head_dim] window
 of the stored rows, so the pad is never read and a model sees [b, t,
@@ -73,7 +97,7 @@ import jax
 import jax.numpy as jnp
 from jax.tree_util import register_pytree_node_class
 
-KINDS = ("full", "window", "state")
+KINDS = ("full", "window", "state", "latent")
 
 
 class KVLayerSpec(NamedTuple):
@@ -92,6 +116,33 @@ class StateLayerSpec(NamedTuple):
     value_dim: int
     tail_rows: int
     channels: int
+
+
+class LatentLayerSpec(NamedTuple):
+    """A `latent` layer: `rows` positions of `latent_dim + rope_dim` values,
+    shared by all heads."""
+    kind: str
+    rows: int
+    latent_dim: int
+    rope_dim: int
+
+
+SPECS = {"state": StateLayerSpec, "latent": LatentLayerSpec}
+
+
+def latent_width(layer: LatentLayerSpec) -> int:
+    """The width a slot cache STORES a latent row at: a multiple of the
+    chip's 128 lanes (576 -> 640)."""
+    return -(-(layer.latent_dim + layer.rope_dim) // 128) * 128
+
+
+def widened_rows(new, stored):
+    """`new` [b, s, latent + rope] in `stored`'s dtype and as wide as
+    `stored` [slots, rows, width]: zeros in the pad, so that a latent row is
+    written whole."""
+    new = new.astype(stored.dtype)
+    pad = stored.shape[-1] - new.shape[-1]
+    return jnp.pad(new, [(0, 0), (0, 0), (0, pad)]) if pad else new
 
 
 def stored_dims(kv_heads: int, head_dim: int):
@@ -257,6 +308,77 @@ class RingKV(_KV):
         k, keys = _write_rows(self.k, slots, at, k_new)
         v, values = _write_rows(self.v, slots, at, v_new)
         return keys, values, ring_held(pos, rows), self._advanced(k, v, s)
+
+
+class _Latent:
+    """rows [b, rows, width] and `offset`, the count of positions already
+    held (int32: a scalar for the batch or one a row)."""
+
+    fresh = False
+
+    def __init__(self, rows, offset):
+        self.rows, self.offset = rows, offset
+
+    def tree_flatten(self):
+        return (self.rows, self.offset), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(*leaves)
+
+
+@register_pytree_node_class
+class ChunkLatent(_Latent):
+    """Every sequence of the batch at one scalar offset, row p holds
+    position p, the rows as wide as they are given."""
+
+    def __init__(self, rows, offset, fresh=False):
+        super().__init__(rows, offset)
+        self.fresh = fresh
+
+    @classmethod
+    def zeros(cls, batch: int, layer: LatentLayerSpec, dtype, rows=None):
+        return cls(jnp.zeros((batch, layer.rows if rows is None else rows,
+                              layer.latent_dim + layer.rope_dim), dtype),
+                   jnp.int32(0), fresh=True)
+
+    def tree_flatten(self):
+        return (self.rows, self.offset), self.fresh
+
+    @classmethod
+    def tree_unflatten(cls, fresh, leaves):
+        return cls(*leaves, fresh=fresh)
+
+    def positions(self, s: int):
+        return self.offset + jnp.arange(s, dtype=jnp.int32)[None, :]
+
+    def update(self, new):
+        zero = jnp.int32(0)
+        rows = jax.lax.dynamic_update_slice(
+            self.rows, new.astype(self.rows.dtype), (zero, self.offset, zero))
+        held = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, None, :]
+        return rows, held, ChunkLatent(rows,
+                                       self.offset + jnp.int32(new.shape[1]))
+
+
+@register_pytree_node_class
+class SlotLatent(_Latent):
+    """A `latent` layer of the slot cache: each row of the batch is a slot
+    at its own offset, row p of a slot holds position p, stored
+    `latent_width` wide with zeros in the pad. Rows past a slot's offset are
+    never seen (the causal test)."""
+
+    def positions(self, s: int):
+        return self.offset[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+
+    def update(self, new):
+        b, s = new.shape[0], new.shape[1]
+        total = self.rows.shape[1]
+        pos = jnp.clip(self.positions(s), 0, total - 1)
+        rows = self.rows.at[jnp.arange(b)[:, None], pos].set(
+            widened_rows(new, self.rows))
+        held = jnp.arange(total, dtype=jnp.int32)[None, None, :]
+        return rows, held, SlotLatent(rows, self.offset + jnp.int32(s))
 
 
 @register_pytree_node_class

@@ -9,11 +9,21 @@ whole result, and the parts of disjoint shares add up to it. Nothing stands
 in for the experts that live elsewhere or for their traffic: on one chip the
 layer runs without its exchange.
 
-Routing (sigmoid scores, as the `afmoe` and DeepSeek-V3 routers): scores
-`s = sigmoid(m Wr)` in float32; the k experts are `top_k(s + bias)`, where
-`bias` is the load-balancing buffer, which chooses and does not weigh; the
-weights are `s[chosen]`, normalised if `route_norm`, times `route_scale`.
+Routing, in float32: scores `s = sigmoid(m Wr)` (`score_func="sigmoid"`, the
+`afmoe` and DeepSeek-V3 routers) or `s = softmax(m Wr)` over all experts
+(`"softmax"`, DeepSeek-V2's). With `n_group` > 1 the choice is group-limited:
+the experts lie in `n_group` equal groups in order, a group's score is the
+best of its experts' (with the bias), the `topk_group` best groups stay and
+every other group's experts score 0. The k experts are `top_k(s + bias)` of
+what is left, where `bias` is the load-balancing buffer, which chooses and
+does not weigh; the weights are `s[chosen]`, divided by their sum if
+`route_norm`, times `route_scale` (DeepSeek-V2: not normalised, times 16).
 Every token gets its k experts: no capacity, nothing dropped.
+
+The load a step reports: `moe_touched` counts the experts of ALL the
+published `num_experts` that received a row, whoever holds them;
+`touched_held` counts those among the `count` held here, which is what sets
+the expert bytes this chip reads. They are equal where a layer holds all.
 
 `distributed/meta_parallel/moe.py` (GShard's one-hot dispatch with a
 capacity) stays for the dryrun that uses it; a `[tokens, experts, capacity]`
@@ -78,12 +88,26 @@ class RoutedExperts(Layer):
     """forward(x [..., hidden]) -> the held experts' part of the layer's
     result, same shape and dtype. `routed(m)` also returns the step's load:
     (experts of all `num_experts` that received a row, the most rows one
-    expert received)."""
+    expert received); `routed_load(m)` returns the rows each of all experts
+    received instead, from which `touched_held` counts the experts held
+    here that received one."""
 
     def __init__(self, hidden_size, expert_width, num_experts, top_k,
                  first=0, count=None, route_norm=True, route_scale=1.0,
-                 bias_std=0.0, dtype="float32", init_std=0.02):
+                 bias_std=0.0, dtype="float32", init_std=0.02,
+                 score_func="sigmoid", n_group=1, topk_group=1):
         super().__init__(dtype=dtype)
+        if score_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"score_func {score_func!r}: the router scores "
+                             f"with 'sigmoid' or 'softmax'")
+        if num_experts % n_group or not 1 <= topk_group <= n_group:
+            raise ValueError(f"{num_experts} experts in {n_group} groups, "
+                             f"{topk_group} of them kept")
+        if top_k > num_experts // n_group * topk_group:
+            raise ValueError(f"top_k {top_k} of {topk_group} groups of "
+                             f"{num_experts // n_group} experts")
+        self.score_func = score_func
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
         count = num_experts - first if count is None else int(count)
         if not (0 <= first and count >= 1 and first + count <= num_experts):
             raise ValueError(f"experts held ({first}, {count}) do not lie in "
@@ -107,8 +131,19 @@ class RoutedExperts(Layer):
         removes most of the choices that rounding would flip."""
         logits = jnp.dot(m.astype(jnp.float32), self.router.weight._data,
                          precision=jax.lax.Precision.HIGHEST)
-        scores = jax.nn.sigmoid(logits)
-        _, chosen = jax.lax.top_k(scores + self.expert_bias._data, self.top_k)
+        if self.score_func == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        else:
+            scores = jax.nn.sigmoid(logits)
+        choose = scores + self.expert_bias._data
+        if self.n_group > 1:
+            groups = choose.reshape(choose.shape[0], self.n_group, -1)
+            _, kept = jax.lax.top_k(groups.max(-1), self.topk_group)
+            stays = jnp.zeros(groups.shape[:2], bool).at[
+                jnp.arange(groups.shape[0])[:, None], kept].set(True)
+            choose = jnp.where(stays[:, :, None], groups, 0.0).reshape(
+                choose.shape)
+        _, chosen = jax.lax.top_k(choose, self.top_k)
         w = jnp.take_along_axis(scores, chosen, axis=-1)
         if self.route_norm:
             w = w / (w.sum(-1, keepdims=True) + 1e-20)
@@ -116,6 +151,24 @@ class RoutedExperts(Layer):
 
     def routed(self, m):
         """m [rows, hidden] -> (out [rows, hidden], touched, max_load)."""
+        out, load = self.routed_load(m)
+        return (out, *self.load_stats(load))
+
+    @staticmethod
+    def load_stats(load):
+        """(experts of all that received a row, the most rows one received)
+        of `load` as `routed_load` returns it."""
+        return (load > 0).sum().astype(jnp.float32), load.max()
+
+    def touched_held(self, load):
+        """Of the experts held here, how many received a row (`load` as
+        `routed_load` returns it)."""
+        held = jax.lax.dynamic_slice_in_dim(load, self.first, self.count)
+        return (held > 0).sum().astype(jnp.float32)
+
+    def routed_load(self, m):
+        """m [rows, hidden] -> (out [rows, hidden], load [num_experts]: the
+        rows each published expert received)."""
         rows, k = m.shape[0], self.top_k
         ex = self.experts
         with jax.named_scope("router"):
@@ -141,7 +194,7 @@ class RoutedExperts(Layer):
             mine = held.reshape(rows, k, 1)
             y = jnp.where(mine, y.astype(jnp.float32) * w[..., None], 0.0)
             out = y.sum(1).astype(m.dtype)
-        return out, (load > 0).sum().astype(jnp.float32), load.max()
+        return out, load
 
     def forward(self, x):
         data = x._data if isinstance(x, Tensor) else x

@@ -26,7 +26,8 @@ import re
 from typing import Dict, Iterator, List, Optional, Tuple
 
 # ---- the vocabulary (paddle_tpu/models/gpt.py, models/afmoe.py,
-# models/olmo_hybrid.py, nn/layers/routed_experts.py, ops/fused.py,
+# models/olmo_hybrid.py, models/deepseek_v2.py,
+# nn/layers/routed_experts.py, ops/fused.py,
 # ops/pallas/flash_attention.py, distributed/engine.py, grad_comm.py,
 # serving/engine.py, serving/kv_state.py, serving/sampling.py) ------------
 ROOTS = ("prefill", "decode")                       # the serving programs
@@ -42,7 +43,12 @@ SCOPES = frozenset(ROOTS + KERNELS + (
     # the olmo_hybrid block: linear_attn > proj, conv, gates, delta_rule,
     # out_gate, out; the slot's state written back
     "linear_attn", "proj", "conv", "gates", "delta_rule", "out_gate",
-    "state_write"))
+    "state_write",
+    # the deepseek_v2 block: mla > q_lora, kv_latent, rope, expand (a
+    # prefill: keys and values a head from the latent), absorb / unabsorb (a
+    # decode step: queries into the latent space, the result back), core,
+    # out, cache_write; moe as afmoe's
+    "mla", "q_lora", "kv_latent", "expand", "absorb", "unabsorb"))
 SPAN_PREFIXES = ("serve.", "engine.")               # the engines' spans
 
 UNNAMED = "unnamed"           # an op_name, and no scope of the vocabulary

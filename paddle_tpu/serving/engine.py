@@ -308,6 +308,11 @@ class ServingEngine:
                 "the verify program rewinds rejected positions by offset, "
                 "which a state cannot be; construct the engine without "
                 "draft_model")
+            _kvs.refuse_latent_layers(
+                spec, "speculative decoding",
+                "a verify window through the absorbed form, with rejected "
+                "rows rewound by offset, is not written or held to the "
+                "reference yet; construct the engine without draft_model")
         # the slot cache (kv_state.py): ONE object knows where a slot's rows
         # live; the programs below are written over it. Paged: per-layer
         # page pools + one page table traced as a gather index, and the
@@ -341,6 +346,10 @@ class ServingEngine:
                 dspec, "a draft model's cache",
                 "the verify program rewinds the draft's rejected positions "
                 "by offset, which a state cannot be")
+            _kvs.refuse_latent_layers(
+                dspec, "a draft model's cache",
+                "a draft that decodes through the absorbed form beside the "
+                "verify program is not written or held to the reference yet")
             self._dkv = _kvs.SlotCache(dspec, S, T, self._cache_dtype)
 
         # host-side per-slot state (tiny arrays; sent to the device after a

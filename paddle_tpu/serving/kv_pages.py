@@ -46,7 +46,7 @@ from typing import List, Optional
 
 from jax.tree_util import register_pytree_node_class
 
-from .kv_state import refuse_state_layers
+from .kv_state import refuse_latent_layers, refuse_state_layers
 
 ZERO_PAGE = 0
 SCRATCH_PAGE = 1
@@ -303,6 +303,11 @@ class PagedSlotCache:
             "one page table for all layers, and its prefix cache shares the "
             "pages up to a position, where a state cannot be cut; use "
             "'contiguous'")
+        refuse_latent_layers(
+            spec, "kv_layout='paged'",
+            "kv_pages.py's pools hold a page of keys beside a page of values "
+            "a head, which a latent row has not, so neither the pool nor "
+            "its prefix cache can keep one; use 'contiguous'")
         self.spec = list(spec)
         self.compute_dtype = compute_dtype
         self.page_tokens = pt = int(page_tokens)

@@ -45,15 +45,22 @@ are real (`valid`: below `length` in a prefill, the active rows in a decode
 step) and the layer leaves the rest alone, so an idle slot's state stays as
 it was; a prefill starts from zeros and `commit_prefill` copies its last
 state over the slot's, so nothing of the slot's last request is left.
+
+A `latent` layer (nn/kv_cache.py: one row a position for all heads) has ONE
+array in `latent_stored`, [slots, rows, `latent_width`], after the others in
+`args()`; `latent` is the same rows as [slots, rows, latent_dim + rope_dim]
+for the host's readers. A prefill runs over a `ChunkLatent` of its bucket's
+rows and `commit_prefill` writes them, padded, at the slot's row 0.
 """
 from __future__ import annotations
 
 from collections.abc import Sequence
 from typing import List
 
-from ..nn.kv_cache import (KINDS, ChunkKV, KVLayerSpec, RingKV, SlotKV,
-                           SlotState, StateLayerSpec, logical_rows,
-                           padded_rows, ring_held, stored_dims)
+from ..nn.kv_cache import (KINDS, SPECS, ChunkKV, ChunkLatent, KVLayerSpec,
+                           RingKV, SlotKV, SlotLatent, SlotState,
+                           latent_width, logical_rows, padded_rows, ring_held,
+                           stored_dims, widened_rows)
 
 
 def spec_of(model, max_seq_len: int) -> list:
@@ -62,7 +69,7 @@ def spec_of(model, max_seq_len: int) -> list:
         if s[0] not in KINDS:
             raise ValueError(f"unknown cache kind {s[0]!r} "
                              f"(expected one of {KINDS})")
-        spec.append((StateLayerSpec if s[0] == "state" else KVLayerSpec)(*s))
+        spec.append(SPECS.get(s[0], KVLayerSpec)(*s))
     return spec
 
 
@@ -74,14 +81,28 @@ def state_layers(spec: Sequence) -> List[int]:
     return [i for i, s in enumerate(spec) if s.kind == "state"]
 
 
+def latent_layers(spec: Sequence) -> List[int]:
+    return [i for i, s in enumerate(spec) if s.kind == "latent"]
+
+
+def _refuse(layers: List[int], keep: str, who: str, why: str) -> None:
+    if layers:
+        raise ValueError(f"{who} cannot hold this model: layers {layers} "
+                         f"keep {keep}, and {why}")
+
+
+def refuse_latent_layers(spec: Sequence, who: str, why: str) -> None:
+    """Raise for a model whose layers `who` cannot hold, by name."""
+    _refuse(latent_layers(spec),
+            "latent rows (one row a position shared by all heads, with no "
+            "values beside it)", who, why)
+
+
 def refuse_state_layers(spec: Sequence, who: str, why: str) -> None:
     """Raise for a model whose layers `who` cannot hold, by name."""
-    layers = state_layers(spec)
-    if layers:
-        raise ValueError(
-            f"{who} cannot hold this model: layers {layers} keep a "
-            f"recurrent state (a matrix a head and a convolution's tail, "
-            f"rewritten whole at every position), and {why}")
+    _refuse(state_layers(spec),
+            "a recurrent state (a matrix a head and a convolution's tail, "
+            "rewritten whole at every position)", who, why)
 
 
 def scatter_prefill(layer: KVLayerSpec, big, local, slot, plen):
@@ -104,6 +125,16 @@ def scatter_prefill(layer: KVLayerSpec, big, local, slot, plen):
         (slot, jnp.int32(0), jnp.int32(0), jnp.int32(0)))
 
 
+# a `SlotCache`'s groups of arrays, in the order of its arguments: rows of
+# keys and values (always there), a state and its tail, latent rows
+_GROUPS = (("k_stored", "v_stored"), ("state", "tail"), ("latent_stored",))
+
+
+def _group(layer) -> int:
+    """Which of `_GROUPS` holds a layer's arrays."""
+    return {"state": 1, "latent": 2}.get(layer.kind, 0)
+
+
 class _Rows(Sequence):
     """Stored arrays read as one [slots, rows, kv_heads, head_dim] array a
     layer; a layer's is cut out of its stored array when it is indexed (on
@@ -111,7 +142,7 @@ class _Rows(Sequence):
 
     def __init__(self, spec, stored):
         self._dims = [(s.kv_heads, s.head_dim) for s in spec
-                      if s.kind != "state"]
+                      if _group(s) == 0]
         self._stored = stored
 
     def __len__(self):
@@ -127,9 +158,10 @@ class SlotCache:
     `full` or `window` layer: `rows` is `max_seq_len` for a `full` layer, the
     window for a `window` layer. One [slots, heads, key_dim, value_dim]
     float32 matrix (`state`) and one [slots, tail_rows, channels] array
-    (`tail`) a `state` layer. Each list holds its own layers in the order of
-    the spec; a spec with no `state` layer has the two arguments `k`, `v`
-    and nothing else."""
+    (`tail`) a `state` layer. One [slots, rows, latent_width] array
+    (`latent_stored`) a `latent` layer. Each list holds its own layers in
+    the order of the spec; a spec with no `state` and no `latent` layer has
+    the two arguments `k`, `v` and nothing else."""
 
     masks_writes = False
     prefill_at = ("slot",)
@@ -140,8 +172,8 @@ class SlotCache:
         self.spec = list(spec)
         self.max_seq_len = int(max_seq_len)
         self.dtype = dtype
-        rows = [s for s in self.spec if s.kind != "state"]
-        held = [s for s in self.spec if s.kind == "state"]
+        rows, held, latent = ([s for s in self.spec if _group(s) == g]
+                              for g in range(3))
 
         def make():
             return [jnp.zeros((slots, s.rows)
@@ -153,7 +185,12 @@ class SlotCache:
                                 jnp.float32) for s in held]
         self.tail = [jnp.zeros((slots, s.tail_rows, s.channels), dtype)
                      for s in held]
-        self.n_args = 4 if held else 2
+        self.latent_stored = [jnp.zeros((slots, s.rows, latent_width(s)),
+                                        dtype) for s in latent]
+        # the arguments: `k`, `v`, then the groups this spec has, in order
+        self._names = _GROUPS[0] + (_GROUPS[1] if held else ()) \
+            + (_GROUPS[2] if latent else ())
+        self.n_args = len(self._names)
 
     @property
     def k(self):
@@ -166,22 +203,31 @@ class SlotCache:
     def v(self):
         return _Rows(self.spec, self.v_stored)
 
+    @property
+    def latent(self):
+        """One [slots, rows, latent_dim + rope_dim] array a `latent` layer,
+        the stored rows without their pad."""
+        widths = [s.latent_dim + s.rope_dim for s in self.spec
+                  if _group(s) == 2]
+        return [a[..., :w] for a, w in zip(self.latent_stored, widths)]
+
     # ---- between dispatches -------------------------------------------
     def args(self):
-        return (self.k_stored, self.v_stored, self.state,
-                self.tail)[:self.n_args]
+        return tuple(getattr(self, name) for name in self._names)
 
     def take(self, results, stepped=None) -> None:
-        self.k_stored, self.v_stored, *held = results
-        if held:
-            self.state, self.tail = held
+        for name, arrays in zip(self._names, results):
+            setattr(self, name, arrays)
 
     def state_bytes(self) -> int:
         return sum(int(a.size) * a.dtype.itemsize
                    for a in (*self.state, *self.tail))
 
+    def latent_bytes(self) -> int:
+        return sum(int(a.size) * a.dtype.itemsize for a in self.latent_stored)
+
     def nbytes(self) -> int:
-        return self.state_bytes() + sum(
+        return self.state_bytes() + self.latent_bytes() + sum(
             int(a.size) * a.dtype.itemsize
             for a in (*self.k_stored, *self.v_stored))
 
@@ -195,25 +241,29 @@ class SlotCache:
         pass
 
     def gauges(self) -> dict:
-        return {"state_bytes": self.state_bytes()} if self.state else {}
+        out = {"state_bytes": self.state_bytes()} if self.state else {}
+        if self.latent_stored:
+            out["latent_bytes"] = self.latent_bytes()
+        return out
 
     # ---- inside a traced program --------------------------------------
     def _by_layer(self, args):
-        """The traced `args` as one tuple a layer of the spec: (k, v) or
-        (state, tail)."""
-        rows = zip(args[0], args[1])
-        held = zip(*args[2:]) if self.n_args == 4 else iter(())
-        return [next(held if s.kind == "state" else rows) for s in self.spec]
+        """The traced `args` as one tuple a layer of the spec: (k, v),
+        (state, tail) or (latent rows,)."""
+        given = dict(zip(self._names, args))
+        groups = [zip(*(given[n] for n in names)) if names[0] in given
+                  else iter(()) for names in _GROUPS]
+        return [next(groups[_group(s)]) for s in self.spec]
 
-    def _as_args(self, pairs):
-        """One pair of arrays a layer, (k, v) or (state, tail) -> the
+    def _as_args(self, parts):
+        """One tuple of arrays a layer, as `_by_layer` gives them -> the
         arguments, as `args()` orders them."""
-        rows = [p for s, p in zip(self.spec, pairs) if s.kind != "state"]
-        held = [p for s, p in zip(self.spec, pairs) if s.kind == "state"]
-        out = ([a for a, _ in rows], [b for _, b in rows])
-        if self.n_args == 4:
-            out += ([a for a, _ in held], [b for _, b in held])
-        return out
+        out = {}
+        for g, names in enumerate(_GROUPS):
+            mine = [p for s, p in zip(self.spec, parts) if _group(s) == g]
+            for i, name in enumerate(names):
+                out[name] = [p[i] for p in mine]
+        return tuple(out[name] for name in self._names)
 
     def tip(self, offsets):
         """Idle slots keep writing their (unread) tip row; a full slot must
@@ -229,12 +279,20 @@ class SlotCache:
         import jax.numpy as jnp
 
         offsets = offsets.astype(jnp.int32)
-        return [SlotState(a, b, write_mask[:, None]) if s.kind == "state"
-                else (RingKV if s.kind == "window" else SlotKV)(a, b, offsets)
-                for s, (a, b) in zip(self.spec, self._by_layer(args))]
+
+        def view(s, held):
+            if s.kind == "state":
+                return SlotState(*held, write_mask[:, None])
+            if s.kind == "latent":
+                return SlotLatent(*held, offsets)
+            return (RingKV if s.kind == "window" else SlotKV)(*held, offsets)
+
+        return [view(s, held)
+                for s, held in zip(self.spec, self._by_layer(args))]
 
     def absorb(self, args, handles, active):
         return self._as_args([(h.state, h.tail) if s.kind == "state"
+                              else (h.rows,) if s.kind == "latent"
                               else (h.k, h.v)
                               for s, h in zip(self.spec, handles)])
 
@@ -245,14 +303,16 @@ class SlotCache:
         rows go (`slot`) is `commit_prefill`'s to read."""
         import jax.numpy as jnp
 
-        def held(s):
-            valid = jnp.arange(bucket, dtype=jnp.int32)[None, :] < length
-            return SlotState.zeros(1, s, self.dtype, valid)
+        def fresh(s):
+            if s.kind == "state":
+                valid = jnp.arange(bucket, dtype=jnp.int32)[None, :] < length
+                return SlotState.zeros(1, s, self.dtype, valid)
+            if s.kind == "latent":
+                return ChunkLatent.zeros(1, s, self.dtype, rows=bucket)
+            return ChunkKV.zeros(1, bucket, s.kv_heads, s.head_dim,
+                                 self.dtype)
 
-        return [held(s) if s.kind == "state"
-                else ChunkKV.zeros(1, bucket, s.kv_heads, s.head_dim,
-                                   self.dtype)
-                for s in self.spec]
+        return [fresh(s) for s in self.spec]
 
     def commit_prefill(self, args, handles, length, slot):
         import jax
@@ -264,13 +324,20 @@ class SlotCache:
                 big, local.astype(big.dtype), at)
 
         out = []
-        for s, (a, b), local in zip(self.spec, self._by_layer(args), handles):
+        for s, held, local in zip(self.spec, self._by_layer(args), handles):
             if s.kind == "state":
                 with jax.named_scope("state_write"):
-                    out.append((put(a, local.state), put(b, local.tail)))
+                    out.append((put(held[0], local.state),
+                                put(held[1], local.tail)))
+            elif s.kind == "latent":
+                # whole rows: zeros in the pad, as a decode step writes them
+                big, = held
+                out.append((put(big, widened_rows(local.rows, big)),))
             else:
-                out.append((scatter_prefill(s, a, local.k, slot, length),
-                            scatter_prefill(s, b, local.v, slot, length)))
+                out.append((scatter_prefill(s, held[0], local.k, slot,
+                                            length),
+                            scatter_prefill(s, held[1], local.v, slot,
+                                            length)))
         return self._as_args(out)
 
     @staticmethod

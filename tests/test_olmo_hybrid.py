@@ -451,7 +451,7 @@ def test_what_state_layers_cannot_do_is_refused_by_name(tiny):
                       max_new_cap=8, draft_model=model)
     with pytest.raises(ValueError, match="unknown cache kind"):
         kv_state.spec_of(type("M", (), {"kv_cache_spec": staticmethod(
-            lambda n: [("latent", n, 1, 8)])})(), 16)
+            lambda n: [("compressed", n, 1, 8)])})(), 16)
 
 
 # ------------------------------------------------------------ 8. the bytes

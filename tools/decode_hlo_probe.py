@@ -19,9 +19,10 @@ any weight-shaped convert signals suspect 1.
 
 Usage: python tools/decode_hlo_probe.py [--model tiny|base] [--device cpu]
 
-`--serving CONFIG` (gpt2-large, trinity-mini, olmo-hybrid-7b) reads another
-program instead: `ServingEngine`'s decode chunk (`serve.decode_sample`) of a
-benchmark configuration at its cell's settings, compiled for a DESCRIBED TPU
+`--serving CONFIG` (gpt2-large, trinity-mini, olmo-hybrid-7b, deepseek-v2)
+reads another program instead: `ServingEngine`'s decode chunk
+(`serve.decode_sample`) of a benchmark configuration at its cell's settings,
+compiled for a DESCRIBED TPU
 v5e as benchmarks/rehearse_*.py compile it. It prints where the slot cache
 crosses the program's boundary: each kind of cache argument with its entry
 layout and the layout the same array has inside the `while`, the cache-sized
@@ -48,6 +49,7 @@ _SERVING = {   # configuration -> (its decode cell, the runner that builds it)
     "gpt2-large": ("serve-gpt2-large-decode", "common"),
     "trinity-mini": ("serve-trinity-mini-decode", "serve_afmoe"),
     "olmo-hybrid-7b": ("serve-olmo-hybrid-decode", "serve_hybrid"),
+    "deepseek-v2": ("serve-deepseek-v2-decode", "serve_deepseek"),
 }
 def serving(config, slots, dump=None, rung=0):
     """Compile `serve.decode_sample` of a benchmark configuration (or, with
