@@ -23,7 +23,9 @@ key is listed under `assumed` in benchmarks/configs/deepseek-v2.json):
   decode step first of all, takes the absorbed form of the same numbers
   (`ops.latent_attention.absorbed`): q_l = q_n W_uk^T, scores against the
   cached rows [n_t | rope(k_r,t)], o = (softmax n) W_uv, where W_uk and W_uv
-  are the two halves a head of W_kvb (views of the one parameter).
+  are the two halves a head of W_kvb (views of the one parameter). A decode
+  step over a slot cache hands it the slots' lengths, and on the chip its
+  softmax and both products are one kernel (ops/pallas/latent_decode.py).
 - f is a SwiGLU MLP on the first `first_k_dense_replace` layers, and on the
   rest `nn.RoutedExperts` (softmax scores over all `n_routed_experts`, the
   `topk_group` best of `n_group` groups, `num_experts_per_tok` of what is
@@ -314,9 +316,12 @@ class DeepseekV2Attention(nn.Layer):
             with jax.named_scope("absorb"):
                 q_l = jnp.einsum("bshd,rhd->bshr", q_n,
                                  w_kvb[..., :self.d_n])
+            # the new handle's offset counts the positions held now: one
+            # a slot (SlotLatent) lets a decode step bound its rows by it
             with jax.named_scope("core"):
                 o_l = latent_attention.absorbed(
-                    q_l, q_r, rows, held <= pos[:, :, None], self.scale)
+                    q_l, q_r, rows, held <= pos[:, :, None], self.scale,
+                    lengths=cache.offset)
             with jax.named_scope("unabsorb"):
                 o = jnp.einsum("bshr,rhd->bshd", o_l, w_kvb[..., self.d_n:])
         with jax.named_scope("out"):

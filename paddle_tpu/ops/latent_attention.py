@@ -1,5 +1,6 @@
-"""The two cores of multi-head latent attention (DeepSeek-V2's MLA): the
-same mathematics written twice, so that a kernel can replace one function.
+"""The cores of multi-head latent attention (DeepSeek-V2's MLA): the same
+mathematics in two plain forms, so that a kernel can replace one function,
+and the kernel that replaces the one a decode step takes.
 
 A position keeps ONE row for all heads: its normalised latent `n` [r] (keys
 `k_n = n W_uk` and values `v = n W_uv` a head are projections of it) and one
@@ -24,8 +25,22 @@ row is read once for all heads, 2 x (r + d_r) bytes a position and not
 zeros behind (nn/kv_cache.py): the query is padded with zeros to the row's
 width, so no slice of the cache stands between it and the two products.
 
+The absorbed form has two bodies that share no logic, chosen by what the
+call can see in its inputs (`pallas/latent_decode.supported`):
+
+- the kernel (`pallas/latent_decode.py`): ONE query a slot (s == 1) whose
+  caller gives one length a slot (a decode step over `SlotLatent` rows), rows
+  a multiple of 128 wide and of the kernel's block in count, a single-device
+  program on a TPU. A slot's rows are fetched to its length only, each once
+  for both products, and no score leaves VMEM;
+- the plain einsums, for everything else: a chunk of s > 1 behind held rows,
+  a scalar offset (`generate()`'s `ChunkLatent`), a mesh, the CPU. They read
+  every row twice and keep the [b, h, s, t] float32 scores in HBM between
+  the products, and are the kernel's reference in the tests.
+
 Scores and softmax are float32; the products take the inputs' dtype with
-float32 accumulation. `mla.calls.<form>` counts the calls traced.
+float32 accumulation. `mla.calls.<form>` counts the calls traced: a call
+that takes the kernel counts under `absorbed` and under `absorbed_kernel`.
 """
 from __future__ import annotations
 
@@ -69,10 +84,13 @@ def expanded(q_n, q_r, k_n, k_r, v, scale: float, block: int = None):
     return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
 
 
-def absorbed(q_l, q_r, rows, mask, scale: float):
+def absorbed(q_l, q_r, rows, mask, scale: float, lengths=None):
     """q_l [b, s, h, r], q_r [b, s, h, d_r] against rows [b, t, width >= r +
     d_r] (`[n_t | k_r,t | zeros]`) under mask [b or 1, s, t] -> o_l
-    [b, s, h, r], the weighted sum of the rows' latents."""
+    [b, s, h, r], the weighted sum of the rows' latents. `lengths` [b]
+    int32, where the caller has one a row of the batch, says the mask again
+    for s == 1 (row t is seen where t < length): with it a decode step
+    takes the kernel where `latent_decode.supported()` allows."""
     _count("absorbed")
     r = q_l.shape[-1]
     q = jnp.concatenate([q_l, q_r.astype(q_l.dtype)], axis=-1)
@@ -80,6 +98,16 @@ def absorbed(q_l, q_r, rows, mask, scale: float):
     if pad:
         q = jnp.pad(q, [(0, 0)] * 3 + [(0, pad)])
     q = q.astype(rows.dtype)
+    if lengths is not None:
+        # imported where a caller may take the kernel: the kernels' toolkit
+        # takes over a second to import, and the other families never call
+        from .pallas import latent_decode
+        if latent_decode.supported(q.shape, rows.shape, lengths):
+            _count("absorbed_kernel")
+            # whole lanes of the rows' latent part; the width is one too
+            o = latent_decode.latent_decode(q[:, 0], rows, lengths, scale,
+                                            out=-(-r // 128) * 128)
+            return o[:, None, :, :r]
     scores = jnp.einsum("bshw,btw->bhst", q, rows,
                         preferred_element_type=jnp.float32)
     att = _softmax(scores * scale, mask[:, None], rows.dtype)

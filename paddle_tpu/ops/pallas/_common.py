@@ -1,4 +1,5 @@
-"""Shared helpers for the TPU Pallas kernels (flash_attention, lm_loss)."""
+"""Shared helpers for the TPU Pallas kernels (flash_attention, lm_loss,
+latent_decode)."""
 from __future__ import annotations
 
 import contextlib
@@ -56,6 +57,15 @@ def mesh_scope(mesh):
         yield
     finally:
         _trace.mesh = prev
+
+
+def single_device_program() -> bool:
+    """Whether the program being traced runs on one device, as far as a
+    trace can see: no mesh of several devices is scoped here or set in
+    jax's own context. A kernel with no shard_map of its own asks this."""
+    mesh = getattr(_trace, "mesh", None)
+    return ((mesh is None or mesh.size == 1)
+            and jax.sharding.get_abstract_mesh().size <= 1)
 
 
 def attention_partition():

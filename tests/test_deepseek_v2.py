@@ -30,6 +30,7 @@ from paddle_tpu.nn.kv_cache import (ChunkLatent, LatentLayerSpec, SlotLatent,
                                     latent_width)
 from paddle_tpu.observability import device_trace, metrics
 from paddle_tpu.ops import latent_attention
+from paddle_tpu.ops.pallas import latent_decode
 from paddle_tpu.serving import ServingEngine, kv_state
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -168,14 +169,14 @@ def test_absorbed_form_is_the_expanded_form(s, monkeypatch):
     assert float(jnp.abs(got - want).max()) <= 1e-5
 
 
-def test_calls_are_counted_by_form(tiny):
+def test_calls_are_counted_by_form(tiny, monkeypatch):
     model, _, _ = tiny
     reg = metrics.default_registry()
 
     def count(form):
         return reg.counter("mla.calls." + form).value
 
-    before = count("expanded"), count("absorbed")
+    before = count("expanded"), count("absorbed"), count("absorbed_kernel")
     model(paddle.to_tensor(_ids(5)[None]))
     assert (count("expanded"), count("absorbed")) == (before[0] + 4,
                                                       before[1])
@@ -183,6 +184,17 @@ def test_calls_are_counted_by_form(tiny):
     cache = ChunkLatent(jnp.zeros((1, 8, 40)), jnp.int32(0))
     model.model.layers[0].self_attn(jnp.zeros((1, 1, 64)), cache=cache)
     assert count("absorbed") == before[1] + 1
+    # one position a slot of a slot cache is the absorbed form too; that it
+    # took the kernel is counted beside, and the CPU does not take it unasked
+    slots = SlotLatent(jnp.zeros((2, 16, 128)), jnp.asarray([3, 7], jnp.int32))
+    model.model.layers[0].self_attn(jnp.zeros((2, 1, 64)), cache=slots)
+    assert (count("absorbed"), count("absorbed_kernel")) == (before[1] + 2,
+                                                             before[2])
+    monkeypatch.setattr(latent_decode, "_target", lambda: "interpret")
+    monkeypatch.setattr(latent_decode, "BLOCK_ROWS", 16)
+    model.model.layers[0].self_attn(jnp.zeros((2, 1, 64)), cache=slots)
+    assert (count("absorbed"), count("absorbed_kernel")) == (before[1] + 3,
+                                                             before[2] + 1)
 
 
 # ----------------------------------------------------- 3. YaRN by hand
@@ -470,6 +482,40 @@ def test_slots_at_different_depths_and_a_slot_seated_again(tiny):
                          temperature=0.0)
     fresh.run()
     assert alone.tokens == reqs[4].tokens
+
+
+def test_the_kernel_forced_gives_the_plain_forms_tokens(tiny, monkeypatch):
+    """Slots at different depths and one seated again, with the decode
+    steps' absorbed core through the Pallas kernel
+    (ops/pallas/latent_decode.py; interpreted here, in blocks of 16 of the
+    48 rows): greedy tokens are the plain form's, which the test above
+    holds to the reference, and the slots hold the same latent rows."""
+    model, _, _ = tiny
+    prompts = [_ids(n, seed=n) for n in (3, 13, 30, 7)]
+    budgets = (8, 5, 8, 8)      # the fourth request takes the second's slot
+
+    def serve():
+        eng = _engine(model)
+        reqs = [eng.submit(p, max_new_tokens=new, temperature=0.0)
+                for p, new in zip(prompts, budgets)]
+        eng.run()
+        assert all(r.done and r.outcome == "length" for r in reqs)
+        return eng, reqs
+
+    reg = metrics.default_registry()
+    plain_eng, plain = serve()
+    monkeypatch.setattr(latent_decode, "_target", lambda: "interpret")
+    monkeypatch.setattr(latent_decode, "BLOCK_ROWS", 16)
+    before = [reg.counter("mla.calls." + f).value
+              for f in ("absorbed", "absorbed_kernel")]
+    eng, forced = serve()
+    traced = [reg.counter("mla.calls." + f).value - b
+              for f, b in zip(("absorbed", "absorbed_kernel"), before)]
+    # every absorbed core of a decode program, one a layer a program
+    assert traced[0] == traced[1] > 0 and traced[1] % 4 == 0
+    assert [r.tokens for r in forced] == [r.tokens for r in plain]
+    for mine, theirs in zip(eng.slot_cache.latent, plain_eng.slot_cache.latent):
+        assert float(jnp.abs(mine - theirs).max()) <= 1e-5
 
 
 @pytest.mark.parametrize("sampling", [dict(temperature=0.0),

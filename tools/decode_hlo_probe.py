@@ -27,9 +27,10 @@ v5e as benchmarks/rehearse_*.py compile it. It prints where the slot cache
 crosses the program's boundary: each kind of cache argument with its entry
 layout and the layout the same array has inside the `while`, the cache-sized
 `copy` / `copy-start` instructions outside and inside the loop (count and
-bytes), and `memory_analysis()`. It loads the TPU's compiler library, which
-one process holds at a time: run it by hand, one configuration a process,
-never from a test. `--slots N` compiles for another slot count than the
+bytes), the Mosaic kernels the program holds (`mosaic_calls`: the grouped
+matmuls' and, for deepseek-v2, the absorbed core's) and `memory_analysis()`.
+It loads the TPU's compiler library, which one process holds at a time: run
+it by hand, one configuration a process, never from a test. `--slots N` compiles for another slot count than the
 cell's (a compile the chip's memory refuses is reported, not raised);
 `--rung N` reads the prefill program of that rung instead (no loop there:
 every cache-sized copy counts as outside).
@@ -82,6 +83,10 @@ def serving(config, slots, dump=None, rung=0):
         platform="tpu", topology_name="v5e:2x2").devices[0])
     # zeros, not billions of normal draws on the host
     routed_experts._draw = lambda key, shape, std, dtype: jnp.zeros(shape, dtype)
+    # the program is compiled for the chip, so it holds the chip's kernels:
+    # a trace here sees the CPU backend and would take the plain forms
+    from paddle_tpu.ops.pallas import latent_decode
+    latent_decode._target = lambda: "mosaic"
     build = importlib.import_module(f"benchmarks.runners.{runner}").build_model
     model = build(load("configs", cell["config"]), 0)
     model.eval()
@@ -138,6 +143,7 @@ def serving(config, slots, dump=None, rung=0):
         with open(dump, "w") as f:
             f.write(text)
     out.update(hi.boundary_report(text, shapes))
+    out["mosaic_calls"] = text.count('custom_call_target="tpu_custom_call"')
     out["GiB"] = {
         "arguments": round(ma.argument_size_in_bytes / gib, 3),
         "aliased": round(ma.alias_size_in_bytes / gib, 3),
