@@ -5,6 +5,10 @@ reference blueprint this build follows; reference file:line citations appear in 
 """
 from __future__ import annotations
 
+import time as _time
+
+_import_t0 = _time.perf_counter()      # the span `startup.import` starts here
+
 from .version import full_version as __version__
 
 # int64 is paddle's default integer dtype; jax demotes to 32-bit unless x64 is on.
@@ -191,3 +195,11 @@ def unsqueeze_(x, axis, name=None):
 
 def scatter_(x, index, updates, overwrite=True, name=None):
     return x.scatter_(index, updates, overwrite)
+
+
+from .observability import tracer as _tracer  # noqa: E402
+
+_tracer.get_tracer().record_complete(
+    "startup.import", _import_t0, _time.perf_counter(),
+    span_id=_tracer.new_span_id(), always=True)
+del _time, _import_t0, _tracer

@@ -30,12 +30,35 @@ request missed, the compile was COLD (paid XLA), otherwise it was WARM
 compile as warm when an evicted entry was rebuilt under a size cap, because
 the count did not grow. Counters land in core.monitor (`engine.compile_cold`
 / `engine.compile_warm` and their _ms twins) and ride into StepTelemetry.
+
+Every jit phase of the process is an event of the span ring
+(`observability/tracer.py`), whether or not the persistent cache is on. jax
+times each trace, lowering and backend compile of each jit, and each load
+from the persistent cache, and hands them to listeners: it announces a
+phase's start (`record_scalar`) and its duration at the end. Two listeners
+keep a stack a thread, so an event knows at its end whether it is the
+OUTERMOST: no other phase of any jit was open when it started (a jit traced
+inside another's trace ends first, inside the outer's interval; a lowering
+fires a short trace event for every inner jit it meets, 2,247 of them in a
+DeepSeek-V2 start). An outermost event is recorded `always` (`jit.trace` /
+`jit.lower` / `jit.backend`, args `fun` and, where events started inside
+it, their count `inner`), under the innermost boundary span open on the
+thread, and bumps `jit.trace_ms` / `jit.lower_ms` / `jit.backend_ms` and
+`jit.traces`: the three sums never count a second twice. An inner event is
+folded into that count, and recorded as well, under the event it started
+in, only from 1 ms up. The cache's load is a part of a backend phase with
+no start of its own: `jit.cache_load` is always recorded and counted
+(`jit.cache_load_ms`), inside its `jit.backend`. Nothing runs unless jax
+compiles: a steady-state step calls neither listener.
 """
 from __future__ import annotations
 
 import os
+import threading
+import time
 from typing import Optional
 
+from ..observability import tracer as _obs_tracer
 from . import monitor as _monitor
 from .flags import flag
 
@@ -52,6 +75,73 @@ _COLD = _monitor.stat("engine.compile_cold")
 _WARM = _monitor.stat("engine.compile_warm")
 _COLD_MS = _monitor.stat("engine.compile_cold_ms")
 _WARM_MS = _monitor.stat("engine.compile_warm_ms")
+
+
+# jax's event -> the ring's event name; `<name>_ms` is its counter
+_JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jit.cache_load",
+}
+_JIT_MS = {name: _monitor.stat(name + "_ms") for name in _JIT_EVENTS.values()}
+_JIT_TRACES = _monitor.stat("jit.traces")
+_INNER_FROM_S = 1e-3          # an inner event shorter than this is only counted
+_jit_open = threading.local()     # .stack: [name, id, start, inner] a phase
+
+
+def _jit_stack() -> list:
+    stack = getattr(_jit_open, "stack", None)
+    if stack is None:
+        stack = _jit_open.stack = []
+    return stack
+
+
+def _on_jit_start(event: str, _value, **_kw) -> None:
+    name = _JIT_EVENTS.get(event)
+    if name is not None:
+        _jit_stack().append([name, _obs_tracer.new_span_id(),
+                             time.perf_counter(), 0])
+
+
+def _on_jit_duration(event: str, duration: float, fun_name=None,
+                     **_kw) -> None:
+    name = _JIT_EVENTS.get(event)
+    if name is None:
+        return
+    t1 = time.perf_counter()
+    stack = _jit_stack()
+    if stack and stack[-1][0] == name:
+        _, sid, t0, inner = stack.pop()
+    else:       # no start was announced: the cache's load
+        sid, t0, inner = _obs_tracer.new_span_id(), t1 - duration, 0
+    tr = _obs_tracer.get_tracer()
+    outermost = not stack or name == "jit.cache_load"
+    if stack:
+        stack[-1][3] += inner + 1
+        parent = stack[-1][1]
+    else:
+        parent = tr.current_span_id()
+    if outermost:
+        _JIT_MS[name].increase((t1 - t0) * 1e3)
+        if name == "jit.trace":
+            _JIT_TRACES.increase()
+    elif t1 - t0 < _INNER_FROM_S:
+        return
+    if fun_name and fun_name.startswith("jit(") and fun_name.endswith(")"):
+        fun_name = fun_name[4:-1]     # a lowering's `jit(f)` is the trace's `f`
+    args = {"fun": fun_name}
+    if inner:
+        args["inner"] = inner
+    tr.record_complete(name, t0, t1, args, aggregate=outermost, span_id=sid,
+                       parent=parent, always=True)
+
+
+def _listen_to_jit() -> None:
+    import jax
+
+    jax.monitoring.register_scalar_listener(_on_jit_start)
+    jax.monitoring.register_event_duration_secs_listener(_on_jit_duration)
 
 
 def placed_from_outside() -> Optional[str]:
@@ -163,4 +253,5 @@ def _on_flag_change(name):
 from . import flags as _flags  # noqa: E402
 
 _flags.on_change(_on_flag_change)
+_listen_to_jit()
 configure()

@@ -51,6 +51,7 @@ import types
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
+from ..observability import tracer as _obs_tracer
 from . import compile_cache as _compile_cache
 from . import flags as _flags
 from . import monitor as _monitor
@@ -518,10 +519,21 @@ class ExecutableRegistry:
         self._compile_ms.append(wall_ms)
         kind = _compile_cache.note_compile(int(wall_ms), persistent_before,
                                            _compile_cache.misses())
-        self._observe_compile(kind, wall_ms)
+        self._observe_compile(kind, wall_ms, entry.label)
         return grew
 
-    def _observe_compile(self, kind: Optional[str], wall_ms: float) -> None:
+    def _observe_compile(self, kind: Optional[str], wall_ms: float,
+                         label: str) -> None:
+        # the call that compiled, as a span after the fact: the jit events
+        # of the ring that lie in its interval are this entry's, all others
+        # no registry's (`kind` is None with the persistent cache off)
+        now = time.perf_counter()
+        tr = _obs_tracer.get_tracer()
+        tr.record_complete(
+            _obs_tracer.FIRST_CALL, now - wall_ms / 1e3, now,
+            {"label": label, "kind": kind},
+            span_id=_obs_tracer.new_span_id(), parent=tr.current_span_id(),
+            always=True)
         if kind == "cold":
             self._compile_cold_ms.append(wall_ms)
         elif kind == "warm":
@@ -551,7 +563,7 @@ class ExecutableRegistry:
         self._compile_ms.append(wall_ms)
         kind = _compile_cache.note_compile(int(wall_ms), p0,
                                            _compile_cache.misses())
-        self._observe_compile(kind, wall_ms)
+        self._observe_compile(kind, wall_ms, entry.label)
         if _flags.flag("exec_introspect"):
             try:
                 from ..observability import exec_introspect as _obs_exec

@@ -142,6 +142,7 @@ class TrainStepEngine:
     optimizer: a paddle_tpu.optimizer.Optimizer (its functional rule is reused).
     """
 
+    @_obs_tracer.in_boundary("engine.init")
     def __init__(self, model, optimizer, loss_fn: Optional[Callable] = None,
                  hcg: Optional[HybridCommunicateGroup] = None, strategy=None,
                  input_specs: Optional[List[P]] = None, donate: bool = True,

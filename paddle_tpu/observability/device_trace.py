@@ -28,10 +28,12 @@ from typing import Dict, Iterator, List, Optional, Tuple
 # ---- the vocabulary (paddle_tpu/models/gpt.py, models/afmoe.py,
 # models/olmo_hybrid.py, models/deepseek_v2.py,
 # nn/layers/routed_experts.py, ops/fused.py,
-# ops/pallas/flash_attention.py, distributed/engine.py, grad_comm.py,
+# ops/pallas/flash_attention.py, ops/pallas/latent_decode.py,
+# distributed/engine.py, grad_comm.py,
 # serving/engine.py, serving/kv_state.py, serving/sampling.py) ------------
 ROOTS = ("prefill", "decode")                       # the serving programs
-KERNELS = ("flash_fwd", "flash_bwd", "flash_bwd_dkv", "flash_bwd_dq")
+KERNELS = ("flash_fwd", "flash_bwd", "flash_bwd_dkv", "flash_bwd_dq",
+           "latent_decode")
 SCOPES = frozenset(ROOTS + KERNELS + (
     "embed", "attn", "qkv", "core", "out", "cache_write", "mlp",
     "final_norm", "lm_head_loss", "lm_head", "sample", "grad_clip",

@@ -194,6 +194,7 @@ class ServingEngine:
     from one thread.
     """
 
+    @_obs_tracer.in_boundary("serve.engine.init")
     def __init__(self, model, slot_count: int = 4,
                  ladder: Sequence[int] = DEFAULT_LADDER,
                  max_seq_len: Optional[int] = None,
@@ -609,7 +610,11 @@ class ServingEngine:
         fetched: `_decode_step`), `completed`, `queued`, `active_slots`,
         `draining`, the ladder and executable counts, the cache's layout and
         bytes; with a draft model the verify counts, on the paged layout the
-        pool's and the prefix cache's."""
+        pool's and the prefix cache's; `startup`, the span ring's table
+        (`observability/tracer.py` `phase_table`: where the process's time
+        went by span, which executable compiled cold, which jit no registry
+        holds; one pass over the ring, tens of milliseconds when it is
+        full)."""
         out = {
             "steps": self._steps,
             "decode_dispatches": self._decode_dispatches,
@@ -625,6 +630,7 @@ class ServingEngine:
             "decode_executables": self._execs.count("serve.decode"),
             "kv_layout": self.kv_layout,
             "kv_cache_bytes": self.kv_cache_bytes(),
+            "startup": _obs_tracer.phase_table(),
         }
         if self.draft_model is not None:
             out.update({

@@ -91,7 +91,8 @@ def _scope_literals():
                 text = open(os.path.join(d, f)).read()
                 found += [(os.path.join(d, f), m) for m in re.findall(
                     r"named_scope\(\s*[\"']([^\"']+)[\"']", text)]
-                if f == "flash_attention.py":   # a kernel's name is its scope
+                if f in ("flash_attention.py", "latent_decode.py"):
+                    # a kernel's name is its scope
                     found += [(f, m) for m in re.findall(
                         r"\bname=\"(\w+)\"", text)]
     return found
@@ -292,6 +293,9 @@ def test_afmoe_serve_step_record_carries_the_experts_load():
      ("attn/core", True, "flash_bwd_dkv")),
     ("jit(step)/transpose(jvp(attn))/core/flash_bwd/pallas_call",
      ("attn/core", True, "flash_bwd")),
+    ("jit(step_chunk)/decode/while/body/closed_call/mla/core/"
+     "jit(_call)/latent_decode/pallas_call",
+     ("decode/mla/core", False, "latent_decode")),
     ("jit(step)/transpose(jvp(lm_head_loss))/jit(fwd)/lm_head_loss/while/"
      "body/closed_call/dot_general", ("lm_head_loss", True, None)),
     ("jit(step_chunk)/decode/while/body/closed_call/attn/cache_write/"
@@ -311,6 +315,13 @@ def test_scope_of(path, want):
 
 
 # ------------------------------------------------- spans in the engines
+def _program_spans(events):
+    """The engines' own spans: a step that compiles leaves `jit.*` and
+    `exec.first_call` events in the ring beside them (PR 37)."""
+    return [e for e in events
+            if e["name"].startswith(device_trace.SPAN_PREFIXES)]
+
+
 def _count_syncs(monkeypatch):
     import jax
 
@@ -333,7 +344,7 @@ def test_train_step_span_tree(tiny_gpt, monkeypatch):
     n0 = len(tr.events())
     eng.step(x, y)
     eng.step(x, y)
-    new = tr.events()[n0:]
+    new = _program_spans(tr.events()[n0:])
     assert not syncs                      # the spans add no device sync
     steps = [e for e in new if e["name"] == "engine.step"]
     assert [e["args"]["compiled"] for e in steps] == [True, False]
@@ -371,7 +382,7 @@ def test_fsdp_step_opens_the_same_spans():
         tr = get_tracer()
         n0 = len(tr.events())
         eng.step(paddle.to_tensor(ids), paddle.to_tensor(ids))
-        new = tr.events()[n0:]
+        new = _program_spans(tr.events()[n0:])
     finally:
         set_hybrid_communicate_group(None)
     assert [e["name"] for e in new] == ["engine.place_batch",
@@ -398,7 +409,7 @@ def test_serving_step_span_tree(tiny_gpt, monkeypatch):
     syncs = _count_syncs(monkeypatch)
     n0 = len(tr.events())
     eng.step()
-    first = tr.events()[n0:]
+    first = _program_spans(tr.events()[n0:])
     eng.run()
     assert not syncs
     by_name = {}
@@ -522,6 +533,471 @@ def test_manifest_names_the_new_metrics():
             got[name]["layer"], "ms", moves, "program_span")
 
 
+# --------------------------------------- start-up's events (PR 37)
+JIT_COUNTERS = ("jit.trace_ms", "jit.lower_ms", "jit.backend_ms",
+                "jit.cache_load_ms", "jit.traces")
+
+
+def _jit_counters():
+    from paddle_tpu.core import monitor
+
+    return {k: monitor.stat(k).get() for k in JIT_COUNTERS}
+
+
+def _jit_events(events):
+    return [e for e in events if e["name"].startswith("jit.")]
+
+
+@pytest.fixture
+def listener_calls():
+    """Counts every call jax makes to a duration or scalar listener."""
+    import jax
+
+    calls = []
+
+    def on_duration(event, duration, **kw):
+        calls.append(event)
+
+    def on_scalar(event, value, **kw):
+        calls.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_scalar_listener(on_scalar)
+    yield calls
+    jax.monitoring.unregister_event_duration_listener(on_duration)
+    jax.monitoring.unregister_scalar_listener(on_scalar)
+
+
+def test_first_call_in_a_span_leaves_its_jit_phases_under_it(listener_calls):
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def probe_fn(x):
+        return jnp.tanh(x) @ x
+
+    x = jnp.ones((8, 8))
+    tr = get_tracer()
+    n0, c0 = len(tr.events()), _jit_counters()
+    with tr.boundary("serve.step") as span:
+        probe_fn(x).block_until_ready()
+    new = tr.events()[n0:]
+    outer = [e for e in _jit_events(new) if e["parent"] == span.id]
+    assert [e["name"] for e in outer] == ["jit.trace", "jit.lower",
+                                          "jit.backend"]
+    assert {e["args"]["fun"] for e in outer} == {"probe_fn"}
+    step = next(e for e in new if e["name"] == "serve.step")
+    for e in _jit_events(new):            # all lie inside the span
+        assert step["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= step["ts"] + step["dur"] + 1e-9
+    c1 = _jit_counters()
+    assert c1["jit.traces"] - c0["jit.traces"] == 1
+    for e in outer:
+        key = e["name"] + "_ms"
+        assert c1[key] - c0[key] == pytest.approx(e["dur"] * 1e3)
+    # steady state: no event, and jax calls no listener at all
+    n1 = len(tr.events())
+    del listener_calls[:]
+    for _ in range(20):
+        probe_fn(x).block_until_ready()
+    assert len(tr.events()) == n1 and not listener_calls
+    assert _jit_counters() == c1
+
+
+def test_nested_jit_counts_its_trace_once():
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner_fn(x):
+        for _ in range(40):               # long enough to be kept (>= 1 ms)
+            x = jnp.tanh(x) * 2 + 1
+        return x
+
+    @jax.jit
+    def outer_fn(x):
+        return jnp.where(x > 0, inner_fn(x), x * 3).sum()
+
+    x = jnp.ones((8, 8))
+    tr = get_tracer()
+    n0, c0 = len(tr.events()), _jit_counters()
+    t0 = time.perf_counter()
+    outer_fn(x).block_until_ready()
+    t1 = time.perf_counter()
+    traces = [e for e in tr.events()[n0:] if e["name"] == "jit.trace"]
+    outer = next(e for e in traces if e["args"]["fun"] == "outer_fn")
+    inner = [e for e in traces if e is not outer]
+    assert inner and all(e["parent"] == outer["id"] or e["parent"] in {
+        i["id"] for i in inner} for e in inner)
+    assert all(e["dur"] >= 1e-3 for e in inner)    # shorter ones are folded
+    assert outer["args"]["inner"] > len(inner)     # ... into this count
+    for e in inner:
+        assert outer["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1e-9
+    c1 = _jit_counters()
+    assert c1["jit.traces"] - c0["jit.traces"] == 1
+    assert c1["jit.trace_ms"] - c0["jit.trace_ms"] == pytest.approx(
+        outer["dur"] * 1e3)
+    table = tr.phase_table(since=t0, until=t1)
+    assert table["jit"]["jit.trace"]["count"] == 1
+    assert table["jit"]["jit.trace"]["total_s"] == pytest.approx(outer["dur"])
+    assert table["rows"]["jit.trace"]["count"] == 1 + len(inner)
+    assert table["rows"]["jit.trace"]["total_s"] == pytest.approx(
+        outer["dur"])
+    assert table["jit"]["unregistered_s"] == pytest.approx(sum(
+        table["jit"][n]["total_s"] for n in ("jit.trace", "jit.lower",
+                                             "jit.backend")))
+
+
+def test_a_trace_a_lowering_fires_is_folded_into_the_lowering():
+    """On the chip a lowering fires a short trace event for every inner jit
+    it meets (2,247 in a DeepSeek-V2 start): inside another phase, so neither
+    counted as a trace nor kept; jax's two calls a phase, made by hand."""
+    from paddle_tpu.core import compile_cache as cc
+
+    trace, lower = (f"/jax/core/compile/{n}_duration"
+                    for n in ("jaxpr_trace", "jaxpr_to_mlir_module"))
+    tr = get_tracer()
+    n0, c0 = len(tr.events()), _jit_counters()
+    cc._on_jit_start(lower, 0.0, fun_name="jit(f)")
+    for _ in range(3):
+        cc._on_jit_start(trace, 0.0, fun_name="g")
+        cc._on_jit_duration(trace, 1e-5, fun_name="g")
+    cc._on_jit_duration(lower, 1e-3, fun_name="jit(f)")
+    (event,) = tr.events()[n0:]
+    assert event["name"] == "jit.lower"
+    assert event["args"] == {"fun": "f", "inner": 3}
+    c1 = _jit_counters()
+    assert c1["jit.traces"] == c0["jit.traces"]
+    assert c1["jit.trace_ms"] == c0["jit.trace_ms"]
+    assert c1["jit.lower_ms"] - c0["jit.lower_ms"] == pytest.approx(
+        event["dur"] * 1e3)
+    # the cache's load has no start of its own: always kept and counted
+    cc._on_jit_start(trace.replace("jaxpr_trace", "backend_compile"), 0.0)
+    cc._on_jit_duration("/jax/compilation_cache/cache_retrieval_time_sec",
+                        2e-4)
+    cc._on_jit_duration(trace.replace("jaxpr_trace", "backend_compile"), 3e-4,
+                        fun_name="jit(f)")
+    load, backend = tr.events()[n0 + 1:]
+    assert (load["name"], backend["name"]) == ("jit.cache_load", "jit.backend")
+    assert load["parent"] == backend["id"] and backend["args"]["inner"] == 1
+    assert _jit_counters()["jit.cache_load_ms"] - c0[
+        "jit.cache_load_ms"] == pytest.approx(0.2, rel=0.2)
+
+
+def test_retrace_inside_a_step_is_counted_and_named(tiny_gpt):
+    """A helper jitted apart from the registry's executables retraces on a
+    new shape inside `serve.step`: no compile counter of the engine sees
+    it; `jit.traces` and the window's table do, by name."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.core import monitor
+    from paddle_tpu.serving import ServingEngine
+
+    @jax.jit
+    def admission_helper(x):
+        return x * 2
+
+    tiny_gpt.eval()
+    eng = ServingEngine(tiny_gpt, slot_count=2, ladder=(8,), max_new_cap=8,
+                        steps_per_dispatch=2)
+    real_step = eng._advance_step
+    shapes = iter(range(3, 100))
+
+    def leaky_step(*a, **k):        # a new shape a step (numpy: no eager op)
+        admission_helper(np.ones((next(shapes),), np.float32))
+        return real_step(*a, **k)
+
+    eng._advance_step = leaky_step
+    eng.submit(np.arange(1, 5, dtype=np.int64), max_new_tokens=8,
+               temperature=0.0)
+    eng.step()                      # compiles the engine's programs
+    tr = get_tracer()
+    compiles = [monitor.stat(k) for k in ("serving.prefill_compiles",
+                                          "serving.decode_compiles")]
+    c0, k0 = _jit_counters(), [c.get() for c in compiles]
+    w0 = time.perf_counter()
+    eng.step()
+    eng.step()
+    w1 = time.perf_counter()
+    assert [c.get() for c in compiles] == k0
+    assert _jit_counters()["jit.traces"] - c0["jit.traces"] == 2
+    table = tr.phase_table(since=w0, until=w1)
+    assert table["jit"]["jit.trace"]["count"] == 2
+    named = [r for r in table["jit"]["largest"] if r["name"] == "jit.trace"]
+    assert {r["fun"] for r in named} == {"admission_helper"}
+    assert all(r["under"] == "serve.step" and not r["in_first_call"]
+               for r in named)
+    assert table["rows"].get("exec.first_call") is None
+
+
+def _inside(e, spans):
+    return any(s["tid"] == e["tid"] and s["ts"] <= e["ts"] + 1e-9
+               and e["ts"] + e["dur"] <= s["ts"] + s["dur"] + 1e-9
+               for s in spans)
+
+
+def test_first_call_spans_agree_with_the_compile_counters(tiny_gpt,
+                                                          tmp_path):
+    import warnings
+
+    from paddle_tpu.core import compile_cache, monitor
+    from paddle_tpu.serving import ServingEngine
+
+    if compile_cache.enabled():
+        pytest.skip("suite launched with a compile cache configured")
+    tiny_gpt.eval()
+    stats = [monitor.stat(k) for k in ("engine.compile_cold",
+                                       "engine.compile_warm")]
+    tr = get_tracer()
+    try:
+        paddle.set_flags({"compile_cache_dir": str(tmp_path / "cc")})
+        for want in ("cold", "warm"):     # the second engine loads
+            n0, k0 = len(tr.events()), [c.get() for c in stats]
+            with warnings.catch_warnings():
+                warnings.simplefilter("ignore")
+                eng = ServingEngine(tiny_gpt, slot_count=2, ladder=(8, 16),
+                                    max_new_cap=8, steps_per_dispatch=2)
+                for n in (4, 12):
+                    eng.submit(np.arange(1, 1 + n, dtype=np.int64),
+                               max_new_tokens=4, temperature=0.0)
+                eng.run()
+            new = tr.events()[n0:]
+            calls = [e for e in new if e["name"] == "exec.first_call"]
+            counted = sum(c.get() - k for c, k in zip(stats, k0))
+            assert len(calls) == counted == 3       # two rungs, one decode
+            assert {e["args"]["kind"] for e in calls} == {want}
+            assert sorted(e["args"]["label"] for e in calls)[1:] == [
+                "serve.prefill_b16", "serve.prefill_b8"]
+            backend = [e for e in new if e["name"] == "jit.backend"
+                       and _inside(e, calls)]
+            assert len(backend) == counted
+            if want == "warm":
+                loads = [e for e in new if e["name"] == "jit.cache_load"]
+                assert len([e for e in loads if _inside(e, backend)]) == 3
+            table = tr.phase_table()["jit"]
+            assert table["jit.backend"]["in_first_call_s"] > 0
+    finally:
+        paddle.set_flags({"compile_cache_dir": ""})
+
+
+def test_engine_constructors_and_the_import_are_spans(tiny_gpt):
+    from paddle_tpu.serving import ServingEngine
+
+    tr = get_tracer()
+    n0 = len(tr.events())
+    _train_engine(tiny_gpt)
+    tiny_gpt.eval()
+    eng = ServingEngine(tiny_gpt, slot_count=2, ladder=(8,), max_new_cap=8,
+                        steps_per_dispatch=2)
+    new = tr.events()[n0:]
+    spans = {e["name"]: e for e in new if not e["name"].startswith("jit.")}
+    assert set(spans) == {"engine.init", "serve.engine.init"}
+    # a jit event inside a constructor has it as parent
+    for e in _jit_events(new):
+        if e["args"].get("inner") is not None or e["name"] != "jit.trace":
+            assert e["parent"] is not None
+    table = eng.stats()["startup"]
+    assert table["rows"]["serve.engine.init"]["count"] >= 1
+    assert table["rows"]["serve.engine.init"]["self_s"] <= (
+        table["rows"]["serve.engine.init"]["total_s"])
+    # the package's import: recorded once, first, before the ring could drop
+    if not tr.dropped:
+        first = tr.events()[0]
+        assert first["name"] == "startup.import" and first["dur"] > 0
+        assert table["rows"]["startup.import"]["count"] == 1
+
+
+def _hand_ring():
+    """One thread: a root of 10 s holding two children, one of which holds a
+    first call with the three jit phases, a trace nested in the trace and
+    one in the lowering among them; a jit outside every span; a second
+    thread's span."""
+    ev = []
+
+    def add(name, ts, dur, tid=1, **args):
+        ev.append({"name": name, "ts": ts, "dur": dur, "tid": tid,
+                   "args": args or None, "id": len(ev) + 1, "parent": None})
+
+    add("serve.engine.init", 1.0, 2.0)
+    add("jit.trace", 1.5, 0.5, fun="zeros")
+    add("serve.step", 4.0, 10.0)
+    add("serve.admit", 4.5, 6.0)
+    add("exec.first_call", 5.0, 5.0, label="serve.prefill.b8", kind="cold")
+    add("jit.trace", 5.0, 2.0, fun="prefill", inner=7)
+    add("jit.trace", 5.5, 1.0, fun="attn")            # inside the outer trace
+    add("jit.lower", 7.0, 1.0, fun="prefill")
+    add("jit.trace", 7.25, 0.25, fun="a_lowering_fires_it")   # in the lowering
+    add("jit.backend", 8.0, 2.0, fun="prefill")
+    add("jit.cache_load", 8.5, 1.0)
+    add("serve.emit", 12.0, 1.0)
+    add("jit.backend", 15.0, 3.0, fun="_normal")      # under no span
+    add("other.thread", 16.0, 1.0, tid=2)
+    return ev
+
+
+def test_span_table_self_times_add_up():
+    from paddle_tpu.observability import tracer
+
+    t = tracer.span_table(_hand_ring(), since=0.0, until=20.0)
+    rows = t["rows"]
+    assert rows["serve.step"] == {"count": 1, "total_s": 10.0, "self_s": 3.0}
+    assert rows["serve.admit"]["self_s"] == pytest.approx(1.0)
+    assert rows["exec.first_call"]["self_s"] == pytest.approx(0.0)
+    assert rows["serve.engine.init"]["self_s"] == pytest.approx(1.5)
+    # the nested traces, in a trace and in a lowering: the outermost alone
+    # in the totals, so the phases share no second
+    assert rows["jit.trace"]["count"] == 4
+    assert rows["jit.trace"]["total_s"] == pytest.approx(2.5)
+    assert rows["jit.trace"]["self_s"] == pytest.approx(2.75)
+    assert rows["jit.lower"] == {"count": 1, "total_s": 1.0, "self_s": 0.75}
+    assert rows["jit.backend"]["self_s"] == pytest.approx(4.0)
+    assert rows["jit.cache_load"]["self_s"] == pytest.approx(1.0)
+    # thread 1's self times and `caller` add up to the table's length
+    one = sum(r["self_s"] for n, r in rows.items() if n != "other.thread")
+    assert one == pytest.approx(t["total_s"]) and t["total_s"] == 20.0
+    # the second thread's span covers [16, 17], which is inside the jit's
+    assert rows["caller"]["total_s"] == pytest.approx(1.0 + 1.0 + 1.0 + 2.0)
+    assert [(g["start"], g["dur_s"], g["before"], g["after"])
+            for g in t["caller_longest"]] == [
+        (18.0, 2.0, "jit.backend:_normal", "end"),
+        (0.0, 1.0, "start", "serve.engine.init"),
+        (3.0, 1.0, "serve.engine.init", "serve.step")]
+    jit = t["jit"]
+    assert jit["jit.trace"] == {"count": 2, "total_s": 2.5,
+                                "in_first_call_s": 2.0, "outside_s": 0.5}
+    assert jit["jit.backend"]["outside_s"] == pytest.approx(3.0)
+    assert jit["jit.backend"]["in_first_call_s"] == pytest.approx(2.0)
+    assert jit["jit.cache_load"]["in_first_call_s"] == pytest.approx(1.0)
+    assert jit["unregistered_s"] == pytest.approx(3.5)
+    assert [(r["fun"], r["under"]) for r in jit["largest"][:3]] == [
+        ("_normal", "caller"), ("prefill", "serve.admit"),
+        ("prefill", "serve.admit")]
+    # clipped to a window: what straddles it counts by its part inside
+    w = tracer.span_table(_hand_ring(), since=6.0, until=9.0)
+    assert w["total_s"] == 3.0 and w["rows"]["caller"]["total_s"] == 0.0
+    assert w["jit"]["jit.trace"] == {"count": 1, "total_s": 1.0,
+                                     "in_first_call_s": 1.0,
+                                     "outside_s": 0.0}
+    assert sum(r["self_s"] for r in w["rows"].values()) == pytest.approx(3.0)
+    assert tracer.span_table([])["total_s"] == 0.0
+
+
+def test_chrome_export_carries_the_table(tmp_path, capsys):
+    """tools/trace_summary.py prints the same table from an exported
+    chrome trace: the same ring, the same export."""
+    from paddle_tpu.observability import tracer
+
+    tr, origin = Tracer(), tracer._ORIGIN
+    for e in _hand_ring():
+        tr.record_complete(e["name"], origin + e["ts"],
+                           origin + e["ts"] + e["dur"], e["args"],
+                           tid=e["tid"], span_id=e["id"], always=True)
+    path = tr.export_chrome_trace(str(tmp_path / "host.json"))
+    back = tracer.events_from_chrome(json.load(open(path)))
+    want = tracer.span_table(tr.events())
+    got = tracer.span_table(back)
+    assert got["jit"]["unregistered_s"] == pytest.approx(
+        want["jit"]["unregistered_s"])
+    assert {n: r["count"] for n, r in got["rows"].items()} == {
+        n: r["count"] for n, r in want["rows"].items()}
+    spec = importlib.util.spec_from_file_location(
+        "trace_summary", os.path.join(REPO, "tools", "trace_summary.py"))
+    import sys
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    try:
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        mod.main([path])
+    finally:
+        sys.path.remove(os.path.join(REPO, "tools"))
+    out = capsys.readouterr().out
+    assert "jit outside every first call: 3.500 s" in out
+    assert re.search(r"exec\.first_call\s+1\s+5\.000\s+0\.000", out)
+    assert ("caller 1.000 s at 3.000: after serve.engine.init, before "
+            "serve.step") in out
+
+
+STARTUP_METRICS = {
+    "setup.import_s": 3.0, "setup.serve_engine_init_s": 1.5,
+    "setup.train_engine_init_s": 0.25, "setup.jit_trace_s": 2.5,
+    "setup.jit_lower_s": 1.0, "setup.jit_backend_s": 5.0,
+    "setup.jit_unregistered_s": 3.5, "train.window_jit_traces": 1,
+    "serve.window_jit_traces": 1, "serve.window_jit_traces.chat": 1}
+
+
+@pytest.mark.parametrize("name", sorted(STARTUP_METRICS))
+def test_startup_readers(name, monkeypatch):
+    """Each reader on a hand-made ring, through `collected` as the serving
+    runners (a `window`) and the train runner (sub-windows and a rate)
+    give it; nothing without jit events, or on a program without the
+    table."""
+    from paddle_tpu.observability import tracer
+
+    read = _reader(name).read
+    from benchmarks.lib import startup_readers
+
+    origin = tracer._ORIGIN
+    tr = get_tracer()
+    saved = list(tr._events)
+    tr.clear()
+    monkeypatch.setattr(startup_readers, "_tables", {})
+    # the window is [20, 30] on the ring's axis, the process started at -3
+    serve = {"window": (origin + 20.0, origin + 30.0), "setup_s": 23.0}
+    train = {"windows": [(5.0, 4), (5.0, 4)], "setup_s": 23.0,
+             "batch_per_chip": 8, "seq": 100, "tokens_per_s_per_chip": 640.0}
+    monkeypatch.setattr(startup_readers, "_clock_origin",
+                        lambda: origin - 3.0)
+    try:
+        run = train if name.startswith("train.") else serve
+        assert startup_readers.window(run) == pytest.approx(
+            (origin + 20.0, origin + 30.0))
+        assert read(run) is None                  # an empty ring
+        for e in _hand_ring():
+            tr.record_complete(e["name"], origin + e["ts"],
+                               origin + e["ts"] + e["dur"], e["args"],
+                               tid=e["tid"], span_id=e["id"], always=True)
+        tr.record_complete("startup.import", origin - 2.5, origin + 0.5,
+                           span_id=90, always=True)
+        tr.record_complete("engine.init", origin + 3.0, origin + 3.25,
+                           span_id=91, always=True)
+        tr.record_complete("jit.trace", origin + 25.0, origin + 25.5,
+                           {"fun": "late"}, span_id=92, always=True)
+        startup_readers._tables.clear()
+        assert read(run) == pytest.approx(STARTUP_METRICS[name])
+        assert read({}) is None
+        # a program without the table (the parent of PR 37)
+        startup_readers._tables.clear()
+        monkeypatch.delattr(tracer, "phase_table")
+        assert read(run) is None
+    finally:
+        tr.clear()
+        tr._events.extend(saved)
+
+
+def test_manifest_names_the_startup_metrics():
+    man = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    got = {m["name"]: m for m in man["per_layer"]}
+    cells = {w["name"] for w in man["workloads"]}
+    e2e = {m["name"]: set(m.get("workloads", cells))
+           for m in man["end_to_end"]}
+    for name in STARTUP_METRICS:
+        m = got[name]
+        mod = _reader(name)
+        assert (mod.LAYER, mod.UNIT, mod.MOVES, mod.SOURCE) == (
+            m["layer"], m["unit"], m["moves"], m["source"])
+        assert m["better"] == "lower" and set(m["workloads"]) <= e2e[
+            m["moves"]]
+    assert set(got["setup.jit_trace_s"]["workloads"]) == cells
+    assert list(got)[-len(STARTUP_METRICS):] == [
+        "setup.import_s", "setup.serve_engine_init_s",
+        "setup.train_engine_init_s", "setup.jit_trace_s",
+        "setup.jit_lower_s", "setup.jit_backend_s",
+        "setup.jit_unregistered_s", "train.window_jit_traces",
+        "serve.window_jit_traces", "serve.window_jit_traces.chat"]
+
+
 # ----------------------------------------------- compile classification
 def test_rebuilt_evicted_entry_is_a_cold_compile(tmp_path):
     """`engine.compile_warm` was decided by whether the cache's entry count
@@ -643,9 +1119,10 @@ def test_scopes_sum_to_busy_time(reduced):
 @needs_probe
 def test_kernels_are_found_by_name(reduced):
     # the probe was recorded from PR 26's program, whose d=64 model ran the
-    # [b*h, s, d] kernels: the packed paths' `flash_bwd` is not in it
+    # [b*h, s, d] kernels: the packed paths' `flash_bwd` is not in it, nor
+    # PR 36's `latent_decode` (test_scope_of has its op path)
     assert set(reduced["by_kernel"]) == set(device_trace.KERNELS) - {
-        "flash_bwd"}
+        "flash_bwd", "latent_decode"}
     assert all(v > 0 for v in reduced["by_kernel"].values())
     core = reduced["by_scope"]["attn/core"]
     assert reduced["by_kernel"]["flash_fwd"] <= core["fwd"]

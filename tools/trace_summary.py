@@ -35,7 +35,11 @@ when serve_request records are present); a JSON object with "histograms"
 (the exporter's /metrics.json shape, also written into flight-recorder
 state.json) gets the registry-percentile table; anything loadable by
 profiler.load_profiler_result gets the per-span table (calls/total/avg/
-max/min, the Profiler.summary layout). Output ends with one
+max/min, the Profiler.summary layout) and, where the trace holds the
+program's own spans (`get_tracer().export_chrome_trace(path)`), the span
+ring's table: self time a span, the time no span was open, every jit phase
+of the process inside and outside a registered executable's first call,
+the largest by function: where a start-up went. Output ends with one
 machine-readable JSON summary line, matching the other tools/ probes'
 convention.
 """
@@ -653,6 +657,26 @@ def summarize_snapshot_doc(doc, emit_json=True):
     return summary
 
 
+def _print_span_tables(path):
+    """The span ring's own table (observability/tracer.py `span_table`) of
+    each exported chrome trace that holds the program's spans: count, total
+    and self time a span, `caller`, the jit phases inside and outside an
+    `exec.first_call`, the largest jit events by `fun`."""
+    from paddle_tpu.observability import tracer
+
+    files = [path] if os.path.isfile(path) else sorted(
+        os.path.join(path, f) for f in os.listdir(path)
+        if f.endswith(".json"))
+    for f in files:
+        with open(f) as fh:
+            doc = json.load(fh)
+        events = tracer.events_from_chrome(doc) if isinstance(doc, dict) else []
+        if any("id" in e for e in events):
+            print(f"\nspans of the program in {os.path.basename(f)} "
+                  f"(seconds from the process's origin):")
+            print(tracer.format_span_table(tracer.span_table(events)))
+
+
 def summarize_trace(path):
     from paddle_tpu.profiler import load_profiler_result
 
@@ -667,6 +691,7 @@ def summarize_trace(path):
             sorted(stats.items(), key=lambda kv: -kv[1][1])]
     _fmt_table(["region", "calls", "total_ms", "avg_ms", "max_ms", "min_ms"],
                rows)
+    _print_span_tables(path)
     t0, t1 = res.time_range()
     top = max(stats.items(), key=lambda kv: kv[1][1])
     summary = {
