@@ -24,6 +24,7 @@ from ..distributed.meta_parallel.mp_layers import (
 )
 from ..ops import creation as C
 from ..ops import manipulation as P
+from ..ops import slot_attention
 from ..nn import functional as F
 from ..nn.kv_cache import ChunkKV, KVLayerSpec
 
@@ -104,11 +105,18 @@ class GPTAttention(nn.Layer):
         with jax.named_scope("cache_write"):
             kc, vc, held, new_cache = cache.update(k._data, v._data)
         with jax.named_scope("core"):
-            qpos = cache.positions(s)                         # [b|1, s]
-            mask = (held <= qpos[:, :, None])[:, None]        # [b|1, 1, s, T]
-            out = F.scaled_dot_product_attention(
-                q, Tensor(kc), Tensor(vc), attn_mask=Tensor(mask),
-                dropout_p=0.0, training=False)
+            # a decode step over the slot cache reads each slot's rows to
+            # its offset only, where a kernel can (ops/slot_attention.py)
+            out = slot_attention.decode_core(q._data[:, :, :, None],
+                                             new_cache)
+            if out is not None:
+                out = Tensor(out)
+            else:
+                qpos = cache.positions(s)                     # [b|1, s]
+                mask = (held <= qpos[:, :, None])[:, None]    # [b|1, 1, s, T]
+                out = F.scaled_dot_product_attention(
+                    q, Tensor(kc), Tensor(vc), attn_mask=Tensor(mask),
+                    dropout_p=0.0, training=False)
             out = P.reshape(out, (b, s, self.hidden_size))
         with jax.named_scope("out"):
             return self.out_proj(out), new_cache

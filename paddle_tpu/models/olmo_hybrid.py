@@ -43,6 +43,7 @@ from ..core import random as random_mod
 from ..core.tensor import Tensor
 from ..nn.kv_cache import (KVLayerSpec, SlotState, StateLayerSpec,
                            conv_tail)
+from ..ops import slot_attention
 from ..ops.gated_delta import gated_delta_chunked, gated_delta_step
 from .afmoe import _QUERY_BLOCK, AfmoeMLP, _attend, _Norm, _Weight
 
@@ -329,7 +330,11 @@ class OlmoHybridAttention(nn.Layer):
             if alone:
                 o = self._prefill_core(q, k, v)
             else:
-                o = _attend(q, kc, vc, held <= pos[:, :, None])
+                # a decode step over the slot cache reads each slot's rows
+                # to its offset only, where a kernel can
+                o = slot_attention.decode_core(q, cache)
+                if o is None:
+                    o = _attend(q, kc, vc, held <= pos[:, :, None])
         with jax.named_scope("out"):
             out = self.o_proj(o.reshape(b, s, -1))
         return out if cache is None else (out, cache)

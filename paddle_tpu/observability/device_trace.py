@@ -29,11 +29,12 @@ from typing import Dict, Iterator, List, Optional, Tuple
 # models/olmo_hybrid.py, models/deepseek_v2.py,
 # nn/layers/routed_experts.py, ops/fused.py,
 # ops/pallas/flash_attention.py, ops/pallas/latent_decode.py,
+# ops/pallas/slot_decode.py,
 # distributed/engine.py, grad_comm.py,
 # serving/engine.py, serving/kv_state.py, serving/sampling.py) ------------
 ROOTS = ("prefill", "decode")                       # the serving programs
 KERNELS = ("flash_fwd", "flash_bwd", "flash_bwd_dkv", "flash_bwd_dq",
-           "latent_decode")
+           "latent_decode", "slot_decode")
 SCOPES = frozenset(ROOTS + KERNELS + (
     "embed", "attn", "qkv", "core", "out", "cache_write", "mlp",
     "final_norm", "lm_head_loss", "lm_head", "sample", "grad_clip",

@@ -11,6 +11,7 @@ import json
 import math
 import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -755,53 +756,21 @@ def test_the_two_copies_of_the_reference_are_one_text():
 
 
 # --------- the other families' serving programs are the parent's, to a byte
-def _program_texts(eng):
-    kv, s = eng.slot_cache, eng.slot_count
-
-    def vec(dtype):
-        return jnp.zeros((s,), dtype)
-
-    out = {}
-    for rung in eng.ladder:
-        out[f"prefill{rung}"] = eng._build_prefill(rung).lower(
-            eng._params, *kv.args(), jnp.zeros((1, rung), jnp.int64),
-            jnp.int32(3), jnp.int32(0), jnp.float32(0.0), jnp.int32(0),
-            jnp.float32(1.0), jnp.int32(0)).as_text()
-    for family in ("greedy", "sample"):
-        out[f"decode_{family}"] = eng._build_decode(family).lower(
-            eng._params, *kv.args(), vec(jnp.int32), vec(jnp.int32),
-            vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32),
-            vec(jnp.float32), vec(jnp.int32), vec(jnp.int32),
-            vec(jnp.int32)).as_text()
-    return out
-
-
 @pytest.mark.parametrize("family", ["gpt", "afmoe", "olmo"])
 def test_the_other_families_serving_programs_are_the_parents(family):
     """The `latent` kind, the router's new arguments and `routed_load` leave
     GPT-2's, Trinity's and Olmo-Hybrid's prefill and decode programs the
     text they had before them (tests/data/serving_program_digests.json,
-    taken at PR 34), so no cell of theirs compiles anew. A PR that changes
-    one of those programs on purpose takes the digests again
-    (`chip_scratch/lowered_texts.py` prints them)."""
-    import hashlib
-
-    from paddle_tpu.models import (AfmoeForCausalLM, OlmoHybridForCausalLM,
-                                   gpt_tiny, olmo_hybrid_tiny)
-
-    build = {"gpt": (0, lambda: GPTForPretraining(gpt_tiny())),
-             "afmoe": (2, lambda: AfmoeForCausalLM(afmoe_tiny())),
-             "olmo": (4, lambda: OlmoHybridForCausalLM(olmo_hybrid_tiny()))}
-    seed, make = build[family]
-    paddle.seed(seed)
-    model = make()
-    model.eval()
-    eng = ServingEngine(model, slot_count=3, ladder=(8, 16), max_seq_len=48,
-                        max_new_cap=8, steps_per_dispatch=4)
-    with open(os.path.join(REPO, "tests", "data",
-                           "serving_program_digests.json")) as f:
+    taken at PR 34), so no cell of theirs compiles anew; so does the slot
+    kernel's door (PR 38: the CPU lowers the plain cores). A PR that changes
+    one of those programs on purpose takes the digests again:
+    `python tools/serving_program_digests.py --write AT`."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    try:
+        import serving_program_digests as tool
+    finally:
+        sys.path.pop(0)
+    with open(tool.FILE) as f:
         want = json.load(f)["digests"]
-    got = {f"{family}.{k}": hashlib.sha256(t.encode()).hexdigest()[:16]
-           for k, t in _program_texts(eng).items()}
-    assert got == {k: v for k, v in want.items()
-                   if k.startswith(family + ".")}
+    assert tool.digests(family) == {k: v for k, v in want.items()
+                                    if k.startswith(family + ".")}

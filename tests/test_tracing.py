@@ -91,7 +91,8 @@ def _scope_literals():
                 text = open(os.path.join(d, f)).read()
                 found += [(os.path.join(d, f), m) for m in re.findall(
                     r"named_scope\(\s*[\"']([^\"']+)[\"']", text)]
-                if f in ("flash_attention.py", "latent_decode.py"):
+                if f in ("flash_attention.py", "latent_decode.py",
+                         "slot_decode.py"):
                     # a kernel's name is its scope
                     found += [(f, m) for m in re.findall(
                         r"\bname=\"(\w+)\"", text)]
@@ -296,6 +297,9 @@ def test_afmoe_serve_step_record_carries_the_experts_load():
     ("jit(step_chunk)/decode/while/body/closed_call/mla/core/"
      "jit(_call)/latent_decode/pallas_call",
      ("decode/mla/core", False, "latent_decode")),
+    ("jit(step_chunk)/decode/while/body/closed_call/attn/core/"
+     "jit(_call)/slot_decode/pallas_call",
+     ("decode/attn/core", False, "slot_decode")),
     ("jit(step)/transpose(jvp(lm_head_loss))/jit(fwd)/lm_head_loss/while/"
      "body/closed_call/dot_general", ("lm_head_loss", True, None)),
     ("jit(step_chunk)/decode/while/body/closed_call/attn/cache_write/"
@@ -1120,9 +1124,10 @@ def test_scopes_sum_to_busy_time(reduced):
 def test_kernels_are_found_by_name(reduced):
     # the probe was recorded from PR 26's program, whose d=64 model ran the
     # [b*h, s, d] kernels: the packed paths' `flash_bwd` is not in it, nor
-    # PR 36's `latent_decode` (test_scope_of has its op path)
+    # PR 36's `latent_decode` and PR 38's `slot_decode` (test_scope_of has
+    # their op paths)
     assert set(reduced["by_kernel"]) == set(device_trace.KERNELS) - {
-        "flash_bwd", "latent_decode"}
+        "flash_bwd", "latent_decode", "slot_decode"}
     assert all(v > 0 for v in reduced["by_kernel"].values())
     core = reduced["by_scope"]["attn/core"]
     assert reduced["by_kernel"]["flash_fwd"] <= core["fwd"]

@@ -28,7 +28,9 @@ crosses the program's boundary: each kind of cache argument with its entry
 layout and the layout the same array has inside the `while`, the cache-sized
 `copy` / `copy-start` instructions outside and inside the loop (count and
 bytes), the Mosaic kernels the program holds (`mosaic_calls`: the grouped
-matmuls' and, for deepseek-v2, the absorbed core's) and `memory_analysis()`.
+matmuls', for deepseek-v2 the absorbed core's, for gpt2-large and
+olmo-hybrid-7b the full layers' core, ops/pallas/slot_decode.py) and
+`memory_analysis()`.
 It loads the TPU's compiler library, which one process holds at a time: run
 it by hand, one configuration a process, never from a test. `--slots N` compiles for another slot count than the
 cell's (a compile the chip's memory refuses is reported, not raised);
@@ -85,8 +87,8 @@ def serving(config, slots, dump=None, rung=0):
     routed_experts._draw = lambda key, shape, std, dtype: jnp.zeros(shape, dtype)
     # the program is compiled for the chip, so it holds the chip's kernels:
     # a trace here sees the CPU backend and would take the plain forms
-    from paddle_tpu.ops.pallas import latent_decode
-    latent_decode._target = lambda: "mosaic"
+    from paddle_tpu.ops.pallas import latent_decode, slot_decode
+    latent_decode._target = slot_decode._target = lambda: "mosaic"
     build = importlib.import_module(f"benchmarks.runners.{runner}").build_model
     model = build(load("configs", cell["config"]), 0)
     model.eval()

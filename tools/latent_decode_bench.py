@@ -42,14 +42,19 @@ BLOCKS = (256, 512, 1024, 2048)
 def contexts(traffic: dict, slots: int, seed: int, rows: int):
     """Positions held by `slots` slots somewhere in a long run of the
     traffic: a prompt of its distribution and a uniform share of the
-    tokens it asks for."""
+    tokens it asks for (tools/slot_decode_bench.py draws its own so)."""
     import numpy as np
 
     rng = np.random.default_rng(seed)
-    p = traffic["prompt_len"]
-    prompt = np.clip(np.exp(rng.normal(np.log(p["median"]), p["sigma"],
-                                       slots)), p["min"], p["max"])
-    out = rng.uniform(0, traffic["max_new"]["value"], slots)
+
+    def draw(d):
+        if d["dist"] == "fixed":
+            return np.full(slots, float(d["value"]))
+        return np.clip(np.exp(rng.normal(np.log(d["median"]), d["sigma"],
+                                         slots)), d["min"], d["max"])
+
+    prompt = draw(traffic["prompt_len"])
+    out = rng.uniform(0, draw(traffic["max_new"]), slots)
     return np.clip((prompt + out).astype(np.int64), 1, rows)
 
 
