@@ -12,6 +12,9 @@ from .deepseek_v2 import (  # noqa: F401
     DeepseekV2Config, DeepseekV2ForCausalLM, DeepseekV2Model,
     deepseek_v2_tiny,
 )
+from .sdar import (  # noqa: F401
+    SdarConfig, SdarForCausalLM, SdarModel, sdar_tiny,
+)
 from .ernie import (  # noqa: F401
     BertConfig, BertForPretraining, BertModel, ErnieConfig, ErnieForPretraining,
     ErnieModel, bert_base, bert_large, ernie_base, ernie_large, ernie_tiny,

@@ -37,6 +37,18 @@ window adds `held > position - window`. `cache.fresh` (static) says that
 nothing was held before this chunk, so the chunk's own keys are all there is
 to see.
 
+A model that generates by diffusion over blocks of `block` positions (every
+query of a block sees the whole block, in both directions, and every block
+before it) asks `block_causal_mask(held, positions, block)` for the test
+instead: `held < (position // block + 1) * block`. The same rule about rows
+holds, stated once here: a block's `block` queries are written together at
+rows `[off, off + block)` (`SlotKV.update` at `s = block`), so those rows are
+REWRITTEN by every forward over the block and only count as held once the
+offset has passed them ("store the keys and values" is "advance the
+offset"); rows at or past `off + block` hold positions after every query of
+the block, unwritten or stale alike, and stay hidden. With `block` 1 it is
+the causal test.
+
 The handles are pytrees (`lax.scan` carries them, `tree_map` reorders beams):
 `ChunkKV` here for a whole batch at one offset (generate(), beam search, a
 request's prefill alone), `SlotKV` and `RingKV` for the serving engine's slot
@@ -130,6 +142,24 @@ class LatentLayerSpec(NamedTuple):
 SPECS = {"state": StateLayerSpec, "latent": LatentLayerSpec}
 
 
+class BlockDiffusion(NamedTuple):
+    """How a model generates, where it is not one token after another: by
+    diffusion over blocks of `block_length` positions (a model's
+    `generation`). A block starts as `mask_token_id` at every position that
+    is not given; a forward over it under `block_causal_mask` proposes a
+    token at every position and the most confident are kept, until a forward
+    over a block with no mask left commits it. The rest are a request's
+    defaults: the forwards a block's positions are spread over, how the
+    positions to keep are chosen (serving/diffusion.py `REMASKING`), and the
+    confidence above which `low_confidence_dynamic` keeps a position at
+    once."""
+    block_length: int
+    mask_token_id: int
+    denoising_steps: int = 4
+    remasking: str = "low_confidence_static"
+    confidence_threshold: float = 0.9
+
+
 def latent_width(layer: LatentLayerSpec) -> int:
     """The width a slot cache STORES a latent row at: a multiple of the
     chip's 128 lanes (576 -> 640)."""
@@ -186,6 +216,15 @@ def _write_rows(stored, slots, rows, new):
     [b, rows, kv_heads, head_dim])."""
     stored = stored.at[slots, rows].set(padded_rows(new, stored))
     return stored, logical_rows(stored, *new.shape[2:])
+
+
+def block_causal_mask(held, positions, block: int):
+    """[b, s, t] bool: the query at `positions` [b or 1, s] sees the row that
+    holds position `held` (broadcasts against [b, s, t]) iff the row's block
+    is the query's or an earlier one, `held // block <= position // block`,
+    written as one comparison against the end of the query's block."""
+    ends = (positions // block + 1) * block
+    return held < ends[:, :, None]
 
 
 def ring_row(pos, rows: int):

@@ -51,7 +51,10 @@ SCOPES = frozenset(ROOTS + KERNELS + (
     # prefill: keys and values a head from the latent), absorb / unabsorb (a
     # decode step: queries into the latent space, the result back), core,
     # out, cache_write; moe as afmoe's
-    "mla", "q_lora", "kv_latent", "expand", "absorb", "unabsorb"))
+    "mla", "q_lora", "kv_latent", "expand", "absorb", "unabsorb",
+    # a block step of generation by diffusion (serving/engine.py
+    # `_build_block_decode`): `denoise` > `confidence`, `unmask`, `commit`
+    "denoise", "confidence", "unmask", "commit"))
 SPAN_PREFIXES = ("serve.", "engine.")               # the engines' spans
 
 UNNAMED = "unnamed"           # an op_name, and no scope of the vocabulary

@@ -24,6 +24,7 @@ legacy generate() adds decode.jit_compiles / decode.cache_evictions
 from ..core.bucketing import (  # noqa: F401
     DEFAULT_LADDER, bucket_for, clip_ladder, resolve_bucket,
 )
+from .diffusion import BlockDiffusion  # noqa: F401
 from .engine import Request, ServingEngine  # noqa: F401
 from .kv_pages import PagePool, PoolExhausted  # noqa: F401
 from .loadgen import (  # noqa: F401
@@ -32,7 +33,7 @@ from .loadgen import (  # noqa: F401
 from .prefix_cache import RadixPrefixCache  # noqa: F401
 from .router import ReplicaRouter  # noqa: F401
 from .sampling import (  # noqa: F401
-    filter_topk_topp, request_key, sample_tokens,
+    filter_topk_topp, request_key, sample_tokens, sample_tokens_with_prob,
 )
 
 __all__ = [
@@ -40,5 +41,6 @@ __all__ = [
     "Scenario", "LoadGenerator", "spike_scenario", "zipf_tenants",
     "PagePool", "PoolExhausted", "RadixPrefixCache",
     "DEFAULT_LADDER", "bucket_for", "clip_ladder", "resolve_bucket",
-    "sample_tokens", "filter_topk_topp", "request_key",
+    "sample_tokens", "sample_tokens_with_prob", "filter_topk_topp",
+    "request_key", "BlockDiffusion",
 ]

@@ -19,6 +19,14 @@ resident-program philosophy of MPK, arxiv 2512.22219):
   program here is written once) with per-slot write offsets, per-slot sampling params (traced — mixed greedy/top-k/top-p
   share the program), per-slot EOS/budget masks, and per-slot RNG streams.
 
+A model that generates by diffusion over blocks (`model.generation`, a
+`BlockDiffusion`) gets two others of the same form: a **block prefill** a
+rung, which runs the prompt's whole blocks and draws nothing, and a **block
+step** (`_build_block_decode`): the same scan over the donated slot cache
+whose every forward covers a block of B positions a slot and yields 0 or B
+tokens a slot (serving/diffusion.py). Budgets, run-ahead and every counter
+of tokens count tokens either way.
+
 On top sits continuous batching: finished sequences retire their slot
 mid-flight and queued requests are prefilled into free slots between decode
 steps — the decode loop itself never recompiles and never runs a step for
@@ -47,6 +55,7 @@ from ..observability import flight_recorder as _obs_flight
 from ..observability import metrics as _obs_metrics
 from ..observability import tracer as _obs_tracer
 from . import kv_state as _kvs
+from .diffusion import remasking_id
 from ..core.bucketing import DEFAULT_LADDER, bucket_for, clip_ladder
 
 _NO_EOS = -1
@@ -74,7 +83,8 @@ class Request:
 
     def __init__(self, prompt_ids, max_new_tokens, temperature, top_k, top_p,
                  eos_token_id, seed, trace_ctx=None, tenant=None,
-                 speculate_k=0):
+                 speculate_k=0, denoising_steps=None, remasking=None,
+                 confidence_threshold=None, record_blocks=False):
         import numpy as np
 
         self.id = next(Request._ids)
@@ -102,6 +112,18 @@ class Request:
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.spec_bonus = 0
+        # generation by diffusion over blocks (serving/diffusion.py): how
+        # many forwards a block's positions are spread over, how positions
+        # are chosen, the dynamic schedule's threshold (None: the model's).
+        # `block_states`, where asked for, is the request's blocks forward by
+        # forward: (offset, the block after the forward, whether it was
+        # committed, the tokens drawn, their confidences)
+        self.denoising_steps = denoising_steps
+        self.remasking = remasking
+        self.confidence_threshold = confidence_threshold
+        self.block_states: Optional[list] = [] if record_blocks else None
+        self.given_in_block = 0          # prompt tokens that open a block
+        self.block_offset = 0            # positions held before the block
         self.tokens: List[int] = []      # generated tokens (incl. eos if hit)
         self.prefix_hit = False          # paged: >= 1 page matched the trie
         self.shared_tokens = 0           # paged: prompt tokens served from
@@ -173,13 +195,16 @@ class ServingEngine:
 
     model: a causal LM that says what the engine needs of it and nothing of
     its architecture (GPTForPretraining, AfmoeForCausalLM,
-    OlmoHybridForCausalLM): `config`
+    OlmoHybridForCausalLM, DeepseekV2ForCausalLM, SdarForCausalLM): `config`
     (`vocab_size`, `max_seq_len`), `serving_backbone()` (the layer called
     with `(ids, caches=...)` and its prefix in `state_dict`),
     `kv_cache_spec(max_seq_len)` (what each layer keeps a slot, rows of keys
     and values or a recurrent state: nn/kv_cache.py, kv_state.py),
-    `_head_logits(h)`, and `serving_step_stats` (what a decode step reports
-    beside its tokens). Eval mode is forced. slot_count fixes the
+    `_head_logits(h)`, `serving_step_stats` (what a decode step reports
+    beside its tokens) and, where it does not generate one token after
+    another, `generation` (a `BlockDiffusion`: the decode program is then a
+    step of a block of positions a slot, `_build_block_decode`). Eval mode
+    is forced. slot_count fixes the
     decode batch; ladder the prefill rungs (clipped to what fits
     max_seq_len with max_new_cap headroom). Weights are snapshotted (and
     pre-cast to the active AMP compute dtype) at construction — call
@@ -210,6 +235,26 @@ class ServingEngine:
         cfg = model.config
         self.model = model
         model.eval()
+        # a model that generates by diffusion over blocks says so
+        # (nn/kv_cache.py `BlockDiffusion`): the decode program is then
+        # `_build_block_decode`, a step of B positions a slot
+        self._diffusion = getattr(model, "generation", None)
+        if self._diffusion is not None:
+            if draft_model is not None:
+                raise ValueError(
+                    "speculative decoding cannot run this model: it "
+                    "generates by diffusion over blocks, a step yields 0 or "
+                    f"{self._diffusion.block_length} tokens a slot, and the "
+                    "verify program accepts a prefix of single tokens; "
+                    "construct the engine without draft_model")
+            if kv_layout == "paged":
+                raise ValueError(
+                    "kv_layout='paged' cannot hold this model: it generates "
+                    "by diffusion over blocks, whose rows are rewritten by "
+                    "every forward over a block, and a prefix shared across "
+                    "requests may be cut at a block's edge only; neither is "
+                    "written for the page pool or the prefix cache, use "
+                    "'contiguous'")
         # speculative decoding (opt-in per request via submit(speculate_k=)):
         # a small draft GPT proposes k tokens, one shape-stable verify
         # dispatch scores all k+1 positions through the target. The draft
@@ -241,6 +286,13 @@ class ServingEngine:
                 f"max_new_cap {max_new_cap} must be in [1, max_seq_len)")
         self.ladder = clip_ladder(ladder, self.max_seq_len,
                                   reserve=self.max_new_cap)
+        if self._diffusion is not None:
+            B = self._diffusion.block_length
+            if self.max_seq_len % B or any(r % B for r in self.ladder):
+                raise ValueError(
+                    f"max_seq_len {self.max_seq_len} and the ladder "
+                    f"{self.ladder} must be multiples of the model's block "
+                    f"length {B}: a prefill's pad starts on a block's edge")
         # decode steps fused into one dispatch (inner lax.scan): divides the
         # per-step host round-trip by N at the cost of (a) retired slots
         # idling masked until the chunk ends (<= N-1 wasted slot-steps per
@@ -369,6 +421,34 @@ class ServingEngine:
         # rows run it as a 1-wide window, emitting exactly the decode token
         self._spec_k = np.zeros(S, np.int32)
         self._slot_req: List[Optional[Request]] = [None] * S
+        # the decode program's carry and per-slot constants as the host's
+        # arrays, in the program's order, and what it returns of each fused
+        # step after the carry, by the names `_emit_decoded` /
+        # `_emit_block_decoded` take them under ([n_inner, S] each; a block
+        # step's blocks, draws and confidences [n_inner, S, B])
+        self._carry_names = ("_offsets", "_last_tok", "_active", "_remaining")
+        self._const_names = ("_temps", "_topk", "_topp", "_eos", "_seeds")
+        self._out_names = ("toks", "was_active", "hits")
+        # block diffusion: a slot's block, the forwards it has had, the
+        # given tokens that open it, and the request's schedule
+        if self._diffusion is not None:
+            self._carry_names = ("_offsets", "_block", "_block_k", "_given",
+                                 "_active", "_remaining")
+            self._const_names += ("_dsteps", "_remask", "_thresh")
+            self._out_names = ("blocks", "was_active", "commits", "unmasked",
+                               "draws", "confs")
+            B = self._diffusion.block_length
+            self._block = np.full((S, B), self._diffusion.mask_token_id,
+                                  np.int32)
+            self._block_k = np.zeros(S, np.int32)
+            self._given = np.zeros(S, np.int32)
+            self._dsteps = np.ones(S, np.int32)
+            self._remask = np.zeros(S, np.int32)
+            self._thresh = np.ones(S, np.float32)
+            # since start: slot-forwards of live slots, blocks committed,
+            # positions unmasked, tokens emitted
+            self._forwards = self._blocks = self._unmasked = 0
+            self._block_tokens = 0
 
         # ONE keyed ExecutableRegistry replaces the four parallel executable
         # dicts this engine used to carry (prefill rungs, draft-prefill
@@ -426,14 +506,23 @@ class ServingEngine:
     def submit(self, prompt_ids, max_new_tokens: int = 32,
                temperature: float = 0.0, top_k: int = 0, top_p: float = 1.0,
                eos_token_id=None, seed: int = 0, trace_ctx=None,
-               tenant=None, speculate_k: int = 0) -> Request:
+               tenant=None, speculate_k: int = 0,
+               denoising_steps: Optional[int] = None,
+               remasking: Optional[str] = None,
+               confidence_threshold: Optional[float] = None,
+               record_blocks: bool = False) -> Request:
         """Enqueue a request; returns the live Request handle (tokens fill
         in as the engine runs). max_new_tokens is clamped to the engine cap
         and to the cache room left after the prompt's bucket. trace_ctx
         (fleet.TraceContext) threads a fleet request id + parent span
         through every span this request records. speculate_k > 0 opts this
         request into speculative decoding (snapped up to the engine's
-        spec_ladder rung; needs a draft model)."""
+        spec_ladder rung; needs a draft model). `denoising_steps`,
+        `remasking` and `confidence_threshold` are the schedule of a model
+        that generates by diffusion over blocks (serving/diffusion.py; None:
+        the model's own), and `record_blocks` keeps the request's blocks
+        forward by forward in `Request.block_states`; an engine whose model
+        generates a token a step refuses them."""
         if self._draining:
             raise RuntimeError(
                 "ServingEngine is draining (SIGTERM/begin_drain): admission "
@@ -452,9 +541,43 @@ class ServingEngine:
                 raise ValueError(
                     "speculate_k > 0 needs a draft model: construct the "
                     "engine with draft_model=")
+        gen = self._diffusion
+        asked = {"denoising_steps": denoising_steps, "remasking": remasking,
+                 "confidence_threshold": confidence_threshold,
+                 "record_blocks": record_blocks or None}
+        if gen is None:
+            given = [k for k, v in asked.items() if v is not None]
+            if given:
+                raise ValueError(
+                    f"{', '.join(given)} cannot be served: this engine's "
+                    "model generates one token a slot a step, and these are "
+                    "the schedule of generation by diffusion over blocks")
+        else:
+            if speculate_k:
+                raise ValueError(
+                    "speculate_k > 0 cannot be served: this engine's model "
+                    "generates by diffusion over blocks")
+            denoising_steps = int(gen.denoising_steps if denoising_steps
+                                  is None else denoising_steps)
+            if denoising_steps < 1:
+                raise ValueError(f"denoising_steps must be >= 1, got "
+                                 f"{denoising_steps}")
+            remasking = gen.remasking if remasking is None else remasking
+            remasking_id(remasking)               # raises by name
+            confidence_threshold = float(
+                gen.confidence_threshold if confidence_threshold is None
+                else confidence_threshold)
         req = Request(prompt_ids, max_new_tokens, temperature, top_k, top_p,
                       eos_token_id, seed, trace_ctx=trace_ctx, tenant=tenant,
-                      speculate_k=speculate_k)
+                      speculate_k=speculate_k,
+                      denoising_steps=denoising_steps, remasking=remasking,
+                      confidence_threshold=confidence_threshold,
+                      record_blocks=record_blocks)
+        if gen is not None and (req.prompt_ids == gen.mask_token_id).any():
+            raise ValueError(
+                f"the prompt holds the mask token {gen.mask_token_id}: a "
+                "given token that reads as masked would be overwritten by "
+                "the first forward over its block")
         plen = len(req.prompt_ids)
         req.bucket = bucket_for(plen, self.ladder)  # raises if oversize
         room = self.max_seq_len - req.bucket
@@ -610,7 +733,10 @@ class ServingEngine:
         fetched: `_decode_step`), `completed`, `queued`, `active_slots`,
         `draining`, the ladder and executable counts, the cache's layout and
         bytes; with a draft model the verify counts, on the paged layout the
-        pool's and the prefix cache's; `startup`, the span ring's table
+        pool's and the prefix cache's; for a model that generates by diffusion
+        over blocks `forwards` (slot-forwards of live slots),
+        `blocks_committed`, `positions_unmasked`, `forwards_per_block` and
+        `tokens_per_forward` since start; `startup`, the span ring's table
         (`observability/tracer.py` `phase_table`: where the process's time
         went by span, which executable compiled cold, which jit no registry
         holds; one pass over the ring, tens of milliseconds when it is
@@ -632,6 +758,15 @@ class ServingEngine:
             "kv_cache_bytes": self.kv_cache_bytes(),
             "startup": _obs_tracer.phase_table(),
         }
+        if self._diffusion is not None:
+            out.update({
+                "forwards": self._forwards,
+                "blocks_committed": self._blocks,
+                "positions_unmasked": self._unmasked,
+                "forwards_per_block": self._forwards / max(1, self._blocks),
+                "tokens_per_forward": (self._block_tokens
+                                       / max(1, self._forwards)),
+            })
         if self.draft_model is not None:
             out.update({
                 "spec_ladder": self.spec_ladder,
@@ -845,14 +980,18 @@ class ServingEngine:
                     jnp.asarray(self._remaining), jnp.asarray(self._seeds))
 
         plan = []  # (key, build, label, donate, call_args)
+        blocks = self._diffusion is not None
         for bucket in self.ladder:
             padded = jnp.asarray(np.zeros((1, bucket), np.int64))
             at = tuple(jnp.int32(0) for _ in kv.prefill_at)
-            args = (self._params, *kv.args(), padded, jnp.int32(0), *at,
-                    jnp.float32(0.0), jnp.int32(0), jnp.float32(1.0),
-                    jnp.int32(0))
+            args = (self._params, *kv.args(), padded, jnp.int32(0), *at)
+            if not blocks:
+                args += (jnp.float32(0.0), jnp.int32(0), jnp.float32(1.0),
+                         jnp.int32(0))
             plan.append((("serve.prefill", bucket),
-                         (lambda b=bucket: self._build_prefill(b)),
+                         (lambda b=bucket: (self._build_block_prefill(b)
+                                            if blocks else
+                                            self._build_prefill(b))),
                          f"serve.prefill_b{bucket}", self._donate(1, kv),
                          args))
             if dkv is not None:
@@ -864,9 +1003,17 @@ class ServingEngine:
                              f"serve.dprefill_b{bucket}",
                              self._donate(1, dkv), dargs))
         for family in families:
-            args = (self._params, *kv.args(), *slot_vecs(), *sampling_vecs())
+            if blocks:
+                args = (self._params, *kv.args(), *(
+                    jnp.asarray(a) for a in (*self._host_carry(),
+                                             *self._host_consts())))
+            else:
+                args = (self._params, *kv.args(), *slot_vecs(),
+                        *sampling_vecs())
             plan.append((("serve.decode", family),
-                         (lambda f=family: self._build_decode(f)),
+                         (lambda f=family: (self._build_block_decode(f)
+                                            if blocks else
+                                            self._build_decode(f))),
                          f"serve.decode_{family}", self._donate(1, kv), args))
             if dkv is None:
                 continue
@@ -971,6 +1118,50 @@ class ServingEngine:
         return jax.jit(jax.named_scope("prefill")(prefill),
                        donate_argnums=self._donate(1, kv))
 
+    def _build_block_prefill(self, bucket: int):
+        """A prompt's whole blocks for a model that generates by diffusion:
+        `length` tokens (a multiple of the block length) right-padded to the
+        rung run through the backbone under the block-causal mask, and the
+        cache commits their rows to the slot. The pad starts on a block's
+        edge, so no real query sees it. No logits and no token: a request's
+        first tokens come from its first committed block."""
+        import jax
+
+        kv = self._kv
+
+        def block_prefill(params, *args):
+            cache, (ids, length, *at) = _split(args, kv.n_args)
+            caches = kv.prefill_views(cache, bucket, length, *at)
+            _h, caches, _ = self._backbone(self.model, params, ids, caches)
+            return kv.commit_prefill(cache, caches, length, *at)
+
+        return jax.jit(jax.named_scope("prefill")(block_prefill),
+                       donate_argnums=self._donate(1, kv))
+
+    def _admit_block(self, req: Request, slot: int) -> None:
+        """Seat a request of a model that generates by diffusion: its whole
+        blocks are prefilled (none for a prompt shorter than a block), the
+        prompt tokens left over open the first block as given tokens."""
+        gen = self._diffusion
+        B = gen.block_length
+        head = len(req.prompt_ids) // B * B
+        req.admit_ts = time.perf_counter()    # queue wait ends here
+        self._note_queue_wait(req)
+        if head:
+            self._run_prefill(
+                req, req.bucket, req.prompt_ids[:head], (slot,),
+                req.trace_args(bucket=req.bucket, slot=slot))
+        given = req.prompt_ids[head:]
+        req.given_in_block, req.block_offset = len(given), head
+        self._seat(req, slot, head, 0, req.max_new_tokens)
+        self._block[slot] = gen.mask_token_id
+        self._block[slot, :len(given)] = given
+        self._block_k[slot] = 0
+        self._given[slot] = len(given)
+        self._dsteps[slot] = req.denoising_steps
+        self._remask[slot] = remasking_id(req.remasking)
+        self._thresh[slot] = req.confidence_threshold
+
     # ---- speculative decoding: draft prefill ---------------------------
     def _build_draft_prefill(self, bucket: int):
         """Draft-model prompt prefill, one executable per prompt rung.
@@ -1039,11 +1230,14 @@ class ServingEngine:
             counter="serving.draft_prefill_compiles")
 
     def _run_prefill(self, req: Request, bucket: int, prompt, at,
-                     span_args: dict) -> int:
+                     span_args: dict) -> Optional[int]:
         """Dispatch the rung's prefill program over `prompt` (the whole
         prompt, or its unshared tail) at `at` and sync on the first token.
-        A failure dumps to the flight recorder, finishes the request as an
-        error and re-raises."""
+        For a model that generates by diffusion over blocks `prompt` is the
+        prompt's whole blocks, the program draws nothing and nothing is read
+        back (the decode dispatch behind it is what the host waits for): ->
+        None. A failure dumps to the flight recorder, finishes the request
+        as an error and re-raises."""
         import jax.numpy as jnp
         import numpy as np
 
@@ -1051,34 +1245,41 @@ class ServingEngine:
 
         kv = self._kv
         tr = _obs_tracer.get_tracer()
+        blocks = self._diffusion is not None
         try:
             with tr.boundary("serve.prefill.dispatch",
                              **span_args) as dispatch:
                 label, donate = f"serve.prefill_b{bucket}", self._donate(1, kv)
+                build = (self._build_block_prefill if blocks
+                         else self._build_prefill)
                 entry = self._execs.get_or_build(
-                    ("serve.prefill", bucket),
-                    lambda: self._build_prefill(bucket),
+                    ("serve.prefill", bucket), lambda: build(bucket),
                     label=label, donate=donate, pin=True)
                 padded = np.zeros((1, bucket), np.int64)
                 padded[0, :len(prompt)] = prompt
                 call_args = (self._params, *kv.args(), jnp.asarray(padded),
                              jnp.int32(len(prompt)),
-                             *(jnp.int32(a) for a in at),
-                             jnp.float32(req.temperature),
-                             jnp.int32(req.top_k), jnp.float32(req.top_p),
-                             jnp.int32(req.seed))
+                             *(jnp.int32(a) for a in at))
+                if not blocks:
+                    call_args += (jnp.float32(req.temperature),
+                                  jnp.int32(req.top_k),
+                                  jnp.float32(req.top_p), jnp.int32(req.seed))
                 self._stash_exec(label, entry.fn, call_args, donate=donate)
                 monitor.stat("serving.prefill_dispatches").increase()
                 p0 = self._execs.persistent_before(entry)
                 t0 = time.perf_counter()
-                *cache, tok = entry(*call_args)
+                cache, tok = _split(entry(*call_args), kv.n_args)
                 kv.take(cache)
                 self._execs.note_compiles(
                     entry, wall_s=time.perf_counter() - t0,
                     persistent_before=p0, counter="serving.prefill_compiles")
-            with tr.boundary("serve.prefill.sync", **span_args) as sync:
-                first = int(tok)                  # device sync = first token
-            self._prefill_ms.append((dispatch.ms, sync.ms))
+            if blocks:
+                first, sync_ms = None, 0.0
+            else:
+                with tr.boundary("serve.prefill.sync", **span_args) as sync:
+                    first = int(tok[0])           # device sync = first token
+                sync_ms = sync.ms
+            self._prefill_ms.append((dispatch.ms, sync_ms))
         except Exception as e:
             fr = _obs_flight.get()
             if fr is not None:
@@ -1087,10 +1288,12 @@ class ServingEngine:
                          "at": [int(a) for a in at], "error": repr(e)})
             self._finish(req, outcome="error")
             raise
-        req.first_token_ts = time.perf_counter()
+        done = time.perf_counter()
         if tr.enabled:
-            tr.record_complete("serve.prefill", req.admit_ts,
-                               req.first_token_ts, span_args)
+            tr.record_complete("serve.prefill", req.admit_ts, done, span_args)
+        if blocks:          # its first tokens come with its first block
+            return None
+        req.first_token_ts = done
         mreg = _obs_metrics.active_registry()
         if mreg is not None:
             mreg.histogram("serve.prefill_ms").observe(
@@ -1158,6 +1361,9 @@ class ServingEngine:
                     return
                 req = self._queue.popleft()
             slot = free[0]
+            if self._diffusion is not None:
+                self._admit_block(req, slot)
+                continue
             if self.kv_layout == "paged":
                 if not self._admit_paged(req, slot):
                     return
@@ -1332,6 +1538,116 @@ class ServingEngine:
                     hits, self._fold_step_stats(stats))
 
         return jax.jit(jax.named_scope("decode")(step_chunk),
+                       donate_argnums=self._donate(1, kv))
+
+    def _build_block_decode(self, family: str):
+        """The decode chunk of a model that generates by diffusion over
+        blocks (serving/diffusion.py), ONE executable a sampling family:
+        `steps_per_dispatch` forwards in a scan over the donated slot cache,
+        each over a block of B positions a slot. The carry holds a slot's
+        block `x` [slots, B], the forwards it has had (`k`) and the given
+        tokens that open it (`given`) beside `off`, `active` and `remaining`
+        (tokens still owed). One forward serves slots in any phase: a slot
+        whose block has no mask left COMMITS (its offset moves by B, so the
+        rows this forward wrote are the block's; the block goes out and the
+        next starts all mask), every other draws a token and its confidence
+        at every position and unmasks the chosen; both are selects over the
+        same forward. A forward returns the blocks as it left them (a
+        committed block as it was committed), which slots were live and which
+        committed, the positions it unmasked, its draws and confidences;
+        whether a request ended at an end token the host reads off the
+        block."""
+        import jax
+        import jax.numpy as jnp
+
+        from . import diffusion
+        from .sampling import request_key, sample_tokens_with_prob
+
+        T = self.max_seq_len
+        n_inner = self.steps_per_dispatch
+        greedy_only = family == "greedy"
+        kv = self._kv
+        B, mask_id = (self._diffusion.block_length,
+                      self._diffusion.mask_token_id)
+        _obs_metrics.default_registry().counter(
+            "diffusion.calls.block_step",
+            "block-diffusion decode programs traced").inc()
+
+        def block_chunk(params, *args):
+            cache, (off, x, k, given, active, remaining, temps, top_k, top_p,
+                    eos, seeds, dsteps, remask, thresh) = _split(
+                args, kv.n_args)
+            S = off.shape[0]
+            at = jnp.arange(B, dtype=jnp.int32)[None, :]
+
+            def one(carry, _):
+                cache, off, x, k, given, active, remaining = carry
+                caches = kv.views(cache, kv.tip(off), active)
+                h, caches, stats = self._backbone(
+                    self.model, params, x.astype(jnp.int64), caches)
+                logits = self._head_traced(
+                    self.model, params,
+                    h.reshape((S * B, -1))).astype(jnp.float32)  # [S*B, V]
+                with jax.named_scope("denoise"):
+                    # a trained model never predicts the mask token; random
+                    # weights would, and the position would stay masked
+                    logits = logits.at[:, mask_id].set(-jnp.inf)
+                    masked = x == mask_id
+                    commit = active & ~masked.any(axis=1)
+                    with jax.named_scope("confidence"):
+                        if greedy_only:
+                            x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                            conf = jnp.exp(
+                                jnp.max(logits, axis=-1)
+                                - jax.nn.logsumexp(logits, axis=-1))
+                        else:
+                            # one stream a (request, position, forward)
+                            keys = jax.vmap(lambda s, p, i: jax.random.fold_in(
+                                request_key(s, p), i))(
+                                jnp.repeat(seeds, B),
+                                (off[:, None] + at).reshape(-1),
+                                jnp.repeat(k, B))
+                            # a retired slot draws as a greedy row: no search
+                            x0, conf = sample_tokens_with_prob(
+                                logits, keys,
+                                jnp.repeat(jnp.where(active, temps, 0.0), B),
+                                jnp.repeat(top_k, B), jnp.repeat(top_p, B))
+                        x0, conf = x0.reshape((S, B)), conf.reshape((S, B))
+                    with jax.named_scope("unmask"):
+                        take = diffusion.unmask(
+                            masked, conf, diffusion.share(B, dsteps, k),
+                            remask, thresh)
+                        take = take & (active & ~commit)[:, None]
+                        left = jnp.where(take, x0, x)
+                    with jax.named_scope("commit"):
+                        # of a committed block, the positions that are the
+                        # request's output: past the given, inside the budget
+                        out = (at >= given[:, None]) & (
+                            at < (given + remaining)[:, None])
+                        hit_eos = commit & (eos != _NO_EOS) & (
+                            out & (x == eos[:, None])).any(axis=1)
+                        new_off = jnp.where(commit, off + B, off)
+                        new_remaining = jnp.where(
+                            commit, remaining - (B - given), remaining)
+                        new_active = active & ~(commit & (
+                            hit_eos | (new_remaining <= 0)
+                            | (new_off + B > T)))
+                        new_x = jnp.where(commit[:, None], mask_id, left)
+                        new_k = jnp.where(commit, 0,
+                                          k + active.astype(jnp.int32))
+                        new_given = jnp.where(commit, 0, given)
+                return ((kv.absorb(cache, caches, active), new_off, new_x,
+                         new_k, new_given, new_active, new_remaining),
+                        (left, active, commit,
+                         take.sum(axis=1, dtype=jnp.int32), x0, conf, stats))
+
+            carry = (cache, off, x, k, given, active, remaining)
+            (cache, *carry), (*outs, stats) = jax.lax.scan(
+                one, carry, None, length=n_inner)
+            # outs: [n_inner, S(, B)] each; stats folded over the forwards
+            return (*cache, *carry, *outs, self._fold_step_stats(stats))
+
+        return jax.jit(jax.named_scope("decode")(block_chunk),
                        donate_argnums=self._donate(1, kv))
 
     # ---- speculative decoding: verify ----------------------------------
@@ -1708,6 +2024,15 @@ class ServingEngine:
         cannot foresee: the device masks that slot in the chunk enqueued
         ahead, which costs the slot's next request one chunk."""
         n = self.steps_per_dispatch
+        if self._diffusion is not None:
+            # a block needs at least two forwards (one that unmasks what is
+            # left, one that commits), so a chunk of n forwards commits at
+            # most ceil(n / 2) blocks a slot, whatever the schedule
+            n = (n + 1) // 2 * self._diffusion.block_length
+            return bool(not self._draining and self._active.all()
+                        and int(self._remaining.min()) > n
+                        and int(self._offsets.max()) + n
+                        + self._diffusion.block_length <= self.max_seq_len)
         return bool(not self._draining and self._active.all()
                     and int(self._remaining.min()) > n
                     and int(self._offsets.max()) + n < self.max_seq_len
@@ -1746,9 +2071,10 @@ class ServingEngine:
                 requests=[r.id for r in self._slot_req
                           if r is not None]) as dispatch:
             label, donate = f"serve.decode_{family}", self._donate(1, kv)
+            build = (self._build_decode if self._diffusion is None
+                     else self._build_block_decode)
             entry = self._execs.get_or_build(
-                ("serve.decode", family),
-                lambda: self._build_decode(family),
+                ("serve.decode", family), lambda: build(family),
                 label=label, donate=donate, pin=True)
             # the positions this chunk may write (a slot's table row is
             # static within a dispatch)
@@ -1756,24 +2082,27 @@ class ServingEngine:
             kv.cover(self._active, offsets,
                      np.minimum(offsets + n_inner, self.max_seq_len) - 1)
             if self._carry is None:
-                self._carry = tuple(jnp.asarray(a) for a in (
-                    self._offsets, self._last_tok, self._active,
-                    self._remaining))
+                self._carry = tuple(jnp.asarray(a)
+                                    for a in self._host_carry())
             if self._consts is None:
-                self._consts = tuple(jnp.asarray(a) for a in (
-                    self._temps, self._topk, self._topp, self._eos,
-                    self._seeds))
-            off, tok, active, remaining = self._carry
-            temps, top_k, top_p, eos, seeds = self._consts
-            call_args = (self._params, *kv.args(), off, tok, active, temps,
-                         top_k, top_p, eos, remaining, seeds)
+                self._consts = tuple(jnp.asarray(a)
+                                     for a in self._host_consts())
+            if self._diffusion is None:
+                off, tok, active, remaining = self._carry
+                temps, top_k, top_p, eos, seeds = self._consts
+                call_args = (self._params, *kv.args(), off, tok, active,
+                             temps, top_k, top_p, eos, remaining, seeds)
+            else:
+                call_args = (self._params, *kv.args(), *self._carry,
+                             *self._consts)
             self._stash_exec(label, entry.fn, call_args, donate=donate)
             p0 = self._execs.persistent_before(entry)
             t0 = time.perf_counter()
-            cache, (off, tok, active, remaining, toks, was_active, hits,
-                    stats) = _split(entry(*call_args), kv.n_args)
+            cache, (*outs, stats) = _split(entry(*call_args), kv.n_args)
             kv.take(cache, self._active)
-            self._carry = (off, tok, active, remaining)
+            # the program's results: the carry, then what each step gave
+            n_carry = len(self._carry)
+            self._carry, outs = tuple(outs[:n_carry]), outs[n_carry:]
             self._execs.note_compiles(
                 entry, wall_s=time.perf_counter() - t0,
                 persistent_before=p0, counter="serving.decode_compiles")
@@ -1786,10 +2115,18 @@ class ServingEngine:
         # the chunk before this one was still in flight
         host_gap_ms = (0.0 if ahead else None if self._fetch_end is None
                        else (dispatch.t1 - self._fetch_end) * 1e3)
-        return {"carry": self._carry, "toks": toks, "was_active": was_active,
-                "hits": hits, "stats": stats, "family": family,
-                "ahead": ahead, "dispatch_ms": dispatch.ms,
-                "host_gap_ms": host_gap_ms}
+        return {"carry": self._carry, **dict(zip(self._out_names, outs)),
+                "stats": stats, "family": family, "ahead": ahead,
+                "dispatch_ms": dispatch.ms, "host_gap_ms": host_gap_ms}
+
+    def _host_carry(self) -> tuple:
+        """The host's copy of the decode program's carry, in its order."""
+        return tuple(getattr(self, name) for name in self._carry_names)
+
+    def _host_consts(self) -> tuple:
+        """What the decode program is told of each slot's request, sent up
+        once a seat, in the program's order."""
+        return tuple(getattr(self, name) for name in self._const_names)
 
     def _decode_step(self) -> None:
         """Fetch and deliver one decode chunk: the one in flight, or one
@@ -1812,14 +2149,10 @@ class ServingEngine:
                 # np.array (copy): zero-copy views of jax buffers are
                 # read-only, and _admit mutates these in place when it
                 # seats the next request
-                off, tok, active, remaining = chunk["carry"]
-                self._offsets = np.array(off)
-                self._last_tok = np.array(tok)
-                self._active = np.array(active)
-                self._remaining = np.array(remaining)
-                toks = np.asarray(chunk["toks"])           # [n_inner, S]
-                was_active = np.asarray(chunk["was_active"])
-                hits = np.asarray(chunk["hits"])
+                for name, a in zip(self._carry_names, chunk["carry"]):
+                    setattr(self, name, np.array(a))
+                outs = {name: np.asarray(chunk[name])
+                        for name in self._out_names}
                 stats = {name: float(v)
                          for name, v in chunk["stats"].items()}
             self._fetch_end = fetch.t1
@@ -1848,9 +2181,11 @@ class ServingEngine:
         self._admit_ms, self._prefill_ms = None, []   # told once (drain()
         #                               dispatches without a step() before it)
         with tr.boundary("serve.emit") as emit:
-            self._emit_decoded(toks, was_active, hits, spans_ms,
-                               chunk["host_gap_ms"], emit, stats,
-                               chunk["ahead"])
+            deliver = (self._emit_decoded if self._diffusion is None
+                       else self._emit_block_decoded)
+            deliver(**outs, spans_ms=spans_ms,
+                    host_gap_ms=chunk["host_gap_ms"], emit=emit, stats=stats,
+                    ahead=chunk["ahead"])
         if self._inflight is not None and not self._active.any():
             # every slot ended at an EOS: the chunk enqueued ahead ran with
             # all of them masked. Fetched now, so that an engine with no
@@ -1890,7 +2225,79 @@ class ServingEngine:
                     self._slot_req[slot] = None
                     self._kv.release(slot)
                     self._finish(req, now)
-        emitted = int(was_active.sum())
+        self._record_dispatch(
+            was_active, int(was_active.sum()), spans_ms, host_gap_ms, emit,
+            stats, ahead,
+            # positions held by the slots still live after the dispatch:
+            # what the next step's attention reads
+            contexts=self._offsets[self._active].tolist())
+
+    def _emit_block_decoded(self, blocks, was_active, commits, unmasked,
+                            draws, confs, spans_ms, host_gap_ms, emit, stats,
+                            ahead) -> None:
+        """`_emit_decoded` for a dispatch of block steps: a forward in which
+        a slot committed hands its request the block's tokens (past the
+        given ones, cut to the budget and after an end token) where a token
+        step hands it one. `blocks` [n_inner, S, B] are the blocks as each
+        forward left them."""
+        import numpy as np
+
+        n_inner, emitted, now = blocks.shape[0], 0, time.perf_counter()
+        B = self._diffusion.block_length
+        self._steps += n_inner
+        for j in range(n_inner):
+            alive_after = (was_active[j + 1] if j + 1 < n_inner
+                           else self._active)
+            for slot in np.nonzero(was_active[j])[0]:
+                req = self._slot_req[slot]
+                if req.block_states is not None:
+                    req.block_states.append({
+                        "offset": req.block_offset,
+                        "block": blocks[j, slot].tolist(),
+                        "committed": bool(commits[j, slot]),
+                        "draws": draws[j, slot].tolist(),
+                        "confidences": confs[j, slot].tolist()})
+                if not commits[j, slot]:
+                    continue
+                new = blocks[j, slot, req.given_in_block:].tolist()
+                req.given_in_block = 0
+                req.block_offset += B
+                new = new[:req.max_new_tokens - len(req.tokens)]
+                if req.eos_token_id is not None and req.eos_token_id in new:
+                    new = new[:new.index(req.eos_token_id) + 1]
+                    req.finish_reason = "eos"
+                req.tokens.extend(new)
+                emitted += len(new)
+                if req.first_token_ts is None:
+                    req.first_token_ts = now
+                if not alive_after[slot]:     # retired at this forward
+                    req.finish_reason = req.finish_reason or "length"
+                    self._slot_req[slot] = None
+                    self._kv.release(slot)
+                    self._finish(req, now)
+        from ..core import monitor
+
+        counts = {"forwards": int(was_active.sum()),
+                  "blocks_committed": int(commits.sum()),
+                  "positions_unmasked": int(unmasked.sum())}
+        self._forwards += counts["forwards"]
+        self._blocks += counts["blocks_committed"]
+        self._unmasked += counts["positions_unmasked"]
+        self._block_tokens += emitted
+        for name, n in counts.items():
+            monitor.stat("serving." + name).increase(n)
+        self._record_dispatch(
+            was_active, emitted, spans_ms, host_gap_ms, emit, stats, ahead,
+            **counts,
+            # positions the live slots' next forward reads: what they hold
+            # and the block's own
+            contexts=(self._offsets[self._active] + B).tolist())
+
+    def _record_dispatch(self, was_active, emitted: int, spans_ms,
+                         host_gap_ms, emit, stats, ahead, **counts) -> None:
+        """Count a delivered dispatch and write its `serve_step` sink
+        record; `counts` are the fields only its kind of step has."""
+        n_inner = was_active.shape[0]
         self._count_tokens(emitted)
         from ..core import monitor
 
@@ -1934,9 +2341,7 @@ class ServingEngine:
                 "host_gap_ms": host_gap_ms,
                 # enqueued while the dispatch before it was still in flight
                 "ahead": ahead,
-                # positions held by the slots still live after the dispatch:
-                # what the next step's attention reads
-                "contexts": self._offsets[self._active].tolist(),
+                **counts,
                 **stats,
                 **{name: round(v, 4) for name, v in gauges.items()},
             }

@@ -283,7 +283,12 @@ class ReplicaRouter:
                             max_new_tokens=req.max_new_tokens,
                             temperature=req.temperature, top_k=req.top_k,
                             top_p=req.top_p, eos_token_id=req.eos_token_id,
-                            seed=req.seed, tenant=req.tenant)
+                            seed=req.seed, tenant=req.tenant,
+                            # a block-diffusion request keeps its schedule
+                            **{k: getattr(req, k) for k in (
+                                "denoising_steps", "remasking",
+                                "confidence_threshold")
+                               if getattr(req, k, None) is not None})
                 for req in requeue]
 
     def drained(self, name: str) -> bool:

@@ -147,6 +147,30 @@ def sample_tokens(logits, keys, temperature, top_k, top_p):
         return jnp.where(is_greedy, greedy, sampled.astype(jnp.int32))
 
 
+def sample_tokens_with_prob(logits, keys, temperature, top_k, top_p):
+    """`sample_tokens`, and beside each token its probability under the
+    distribution it was drawn from: [n, V] logits -> (int32 [n], float32
+    [n]). A sampled row's distribution is the softmax of its tempered,
+    filtered logits; a greedy row (temperature 0) draws the argmax, and its
+    probability is that of the plain softmax of its logits (a delta would
+    say 1 of every row). What generation by diffusion ranks positions by."""
+    with jax.named_scope("sample"):
+        logits = jnp.asarray(logits, jnp.float32)
+        temperature = jnp.asarray(temperature, jnp.float32)
+        is_greedy = temperature == 0.0
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        # a greedy row at temperature 1 and no filter: its own logits
+        scaled = logits / jnp.where(is_greedy, 1.0,
+                                    jnp.maximum(temperature, 1e-6))[:, None]
+        filtered = filter_topk_topp(scaled, jnp.where(is_greedy, 0, top_k),
+                                    jnp.where(is_greedy, 1.0, top_p))
+        sampled = jax.vmap(jax.random.categorical)(keys, filtered)
+        tok = jnp.where(is_greedy, greedy, sampled.astype(jnp.int32))
+        drawn = jnp.take_along_axis(filtered, tok[:, None], axis=-1)[:, 0]
+        prob = jnp.exp(drawn - jax.nn.logsumexp(filtered, axis=-1))
+        return tok, prob
+
+
 def request_key(seed, position, base=None):
     """Deterministic per-(request, position) PRNG key: the token emitted at
     sequence position p for a request with seed s is sampled with

@@ -19,9 +19,10 @@ any weight-shaped convert signals suspect 1.
 
 Usage: python tools/decode_hlo_probe.py [--model tiny|base] [--device cpu]
 
-`--serving CONFIG` (gpt2-large, trinity-mini, olmo-hybrid-7b, deepseek-v2)
-reads another program instead: `ServingEngine`'s decode chunk
-(`serve.decode_sample`) of a benchmark configuration at its cell's settings,
+`--serving CONFIG` (gpt2-large, trinity-mini, olmo-hybrid-7b, deepseek-v2,
+sdar-30b-a3b) reads another program instead: `ServingEngine`'s decode chunk
+(`serve.decode_sample`; for sdar-30b-a3b the block-step program) of a
+benchmark configuration at its cell's settings,
 compiled for a DESCRIBED TPU
 v5e as benchmarks/rehearse_*.py compile it. It prints where the slot cache
 crosses the program's boundary: each kind of cache argument with its entry
@@ -53,6 +54,7 @@ _SERVING = {   # configuration -> (its decode cell, the runner that builds it)
     "trinity-mini": ("serve-trinity-mini-decode", "serve_afmoe"),
     "olmo-hybrid-7b": ("serve-olmo-hybrid-decode", "serve_hybrid"),
     "deepseek-v2": ("serve-deepseek-v2-decode", "serve_deepseek"),
+    "sdar-30b-a3b": ("serve-sdar-30b-a3b-diffusion", "serve_sdar"),
 }
 def serving(config, slots, dump=None, rung=0):
     """Compile `serve.decode_sample` of a benchmark configuration (or, with
@@ -111,10 +113,24 @@ def serving(config, slots, dump=None, rung=0):
     with paddle.amp.auto_cast(dtype="bfloat16"):
         eng = ServingEngine(model, **kw)
         cache = on_chip(eng.slot_cache.args(), lead=slots)
-        if rung:
-            def scalar(dtype):
-                return jax.ShapeDtypeStruct((), dtype, sharding=chip)
+        def scalar(dtype):
+            return jax.ShapeDtypeStruct((), dtype, sharding=chip)
 
+        if getattr(model, "generation", None) is not None:
+            # a model that generates by diffusion over blocks: its own
+            # prefill and its block-step decode program
+            if rung:
+                lowered = eng._build_block_prefill(rung).lower(
+                    on_chip(eng._params), *cache,
+                    jax.ShapeDtypeStruct((1, rung), jnp.int64, sharding=chip),
+                    scalar(jnp.int32), scalar(jnp.int32))
+            else:
+                lowered = eng._build_block_decode("sample").lower(
+                    on_chip(eng._params), *cache, *on_chip(tuple(
+                        jnp.asarray(a) for a in (*eng._host_carry(),
+                                                 *eng._host_consts())),
+                        lead=slots))
+        elif rung:
             lowered = eng._build_prefill(rung).lower(
                 on_chip(eng._params), *cache,
                 jax.ShapeDtypeStruct((1, rung), jnp.int64, sharding=chip),

@@ -1,8 +1,10 @@
 """Take the digests of the serving programs again: sha256[:16] of the
 StableHLO text of every prefill rung and both decode programs of a tiny
-GPT-2, Trinity (`afmoe`) and Olmo-Hybrid `ServingEngine`, as
+GPT-2, Trinity (`afmoe`), Olmo-Hybrid, DeepSeek-V2 and SDAR (`sdar`: its
+block prefills and block-step decode programs) `ServingEngine`, as
 tests/test_deepseek_v2.py::test_the_other_families_serving_programs_are_the_parents
-compares them with tests/data/serving_program_digests.json.
+and tests/test_sdar.py compare them with
+tests/data/serving_program_digests.json.
 
     JAX_PLATFORMS=cpu python tools/serving_program_digests.py            # print
     JAX_PLATFORMS=cpu python tools/serving_program_digests.py --write AT # and
@@ -30,13 +32,18 @@ ENGINE = dict(slot_count=3, ladder=(8, 16), max_seq_len=48, max_new_cap=8,
 
 def models():
     """family -> (the seed its weights are drawn from, its constructor)."""
-    from paddle_tpu.models import (AfmoeForCausalLM, GPTForPretraining,
-                                   OlmoHybridForCausalLM, afmoe_tiny,
-                                   gpt_tiny, olmo_hybrid_tiny)
+    from paddle_tpu.models import (AfmoeForCausalLM, DeepseekV2ForCausalLM,
+                                   GPTForPretraining, OlmoHybridForCausalLM,
+                                   SdarForCausalLM, afmoe_tiny,
+                                   deepseek_v2_tiny, gpt_tiny,
+                                   olmo_hybrid_tiny, sdar_tiny)
 
     return {"gpt": (0, lambda: GPTForPretraining(gpt_tiny())),
             "afmoe": (2, lambda: AfmoeForCausalLM(afmoe_tiny())),
-            "olmo": (4, lambda: OlmoHybridForCausalLM(olmo_hybrid_tiny()))}
+            "olmo": (4, lambda: OlmoHybridForCausalLM(olmo_hybrid_tiny())),
+            "sdar": (6, lambda: SdarForCausalLM(sdar_tiny())),
+            "deepseek": (8, lambda: DeepseekV2ForCausalLM(
+                deepseek_v2_tiny()))}
 
 
 def program_texts(eng):
@@ -49,6 +56,17 @@ def program_texts(eng):
         return jnp.zeros((s,), dtype)
 
     out = {}
+    if getattr(eng.model, "generation", None) is not None:
+        # a model that generates by diffusion over blocks: its own programs
+        for rung in eng.ladder:
+            out[f"prefill{rung}"] = eng._build_block_prefill(rung).lower(
+                eng._params, *kv.args(), jnp.zeros((1, rung), jnp.int64),
+                jnp.int32(4), jnp.int32(0)).as_text()
+        for family in ("greedy", "sample"):
+            out[f"decode_{family}"] = eng._build_block_decode(family).lower(
+                eng._params, *kv.args(), *eng._host_carry(),
+                *eng._host_consts()).as_text()
+        return out
     for rung in eng.ladder:
         out[f"prefill{rung}"] = eng._build_prefill(rung).lower(
             eng._params, *kv.args(), jnp.zeros((1, rung), jnp.int64),
@@ -79,7 +97,7 @@ def digests(family: str) -> dict:
 
 def main(argv) -> int:
     got = {}
-    for family in ("afmoe", "gpt", "olmo"):
+    for family in ("afmoe", "deepseek", "gpt", "olmo", "sdar"):
         got.update(digests(family))
     got = dict(sorted(got.items()))
     if "--write" in argv:
