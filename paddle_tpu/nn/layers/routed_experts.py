@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.tensor import Tensor
+from ...observability import metrics
 from .. import initializer as I
 from ..layer import Layer
 
@@ -82,6 +83,25 @@ class _Router(Layer):
         super().__init__(dtype="float32")
         self.weight = self.create_parameter((hidden, num_experts),
                                             default_initializer=NormalInto(std))
+
+
+def _swiglu(xs, ex, sizes, num_experts):
+    """The held experts' SwiGLU over `xs` sorted by expert: one Pallas call
+    where `grouped_experts.takes_kernel` allows (a band of mean rows an
+    expert, on a TPU, in a single-device program), else three `ragged_dot`
+    calls. `moe.calls.ragged_dot` / `moe.calls.grouped_kernel` count the
+    forms traced."""
+    # imported at the call: the kernels' toolkit takes over a second
+    from ...ops.pallas import grouped_experts as ge
+    w = (ex.w_gate._data, ex.w_up._data, ex.w_down._data)
+    rows, hidden = xs.shape
+    form = ("grouped_kernel" if ge.takes_kernel(
+        rows, num_experts, hidden, w[0].shape[2], xs.dtype, w[0].dtype)
+        else "ragged_dot")
+    metrics.default_registry().counter(
+        "moe.calls." + form, "routed experts' SwiGLU traced, by form").inc()
+    return (ge.swiglu if form == "grouped_kernel" else ge.plain)(
+        xs, *w, sizes)
 
 
 class RoutedExperts(Layer):
@@ -184,10 +204,7 @@ class RoutedExperts(Layer):
             sizes = jax.lax.dynamic_slice_in_dim(load, self.first, self.count)
             xs = jnp.take(m, order // k, axis=0)               # [rows * k, h]
         with jax.named_scope("experts"):
-            sizes = sizes.astype(jnp.int32)
-            a = jax.nn.silu(jax.lax.ragged_dot(xs, ex.w_gate._data, sizes)) \
-                * jax.lax.ragged_dot(xs, ex.w_up._data, sizes)
-            y = jax.lax.ragged_dot(a.astype(xs.dtype), ex.w_down._data, sizes)
+            y = _swiglu(xs, ex, sizes.astype(jnp.int32), self.num_experts)
         with jax.named_scope("combine"):
             # back to token order, then the weighted sum of a token's k rows
             y = jnp.take(y, jnp.argsort(order), axis=0).reshape(rows, k, -1)

@@ -29,9 +29,10 @@ crosses the program's boundary: each kind of cache argument with its entry
 layout and the layout the same array has inside the `while`, the cache-sized
 `copy` / `copy-start` instructions outside and inside the loop (count and
 bytes), the Mosaic kernels the program holds (`mosaic_calls`: the grouped
-matmuls', for deepseek-v2 the absorbed core's, for gpt2-large and
-olmo-hybrid-7b the full layers' core, ops/pallas/slot_decode.py) and
-`memory_analysis()`.
+matmuls', XLA's `ragged_dot` or, in the band of rows an expert,
+ops/pallas/grouped_experts.py (`moe_calls` counts each form traced); for
+deepseek-v2 the absorbed core's, for gpt2-large and olmo-hybrid-7b the full
+layers' core, ops/pallas/slot_decode.py) and `memory_analysis()`.
 It loads the TPU's compiler library, which one process holds at a time: run
 it by hand, one configuration a process, never from a test. `--slots N` compiles for another slot count than the
 cell's (a compile the chip's memory refuses is reported, not raised);
@@ -89,8 +90,16 @@ def serving(config, slots, dump=None, rung=0):
     routed_experts._draw = lambda key, shape, std, dtype: jnp.zeros(shape, dtype)
     # the program is compiled for the chip, so it holds the chip's kernels:
     # a trace here sees the CPU backend and would take the plain forms
-    from paddle_tpu.ops.pallas import latent_decode, slot_decode
+    from paddle_tpu.observability import metrics
+    from paddle_tpu.ops.pallas import (grouped_experts, latent_decode,
+                                       slot_decode)
     latent_decode._target = slot_decode._target = lambda: "mosaic"
+    grouped_experts._target = lambda: "mosaic"
+    moe_forms = ("ragged_dot", "grouped_kernel")
+
+    def moe_calls():
+        return [metrics.default_registry().counter("moe.calls." + f).value
+                for f in moe_forms]
     build = importlib.import_module(f"benchmarks.runners.{runner}").build_model
     model = build(load("configs", cell["config"]), 0)
     model.eval()
@@ -112,6 +121,7 @@ def serving(config, slots, dump=None, rung=0):
     # models hold bf16 weights and the scope changes nothing for them
     with paddle.amp.auto_cast(dtype="bfloat16"):
         eng = ServingEngine(model, **kw)
+        before = moe_calls()
         cache = on_chip(eng.slot_cache.args(), lead=slots)
         def scalar(dtype):
             return jax.ShapeDtypeStruct((), dtype, sharding=chip)
@@ -145,7 +155,10 @@ def serving(config, slots, dump=None, rung=0):
     out = {"config": config, "slots": slots,
            "program": (f"serve.prefill_b{rung}" if rung
                        else "serve.decode_sample"),
-           "steps_per_dispatch": eng.steps_per_dispatch}
+           "steps_per_dispatch": eng.steps_per_dispatch,
+           # the routed experts' SwiGLU traced in this program, by form
+           "moe_calls": dict(zip(moe_forms, (
+               n - b for n, b in zip(moe_calls(), before))))}
     try:
         compiled = lowered.compile()
     except Exception as e:     # the chip's memory refuses the program
