@@ -319,11 +319,16 @@ class ServingEngine:
         # returned (None once a seat has changed the host's copy) and the
         # per-slot constants as sent at the last seat; and how many decode
         # dispatches there were, how many of them enqueued ahead of a fetch
+        # and how many sampled with the filter's full search where its
+        # narrowed form exists (`sampling.takes_narrowed`)
         self._inflight = None
         self._carry = None
         self._consts = None
         self._decode_dispatches = 0
         self._decode_ahead = 0
+        self._sample_full = 0
+        from .sampling import takes_narrowed
+        self._sample_narrows = takes_narrowed(cfg.vocab_size)
         # elastic drain state (distributed/membership.py protocol): once
         # draining, submit() refuses and _admit() stops pulling the queue —
         # active slots run to completion, then the replica retires
@@ -730,7 +735,13 @@ class ServingEngine:
         """Counts of this engine: `steps` (decode steps delivered),
         `decode_dispatches` and `decode_ahead_share` (plain decode dispatches,
         and the share of them enqueued before the dispatch ahead of them was
-        fetched: `_decode_step`), `completed`, `queued`, `active_slots`,
+        fetched: `_decode_step`), `sample_full_dispatches` (decode
+        dispatches in which a seated slot's sampling parameters send the
+        top-k / top-p filter down its full search, `sampling.narrowed_exact`,
+        where the vocabulary holds the narrowed form; counted per dispatch
+        from the host's slots, an upper bound: a slot that retires inside
+        the dispatch counts),
+        `completed`, `queued`, `active_slots`,
         `draining`, the ladder and executable counts, the cache's layout and
         bytes; with a draft model the verify counts, on the paged layout the
         pool's and the prefix cache's; for a model that generates by diffusion
@@ -746,6 +757,7 @@ class ServingEngine:
             "decode_dispatches": self._decode_dispatches,
             "decode_ahead_share": (self._decode_ahead
                                    / max(1, self._decode_dispatches)),
+            "sample_full_dispatches": self._sample_full,
             "completed": len(self._completed),
             "queued": len(self._queue),
             "active_slots": int(self._active.sum()),
@@ -2110,6 +2122,8 @@ class ServingEngine:
         if ahead:
             self._decode_ahead += 1
             monitor.stat("serving.decode_ahead").increase()
+        if family == "sample" and self._sample_narrows:
+            self._note_sample_form()
         # the time the decode program had nothing enqueued: from the end of
         # the last dispatch's fetch to this dispatch's enqueue; none when
         # the chunk before this one was still in flight
@@ -2118,6 +2132,19 @@ class ServingEngine:
         return {"carry": self._carry, **dict(zip(self._out_names, outs)),
                 "stats": stats, "family": family, "ahead": ahead,
                 "dispatch_ms": dispatch.ms, "host_gap_ms": host_gap_ms}
+
+    def _note_sample_form(self) -> None:
+        """Count a decode dispatch whose seated sampling slots do not all
+        ask for what the filter's narrowed search answers exactly: such a
+        dispatch runs the full search. From the slots' own parameters: the
+        program reports nothing of it."""
+        from ..core import monitor
+        from .sampling import narrowed_exact
+
+        live = self._active & (self._temps > 0)
+        if not narrowed_exact(self._topk[live], self._topp[live]).all():
+            self._sample_full += 1
+            monitor.stat("serving.sample_full_dispatches").increase()
 
     def _host_carry(self) -> tuple:
         """The host's copy of the decode program's carry, in its order."""

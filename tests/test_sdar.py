@@ -145,7 +145,8 @@ def test_config_refuses_by_name_what_it_does_not_compute():
 
 
 # --------------------------------------------------------------- the sampler
-def test_sample_tokens_with_prob_is_the_draw_and_its_probability():
+def test_sample_tokens_with_prob_is_the_draw_and_its_probability(
+        monkeypatch):
     rng = np.random.default_rng(2)
     logits = jnp.asarray(rng.normal(size=(6, 64)) * 3, jnp.float32)
     keys = jax.random.split(jax.random.key(5), 6)
@@ -160,6 +161,23 @@ def test_sample_tokens_with_prob_is_the_draw_and_its_probability():
                               int(top_k[i]), float(top_p[i]))
         assert abs(float(prob[i]) - float(want)) < 1e-5
         assert float(prob[i]) > 0
+    # at SDAR's width the filter searches the candidates (every row asks
+    # for top_k <= 64 or nothing): the draws and probabilities of the
+    # search over the whole row, and the reference's
+    from paddle_tpu.serving import sampling
+
+    wide = jnp.asarray(rng.normal(size=(6, 151_936)) * 3, jnp.float32)
+    tok, prob = sample_tokens_with_prob(wide, keys, temps, top_k, top_p)
+    with monkeypatch.context() as m:
+        m.setattr(sampling, "takes_narrowed", lambda vocab: False)
+        was_tok, was = sample_tokens_with_prob(wide, keys, temps, top_k,
+                                               top_p)
+    np.testing.assert_array_equal(tok, was_tok)
+    np.testing.assert_allclose(prob, was, rtol=1e-6)
+    for i in range(6):
+        want = ref.confidence(wide[i], int(tok[i]), float(temps[i]),
+                              int(top_k[i]), float(top_p[i]))
+        assert abs(float(prob[i]) - float(want)) < 1e-5 * float(want)
 
 
 def test_unmask_chooses_as_the_reference_selects():

@@ -14,7 +14,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.core import monitor
-from paddle_tpu.models import GPTForPretraining, gpt_tiny
+from paddle_tpu.models import GPTConfig, GPTForPretraining, gpt_tiny
 from paddle_tpu.observability import InMemorySink
 from paddle_tpu.serving import (
     ServingEngine, bucket_for, clip_ladder, filter_topk_topp, sample_tokens,
@@ -229,13 +229,17 @@ def legacy_mask(row, top_k, top_p):
     return np.isinf(row), near
 
 
-_WIDTHS = (50, 50_304, 200_192)
+# 50,304, 151,936 and 200,192 take the narrowed form
+# (sampling.takes_narrowed), 50 does not
+_WIDTHS = (50, 50_304, 151_936, 200_192)
 
 
 def _filter_rows():
     """(id, vocab, kind, top_k, top_p): the four cases this test always
-    had, the grid of ISSUE 29 at three widths, and the rows a selection
-    could get wrong where a sort cannot."""
+    had, the grid of top_k x top_p at each width, the rows a selection could
+    get wrong where a sort cannot, and those the narrowed search could get wrong
+    where the whole row's cannot (top_k 65 and top_k 0 with top_p < 1 are
+    the full search's at run time)."""
     rows = [(f"v50-randn-k{k}-p{p}", 50, "randn", k, p)
             for k, p in ((0, 1.0), (10, 1.0), (0, 0.7), (10, 0.7))]
     for v in _WIDTHS:
@@ -252,6 +256,17 @@ def _filter_rows():
             (f"v{v}-one_hot", v, "one_hot", 50, 0.9),
             (f"v{v}-constant", v, "constant", 7, 0.5),
         ]
+        if v > 128:
+            rows += [
+                (f"v{v}-ties_spread", v, "ties_spread", 50, 1.0),
+                (f"v{v}-ties_spread-p0.9", v, "ties_spread", 50, 0.9),
+                (f"v{v}-one_chunk", v, "one_chunk", 50, 0.9),
+                (f"v{v}-one_chunk-k64", v, "one_chunk", 64, 1.0),
+                (f"v{v}-randn-k64-p0.9", v, "randn", 64, 0.9),
+                (f"v{v}-randn-k65-p0.9", v, "randn", 65, 0.9),
+                (f"v{v}-mask_column", v, "mask_column", 50, 0.9),
+                (f"v{v}-beside_greedy", v, "beside_greedy", 7, 0.5),
+            ]
     return rows
 
 
@@ -275,6 +290,21 @@ def _filter_row(vocab, kind):
         row[vocab // 3] = 0.0
     elif kind == "constant":
         row[:] = 1.25
+    elif kind == "ties_spread":
+        # a bfloat16 head's logits, the 50th value tied in about 300 more
+        # columns, each in a chunk of 128 of its own: more than 64 chunks
+        import jax.numpy as jnp
+
+        row = np.asarray(jnp.asarray(row).astype(jnp.bfloat16), np.float32)
+        kth = np.sort(row)[-50]
+        at = (rng.choice(vocab // 128, 300, replace=False) * 128
+              + rng.randint(0, 128, 300))
+        row[at[row[at] < kth]] = kth
+    elif kind == "one_chunk":       # the top 64 in the chunk at 3 x 128
+        row[384:448] = 20 + rng.rand(64)
+    elif kind == "mask_column":     # the mask token's column
+        row[151_669 % vocab] = -np.inf
+        row[151_669 % vocab - 1] = row.max() + 1
     return row
 
 
@@ -290,47 +320,94 @@ def test_filter_topk_topp_matches_legacy_reference(vocab, kind, top_k,
     within 1e-5 of top_p."""
     import jax.numpy as jnp
 
+    from paddle_tpu.serving import sampling
+
     row = _filter_row(vocab, kind)
-    k = jnp.asarray([top_k], jnp.int32)
-    p = jnp.asarray([top_p], jnp.float32)
-    got = np.asarray(filter_topk_topp(jnp.asarray(row[None]), k, p))[0]
+    rows, ks, ps = row[None], [top_k], [top_p]
+    if kind == "beside_greedy":     # greedy rows ask the filter for nothing
+        rows = np.stack([row[::-1], row, row[::2].repeat(2)[:vocab]])
+        ks, ps = [0, top_k, 0], [1.0, top_p, 1.0]
+    k = jnp.asarray(ks, jnp.int32)
+    p = jnp.asarray(ps, jnp.float32)
+    out = np.asarray(filter_topk_topp(jnp.asarray(rows), k, p))
+    got = out[ks.index(top_k)]
+    if kind == "beside_greedy":
+        np.testing.assert_array_equal(out[[0, 2]], rows[[0, 2]])
+    if sampling.takes_narrowed(vocab) and sampling.narrowed_exact(
+            top_k, top_p):      # the narrowed search by itself
+        cut = sampling._cutoff(jnp.asarray(rows, jnp.float32), k, p,
+                               narrowed=True)
+        np.testing.assert_array_equal(
+            np.isinf(out), (rows < np.asarray(cut)[:, None]) | np.isinf(rows))
     masked, near = legacy_mask(row, top_k, top_p)
     kept = ~np.isinf(got)
     np.testing.assert_array_equal(got[kept], row[kept])
     np.testing.assert_array_equal(np.isinf(got)[~near], masked[~near])
     assert near.sum() <= 4            # the exemption is a few columns
     assert kept.any()
-    was = np.asarray(sorted_filter_topk_topp(jnp.asarray(row[None]), k, p))
+    was = np.asarray(sorted_filter_topk_topp(
+        jnp.asarray(row[None]), jnp.asarray([top_k], jnp.int32),
+        jnp.asarray([top_p], jnp.float32)))
     np.testing.assert_array_equal(np.isinf(got)[~near],
                                   np.isinf(was[0])[~near])
     if kind == "ties_at_kth" and top_p >= 1.0:
         assert kept.sum() == 12         # 7 and the five ties: all stay
+    if kind == "ties_spread" and top_p >= 1.0:
+        assert kept.sum() > 340         # 50 and the ties: all stay
+    if kind == "one_chunk" and top_p >= 1.0:
+        assert (np.flatnonzero(kept) == np.arange(384, 448)).all()
     if kind == "ties_at_nucleus":
         assert kept.sum() == 5
     if kind in ("one_hot", "constant"):
         assert kept.sum() == (1 if kind == "one_hot" else vocab)
 
 
-@pytest.mark.parametrize("vocab", _WIDTHS)
-def test_sample_tokens_draws_what_the_sorted_filter_drew(vocab):
+@pytest.mark.parametrize(
+    "vocab,mix",
+    [pytest.param(v, "mixed", id=str(v)) for v in _WIDTHS + (25_600,)]
+    + [pytest.param(v, "narrow", id=f"{v}-narrow")
+       for v in (50_304, 151_936, 200_192, 25_600, 50_257)])
+def test_sample_tokens_draws_what_the_sorted_filter_drew(vocab, mix):
     """16 rows of mixed greedy / top-k / top-p / plain parameters with
     fixed seeds: the token drawn through the selection is the token
-    `categorical` draws over the sort-filtered row with the same key."""
+    `categorical` draws over the sort-filtered row with the same key.
+    `mixed` holds rows that send a wide row's filter down its full search
+    (top_k over 64, top_p alone); in `narrow` every row asks for the
+    narrowed search (0 < top_k <= 64, or nothing filtered). The form
+    traced is the width's: `sample.calls.narrowed` where the row is 64
+    chunks of 128 columns or more, `.full` below and off whole chunks
+    (50,257)."""
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.serving import request_key
+    from paddle_tpu.observability import metrics
+    from paddle_tpu.serving import request_key, sampling
+
+    def calls(form):
+        return metrics.default_registry().counter("sample.calls." + form).value
 
     rng = np.random.RandomState(11)
     logits = jnp.asarray((rng.randn(16, vocab) * 3).astype(np.float32))
     temps = jnp.asarray([0.0, 0.8, 1.0, 1.3] * 4, jnp.float32)
-    top_k = jnp.asarray([50, 50, 0, 0, 7, 1, 1000, vocab + 5] * 2, jnp.int32)
-    top_p = jnp.asarray([0.9] * 4 + [1.0] * 4 + [0.5] * 4 + [0.0, 0.3, 0.9,
-                                                             1.0],
-                        jnp.float32)
+    if mix == "mixed":
+        top_k = [50, 50, 0, 0, 7, 1, 1000, vocab + 5] * 2
+        top_p = [0.9] * 4 + [1.0] * 4 + [0.5] * 4 + [0.0, 0.3, 0.9, 1.0]
+    else:
+        top_k = [50, 50, 0, 64, 7, 1, 64, 2] * 2
+        top_p = [0.9] * 4 + [1.0] * 4 + [0.5] * 4 + [0.0, 0.3, 0.9, 1.0]
+        top_p[2] = top_p[10] = 1.0          # no top_k: nothing filtered
+    top_k = jnp.asarray(top_k, jnp.int32)
+    top_p = jnp.asarray(top_p, jnp.float32)
     keys = jax.vmap(request_key)(jnp.arange(16, dtype=jnp.int32) * 7 + 1,
                                  jnp.arange(16, dtype=jnp.int32) + 3)
+    sampled = np.asarray(temps) > 0       # a greedy row asks for nothing
+    assert sampling.narrowed_exact(np.where(sampled, top_k, 0), np.where(
+        sampled, top_p, 1.0)).all() == (mix == "narrow")
+    before = calls("narrowed"), calls("full")
     got = np.asarray(sample_tokens(logits, keys, temps, top_k, top_p))
+    wide = vocab % 128 == 0 and vocab >= 8_192
+    assert (calls("narrowed") - before[0], calls("full") - before[1]) == (
+        (1, 0) if wide else (0, 1))
     scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
     want = np.asarray(jax.vmap(jax.random.categorical)(
         keys, sorted_filter_topk_topp(scaled, top_k, top_p)))
@@ -341,11 +418,22 @@ def test_sample_tokens_draws_what_the_sorted_filter_drew(vocab):
     assert (got != greedy).sum() >= 4
 
 
-def test_engine_tokens_equal_the_sorted_filters(model, monkeypatch):
+def test_engine_tokens_equal_the_sorted_filters(monkeypatch):
     """An engine run of mixed requests emits the tokens of the same run
     with the sort-based oracle patched in for `filter_topk_topp`, from the
-    prefill program (first token) and the decode program (the rest)."""
+    prefill program (first token) and the decode program (the rest). The
+    tiny model's head is 8,192 columns wide, so its programs hold the
+    filter's narrowed form and its full search, and run both."""
+    from paddle_tpu.distributed.mesh import set_hybrid_communicate_group
     from paddle_tpu.serving import sampling
+
+    set_hybrid_communicate_group(None)
+    paddle.seed(0)
+    model = GPTForPretraining(GPTConfig(
+        vocab_size=8192, hidden_size=128, num_layers=2, num_heads=4,
+        max_seq_len=128))
+    model.eval()
+    assert sampling.takes_narrowed(model.config.vocab_size)
 
     rng = np.random.RandomState(12)
     prompts = [rng.randint(0, 1024, (n,)).astype(np.int64)
@@ -364,6 +452,10 @@ def test_engine_tokens_equal_the_sorted_filters(model, monkeypatch):
                 for p, kw in zip(prompts, configs)]
         eng.run()
         assert all(r.done for r in reqs)
+        # top_p alone, top_k 2000: dispatches the full search answers;
+        # the first three requests' the narrowed search
+        st = eng.stats()
+        assert 0 < st["sample_full_dispatches"] < st["decode_dispatches"]
         return [r.tokens for r in reqs]
 
     got = run()
