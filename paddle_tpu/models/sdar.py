@@ -34,7 +34,9 @@ the noise schedule, which `config.json` does not give).
 Serving: `kv_cache_spec` declares a `full` cache a layer; the attention
 takes the handle it is given, calls `positions` and `update` once and masks
 what comes back by the block-causal test. A prefill over a fresh cache
-attends the chunk's own keys a block of queries at a time.
+attends the chunk's own keys a block of queries at a time; a block step
+over the slot cache goes through ops/slot_attention.decode_core, whose
+kernel reads a slot's rows only to the end of its block.
 """
 from __future__ import annotations
 
@@ -44,6 +46,7 @@ import jax.numpy as jnp
 from .. import nn
 from ..core.tensor import Tensor
 from ..nn.kv_cache import BlockDiffusion, KVLayerSpec, block_causal_mask
+from ..ops import slot_attention
 from .afmoe import _QUERY_BLOCK, _Norm, _Weight, _attend, _rope
 
 
@@ -209,8 +212,13 @@ class SdarAttention(nn.Layer):
             if alone:
                 o = self._prefill_core(q, k, v)
             else:
-                o = _attend(q, kc, vc,
-                            block_causal_mask(held, pos, self.block))
+                # a block step's slots sit on a block's edge (the engine
+                # commits a block whole), so each query sees the rows before
+                # the offset `update` returned: the kernel's bound
+                o = slot_attention.decode_core(q, cache, block=self.block)
+                if o is None:
+                    o = _attend(q, kc, vc,
+                                block_causal_mask(held, pos, self.block))
         with jax.named_scope("out"):
             out = self.o_proj(o.reshape(b, s, self.num_heads * self.head_dim))
         return out if cache is None else (out, cache)

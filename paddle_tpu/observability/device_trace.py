@@ -34,7 +34,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 # serving/engine.py, serving/kv_state.py, serving/sampling.py) ------------
 ROOTS = ("prefill", "decode")                       # the serving programs
 KERNELS = ("flash_fwd", "flash_bwd", "flash_bwd_dkv", "flash_bwd_dq",
-           "latent_decode", "slot_decode", "grouped_experts")
+           "latent_decode", "slot_decode", "block_decode", "grouped_experts")
 SCOPES = frozenset(ROOTS + KERNELS + (
     "embed", "attn", "qkv", "core", "out", "cache_write", "mlp",
     "final_norm", "lm_head_loss", "lm_head", "sample", "grad_clip",

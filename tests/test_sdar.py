@@ -20,6 +20,8 @@ from paddle_tpu.core import monitor
 from paddle_tpu.models import (GPTForPretraining, SdarConfig,
                                SdarForCausalLM, gpt_tiny, sdar_tiny)
 from paddle_tpu.nn.kv_cache import BlockDiffusion, block_causal_mask
+from paddle_tpu.observability import metrics
+from paddle_tpu.ops.pallas import block_decode
 from paddle_tpu.serving import (ServingEngine, diffusion, sample_tokens,
                                 sample_tokens_with_prob)
 
@@ -383,6 +385,53 @@ def test_refusals_by_name(model):
         with pytest.raises(ValueError, match=next(iter(kw))):
             plain.submit([1, 2, 3], **kw)
     assert model.generation == BlockDiffusion(4, MASK)
+
+
+def test_the_block_kernel_forced_gives_the_plain_engines_blocks(
+        model, monkeypatch):
+    """Every block step's attention core through the kernel
+    (ops/pallas/block_decode.py, interpreted, in blocks of 16 of the 48
+    rows): greedy and sampled requests at different depths, the fourth
+    seated again in a slot the second left, hold the plain engine's tokens
+    and its block after every forward, with the same draws and confidences;
+    the slots hold the same rows; each block-step program traced counts one
+    kernel a layer."""
+    plans = [(9, 16, {}), (14, 12, dict(temperature=0.8, top_k=20,
+                                        top_p=0.9, seed=3)),
+             (5, 16, {}), (11, 8, {})]
+
+    def serve():
+        eng = ServingEngine(model, **ENGINE)
+        reqs = [eng.submit(prompt_of(n, 80 + n), max_new_tokens=new,
+                           record_blocks=True, **kw) for n, new, kw in plans]
+        eng.run()
+        assert all(r.done and r.finish_reason == "length" for r in reqs)
+        return eng, reqs
+
+    def count(form):
+        return metrics.default_registry().counter("attn.calls." + form).value
+
+    plain_eng, plain = serve()
+    monkeypatch.setattr(block_decode, "_target", lambda: "interpret")
+    monkeypatch.setattr(block_decode, "BLOCK_ROWS", 16)
+    before = count("block"), count("block_kernel")
+    eng, forced = serve()
+    traced = count("block") - before[0], count("block_kernel") - before[1]
+    layers = model.config.num_hidden_layers
+    assert traced[0] == traced[1] > 0 and traced[1] % layers == 0
+    for theirs, mine in zip(plain, forced):
+        assert mine.tokens == theirs.tokens
+        assert [(s["offset"], s["block"], s["committed"], s["draws"])
+                for s in mine.block_states] == [
+            (s["offset"], s["block"], s["committed"], s["draws"])
+            for s in theirs.block_states]
+        for a, b in zip(mine.block_states, theirs.block_states):
+            assert np.abs(np.asarray(a["confidences"])
+                          - np.asarray(b["confidences"])).max() <= 1e-5
+    for mine, theirs in zip(eng.slot_cache.k_stored + eng.slot_cache.v_stored,
+                            plain_eng.slot_cache.k_stored
+                            + plain_eng.slot_cache.v_stored):
+        assert float(jnp.abs(mine - theirs).max()) <= 1e-5
 
 
 # ------------------------------------------------------------- the programs

@@ -92,7 +92,8 @@ def _scope_literals():
                 found += [(os.path.join(d, f), m) for m in re.findall(
                     r"named_scope\(\s*[\"']([^\"']+)[\"']", text)]
                 if f in ("flash_attention.py", "latent_decode.py",
-                         "slot_decode.py", "grouped_experts.py"):
+                         "slot_decode.py", "block_decode.py",
+                         "grouped_experts.py"):
                     # a kernel's name is its scope
                     found += [(f, m) for m in re.findall(
                         r"\bname=\"(\w+)\"", text)]
@@ -300,6 +301,9 @@ def test_afmoe_serve_step_record_carries_the_experts_load():
     ("jit(step_chunk)/decode/while/body/closed_call/attn/core/"
      "jit(_call)/slot_decode/pallas_call",
      ("decode/attn/core", False, "slot_decode")),
+    ("jit(block_chunk)/decode/while/body/closed_call/attn/core/"
+     "jit(_call)/block_decode/pallas_call",
+     ("decode/attn/core", False, "block_decode")),
     ("jit(block_chunk)/decode/while/body/closed_call/moe/experts/"
      "custom_vjp_call/jit(_call)/grouped_experts/pallas_call",
      ("decode/moe/experts", False, "grouped_experts")),
@@ -1130,10 +1134,11 @@ def test_scopes_sum_to_busy_time(reduced):
 def test_kernels_are_found_by_name(reduced):
     # the probe was recorded from PR 26's program, whose d=64 model ran the
     # [b*h, s, d] kernels: the packed paths' `flash_bwd` is not in it, nor
-    # PR 36's `latent_decode`, PR 38's `slot_decode` and PR 40's
-    # `grouped_experts` (test_scope_of has their op paths)
+    # the later kernels `latent_decode`, `slot_decode`, `grouped_experts`
+    # and `block_decode` (test_scope_of has their op paths)
     assert set(reduced["by_kernel"]) == set(device_trace.KERNELS) - {
-        "flash_bwd", "latent_decode", "slot_decode", "grouped_experts"}
+        "flash_bwd", "latent_decode", "slot_decode", "grouped_experts",
+        "block_decode"}
     assert all(v > 0 for v in reduced["by_kernel"].values())
     core = reduced["by_scope"]["attn/core"]
     assert reduced["by_kernel"]["flash_fwd"] <= core["fwd"]

@@ -32,7 +32,9 @@ bytes), the Mosaic kernels the program holds (`mosaic_calls`: the grouped
 matmuls', XLA's `ragged_dot` or, in the band of rows an expert,
 ops/pallas/grouped_experts.py (`moe_calls` counts each form traced); for
 deepseek-v2 the absorbed core's, for gpt2-large and olmo-hybrid-7b the full
-layers' core, ops/pallas/slot_decode.py) and `memory_analysis()`.
+layers' core, ops/pallas/slot_decode.py, for sdar-30b-a3b the block step's
+core, ops/pallas/block_decode.py: `attn_calls` counts each form traced) and
+`memory_analysis()`.
 It loads the TPU's compiler library, which one process holds at a time: run
 it by hand, one configuration a process, never from a test. `--slots N` compiles for another slot count than the
 cell's (a compile the chip's memory refuses is reported, not raised);
@@ -91,15 +93,20 @@ def serving(config, slots, dump=None, rung=0):
     # the program is compiled for the chip, so it holds the chip's kernels:
     # a trace here sees the CPU backend and would take the plain forms
     from paddle_tpu.observability import metrics
-    from paddle_tpu.ops.pallas import (grouped_experts, latent_decode,
-                                       slot_decode)
+    from paddle_tpu.ops.pallas import (block_decode, grouped_experts,
+                                       latent_decode, slot_decode)
     latent_decode._target = slot_decode._target = lambda: "mosaic"
-    grouped_experts._target = lambda: "mosaic"
+    grouped_experts._target = block_decode._target = lambda: "mosaic"
     moe_forms = ("ragged_dot", "grouped_kernel")
+    attn_forms = ("slot", "slot_kernel", "block", "block_kernel")
 
     def moe_calls():
         return [metrics.default_registry().counter("moe.calls." + f).value
                 for f in moe_forms]
+
+    def attn_calls():
+        return [metrics.default_registry().counter("attn.calls." + f).value
+                for f in attn_forms]
     build = importlib.import_module(f"benchmarks.runners.{runner}").build_model
     model = build(load("configs", cell["config"]), 0)
     model.eval()
@@ -121,7 +128,7 @@ def serving(config, slots, dump=None, rung=0):
     # models hold bf16 weights and the scope changes nothing for them
     with paddle.amp.auto_cast(dtype="bfloat16"):
         eng = ServingEngine(model, **kw)
-        before = moe_calls()
+        before, attn_before = moe_calls(), attn_calls()
         cache = on_chip(eng.slot_cache.args(), lead=slots)
         def scalar(dtype):
             return jax.ShapeDtypeStruct((), dtype, sharding=chip)
@@ -158,7 +165,11 @@ def serving(config, slots, dump=None, rung=0):
            "steps_per_dispatch": eng.steps_per_dispatch,
            # the routed experts' SwiGLU traced in this program, by form
            "moe_calls": dict(zip(moe_forms, (
-               n - b for n, b in zip(moe_calls(), before))))}
+               n - b for n, b in zip(moe_calls(), before)))),
+           # the full layers' attention core traced in this program, by
+           # form: a decode step's (`slot`) or a block step's (`block`)
+           "attn_calls": dict(zip(attn_forms, (
+               n - b for n, b in zip(attn_calls(), attn_before))))}
     try:
         compiled = lowered.compile()
     except Exception as e:     # the chip's memory refuses the program
