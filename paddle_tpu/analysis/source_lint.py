@@ -27,6 +27,10 @@ model-cache     a file under ``paddle_tpu/models/`` writes an array in
                 whatever ``x`` is called: where a cache's row lives is
                 nn/kv_cache.py's to know, and a write that is not a cache's
                 (beam search's token table) is named in the baseline
+model-model     a file under ``paddle_tpu/models/`` imports an underscore
+                name from another model module: what two models share
+                lives in nn/ or ops/, so an edit to one model's file
+                changes no other model's program
 =============== ==========================================================
 
 Tracedness is syntactic: a function is traced when it is decorated with
@@ -224,6 +228,8 @@ class _FileLinter(ast.NodeVisitor):
             self._check_import(node, [a.name for a in node.names])
 
     def visit_ImportFrom(self, node: ast.ImportFrom) -> None:
+        if self._in_models:
+            self._check_sibling(node)
         if self._in_models and (node.level == 2 or (
                 node.level == 0 and (node.module or "").startswith(
                     "paddle_tpu"))):
@@ -231,6 +237,21 @@ class _FileLinter(ast.NodeVisitor):
             self._check_import(node, [base] if base and base != "paddle_tpu"
                                else [f"{base}.{a.name}".lstrip(".")
                                      for a in node.names])
+
+    def _check_sibling(self, node: ast.ImportFrom) -> None:
+        module = node.module or ""
+        if node.level == 0 and module.startswith("paddle_tpu.models."):
+            module, level = module[len("paddle_tpu.models."):], 1
+        else:
+            level = node.level
+        if level != 1 or not module:
+            return
+        for alias in node.names:
+            if alias.name.startswith("_"):
+                self._emit(node, "model-model", f"{module}.{alias.name}",
+                           f"a model imports {alias.name} from the model "
+                           f"{module}: what models share lives in nn/ or "
+                           f"ops/, under a public name")
 
     def _check_cache_write(self, node, target: ast.AST, how: str) -> None:
         if self._in_models:

@@ -25,9 +25,10 @@ run in float32. `forward(ids)` gives logits; training is not written.
 Serving: `kv_cache_spec` declares a `window` cache of `sliding_window` rows
 for a sliding layer and a `full` one for the rest, and each attention layer
 hands its new keys and values to the cache handle it is given
-(nn/kv_cache.py) and masks what comes back by the position each row holds:
-causal, and the window on a sliding layer. A prefill over a fresh cache
-attends the chunk's own keys blockwise instead.
+(nn/kv_cache.py) and what comes back to ops/slot_attention.core, which masks
+it by the position each row holds: causal, and the window on a sliding
+layer. A prefill over a fresh cache attends the chunk's own keys blockwise
+instead.
 """
 from __future__ import annotations
 
@@ -39,9 +40,8 @@ import jax.numpy as jnp
 from .. import nn
 from ..core.tensor import Tensor
 from ..nn.kv_cache import KVLayerSpec
-from ..nn.layers.routed_experts import NormalInto
-
-_QUERY_BLOCK = 512     # prefill attention runs this many queries at a time
+from ..nn.layers.decoder_parts import Projection, RMSNormF32, SwiGLU, rope
+from ..ops import slot_attention
 
 
 class AfmoeConfig:
@@ -138,58 +138,6 @@ def afmoe_tiny(**kw):
     return AfmoeConfig(**base)
 
 
-class _Weight(nn.Layer):
-    """A bias-free projection, weight [in, out]: y = x @ W."""
-
-    def __init__(self, fan_in, fan_out, dtype, std):
-        super().__init__(dtype=dtype)
-        self.weight = self.create_parameter(
-            (fan_in, fan_out), default_initializer=NormalInto(std))
-
-    def forward(self, x):
-        w = self.weight._data
-        return jnp.dot(x.astype(w.dtype), w)
-
-
-class _Norm(nn.Layer):
-    """RMSNorm in float32, the result in the input's dtype."""
-
-    def __init__(self, width, eps, dtype):
-        super().__init__(dtype=dtype)
-        self.eps = eps
-        self.weight = self.create_parameter(
-            (width,), default_initializer=nn.initializer.Constant(1.0))
-
-    def forward(self, x):
-        y = x.astype(jnp.float32)
-        y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + self.eps)
-        return (y * self.weight._data.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x, pos, theta):
-    """x [b, s, heads, d], pos [b or 1, s] absolute positions."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = pos.astype(jnp.float32)[..., None] * inv               # [b, s, d/2]
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[:, :, None, :]
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[:, :, None, :]
-    y = x.astype(jnp.float32)
-    rot = jnp.concatenate([-y[..., d // 2:], y[..., :d // 2]], -1)
-    return (y * cos + rot * sin).astype(x.dtype)
-
-
-def _attend(q, k, v, mask):
-    """q [b, s, kvh, g, d] against k, v [b, t, kvh, d] under mask
-    [b or 1, s, t]: query head (j, i) reads key head j. Scores and softmax
-    in float32."""
-    scores = jnp.einsum("bskgd,btkd->bkgst", q, k,
-                        preferred_element_type=jnp.float32)
-    scores = scores / math.sqrt(q.shape[-1])
-    scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
-    att = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    return jnp.einsum("bkgst,btkd->bskgd", att, v)
-
-
 class AfmoeAttention(nn.Layer):
     def __init__(self, config: AfmoeConfig, layer_type: str):
         super().__init__(dtype=config.dtype)
@@ -200,33 +148,15 @@ class AfmoeAttention(nn.Layer):
             else None
         self.rope_theta = c.rope_theta
         width, std = c.num_attention_heads * c.head_dim, c.initializer_range
-        self.q_proj = _Weight(c.hidden_size, width, c.dtype, std)
-        self.k_proj = _Weight(c.hidden_size, self.kv_heads * c.head_dim,
-                              c.dtype, std)
-        self.v_proj = _Weight(c.hidden_size, self.kv_heads * c.head_dim,
-                              c.dtype, std)
-        self.gate_proj = _Weight(c.hidden_size, width, c.dtype, std)
-        self.o_proj = _Weight(width, c.hidden_size, c.dtype, std)
-        self.q_norm = _Norm(c.head_dim, c.rms_norm_eps, c.dtype)
-        self.k_norm = _Norm(c.head_dim, c.rms_norm_eps, c.dtype)
-
-    def _prefill_core(self, q, k, v):
-        """Causal (and windowed) attention of a whole chunk whose first token
-        is position 0, a block of queries at a time against the keys it can
-        see, so that no [s, s] score matrix of all heads is alive."""
-        s = q.shape[1]
-        outs = []
-        for q0 in range(0, s, _QUERY_BLOCK):
-            q1 = min(s, q0 + _QUERY_BLOCK)
-            k0 = 0 if self.window is None else max(0, q0 + 1 - self.window)
-            i = jnp.arange(q0, q1)[:, None]
-            j = jnp.arange(k0, q1)[None, :]
-            mask = j <= i
-            if self.window is not None:
-                mask = mask & (j > i - self.window)
-            outs.append(_attend(q[:, q0:q1], k[:, k0:q1], v[:, k0:q1],
-                                mask[None]))
-        return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
+        self.q_proj = Projection(c.hidden_size, width, c.dtype, std)
+        self.k_proj = Projection(c.hidden_size, self.kv_heads * c.head_dim,
+                                 c.dtype, std)
+        self.v_proj = Projection(c.hidden_size, self.kv_heads * c.head_dim,
+                                 c.dtype, std)
+        self.gate_proj = Projection(c.hidden_size, width, c.dtype, std)
+        self.o_proj = Projection(width, c.hidden_size, c.dtype, std)
+        self.q_norm = RMSNormF32(c.head_dim, c.rms_norm_eps, c.dtype)
+        self.k_norm = RMSNormF32(c.head_dim, c.rms_norm_eps, c.dtype)
 
     def forward(self, a, cache=None):
         """a [b, s, hidden], already normalised -> [b, s, hidden], and the
@@ -246,20 +176,17 @@ class AfmoeAttention(nn.Layer):
             q, k = self.q_norm(q), self.k_norm(k)
         if self.window is not None:
             with jax.named_scope("rope"):
-                q = _rope(q, pos, self.rope_theta)
-                k = _rope(k, pos, self.rope_theta)
+                q = rope(q, pos, self.rope_theta)
+                k = rope(k, pos, self.rope_theta)
         q = q.reshape(b, s, self.kv_heads, groups, self.head_dim)
+        seen = None
         if cache is not None:
             with jax.named_scope("cache_write"):
-                kc, vc, held, cache = cache.update(k, v)
+                *rows, cache = cache.update(k, v)
+            seen = None if alone else rows
         with jax.named_scope("core"):
-            if alone:
-                o = self._prefill_core(q, k, v)
-            else:
-                mask = held <= pos[:, :, None]
-                if self.window is not None:
-                    mask = mask & (held > pos[:, :, None] - self.window)
-                o = _attend(q, kc, vc, mask)
+            o = slot_attention.core(q, k, v, pos, seen, cache,
+                                    window=self.window)
         with jax.named_scope("gate"):
             o = o.reshape(b, s, self.num_heads * self.head_dim)
             o = o * jax.nn.sigmoid(
@@ -267,19 +194,6 @@ class AfmoeAttention(nn.Layer):
         with jax.named_scope("out"):
             out = self.o_proj(o)
         return out if cache is None else (out, cache)
-
-
-class AfmoeMLP(nn.Layer):
-    """SwiGLU: (silu(m Wg) * (m Wu)) Wd."""
-
-    def __init__(self, hidden, width, dtype, std):
-        super().__init__(dtype=dtype)
-        self.gate_proj = _Weight(hidden, width, dtype, std)
-        self.up_proj = _Weight(hidden, width, dtype, std)
-        self.down_proj = _Weight(width, hidden, dtype, std)
-
-    def forward(self, m):
-        return self.down_proj(jax.nn.silu(self.gate_proj(m)) * self.up_proj(m))
 
 
 class AfmoeMoE(nn.RoutedExperts):
@@ -295,7 +209,7 @@ class AfmoeMoE(nn.RoutedExperts):
                          route_scale=c.route_scale,
                          bias_std=c.expert_bias_std, dtype=c.dtype,
                          init_std=c.initializer_range)
-        self.shared_experts = (AfmoeMLP(
+        self.shared_experts = (SwiGLU(
             c.hidden_size, c.moe_intermediate_size * c.num_shared_experts,
             c.dtype, c.initializer_range) if c.num_shared_experts else None)
 
@@ -312,16 +226,19 @@ class AfmoeBlock(nn.Layer):
     def __init__(self, config: AfmoeConfig, index: int):
         super().__init__(dtype=config.dtype)
         c = config
-        self.input_layernorm = _Norm(c.hidden_size, c.rms_norm_eps, c.dtype)
+        self.input_layernorm = RMSNormF32(c.hidden_size, c.rms_norm_eps,
+                                          c.dtype)
         self.self_attn = AfmoeAttention(c, c.layer_types[index])
-        self.post_attention_layernorm = _Norm(c.hidden_size, c.rms_norm_eps,
-                                              c.dtype)
-        self.pre_mlp_layernorm = _Norm(c.hidden_size, c.rms_norm_eps, c.dtype)
+        self.post_attention_layernorm = RMSNormF32(c.hidden_size,
+                                                   c.rms_norm_eps, c.dtype)
+        self.pre_mlp_layernorm = RMSNormF32(c.hidden_size, c.rms_norm_eps,
+                                            c.dtype)
         self.dense = index < c.num_dense_layers
-        self.mlp = (AfmoeMLP(c.hidden_size, c.intermediate_size, c.dtype,
-                             c.initializer_range) if self.dense
+        self.mlp = (SwiGLU(c.hidden_size, c.intermediate_size, c.dtype,
+                           c.initializer_range) if self.dense
                     else AfmoeMoE(c))
-        self.post_mlp_layernorm = _Norm(c.hidden_size, c.rms_norm_eps, c.dtype)
+        self.post_mlp_layernorm = RMSNormF32(c.hidden_size, c.rms_norm_eps,
+                                             c.dtype)
 
     def forward(self, h, cache=None):
         """-> (h, new cache or None, (touched, max_load) or None). Each scope
@@ -349,12 +266,12 @@ class AfmoeModel(nn.Layer):
     def __init__(self, config: AfmoeConfig):
         super().__init__(dtype=config.dtype)
         self.config = config
-        self.embed_tokens = _Weight(config.vocab_size, config.hidden_size,
-                                    config.dtype, config.initializer_range)
+        self.embed_tokens = Projection(config.vocab_size, config.hidden_size,
+                                       config.dtype, config.initializer_range)
         self.layers = nn.LayerList([AfmoeBlock(config, i)
                                     for i in range(config.num_hidden_layers)])
-        self.norm = _Norm(config.hidden_size, config.rms_norm_eps,
-                          config.dtype)
+        self.norm = RMSNormF32(config.hidden_size, config.rms_norm_eps,
+                               config.dtype)
 
     def forward(self, input_ids, caches=None):
         ids = input_ids._data if isinstance(input_ids, Tensor) else input_ids
@@ -391,8 +308,8 @@ class AfmoeForCausalLM(nn.Layer):
         super().__init__(dtype=config.dtype)
         self.config = config
         self.model = AfmoeModel(config)
-        self.lm_head = _Weight(config.hidden_size, config.vocab_size,
-                               config.dtype, config.initializer_range)
+        self.lm_head = Projection(config.hidden_size, config.vocab_size,
+                                  config.dtype, config.initializer_range)
 
     def forward(self, input_ids):
         return self._head_logits(self.model(input_ids))

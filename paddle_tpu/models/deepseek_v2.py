@@ -57,8 +57,8 @@ import numpy as np
 from .. import nn
 from ..core.tensor import Tensor
 from ..nn.kv_cache import LatentLayerSpec
+from ..nn.layers.decoder_parts import Projection, RMSNormF32, SwiGLU
 from ..ops import latent_attention
-from .afmoe import AfmoeMLP, _Norm, _Weight
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -262,19 +262,21 @@ class DeepseekV2Attention(nn.Layer):
                                       c.rope_scaling)
         std, q_width = c.initializer_range, self.heads * (self.d_n + self.d_r)
         if c.q_lora_rank is None:
-            self.q_proj = _Weight(c.hidden_size, q_width, c.dtype, std)
+            self.q_proj = Projection(c.hidden_size, q_width, c.dtype, std)
         else:
-            self.q_a_proj = _Weight(c.hidden_size, c.q_lora_rank, c.dtype, std)
-            self.q_a_layernorm = _Norm(c.q_lora_rank, c.rms_norm_eps, c.dtype)
-            self.q_b_proj = _Weight(c.q_lora_rank, q_width, c.dtype, std)
+            self.q_a_proj = Projection(c.hidden_size, c.q_lora_rank, c.dtype,
+                                       std)
+            self.q_a_layernorm = RMSNormF32(c.q_lora_rank, c.rms_norm_eps,
+                                            c.dtype)
+            self.q_b_proj = Projection(c.q_lora_rank, q_width, c.dtype, std)
         self.low_rank_q = c.q_lora_rank is not None
-        self.kv_a_proj_with_mqa = _Weight(c.hidden_size, self.rank + self.d_r,
-                                          c.dtype, std)
-        self.kv_a_layernorm = _Norm(self.rank, c.rms_norm_eps, c.dtype)
-        self.kv_b_proj = _Weight(self.rank, self.heads * (self.d_n + self.d_v),
-                                 c.dtype, std)
-        self.o_proj = _Weight(self.heads * self.d_v, c.hidden_size, c.dtype,
-                              std)
+        self.kv_a_proj_with_mqa = Projection(
+            c.hidden_size, self.rank + self.d_r, c.dtype, std)
+        self.kv_a_layernorm = RMSNormF32(self.rank, c.rms_norm_eps, c.dtype)
+        self.kv_b_proj = Projection(
+            self.rank, self.heads * (self.d_n + self.d_v), c.dtype, std)
+        self.o_proj = Projection(self.heads * self.d_v, c.hidden_size, c.dtype,
+                                 std)
 
     def forward(self, a, cache=None):
         """a [b, s, hidden], already normalised -> [b, s, hidden], and the
@@ -346,7 +348,7 @@ class DeepseekV2MoE(nn.RoutedExperts):
                          dtype=c.dtype, init_std=c.initializer_range,
                          score_func=c.scoring_func, n_group=c.n_group,
                          topk_group=c.topk_group)
-        self.shared_experts = (AfmoeMLP(
+        self.shared_experts = (SwiGLU(
             c.hidden_size, c.moe_intermediate_size * c.n_shared_experts,
             c.dtype, c.initializer_range) if c.n_shared_experts else None)
 
@@ -364,13 +366,14 @@ class DeepseekV2Block(nn.Layer):
     def __init__(self, config: DeepseekV2Config, index: int):
         super().__init__(dtype=config.dtype)
         c = config
-        self.input_layernorm = _Norm(c.hidden_size, c.rms_norm_eps, c.dtype)
+        self.input_layernorm = RMSNormF32(c.hidden_size, c.rms_norm_eps,
+                                          c.dtype)
         self.self_attn = DeepseekV2Attention(c)
-        self.post_attention_layernorm = _Norm(c.hidden_size, c.rms_norm_eps,
-                                              c.dtype)
+        self.post_attention_layernorm = RMSNormF32(c.hidden_size,
+                                                   c.rms_norm_eps, c.dtype)
         self.dense = index < c.first_k_dense_replace
-        self.mlp = (AfmoeMLP(c.hidden_size, c.intermediate_size, c.dtype,
-                             c.initializer_range) if self.dense
+        self.mlp = (SwiGLU(c.hidden_size, c.intermediate_size, c.dtype,
+                           c.initializer_range) if self.dense
                     else DeepseekV2MoE(c))
 
     def forward(self, h, cache=None):
@@ -400,12 +403,12 @@ class DeepseekV2Model(nn.Layer):
     def __init__(self, config: DeepseekV2Config):
         super().__init__(dtype=config.dtype)
         self.config = config
-        self.embed_tokens = _Weight(config.vocab_size, config.hidden_size,
-                                    config.dtype, config.initializer_range)
+        self.embed_tokens = Projection(config.vocab_size, config.hidden_size,
+                                       config.dtype, config.initializer_range)
         self.layers = nn.LayerList([DeepseekV2Block(config, i)
                                     for i in range(config.num_hidden_layers)])
-        self.norm = _Norm(config.hidden_size, config.rms_norm_eps,
-                          config.dtype)
+        self.norm = RMSNormF32(config.hidden_size, config.rms_norm_eps,
+                               config.dtype)
 
     def forward(self, input_ids, caches=None):
         ids = input_ids._data if isinstance(input_ids, Tensor) else input_ids
@@ -442,8 +445,8 @@ class DeepseekV2ForCausalLM(nn.Layer):
         super().__init__(dtype=config.dtype)
         self.config = config
         self.model = DeepseekV2Model(config)
-        self.lm_head = _Weight(config.hidden_size, config.vocab_size,
-                               config.dtype, config.initializer_range)
+        self.lm_head = Projection(config.hidden_size, config.vocab_size,
+                                  config.dtype, config.initializer_range)
 
     def forward(self, input_ids):
         return self._head_logits(self.model(input_ids))

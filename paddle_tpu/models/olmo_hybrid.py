@@ -43,9 +43,9 @@ from ..core import random as random_mod
 from ..core.tensor import Tensor
 from ..nn.kv_cache import (KVLayerSpec, SlotState, StateLayerSpec,
                            conv_tail)
+from ..nn.layers.decoder_parts import Projection, RMSNormF32, SwiGLU
 from ..ops import slot_attention
 from ..ops.gated_delta import gated_delta_chunked, gated_delta_step
-from .afmoe import _QUERY_BLOCK, AfmoeMLP, _attend, _Norm, _Weight
 
 LINEAR, FULL = "linear_attention", "full_attention"
 _L2_EPS = 1e-6
@@ -203,20 +203,20 @@ class GatedDeltaNet(nn.Layer):
         std = c.initializer_range
         keys, values = self.heads * self.dk, self.heads * self.dv
         self.channels = 2 * keys + values
-        self.qkv_proj = _Weight(c.hidden_size, self.channels, c.dtype, std)
+        self.qkv_proj = Projection(c.hidden_size, self.channels, c.dtype, std)
         self.conv_weight = self.create_parameter(
             (self.width, self.channels),
             default_initializer=_Drawn(_conv_weight))
-        self.a_proj = _Weight(c.hidden_size, self.heads, c.dtype, std)
-        self.b_proj = _Weight(c.hidden_size, self.heads, c.dtype, std)
+        self.a_proj = Projection(c.hidden_size, self.heads, c.dtype, std)
+        self.b_proj = Projection(c.hidden_size, self.heads, c.dtype, std)
         self.A_log = self.create_parameter(
             (self.heads,), dtype="float32", default_initializer=_Drawn(_a_log))
         self.dt_bias = self.create_parameter(
             (self.heads,), dtype="float32",
             default_initializer=_Drawn(_dt_bias))
-        self.g_proj = _Weight(c.hidden_size, values, c.dtype, std)
-        self.o_norm = _Norm(self.dv, c.rms_norm_eps, c.dtype)
-        self.o_proj = _Weight(values, c.hidden_size, c.dtype, std)
+        self.g_proj = Projection(c.hidden_size, values, c.dtype, std)
+        self.o_norm = RMSNormF32(self.dv, c.rms_norm_eps, c.dtype)
+        self.o_proj = Projection(values, c.hidden_size, c.dtype, std)
 
     def state_spec(self):
         return _state_spec(self.heads, self.dk, self.dv, self.width)
@@ -292,24 +292,12 @@ class OlmoHybridAttention(nn.Layer):
         self.head_dim = c.head_dim
         std = c.initializer_range
         kv = self.kv_heads * c.head_dim
-        self.q_proj = _Weight(c.hidden_size, c.hidden_size, c.dtype, std)
-        self.k_proj = _Weight(c.hidden_size, kv, c.dtype, std)
-        self.v_proj = _Weight(c.hidden_size, kv, c.dtype, std)
-        self.o_proj = _Weight(c.hidden_size, c.hidden_size, c.dtype, std)
-        self.q_norm = _Norm(c.hidden_size, c.rms_norm_eps, c.dtype)
-        self.k_norm = _Norm(kv, c.rms_norm_eps, c.dtype)
-
-    @staticmethod
-    def _prefill_core(q, k, v):
-        """Causal attention of a whole chunk whose first token is position
-        0, a block of queries at a time against the keys it can see."""
-        s = q.shape[1]
-        outs = []
-        for q0 in range(0, s, _QUERY_BLOCK):
-            q1 = min(s, q0 + _QUERY_BLOCK)
-            mask = jnp.arange(q1)[None, :] <= jnp.arange(q0, q1)[:, None]
-            outs.append(_attend(q[:, q0:q1], k[:, :q1], v[:, :q1], mask[None]))
-        return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
+        self.q_proj = Projection(c.hidden_size, c.hidden_size, c.dtype, std)
+        self.k_proj = Projection(c.hidden_size, kv, c.dtype, std)
+        self.v_proj = Projection(c.hidden_size, kv, c.dtype, std)
+        self.o_proj = Projection(c.hidden_size, c.hidden_size, c.dtype, std)
+        self.q_norm = RMSNormF32(c.hidden_size, c.rms_norm_eps, c.dtype)
+        self.k_norm = RMSNormF32(kv, c.rms_norm_eps, c.dtype)
 
     def forward(self, x, cache=None):
         b, s = x.shape[0], x.shape[1]
@@ -322,19 +310,14 @@ class OlmoHybridAttention(nn.Layer):
         q = q.reshape(b, s, self.kv_heads, groups, self.head_dim)
         k = k.reshape(b, s, self.kv_heads, self.head_dim)
         v = v.reshape(b, s, self.kv_heads, self.head_dim)
+        pos, seen = None, None
         if cache is not None:
             pos = cache.positions(s)
             with jax.named_scope("cache_write"):
-                kc, vc, held, cache = cache.update(k, v)
+                *rows, cache = cache.update(k, v)
+            seen = None if alone else rows
         with jax.named_scope("core"):
-            if alone:
-                o = self._prefill_core(q, k, v)
-            else:
-                # a decode step over the slot cache reads each slot's rows
-                # to its offset only, where a kernel can
-                o = slot_attention.decode_core(q, cache)
-                if o is None:
-                    o = _attend(q, kc, vc, held <= pos[:, :, None])
+            o = slot_attention.core(q, k, v, pos, seen, cache)
         with jax.named_scope("out"):
             out = self.o_proj(o.reshape(b, s, -1))
         return out if cache is None else (out, cache)
@@ -346,12 +329,12 @@ class OlmoHybridBlock(nn.Layer):
         c = config
         self.linear = c.layer_types[index] == LINEAR
         self.mixer = GatedDeltaNet(c) if self.linear else OlmoHybridAttention(c)
-        self.post_attention_layernorm = _Norm(c.hidden_size, c.rms_norm_eps,
-                                              c.dtype)
-        self.mlp = AfmoeMLP(c.hidden_size, c.intermediate_size, c.dtype,
-                            c.initializer_range)
-        self.post_feedforward_layernorm = _Norm(c.hidden_size, c.rms_norm_eps,
-                                                c.dtype)
+        self.post_attention_layernorm = RMSNormF32(c.hidden_size,
+                                                   c.rms_norm_eps, c.dtype)
+        self.mlp = SwiGLU(c.hidden_size, c.intermediate_size, c.dtype,
+                          c.initializer_range)
+        self.post_feedforward_layernorm = RMSNormF32(c.hidden_size,
+                                                     c.rms_norm_eps, c.dtype)
 
     def forward(self, h, cache=None):
         """-> (h, new cache or None, the linear layer's largest |S| or
@@ -377,12 +360,12 @@ class OlmoHybridModel(nn.Layer):
     def __init__(self, config: OlmoHybridConfig):
         super().__init__(dtype=config.dtype)
         self.config = config
-        self.embed_tokens = _Weight(config.vocab_size, config.hidden_size,
-                                    config.dtype, config.initializer_range)
+        self.embed_tokens = Projection(config.vocab_size, config.hidden_size,
+                                       config.dtype, config.initializer_range)
         self.layers = nn.LayerList([OlmoHybridBlock(config, i)
                                     for i in range(config.num_hidden_layers)])
-        self.norm = _Norm(config.hidden_size, config.rms_norm_eps,
-                          config.dtype)
+        self.norm = RMSNormF32(config.hidden_size, config.rms_norm_eps,
+                               config.dtype)
 
     def forward(self, input_ids, caches=None):
         ids = input_ids._data if isinstance(input_ids, Tensor) else input_ids
@@ -413,8 +396,8 @@ class OlmoHybridForCausalLM(nn.Layer):
         super().__init__(dtype=config.dtype)
         self.config = config
         self.model = OlmoHybridModel(config)
-        self.lm_head = _Weight(config.hidden_size, config.vocab_size,
-                               config.dtype, config.initializer_range)
+        self.lm_head = Projection(config.hidden_size, config.vocab_size,
+                                  config.dtype, config.initializer_range)
 
     def forward(self, input_ids):
         return self._head_logits(self.model(input_ids))

@@ -19,7 +19,7 @@ key from SDAR's published modelling code and `generate.py`, listed under
   AT position i: nothing is shifted.
 
 **The mask is block-causal**: with blocks of `block_length` positions, query
-p sees key t iff `t // B <= p // B` (nn/kv_cache.py `block_causal_mask`), in
+p sees key t iff `t // B <= p // B` (nn/kv_cache.py `visible`, `block`), in
 every forward, over a prompt and over a block being denoised alike. The
 model declares how it generates, `generation = BlockDiffusion(block_length,
 mask_token_id)`, and `ServingEngine` builds its block-step decode program
@@ -32,11 +32,11 @@ logits under the block-causal mask; training is not written (its loss needs
 the noise schedule, which `config.json` does not give).
 
 Serving: `kv_cache_spec` declares a `full` cache a layer; the attention
-takes the handle it is given, calls `positions` and `update` once and masks
-what comes back by the block-causal test. A prefill over a fresh cache
-attends the chunk's own keys a block of queries at a time; a block step
-over the slot cache goes through ops/slot_attention.decode_core, whose
-kernel reads a slot's rows only to the end of its block.
+takes the handle it is given, calls `positions` and `update` once and hands
+what comes back to ops/slot_attention.core under the block-causal rule. A
+prefill over a fresh cache attends the chunk's own keys a block of queries
+at a time; a block step over the slot cache takes the kernel that reads a
+slot's rows only to the end of its block, where one is supported.
 """
 from __future__ import annotations
 
@@ -45,9 +45,9 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..core.tensor import Tensor
-from ..nn.kv_cache import BlockDiffusion, KVLayerSpec, block_causal_mask
+from ..nn.kv_cache import BlockDiffusion, KVLayerSpec
+from ..nn.layers.decoder_parts import Projection, RMSNormF32, rope
 from ..ops import slot_attention
-from .afmoe import _QUERY_BLOCK, _Norm, _Weight, _attend, _rope
 
 
 class SdarConfig:
@@ -91,9 +91,10 @@ class SdarConfig:
         if not 0 <= int(mask_token_id) < int(vocab_size):
             raise ValueError(f"mask_token_id {mask_token_id} lies outside "
                              f"the vocabulary of {vocab_size}")
-        if int(block_length) < 1 or _QUERY_BLOCK % int(block_length):
+        query_block = slot_attention.QUERY_BLOCK
+        if int(block_length) < 1 or query_block % int(block_length):
             raise ValueError(f"block_length {block_length} must divide the "
-                             f"prefill's query block of {_QUERY_BLOCK}")
+                             f"prefill's query block of {query_block}")
         self.vocab_size = int(vocab_size)
         self.hidden_size = int(hidden_size)
         self.intermediate_size = int(intermediate_size)
@@ -161,29 +162,14 @@ class SdarAttention(nn.Layer):
         self.head_dim, self.block = c.head_dim, c.block_length
         self.rope_theta = c.rope_theta
         width, std = c.num_attention_heads * c.head_dim, c.initializer_range
-        self.q_proj = _Weight(c.hidden_size, width, c.dtype, std)
-        self.k_proj = _Weight(c.hidden_size, self.kv_heads * c.head_dim,
-                              c.dtype, std)
-        self.v_proj = _Weight(c.hidden_size, self.kv_heads * c.head_dim,
-                              c.dtype, std)
-        self.o_proj = _Weight(width, c.hidden_size, c.dtype, std)
-        self.q_norm = _Norm(c.head_dim, c.rms_norm_eps, c.dtype)
-        self.k_norm = _Norm(c.head_dim, c.rms_norm_eps, c.dtype)
-
-    def _prefill_core(self, q, k, v):
-        """Block-causal attention of a whole chunk whose first token is
-        position 0, a block of queries (a multiple of the diffusion block)
-        at a time against the keys it can see, to the end of its last
-        diffusion block, so that no [s, s] score matrix of all heads is
-        alive."""
-        s, outs = q.shape[1], []
-        for q0 in range(0, s, _QUERY_BLOCK):
-            q1 = min(s, q0 + _QUERY_BLOCK)
-            k1 = min(s, -(-q1 // self.block) * self.block)
-            mask = block_causal_mask(jnp.arange(k1)[None, None, :],
-                                     jnp.arange(q0, q1)[None, :], self.block)
-            outs.append(_attend(q[:, q0:q1], k[:, :k1], v[:, :k1], mask))
-        return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
+        self.q_proj = Projection(c.hidden_size, width, c.dtype, std)
+        self.k_proj = Projection(c.hidden_size, self.kv_heads * c.head_dim,
+                                 c.dtype, std)
+        self.v_proj = Projection(c.hidden_size, self.kv_heads * c.head_dim,
+                                 c.dtype, std)
+        self.o_proj = Projection(width, c.hidden_size, c.dtype, std)
+        self.q_norm = RMSNormF32(c.head_dim, c.rms_norm_eps, c.dtype)
+        self.k_norm = RMSNormF32(c.head_dim, c.rms_norm_eps, c.dtype)
 
     def forward(self, a, cache=None):
         """a [b, s, hidden], already normalised -> [b, s, hidden], and the
@@ -202,23 +188,20 @@ class SdarAttention(nn.Layer):
         with jax.named_scope("qk_norm"):
             q, k = self.q_norm(q), self.k_norm(k)
         with jax.named_scope("rope"):
-            q = _rope(q, pos, self.rope_theta)
-            k = _rope(k, pos, self.rope_theta)
+            q = rope(q, pos, self.rope_theta)
+            k = rope(k, pos, self.rope_theta)
         q = q.reshape(b, s, self.kv_heads, groups, self.head_dim)
+        seen = None
         if cache is not None:
             with jax.named_scope("cache_write"):
-                kc, vc, held, cache = cache.update(k, v)
+                *rows, cache = cache.update(k, v)
+            seen = None if alone else rows
         with jax.named_scope("core"):
-            if alone:
-                o = self._prefill_core(q, k, v)
-            else:
-                # a block step's slots sit on a block's edge (the engine
-                # commits a block whole), so each query sees the rows before
-                # the offset `update` returned: the kernel's bound
-                o = slot_attention.decode_core(q, cache, block=self.block)
-                if o is None:
-                    o = _attend(q, kc, vc,
-                                block_causal_mask(held, pos, self.block))
+            # a block step's slots sit on a block's edge (the engine commits
+            # a block whole), so each query sees the rows before the offset
+            # `update` returned: the kernel's bound
+            o = slot_attention.core(q, k, v, pos, seen, cache,
+                                    block=self.block)
         with jax.named_scope("out"):
             out = self.o_proj(o.reshape(b, s, self.num_heads * self.head_dim))
         return out if cache is None else (out, cache)
@@ -248,10 +231,11 @@ class SdarBlock(nn.Layer):
     def __init__(self, config: SdarConfig):
         super().__init__(dtype=config.dtype)
         c = config
-        self.input_layernorm = _Norm(c.hidden_size, c.rms_norm_eps, c.dtype)
+        self.input_layernorm = RMSNormF32(c.hidden_size, c.rms_norm_eps,
+                                          c.dtype)
         self.self_attn = SdarAttention(c)
-        self.post_attention_layernorm = _Norm(c.hidden_size, c.rms_norm_eps,
-                                              c.dtype)
+        self.post_attention_layernorm = RMSNormF32(c.hidden_size,
+                                                   c.rms_norm_eps, c.dtype)
         self.mlp = SdarMoE(c)
 
     def forward(self, h, cache=None):
@@ -277,12 +261,12 @@ class SdarModel(nn.Layer):
     def __init__(self, config: SdarConfig):
         super().__init__(dtype=config.dtype)
         self.config = config
-        self.embed_tokens = _Weight(config.vocab_size, config.hidden_size,
-                                    config.dtype, config.initializer_range)
+        self.embed_tokens = Projection(config.vocab_size, config.hidden_size,
+                                       config.dtype, config.initializer_range)
         self.layers = nn.LayerList([SdarBlock(config)
                                     for _ in range(config.num_hidden_layers)])
-        self.norm = _Norm(config.hidden_size, config.rms_norm_eps,
-                          config.dtype)
+        self.norm = RMSNormF32(config.hidden_size, config.rms_norm_eps,
+                               config.dtype)
 
     def forward(self, input_ids, caches=None):
         ids = input_ids._data if isinstance(input_ids, Tensor) else input_ids
@@ -320,8 +304,8 @@ class SdarForCausalLM(nn.Layer):
         self.generation = BlockDiffusion(config.block_length,
                                          config.mask_token_id)
         self.model = SdarModel(config)
-        self.lm_head = _Weight(config.hidden_size, config.vocab_size,
-                               config.dtype, config.initializer_range)
+        self.lm_head = Projection(config.hidden_size, config.vocab_size,
+                                  config.dtype, config.initializer_range)
 
     def forward(self, input_ids):
         return self._head_logits(self.model(input_ids))

@@ -33,14 +33,15 @@ An attention layer is handed one handle and calls it once:
 nothing yet reports the first position that will land on it, which is after
 every query of the chunk, so ONE causal test `held <= position` hides unwritten
 rows, stale rows past a rewound offset and later rows alike; a model with a
-window adds `held > position - window`. `cache.fresh` (static) says that
-nothing was held before this chunk, so the chunk's own keys are all there is
-to see.
+window adds `held > position - window`. `visible(held, positions, window,
+block)` is that test, the one every core reads (ops/slot_attention.py).
+`cache.fresh` (static) says that nothing was held before this chunk, so the
+chunk's own keys are all there is to see.
 
 A model that generates by diffusion over blocks of `block` positions (every
 query of a block sees the whole block, in both directions, and every block
-before it) asks `block_causal_mask(held, positions, block)` for the test
-instead: `held < (position // block + 1) * block`. The same rule about rows
+before it) states `block`, and the test is `held < (position // block + 1) *
+block` instead. The same rule about rows
 holds, stated once here: a block's `block` queries are written together at
 rows `[off, off + block)` (`SlotKV.update` at `s = block`), so those rows are
 REWRITTEN by every forward over the block and only count as held once the
@@ -146,9 +147,9 @@ class BlockDiffusion(NamedTuple):
     """How a model generates, where it is not one token after another: by
     diffusion over blocks of `block_length` positions (a model's
     `generation`). A block starts as `mask_token_id` at every position that
-    is not given; a forward over it under `block_causal_mask` proposes a
-    token at every position and the most confident are kept, until a forward
-    over a block with no mask left commits it. The rest are a request's
+    is not given; a forward over it under the block-causal mask (`visible`
+    with `block`) proposes a token at every position and the most confident
+    are kept, until a forward over a block with no mask left commits it. The rest are a request's
     defaults: the forwards a block's positions are spread over, how the
     positions to keep are chosen (serving/diffusion.py `REMASKING`), and the
     confidence above which `low_confidence_dynamic` keeps a position at
@@ -218,13 +219,20 @@ def _write_rows(stored, slots, rows, new):
     return stored, logical_rows(stored, *new.shape[2:])
 
 
-def block_causal_mask(held, positions, block: int):
-    """[b, s, t] bool: the query at `positions` [b or 1, s] sees the row that
-    holds position `held` (broadcasts against [b, s, t]) iff the row's block
-    is the query's or an earlier one, `held // block <= position // block`,
-    written as one comparison against the end of the query's block."""
-    ends = (positions // block + 1) * block
-    return held < ends[:, :, None]
+def visible(held, positions, window=None, block=None):
+    """[..., s, t] bool: whether the query at `positions` [..., s] sees the
+    row that holds position `held` (broadcasts against [..., s, t]), by the
+    layer's rule, which is data: causal, `held <= position`; with `window`,
+    also `held > position - window`; with `block` (diffusion over blocks),
+    the row's block is the query's or an earlier one, `held // block <=
+    position // block`, written as one comparison against the end of the
+    query's block. Every core over a slot's rows masks with this."""
+    if block is not None:
+        return held < ((positions // block + 1) * block)[..., None]
+    mask = held <= positions[..., None]
+    if window is not None:
+        mask = mask & (held > positions[..., None] - window)
+    return mask
 
 
 def ring_row(pos, rows: int):

@@ -1,5 +1,5 @@
-"""Shared helpers for the TPU Pallas kernels (flash_attention, lm_loss,
-latent_decode)."""
+"""Shared helpers for the TPU Pallas kernels (flash_attention, lm_loss, the
+decode kernels, grouped_experts)."""
 from __future__ import annotations
 
 import contextlib
@@ -16,6 +16,14 @@ from jax.sharding import PartitionSpec as P
 I0 = np.int32(0)
 
 NEG_INF = -1e30  # finite (not -inf): keeps exp() and Mosaic happy
+
+
+def _target():
+    """How a decode or expert kernel call is lowered (slot_decode,
+    block_decode, latent_decode, grouped_experts): "mosaic" on a TPU, None
+    elsewhere (the plain form). A test sets "interpret", a tool that
+    compiles for a described chip "mosaic" (tools/decode_hlo_probe.py)."""
+    return "mosaic" if jax.default_backend() == "tpu" else None
 
 
 def interpret() -> bool:

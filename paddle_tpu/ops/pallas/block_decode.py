@@ -18,15 +18,12 @@ slot_decode.py, whose body serves one query a key head.
                                            1..rows
     ->       [slots, kv_heads, m, width]   the rows' dtype
 
-The skeleton is slot_decode.py's: the slot is the grid axis, `lengths` is
-scalar-prefetched, a slot's rows come in blocks of `block` positions through
-two VMEM buffers an array (`make_async_copy`, `[block x heads, width]`
-position-major, as stored), only up to `ceil(length / block)`: a loop of
-dynamic trip count, so a block past a slot's length is never fetched. The
-last block of a slot starts the copies of the NEXT slot's first block (the
-buffers' phase is carried in SMEM). Only a slot's last block is masked by
-`length`, and its value rows past the length are zeroed, so what those rows
-hold, NaN included, reaches nothing.
+The skeleton is slot_rows.py's: the grid over slots, a slot's rows in
+blocks of `block` positions through two VMEM buffers an array (`[block x
+heads, width]` position-major, as stored), only up to its length, the next
+slot's first block fetched behind its last. Only a slot's last block is
+masked by `length`, and its value rows past the length are zeroed, so what
+those rows hold, NaN included, reaches nothing.
 
 The products are not slot_decode's. Each key head has m query rows (32 for
 SDAR's 4 positions x 8 groups), so a head takes its own rows out of the
@@ -48,8 +45,8 @@ state in VMEM, took 0.263 ms a layer at its best block for the 0.225 here
 HBM traffic: the rows held, to the block, of both arrays; q and the result
 once.
 
-`supported()` says which inputs take it; everything else keeps the models'
-plain cores, which are also the reference in the tests.
+`supported()` says which inputs take it; everything else keeps the plain
+core (ops/slot_attention.py), which is also the reference in the tests.
 """
 from __future__ import annotations
 
@@ -57,15 +54,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...nn.kv_cache import SlotKV
 from . import _common
 from ._common import I0 as _I0, NEG_INF
-
-_I1 = np.int32(1)     # an index must be i32 (see _common.I0)
+from .slot_rows import stream_rows
 
 # Positions a pair of copies and a pair of products take; a slot over-reads
 # half a block. A stored position is 1 KB an array at SDAR's shape, and a
@@ -77,13 +72,6 @@ _I1 = np.int32(1)     # an index must be i32 (see _common.I0)
 BLOCK_ROWS = 512
 
 
-def _target():
-    """How a call here is lowered: "mosaic" on a TPU, None elsewhere (the
-    plain form). A test sets "interpret", a tool that compiles for a
-    described chip "mosaic" (tools/decode_hlo_probe.py)."""
-    return "mosaic" if jax.default_backend() == "tpu" else None
-
-
 def supported(q_shape, cache, block: int) -> bool:
     """q [b, s, kv_heads, groups, d] of a block step (s == `block`, every
     query of a slot sees the same rows; any number of query heads a key
@@ -93,7 +81,7 @@ def supported(q_shape, cache, block: int) -> bool:
     cost) and of the block in rows, in a single-device program (jax
     refuses a Mosaic kernel in a multi-device program outside a shard_map)
     on a backend that compiles kernels."""
-    if type(cache) is not SlotKV or _target() is None:
+    if type(cache) is not SlotKV or _common._target() is None:
         return False
     b, s = q_shape[:2]
     rows, heads, width = cache.k.shape[1:]
@@ -122,52 +110,18 @@ def _head_rows(buf, h, heads, block):
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, kbuf, vbuf, sem, phase, *,
             block, heads, kv_heads, scale):
-    b = pl.program_id(0)
-    slots = pl.num_programs(0)
-    length = len_ref[b]
-    # lax.div / bitwise and, not `//` and `%`: jnp's forms of them do not
-    # lower here under jax_enable_x64
-    whole = jax.lax.div(length, jnp.int32(block))   # blocks with no mask
-    tail = length - whole * block           # positions of the masked block
-    blocks = whole + (tail > 0).astype(jnp.int32)
-    n = block * heads                       # rows of a block, a head a row
+    m, width = q_ref.shape[2:]
 
-    def copies(slot, i, at):
-        at_rows = pl.ds(i * n, n)
-        return (pltpu.make_async_copy(k_ref.at[slot, at_rows], kbuf.at[at],
-                                      sem.at[_I0, at]),
-                pltpu.make_async_copy(v_ref.at[slot, at_rows], vbuf.at[at],
-                                      sem.at[_I1, at]))
+    def init():
+        return (jnp.full((kv_heads, m, 1), NEG_INF, jnp.float32),
+                jnp.zeros((kv_heads, m, 1), jnp.float32),
+                jnp.zeros((kv_heads, m, width), jnp.float32))
 
-    def start(slot, i, at):
-        for c in copies(slot, i, at):
-            c.start()
-
-    @pl.when(b == 0)
-    def _first():
-        phase[0] = _I0
-        start(b, _I0, _I0)
-
-    base = phase[0]
-
-    def step(i, state, masked):
-        """The products of block i for every key head at once (a batched
-        matmul a product: the heads' chains interleave); `state` is the
-        online softmax (max, sum, weighted values), carried as values."""
-        at = (base + i) & 1
-
-        # the copies that follow these: the slot's next block, or the next
-        # slot's first
-        @pl.when(i + 1 < blocks)
-        def _next_block():
-            start(b, i + 1, 1 - at)
-
-        @pl.when(jnp.logical_and(i + 1 == blocks, b + 1 < slots))
-        def _next_slot():
-            start(b + 1, _I0, 1 - at)
-
-        for c in copies(b, i, at):
-            c.wait()
+    def body(at, tail, masked, state):
+        """The products of the block in buffers `at` for every key head at
+        once (a batched matmul a product: the heads' chains interleave);
+        `state` is the online softmax (max, sum, weighted values), carried
+        as values."""
         # [kv_heads, block, width] each
         k, v = (jnp.stack([_head_rows(buf.at[at], h, heads, block)
                            for h in range(kv_heads)])
@@ -190,16 +144,9 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, kbuf, vbuf, sem, phase, *,
                     p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
                     preferred_element_type=jnp.float32))
 
-    m, width = q_ref.shape[2:]
-    state = (jnp.full((kv_heads, m, 1), NEG_INF, jnp.float32),
-             jnp.zeros((kv_heads, m, 1), jnp.float32),
-             jnp.zeros((kv_heads, m, width), jnp.float32))
-    state = jax.lax.fori_loop(
-        _I0, whole, lambda i, st: step(i, st, masked=False), state)
-    state = jax.lax.cond(tail > 0, lambda st: step(whole, st, masked=True),
-                         lambda st: st, state)
-    phase[0] = (base + blocks) & 1
-    _, l, acc = state
+    _, l, acc = stream_rows(len_ref, (k_ref, v_ref), (kbuf, vbuf), sem, phase,
+                            block=block, rows=block * heads, init=init,
+                            body=body)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
@@ -210,7 +157,7 @@ def block_decode(q, k, v, lengths, scale: float):
     `length` rows (clipped to 1..rows, as `SlotKV.update` clips its write),
     times the value rows of head h. The caller has asked `supported()`."""
     return _call(q, k, v, lengths, scale=float(scale), block=BLOCK_ROWS,
-                 interpret=_target() == "interpret")
+                 interpret=_common._target() == "interpret")
 
 
 # jitted, so that a program's layers trace and lower ONE kernel between them

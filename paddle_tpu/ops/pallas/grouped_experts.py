@@ -93,13 +93,6 @@ STEP_BYTES = 16 * 2 ** 20   # the three matrices' tiles a step, at most
 ROWS_AN_EXPERT = (8.0, 128.0)
 
 
-def _target():
-    """How a call here is lowered: "mosaic" on a TPU, None elsewhere (the
-    plain form). A test sets "interpret", a tool that compiles for a
-    described chip "mosaic" (tools/decode_hlo_probe.py)."""
-    return "mosaic" if jax.default_backend() == "tpu" else None
-
-
 def takes_kernel(rows, num_experts, hidden, width, dtype, w_dtype) -> bool:
     """`rows` sorted rows (tokens x top_k) on the experts of a layer that
     routes over `num_experts`: the kernel where the mean rows an expert lies
@@ -108,7 +101,7 @@ def takes_kernel(rows, num_experts, hidden, width, dtype, w_dtype) -> bool:
     Mosaic kernel in a multi-device program outside a shard_map) on a
     backend that compiles kernels."""
     lo, hi = ROWS_AN_EXPERT
-    return (_target() is not None and lo <= rows / num_experts <= hi
+    return (_common._target() is not None and lo <= rows / num_experts <= hi
             and hidden % 128 == 0 and width % 128 == 0
             and rows % ROW_TILE == 0 and dtype == w_dtype
             and _common.single_device_program())
@@ -330,7 +323,7 @@ def swiglu(xs, w_gate, w_up, w_down, sizes):
     return _call(xs, w_gate, w_up, w_down, sizes, tile=ROW_TILE,
                  chunk=CHUNK_ROWS,
                  tn=tile_width(hidden, width, w_gate.dtype.itemsize),
-                 interpret=_target() == "interpret")
+                 interpret=_common._target() == "interpret")
 
 
 def plain(xs, w_gate, w_up, w_down, sizes):

@@ -10,13 +10,11 @@ holds, each row read ONCE for both products.
     ->       [slots, heads, out]     the weighted sum of the rows' first
                                      `out` columns, the rows' dtype
 
-The slot is the grid axis; `lengths` is scalar-prefetched. A slot's rows come
-in blocks of `block` rows through two VMEM buffers (`make_async_copy`), only
-up to `ceil(length / block)`: a loop of dynamic trip count, so a block past a
-slot's length is never fetched and costs no grid step. The last block of a
-slot starts the copy of the NEXT slot's first block, so the pipeline stays
-full across the grid (the buffer's phase is carried in SMEM). While a block
-is in VMEM it serves both products: `s = q @ block^T` [heads, block] float32
+The skeleton is slot_rows.py's: the grid over slots, a slot's rows in
+blocks of `block` rows through two VMEM buffers, only up to its length, so a
+block past a slot's length is never fetched and costs no grid step, the next
+slot's first block fetched behind its last. While a block is in VMEM it
+serves both products: `s = q @ block^T` [heads, block] float32
 (heads on the sublanes: a matmul, the one row shared by all heads), online
 softmax in float32, `p` cast to the rows' dtype and `p @ block[:, :out]`
 accumulated in float32. No score leaves VMEM. Only a slot's last block is
@@ -41,19 +39,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import _common
 from ._common import I0 as _I0, NEG_INF
+from .slot_rows import stream_rows
 
 # Rows a copy and a pair of products take. On a v5e at the DeepSeek-V2 cell's
 # sizes (tools/latent_decode_bench.py; PERF.md, PR 36) a layer takes 0.86 ms
 # at 256, 0.66 at 512, 0.57 at 1,024 and 0.58 at 2,048: a larger block
 # fetches more rows past a length, a smaller one pays a loop turn more often.
 BLOCK_ROWS = 1024
-
-
-def _target():
-    """How a call here is lowered: "mosaic" on a TPU, None elsewhere (the
-    plain form). A test sets "interpret", a tool that compiles for a
-    described chip "mosaic" (tools/decode_hlo_probe.py)."""
-    return "mosaic" if jax.default_backend() == "tpu" else None
 
 
 def supported(q_shape, rows_shape, lengths) -> bool:
@@ -63,7 +55,7 @@ def supported(q_shape, rows_shape, lengths) -> bool:
     a Mosaic kernel in a multi-device program outside a shard_map) on a
     backend that compiles kernels."""
     b, s = q_shape[0], q_shape[1]
-    return (_target() is not None and s == 1
+    return (_common._target() is not None and s == 1
             and lengths is not None and tuple(lengths.shape) == (b,)
             and rows_shape[-1] % 128 == 0 and rows_shape[1] % BLOCK_ROWS == 0
             and _common.single_device_program())
@@ -71,44 +63,12 @@ def supported(q_shape, rows_shape, lengths) -> bool:
 
 def _kernel(len_ref, q_ref, rows_ref, o_ref, buf, sem, phase, m_scr, l_scr,
             acc_scr, *, block, out_w, scale):
-    b = pl.program_id(0)
-    slots = pl.num_programs(0)
-    length = len_ref[b]
-    # lax.div / bitwise and, not `//` and `%`: jnp's forms of them do not
-    # lower here under jax_enable_x64
-    whole = jax.lax.div(length, jnp.int32(block))   # blocks with no mask
-    tail = length - whole * block           # rows of the masked block, or 0
-    blocks = whole + (tail > 0).astype(jnp.int32)
+    def init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def copy(slot, i, at):
-        return pltpu.make_async_copy(
-            rows_ref.at[slot, pl.ds(i * block, block)], buf.at[at],
-            sem.at[at])
-
-    @pl.when(b == 0)
-    def _first():
-        phase[0] = _I0
-        copy(b, _I0, _I0).start()
-
-    base = phase[0]
-    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    def step(i, masked):
-        at = (base + i) & 1
-
-        # the copy that follows this one: the slot's next block, or the
-        # next slot's first
-        @pl.when(i + 1 < blocks)
-        def _next_block():
-            copy(b, i + 1, 1 - at).start()
-
-        @pl.when(jnp.logical_and(i + 1 == blocks, b + 1 < slots))
-        def _next_slot():
-            copy(b + 1, _I0, 1 - at).start()
-
-        copy(b, i, at).wait()
+    def body(at, tail, masked, state):
         rows = buf[at]                                        # [block, w]
         if masked:
             live = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0) < tail
@@ -129,17 +89,8 @@ def _kernel(len_ref, q_ref, rows_ref, o_ref, buf, sem, phase, m_scr, l_scr,
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
-    def whole_block(i, carry):
-        step(i, masked=False)
-        return carry
-
-    jax.lax.fori_loop(_I0, whole, whole_block, _I0)
-
-    @pl.when(tail > 0)
-    def _tail():
-        step(whole, masked=True)
-
-    phase[0] = (base + blocks) & 1
+    stream_rows(len_ref, (rows_ref,), (buf,), sem, phase, block=block,
+                rows=block, init=init, body=body)
     o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
@@ -150,7 +101,7 @@ def latent_decode(q, rows, lengths, scale: float, out: int):
     clips its write), times their first `out` columns (a multiple of 128).
     The caller has asked `supported()`."""
     return _call(q, rows, lengths, scale=float(scale), out=int(out),
-                 block=BLOCK_ROWS, interpret=_target() == "interpret")
+                 block=BLOCK_ROWS, interpret=_common._target() == "interpret")
 
 
 # jitted, so that a program's layers trace and lower ONE kernel between them

@@ -13,16 +13,13 @@ and value rows the slot holds, fetched to the slot's offset only.
     lengths  [slots] int32                 positions a slot holds, 1..rows
     ->       [slots, heads, width]         the rows' dtype
 
-The skeleton is latent_decode.py's: the slot is the grid axis, `lengths` is
-scalar-prefetched, a slot's rows come in blocks of `block` positions through
-two VMEM buffers an array (`make_async_copy`), only up to `ceil(length /
-block)`: a loop of dynamic trip count, so a block past a slot's length is
-never fetched. The last block of a slot starts the copies of the NEXT slot's
-first block (the buffers' phase is carried in SMEM). Online softmax in
-float32, `p` cast to the rows' dtype before the second product (the plain
-cores' numerics). Only a slot's last block is masked by `length`, and its
-value rows past the length are zeroed, so what those rows hold, NaN
-included, reaches nothing.
+The skeleton is slot_rows.py's: the grid over slots, a slot's rows in
+blocks of `block` positions through two VMEM buffers an array, only up to
+its length, the next slot's first block fetched behind its last. Online
+softmax in float32 in VMEM scratch, `p` cast to the rows' dtype before the
+second product (the plain core's numerics). Only a slot's last block is
+masked by `length`, and its value rows past the length are zeroed, so what
+those rows hold, NaN included, reaches nothing.
 
 The products are not latent_decode's. There one row served every head; here
 each key head has its own row and ONE query: matrix-vector work, bound by
@@ -44,8 +41,8 @@ read 0.85 and 0.080 at its best blocks and was not kept.
 HBM traffic: the rows held, to the block, of both arrays; q and the result
 once.
 
-`supported()` says which inputs take it; everything else keeps the models'
-plain cores, which are also the reference in the tests.
+`supported()` says which inputs take it; everything else keeps the plain
+core (ops/slot_attention.py), which is also the reference in the tests.
 """
 from __future__ import annotations
 
@@ -53,15 +50,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...nn.kv_cache import SlotKV
 from . import _common
 from ._common import I0 as _I0, NEG_INF
-
-_I1 = np.int32(1)     # an index must be i32 (see _common.I0)
+from .slot_rows import stream_rows
 
 # Positions a pair of copies and a pair of products take; a slot over-reads
 # half a block. A stored position is 6 KB (GPT-2 large) or 8 KB (Olmo-Hybrid)
@@ -72,13 +67,6 @@ _I1 = np.int32(1)     # an index must be i32 (see _common.I0)
 BLOCK_ROWS = 64
 
 
-def _target():
-    """How a call here is lowered: "mosaic" on a TPU, None elsewhere (the
-    plain form). A test sets "interpret", a tool that compiles for a
-    described chip "mosaic" (tools/decode_hlo_probe.py)."""
-    return "mosaic" if jax.default_backend() == "tpu" else None
-
-
 def supported(q_shape, cache) -> bool:
     """q [b, s, kv_heads, groups, d] against the handle `update` returned:
     a `SlotKV` (one offset a slot; a ring's wrap is a second bound), one
@@ -87,7 +75,7 @@ def supported(q_shape, cache) -> bool:
     and of the block in rows, in a single-device program (jax refuses a
     Mosaic kernel in a multi-device program outside a shard_map) on a
     backend that compiles kernels."""
-    if type(cache) is not SlotKV or _target() is None:
+    if type(cache) is not SlotKV or _common._target() is None:
         return False
     b, s, _, groups, _ = q_shape
     rows, heads, width = cache.k.shape[1:]
@@ -99,52 +87,14 @@ def supported(q_shape, cache) -> bool:
 
 def _kernel(len_ref, q_ref, bias_ref, k_ref, v_ref, o_ref, kbuf, vbuf, sem,
             phase, m_scr, l_scr, acc_scr, *, block, heads, scale):
-    b = pl.program_id(0)
-    slots = pl.num_programs(0)
-    length = len_ref[b]
-    # lax.div / bitwise and, not `//` and `%`: jnp's forms of them do not
-    # lower here under jax_enable_x64
-    whole = jax.lax.div(length, jnp.int32(block))   # blocks with no mask
-    tail = length - whole * block           # positions of the masked block
-    blocks = whole + (tail > 0).astype(jnp.int32)
     n = block * heads                       # rows of a block, a head a row
 
-    def copies(slot, i, at):
-        at_rows = pl.ds(i * n, n)
-        return (pltpu.make_async_copy(k_ref.at[slot, at_rows], kbuf.at[at],
-                                      sem.at[_I0, at]),
-                pltpu.make_async_copy(v_ref.at[slot, at_rows], vbuf.at[at],
-                                      sem.at[_I1, at]))
+    def init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def start(slot, i, at):
-        for c in copies(slot, i, at):
-            c.start()
-
-    @pl.when(b == 0)
-    def _first():
-        phase[0] = _I0
-        start(b, _I0, _I0)
-
-    base = phase[0]
-    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    def step(i, masked):
-        at = (base + i) & 1
-
-        # the copies that follow these: the slot's next block, or the next
-        # slot's first
-        @pl.when(i + 1 < blocks)
-        def _next_block():
-            start(b, i + 1, 1 - at)
-
-        @pl.when(jnp.logical_and(i + 1 == blocks, b + 1 < slots))
-        def _next_slot():
-            start(b + 1, _I0, 1 - at)
-
-        for c in copies(b, i, at):
-            c.wait()
+    def body(at, tail, masked, state):
         k, v = kbuf[at], vbuf[at]                             # [n, width]
         s = jax.lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -164,17 +114,8 @@ def _kernel(len_ref, q_ref, bias_ref, k_ref, v_ref, o_ref, kbuf, vbuf, sem,
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
-    def whole_block(i, carry):
-        step(i, masked=False)
-        return carry
-
-    jax.lax.fori_loop(_I0, whole, whole_block, _I0)
-
-    @pl.when(tail > 0)
-    def _tail():
-        step(whole, masked=True)
-
-    phase[0] = (base + blocks) & 1
+    stream_rows(len_ref, (k_ref, v_ref), (kbuf, vbuf), sem, phase,
+                block=block, rows=n, init=init, body=body)
     o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
@@ -185,7 +126,7 @@ def slot_decode(q, k, v, lengths, scale: float):
     `SlotKV.update` clips its write), times the value rows. The caller has
     asked `supported()`."""
     return _call(q, k, v, lengths, scale=float(scale), block=BLOCK_ROWS,
-                 interpret=_target() == "interpret")
+                 interpret=_common._target() == "interpret")
 
 
 # jitted, so that a program's layers trace and lower ONE kernel between them

@@ -80,10 +80,10 @@ def test_prefill_attention_in_query_blocks(tiny, monkeypatch, block):
     """Above 512 tokens the prefill attends a block of queries at a time
     against the keys it can see; with the block cut to 5 or 8 (the window),
     24 tokens take several blocks with cut key ranges, and nothing moves."""
-    from paddle_tpu.models import afmoe
+    from paddle_tpu.ops import slot_attention
 
     model, state, rcfg = tiny
-    monkeypatch.setattr(afmoe, "_QUERY_BLOCK", block)
+    monkeypatch.setattr(slot_attention, "QUERY_BLOCK", block)
     ids = _ids(24, seed=4)
     got = model(paddle.to_tensor(ids[None]))._data[0]
     assert float(jnp.abs(got - ref.logits(state, jnp.asarray(ids), rcfg)
@@ -266,6 +266,35 @@ def test_cache_holds_window_rows_on_window_layers(tiny):
     per_row = 2 * 2 * 128 * 4                      # k and v, f32
     assert eng.kv_cache_bytes() == 3 * (4 * 8 + 48) * per_row
     assert eng.stats()["kv_cache_bytes"] == eng.kv_cache_bytes()
+
+
+def test_the_full_layer_asks_the_door_and_keeps_the_plain_core(
+        tiny, monkeypatch):
+    """A decode step's full layer goes through ops/slot_attention's door,
+    which refuses its two query heads a key head even where kernels compile
+    and the rows are whole tiles: `attn.calls.slot` counts the step,
+    `attn.calls.slot_kernel` does not. A sliding layer's ring never asks."""
+    from paddle_tpu.nn.kv_cache import RingKV, SlotKV
+    from paddle_tpu.observability import metrics
+    from paddle_tpu.ops.pallas import _common
+
+    model, _, _ = tiny
+    monkeypatch.setattr(_common, "_target", lambda: "interpret")
+
+    def count():
+        return [metrics.default_registry().counter("attn.calls." + f).value
+                for f in ("slot", "slot_kernel")]
+
+    a = jax.random.normal(jax.random.key(0), (3, 1, 64))
+    offset = jnp.asarray([2, 5, 9], jnp.int32)
+    layers = model.model.layers
+    before = count()
+    layers[4].self_attn(a, cache=SlotKV(jnp.zeros((3, 64, 8, 128)),
+                                        jnp.zeros((3, 64, 8, 128)), offset))
+    assert count() == [before[0] + 1, before[1]]
+    layers[0].self_attn(a, cache=RingKV(jnp.zeros((3, 8, 8, 128)),
+                                        jnp.zeros((3, 8, 8, 128)), offset))
+    assert count() == [before[0] + 1, before[1]]
 
 
 def test_what_window_layers_cannot_do_is_refused_by_name(tiny):

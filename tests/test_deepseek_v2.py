@@ -31,7 +31,7 @@ from paddle_tpu.nn.kv_cache import (ChunkLatent, LatentLayerSpec, SlotLatent,
                                     latent_width)
 from paddle_tpu.observability import device_trace, metrics
 from paddle_tpu.ops import latent_attention
-from paddle_tpu.ops.pallas import latent_decode
+from paddle_tpu.ops.pallas import _common, latent_decode
 from paddle_tpu.serving import ServingEngine, kv_state
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -191,7 +191,7 @@ def test_calls_are_counted_by_form(tiny, monkeypatch):
     model.model.layers[0].self_attn(jnp.zeros((2, 1, 64)), cache=slots)
     assert (count("absorbed"), count("absorbed_kernel")) == (before[1] + 2,
                                                              before[2])
-    monkeypatch.setattr(latent_decode, "_target", lambda: "interpret")
+    monkeypatch.setattr(_common, "_target", lambda: "interpret")
     monkeypatch.setattr(latent_decode, "BLOCK_ROWS", 16)
     model.model.layers[0].self_attn(jnp.zeros((2, 1, 64)), cache=slots)
     assert (count("absorbed"), count("absorbed_kernel")) == (before[1] + 3,
@@ -505,7 +505,7 @@ def test_the_kernel_forced_gives_the_plain_forms_tokens(tiny, monkeypatch):
 
     reg = metrics.default_registry()
     plain_eng, plain = serve()
-    monkeypatch.setattr(latent_decode, "_target", lambda: "interpret")
+    monkeypatch.setattr(_common, "_target", lambda: "interpret")
     monkeypatch.setattr(latent_decode, "BLOCK_ROWS", 16)
     before = [reg.counter("mla.calls." + f).value
               for f in ("absorbed", "absorbed_kernel")]

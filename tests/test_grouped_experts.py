@@ -25,7 +25,7 @@ CHUNK = 32          # rows a product here; the chip's is ge.CHUNK_ROWS
 @pytest.fixture
 def kernel(monkeypatch):
     """The kernel in interpret mode, in chunks of CHUNK rows."""
-    monkeypatch.setattr(ge, "_target", lambda: "interpret")
+    monkeypatch.setattr(_common, "_target", lambda: "interpret")
     monkeypatch.setattr(ge, "CHUNK_ROWS", CHUNK)
 
 
@@ -120,12 +120,12 @@ SHAPES = {
 @pytest.mark.parametrize("name", sorted(SHAPES))
 def test_the_path_by_rows_an_expert(monkeypatch, name):
     shape, takes = SHAPES[name]
-    monkeypatch.setattr(ge, "_target", lambda: "mosaic")
+    monkeypatch.setattr(_common, "_target", lambda: "mosaic")
     assert ge.takes_kernel(*shape, jnp.bfloat16, jnp.bfloat16) is takes
 
 
 def test_the_band_and_what_else_refuses(monkeypatch):
-    monkeypatch.setattr(ge, "_target", lambda: "mosaic")
+    monkeypatch.setattr(_common, "_target", lambda: "mosaic")
     lo, hi = ge.ROWS_AN_EXPERT
     ok = dict(hidden=2048, width=768, dtype=jnp.bfloat16,
               w_dtype=jnp.bfloat16)
@@ -142,7 +142,7 @@ def test_the_band_and_what_else_refuses(monkeypatch):
 
 
 def test_the_cpu_keeps_ragged_dot_unasked():
-    assert ge._target() is None
+    assert _common._target() is None
     assert not ge.takes_kernel(2048, 128, 2048, 768, jnp.bfloat16,
                                jnp.bfloat16)
 
@@ -164,13 +164,13 @@ def test_counters_name_the_form_traced(kernel, monkeypatch, forced, share):
     layer = _layer(*share)
     m = jax.random.normal(jax.random.key(1), (64, HIDDEN), jnp.float32)
     if not forced:
-        monkeypatch.setattr(ge, "_target", lambda: None)
+        monkeypatch.setattr(_common, "_target", lambda: None)
     before = _count("ragged_dot"), _count("grouped_kernel")
     out, load = layer.routed_load(m)
     after = _count("ragged_dot"), _count("grouped_kernel")
     assert (after[0] - before[0], after[1] - before[1]) == (
         (0, 1) if forced else (1, 0))
-    monkeypatch.setattr(ge, "_target", lambda: None)
+    monkeypatch.setattr(_common, "_target", lambda: None)
     want, _ = layer.routed_load(m)
     assert _gap(out, want) <= 1e-5
     assert int(load.sum()) == 128
@@ -198,7 +198,7 @@ def test_the_kernel_forced_gives_the_plain_engines_blocks(monkeypatch):
         return [r.tokens for r in reqs]
 
     plain = serve()
-    monkeypatch.setattr(ge, "_target", lambda: "interpret")
+    monkeypatch.setattr(_common, "_target", lambda: "interpret")
     monkeypatch.setattr(ge, "CHUNK_ROWS", CHUNK)
     monkeypatch.setattr(ge, "ROWS_AN_EXPERT", (0.0, 1e9))
     before = _count("grouped_kernel")

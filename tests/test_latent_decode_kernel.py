@@ -24,7 +24,7 @@ HEADS, RANK, ROPE = 4, 96, 24
 @pytest.fixture
 def kernel(monkeypatch):
     """The kernel in interpret mode, in blocks of T rows."""
-    monkeypatch.setattr(latent_decode, "_target", lambda: "interpret")
+    monkeypatch.setattr(_common, "_target", lambda: "interpret")
     monkeypatch.setattr(latent_decode, "BLOCK_ROWS", T)
 
 
@@ -123,7 +123,7 @@ def test_supported_refuses(kernel, monkeypatch, name):
     assert latent_decode.supported((3, 1, HEADS, 640), (3, ROWS, 640),
                                    case["lengths"])
     case.update(REFUSED[name])
-    monkeypatch.setattr(latent_decode, "_target", lambda: case["target"])
+    monkeypatch.setattr(_common, "_target", lambda: case["target"])
     assert not latent_decode.supported(
         (3, case["s"], HEADS, case["width"]),
         (3, case["rows"], case["width"]), case["lengths"])
@@ -142,7 +142,7 @@ def test_supported_refuses_a_program_over_a_mesh(kernel):
 
 
 def test_the_cpu_takes_the_plain_form_unasked():
-    assert latent_decode._target() is None
+    assert _common._target() is None
     q_l, q_r, rows, mask, lengths = _inputs([3, T], 128, jnp.float32)
     before = _count("absorbed"), _count("absorbed_kernel")
     latent_attention.absorbed(q_l, q_r, rows, mask, 0.3, lengths=lengths)

@@ -26,6 +26,7 @@ from paddle_tpu.models import olmo_hybrid as hybrid
 from paddle_tpu.nn.kv_cache import (KVLayerSpec, SlotState, StateLayerSpec,
                                     conv_tail, stored_dims)
 from paddle_tpu.observability import device_trace, metrics
+from paddle_tpu.ops import slot_attention
 from paddle_tpu.ops.gated_delta import gated_delta_chunked, gated_delta_step
 from paddle_tpu.serving import ServingEngine, kv_state
 
@@ -166,7 +167,7 @@ def test_logits_match_reference(tiny, reference, length):
 def test_attention_in_query_blocks_and_delta_rule_in_small_chunks(
         tiny, reference, monkeypatch):
     model, _, _ = tiny
-    monkeypatch.setattr(hybrid, "_QUERY_BLOCK", 5)
+    monkeypatch.setattr(slot_attention, "QUERY_BLOCK", 5)
     monkeypatch.setattr(hybrid, "gated_delta_chunked",
                         functools.partial(gated_delta_chunked, chunk=4))
     ids = _ids(24, seed=4)

@@ -19,9 +19,9 @@ import reference_sdar as ref
 from paddle_tpu.core import monitor
 from paddle_tpu.models import (GPTForPretraining, SdarConfig,
                                SdarForCausalLM, gpt_tiny, sdar_tiny)
-from paddle_tpu.nn.kv_cache import BlockDiffusion, block_causal_mask
+from paddle_tpu.nn.kv_cache import BlockDiffusion, visible
 from paddle_tpu.observability import metrics
-from paddle_tpu.ops.pallas import block_decode
+from paddle_tpu.ops.pallas import _common, block_decode
 from paddle_tpu.serving import (ServingEngine, diffusion, sample_tokens,
                                 sample_tokens_with_prob)
 
@@ -101,17 +101,18 @@ def test_block_causal_mask_is_the_written_out_matrix():
                      [1, 1, 1, 1, 1, 1, 1, 1, 1, 1],
                      [1, 1, 1, 1, 1, 1, 1, 1, 1, 1]], bool)
     held = jnp.arange(10)[None, None, :]
-    got = block_causal_mask(held, jnp.arange(10)[None, :], 4)
+    got = visible(held, jnp.arange(10)[None, :], block=4)
     assert (np.asarray(got[0]) == want).all()
     assert (np.asarray(ref.block_causal_mask(10, 4)) == want).all()
     # a slot at its own offset: the block's four queries see the rows before
     # it and its own, nothing past it; with blocks of 1 it is the causal test
-    got = block_causal_mask(held, jnp.asarray([[4, 5, 6, 7], [0, 1, 2, 3]]),
-                            4)
+    got = visible(held, jnp.asarray([[4, 5, 6, 7], [0, 1, 2, 3]]), block=4)
     assert (np.asarray(got[0]) == want[4:8]).all()
     assert (np.asarray(got[1]) == want[0:4]).all()
-    causal = block_causal_mask(held, jnp.arange(10)[None, :], 1)[0]
+    causal = visible(held, jnp.arange(10)[None, :], block=1)[0]
     assert (np.asarray(causal) == np.tril(np.ones((10, 10), bool))).all()
+    assert (np.asarray(visible(held, jnp.arange(10)[None, :])[0])
+            == np.asarray(causal)).all()
 
 
 # --------------------------------------------------------------- the forward
@@ -412,7 +413,7 @@ def test_the_block_kernel_forced_gives_the_plain_engines_blocks(
         return metrics.default_registry().counter("attn.calls." + form).value
 
     plain_eng, plain = serve()
-    monkeypatch.setattr(block_decode, "_target", lambda: "interpret")
+    monkeypatch.setattr(_common, "_target", lambda: "interpret")
     monkeypatch.setattr(block_decode, "BLOCK_ROWS", 16)
     before = count("block"), count("block_kernel")
     eng, forced = serve()

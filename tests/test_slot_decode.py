@@ -1,8 +1,8 @@
 """The Pallas kernels of a decode step's attention core over a `full` layer
 of the slot cache (ops/pallas/slot_decode.py) and of a block step's
 (ops/pallas/block_decode.py: a block of 4 queries a slot, grouped query
-heads) through their door (ops/slot_attention.py) against the models' plain
-cores on the same inputs, on the CPU in interpret mode at small shapes;
+heads) through their door (ops/slot_attention.py) against the one plain
+core on the same inputs, on the CPU in interpret mode at small shapes;
 which calls take them (`supported`); their counters; and a tiny GPT-2 and
 Olmo-Hybrid `ServingEngine` with the kernel forced beside the plain engine
 (SDAR's is in tests/test_sdar.py).
@@ -15,9 +15,8 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.models import (GPTConfig, GPTForPretraining,
                                OlmoHybridForCausalLM, olmo_hybrid_tiny)
-from paddle_tpu.models.afmoe import _attend
-from paddle_tpu.nn.kv_cache import (ChunkKV, RingKV, SlotKV, block_causal_mask,
-                                    logical_rows, stored_dims)
+from paddle_tpu.nn.kv_cache import (ChunkKV, RingKV, SlotKV, logical_rows,
+                                    stored_dims, visible)
 from paddle_tpu.observability import metrics
 from paddle_tpu.ops import slot_attention
 from paddle_tpu.ops.pallas import _common, block_decode, slot_decode
@@ -51,8 +50,8 @@ def _lengths(s, *extra):
 @pytest.fixture
 def kernel(monkeypatch):
     """The kernels in interpret mode, in blocks of T rows."""
+    monkeypatch.setattr(_common, "_target", lambda: "interpret")
     for module in (slot_decode, block_decode):
-        monkeypatch.setattr(module, "_target", lambda: "interpret")
         monkeypatch.setattr(module, "BLOCK_ROWS", T)
 
 
@@ -80,7 +79,7 @@ def _core(q, cache):
 
 
 def _plain(q, cache):
-    """The models' plain core over the rows `update` hands them: a decode
+    """The one plain core over the rows `update` hands a model: a decode
     step's causal mask, a block step's block-causal one (its queries are
     the block that ends at the offset)."""
     kv_heads, s, d = q.shape[2], q.shape[1], q.shape[4]
@@ -89,9 +88,9 @@ def _plain(q, cache):
         mask = held < cache.offset[:, None, None]
     else:
         pos = cache.offset[:, None] - s + jnp.arange(s)[None, :]
-        mask = block_causal_mask(held, pos, B)
-    return _attend(q, logical_rows(cache.k, kv_heads, d),
-                   logical_rows(cache.v, kv_heads, d), mask)
+        mask = visible(held, pos, block=B)
+    return slot_attention.attend(q, logical_rows(cache.k, kv_heads, d),
+                                 logical_rows(cache.v, kv_heads, d), mask)
 
 
 def _forms(s):
@@ -241,15 +240,14 @@ def test_supported_refuses(kernel, monkeypatch, name):
     if name.startswith("block step"):
         case.update(BLOCK_CASE)
     case.update(REFUSED[name])
-    for module in (slot_decode, block_decode):
-        monkeypatch.setattr(module, "_target", lambda: case["target"])
+    monkeypatch.setattr(_common, "_target", lambda: case["target"])
     q_shape = (3, case["s"], case["kv_heads"], case["groups"], 128)
     cache = case["cache"]()
     if case["block"] is None:
         assert not slot_decode.supported(q_shape, cache)
     else:
         assert not block_decode.supported(q_shape, cache, case["block"])
-    # and the door hands the call back to the model's plain core
+    # and the door hands the call back to the plain core
     before = _count("slot_kernel"), _count("block_kernel")
     assert slot_attention.decode_core(jnp.zeros(q_shape), cache,
                                       block=case["block"]) is None
@@ -279,7 +277,7 @@ def test_a_block_step_over_a_mesh_is_refused(kernel):
 
 
 def test_the_cpu_takes_the_plain_core_unasked():
-    assert slot_decode._target() is None
+    assert _common._target() is None
     q, cache = _inputs([3, T], 8, 128, jnp.float32)
     before = _count("slot"), _count("slot_kernel")
     assert slot_attention.decode_core(q, cache) is None
@@ -288,7 +286,7 @@ def test_the_cpu_takes_the_plain_core_unasked():
 
 
 def test_the_cpu_takes_a_block_steps_plain_core_unasked():
-    assert block_decode._target() is None
+    assert _common._target() is None
     q, cache = _inputs([B, T], 4, 128, jnp.float32, B, 8)
     before = _count("block"), _count("block_kernel")
     assert _core(q, cache) is None
@@ -369,7 +367,7 @@ def test_the_kernel_forced_gives_the_plain_engines_tokens(monkeypatch,
         return eng, [r.tokens for r in reqs]
 
     plain_eng, plain = serve()
-    monkeypatch.setattr(slot_decode, "_target", lambda: "interpret")
+    monkeypatch.setattr(_common, "_target", lambda: "interpret")
     monkeypatch.setattr(slot_decode, "BLOCK_ROWS", 16)
     before = _count("slot"), _count("slot_kernel")
     eng, forced = serve()

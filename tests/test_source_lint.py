@@ -297,6 +297,13 @@ _SEAM_CASES = {
             return jax.lax.dynamic_update_slice(state.rows, k, (0, off, 0, 0))
         """, [("model-cache", "buf.at["), ("model-cache", "<expr>.at["),
               ("model-cache", "rows in dynamic_update_slice(")]),
+    # a sibling model's private names, relative or absolute
+    "a-sibling-models-private-names": ("""
+        from .afmoe import _QUERY_BLOCK, AfmoeMoE, _attend
+        from paddle_tpu.models.gpt import _Block
+        """, [("model-model", "afmoe._QUERY_BLOCK"),
+              ("model-model", "afmoe._attend"),
+              ("model-model", "gpt._Block")]),
     # what a model may do
     "the-handle-and-other-packages": ("""
         import jax.numpy as jnp
@@ -305,6 +312,14 @@ _SEAM_CASES = {
         def step(toks, beam_idx, cache, k, v):
             toks = jnp.take_along_axis(toks, beam_idx[..., None], axis=1)
             return cache.update(k, v)
+        """, []),
+    # what models share, from where it lives, and a sibling's public names
+    "shared-parts-and-a-siblings-public-names": ("""
+        from ..nn.layers.decoder_parts import Projection, rope
+        from ..ops.slot_attention import QUERY_BLOCK, core
+        from .afmoe import AfmoeMoE
+        from . import gpt
+        from paddle_tpu.models.sdar import SdarConfig
         """, []),
 }
 

@@ -93,10 +93,8 @@ def serving(config, slots, dump=None, rung=0):
     # the program is compiled for the chip, so it holds the chip's kernels:
     # a trace here sees the CPU backend and would take the plain forms
     from paddle_tpu.observability import metrics
-    from paddle_tpu.ops.pallas import (block_decode, grouped_experts,
-                                       latent_decode, slot_decode)
-    latent_decode._target = slot_decode._target = lambda: "mosaic"
-    grouped_experts._target = block_decode._target = lambda: "mosaic"
+    from paddle_tpu.ops.pallas import _common
+    _common._target = lambda: "mosaic"
     moe_forms = ("ragged_dot", "grouped_kernel")
     attn_forms = ("slot", "slot_kernel", "block", "block_kernel")
 

@@ -12,14 +12,14 @@ cell's traffic fills it (a prompt of its distribution plus a uniform share
 of the tokens it asks for; `latent_decode_bench.contexts`):
 
 - `olmo`: `serve-olmo-hybrid-decode`, `[16, 4096, 32, 128]` (30 heads of 128
-  in 32), `decode-backlog-long`; the plain core is `afmoe._attend`;
+  in 32), `decode-backlog-long`; the plain core is `slot_attention.attend`;
 - `decode`, `chat`: `serve-gpt2-large-decode` / `-chat`, `[16, 1024, 24,
   128]` (20 heads of 64 in 24 x 128), `decode-backlog` / `chat-poisson`; the
   plain core is `F.scaled_dot_product_attention` under the mask;
 - `sdar`: `serve-sdar-30b-a3b-diffusion`'s block step, `[64, 3072, 4, 128]`,
   a block of 4 queries a slot and 8 query heads a key head (32 query rows a
   key head), `diffusion-backlog`, each length rounded up to the end of its
-  block; the plain core is `afmoe._attend` under the mask (the
+  block; the plain core is `slot_attention.attend` under the mask (the
   block-causal mask, at offsets on a block's edge). Its blocks default to
   64 to 512.
 
@@ -73,7 +73,6 @@ def main(argv) -> int:
 
     import paddle_tpu  # noqa: F401  (jax_enable_x64, as the program runs)
     from paddle_tpu.core.tensor import Tensor
-    from paddle_tpu.models.afmoe import _attend
     from paddle_tpu.nn import functional as F
     from paddle_tpu.nn.kv_cache import SlotKV, logical_rows, stored_dims
     from paddle_tpu.observability import metrics
@@ -150,7 +149,7 @@ def main(argv) -> int:
         def plain(q, k, v, offset):
             kc, vc = (logical_rows(a, kv_heads, d) for a in (k, v))
             if cell in ("olmo", "sdar"):
-                return _attend(q, kc, vc, mask)
+                return slot_attention.attend(q, kc, vc, mask)
             return F.scaled_dot_product_attention(
                 Tensor(q[:, :, :, 0]), Tensor(kc), Tensor(vc),
                 attn_mask=Tensor(mask[:, None]), dropout_p=0.0,
